@@ -90,7 +90,4 @@ def run(verbose: bool = True, seed: int = 42):
 
 
 if __name__ == "__main__":
-    from transmogrifai_tpu.utils.jax_setup import (
-        pin_platform_from_env)
-    pin_platform_from_env()
     run()

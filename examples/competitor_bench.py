@@ -1,6 +1,6 @@
 """Single-core C-library competitor baseline for the tree benchmarks.
 
-The north-star (BASELINE.md) compares against the reference's 32-core
+The north-star (BASELINE.json) compares against the reference's 32-core
 Spark + native XGBoost stack, but this build host exposes ONE physical
 core (``nproc`` = 1), so a real multi-core run is impossible here.
 This harness produces the honest substitute: scikit-learn's

@@ -1,4 +1,4 @@
-"""Bin-edge leakage audit at scale (VERDICT r4 #6 / BASELINE.md).
+"""Bin-edge leakage audit at scale.
 
 The batched tree fold x grid kernels default to quantile bin edges from
 the WHOLE prepared matrix (standard histogram-GBM CV practice); the
@@ -29,9 +29,7 @@ def main() -> None:
     ap.add_argument("--folds", type=int, default=3)
     args = ap.parse_args()
 
-    from transmogrifai_tpu.utils.jax_setup import (enable_compilation_cache,
-                                                   pin_platform_from_env)
-    pin_platform_from_env()
+    from transmogrifai_tpu.utils.jax_setup import enable_compilation_cache
     enable_compilation_cache()
     import numpy as np
 

@@ -1,7 +1,7 @@
 """Flagship search on MULTI-CORE XLA-CPU — the honest host baseline.
 
-The north-star target (BASELINE.md) is ">= 20x wall-clock vs 32-core
-CPU Spark"; every historical row in BASELINE.md is single-core because
+The north-star target (BASELINE.json) is ">= 20x wall-clock vs 32-core
+CPU Spark"; every historical CPU figure is single-core because
 the build container exposes exactly one core (``nproc`` = 1), which
 flatters per-chip ratios. This harness produces the missing multi-core
 number on any machine that has the cores:

@@ -1,4 +1,4 @@
-"""Synthetic-scale throughput measurement (BASELINE.md config 4).
+"""Synthetic-scale throughput measurement (BASELINE.json config 4).
 
 Generates an n-row tabular matrix (numeric + one-hot-ish binary blocks,
 the shape a transmogrified wide dataset takes), then times the two
@@ -41,15 +41,11 @@ def main() -> None:
                          "CPU); each pass re-uploads X so warm passes "
                          "time warm PROGRAMS, not cached designs")
     ap.add_argument("--cpu", action="store_true",
-                    help="pin the CPU backend (the env may register a "
-                         "remote TPU platform that wins over "
-                         "JAX_PLATFORMS)")
+                    help="pin the CPU backend")
     args = ap.parse_args()
 
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
-    from transmogrifai_tpu.utils.jax_setup import pin_platform_from_env
-    pin_platform_from_env()
     from transmogrifai_tpu.utils.jax_setup import enable_compilation_cache
     enable_compilation_cache()
     from transmogrifai_tpu.models.trees import (GBTClassifier,
@@ -59,8 +55,9 @@ def main() -> None:
 
     X, y = make_data(args.rows, args.cols)
 
-    # rough matmul-mode histogram FLOPs model for an MFU estimate: the
-    # per-level einsum contraction costs ~2*n*C_l*S*TB FLOPs with
+    # rough matmul-mode histogram FLOPs model (no utilization figure is
+    # derived from it here: the peaks table keyed by device_kind belongs
+    # to ROADMAP S1's benchmark): the per-level einsum contraction costs ~2*n*C_l*S*TB FLOPs with
     # C_l = min(2^l, 256) active slots (models/trees._level_histograms)
     from transmogrifai_tpu.models.trees import (_DEFAULT_NODE_CAP,
                                                 _design_args)
@@ -72,18 +69,11 @@ def main() -> None:
             for l in range(depth))
         return units * per_tree
 
-    #: assumed peak for the MFU denominator; override TX_PEAK_TFLOPS
-    #: (TPU default = v5e bf16 peak; CPU a nominal 100 GFLOPs)
-    peak_tflops = float(os.environ.get(
-        "TX_PEAK_TFLOPS",
-        "197" if jax.default_backend() == "tpu" else "0.1"))
-
-    # phase split (accelerators): a remote/tunneled device charges the
-    # raw host->device copy of X to whoever uploads it — measure it
-    # once, hand every fit the DEVICE-RESIDENT matrix, and report both
-    # end-to-end-from-host and device-resident throughput. On a local
-    # TPU host the transfer is DMA-fast and the two converge; on CPU
-    # the host matrix is kept so binning stays the exact f64 path.
+    # phase split (accelerators): the raw host->device copy of X is
+    # charged to whoever uploads it — measure it once, hand every fit
+    # the DEVICE-RESIDENT matrix, and report both end-to-end-from-host
+    # and device-resident throughput. On CPU the host matrix is kept so
+    # binning stays the exact f64 path.
     from transmogrifai_tpu.models.trees import clear_design_cache
     reps = args.reps or (1 if jax.default_backend() == "cpu" else 2)
     for rep in range(reps):
@@ -129,7 +119,6 @@ def main() -> None:
         _, widths = _design_args(X_in, est.max_bins)
         tb = int(np.sum(widths))
         gflop = hist_flops(args.rows, tb, depth, units, s_dim) / 1e9
-        mfu = gflop / 1e3 / max(fit_s, 1e-9) / peak_tflops * 100.0
         row = {
             "model": name, "pass": rep + 1,
             "rows": args.rows, "cols": args.cols,
@@ -147,7 +136,6 @@ def main() -> None:
             "score_rows_per_sec": round(50_000 / max(score_s, 1e-9)),
             "train_subset_acc": round(acc, 4),
             "hist_gflop_est": round(gflop, 1),
-            "mfu_pct_est": round(mfu, 3),
             "platform": jax.default_backend()}))
 
 

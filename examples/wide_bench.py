@@ -1,4 +1,4 @@
-"""Wide high-cardinality categorical throughput (BASELINE.md config 5).
+"""Wide high-cardinality categorical throughput (BASELINE.json config 5).
 
 Generates records with many high-cardinality categorical fields plus a
 numeric block, runs the REAL feature path — typed features,
@@ -53,8 +53,6 @@ def main() -> None:
     args = ap.parse_args()
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
-    from transmogrifai_tpu.utils.jax_setup import pin_platform_from_env
-    pin_platform_from_env()
     from transmogrifai_tpu.utils.jax_setup import enable_compilation_cache
     enable_compilation_cache()
 

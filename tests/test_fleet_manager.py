@@ -188,3 +188,46 @@ class TestCrashLoopBreaker:
         mgr._heal("r0", rc=1)
         assert mgr.states["r0"] != "failed"
         assert boots == ["r0", "r0"]
+
+
+class TestBootFailuresAreVisible:
+    """One process holds an accelerator, so of N replicas started with
+    one environment only one can get it (docs/fleet.md): the others
+    must fail the fleet's start at once — not be waited on, skipped or
+    healed in a loop."""
+
+    def test_child_that_exits_before_its_banner_fails_at_once(self):
+        import subprocess
+        import sys
+
+        from transmogrifai_tpu.serving.fleet import (ReplicaProcess,
+                                                     ReplicaSpec)
+        proc = subprocess.Popen(
+            [sys.executable, "-c",
+             "import sys; print('Unable to initialize backend'); "
+             "sys.exit(3)"],
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+        rp = ReplicaProcess(ReplicaSpec("r1", ("m=/x",), "/tmp"), proc, 1)
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError) as err:
+            rp.wait_port(timeout=120.0)
+        assert time.monotonic() - t0 < 30.0      # not the 120 s timeout
+        assert "exited 3" in str(err.value)
+        assert "Unable to initialize backend" in str(err.value)
+
+    def test_partial_boot_does_not_start_the_fleet(self, tmp_path,
+                                                   monkeypatch, capsys):
+        mgr = _manager(tmp_path, replicas=2)
+
+        def boot(name, resume):
+            if name == "r1":
+                raise RuntimeError("replica r1 exited 1 before its "
+                                   "serving banner")
+            mgr.states[name] = "ok"
+        monkeypatch.setattr(mgr, "_boot", boot)
+        with pytest.raises(RuntimeError, match="1 of 2 replicas failed "
+                                               "to boot: r1"):
+            mgr.start()
+        assert mgr.states == {"r0": "ok", "r1": "failed"}
+        assert mgr._watch is None                # nothing to heal
+        assert '"fleet": "boot_failed"' in capsys.readouterr().out

@@ -71,9 +71,10 @@ class FakeReplica:
         return self
 
     async def stop(self):
+        # close() only: since Python 3.12 Server.wait_closed() waits for
+        # every accepted connection, and the router's links stay open
         if self.server is not None:
             self.server.close()
-            await self.server.wait_closed()
             self.server = None
 
     async def _handle(self, reader, writer):
@@ -413,7 +414,6 @@ class TestForwarding:
             finally:
                 await link.close()
                 server.close()
-                await server.wait_closed()
         asyncio.run(drive())
 
     def test_all_replicas_dead_is_answered_error(self):
@@ -635,7 +635,6 @@ class TestFrontEnd:
                 assert router._client_writers == {}
             finally:
                 front.close()
-                await front.wait_closed()
                 for rep in reps:
                     await rep.stop()
         asyncio.run(drive())
@@ -656,7 +655,6 @@ class TestFrontEnd:
                 writer.close()
             finally:
                 front.close()
-                await front.wait_closed()
                 for rep in reps:
                     await rep.stop()
         asyncio.run(drive())

@@ -1553,7 +1553,7 @@ class TestJ08ShardClosure:
         findings = self._lint("""
             import jax
             from jax.sharding import PartitionSpec as P
-            from transmogrifai_tpu.utils.jax_setup import shard_map
+            from jax import shard_map
 
             def builder(mesh, X, y):
                 def body(w_loc):
@@ -1571,7 +1571,7 @@ class TestJ08ShardClosure:
         findings = self._lint("""
             import jax
             from jax.sharding import PartitionSpec as P
-            from transmogrifai_tpu.utils.jax_setup import shard_map
+            from jax import shard_map
 
             def builder(mesh, masks):
                 return jax.jit(shard_map(
@@ -1584,7 +1584,7 @@ class TestJ08ShardClosure:
         findings = self._lint("""
             import jax
             from jax.sharding import PartitionSpec as P
-            from transmogrifai_tpu.utils.jax_setup import shard_map
+            from jax import shard_map
 
             def builder(cfg, spec, mesh):
                 data_ax = "data" if "data" in mesh.axis_names else None
@@ -1605,7 +1605,7 @@ class TestJ08ShardClosure:
         findings = self._lint("""
             import jax
             from jax.sharding import PartitionSpec as P
-            from transmogrifai_tpu.utils.jax_setup import shard_map
+            from jax import shard_map
 
             MAX_ITER = 100
 
@@ -1622,7 +1622,7 @@ class TestJ08ShardClosure:
         findings = self._lint("""
             import jax
             from jax.sharding import PartitionSpec as P
-            from transmogrifai_tpu.utils.jax_setup import shard_map
+            from jax import shard_map
 
             def builder(mesh, X):
                 def body(w_loc):

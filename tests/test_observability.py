@@ -575,32 +575,35 @@ class TestProfileStore:
         # no torn temp file left behind
         assert not os.path.exists(path + ".tmp")
 
-    def test_probe_verdict_with_transcript(self, tmp_path):
+    def test_sections_and_profiles_merge_independently(self, tmp_path):
         path = str(tmp_path / "state.json")
         store = ProfileStore(path)
-        store.record_probe("jax-x", False, "tunnel hung",
-                           transcript=["probe 1/3: hung"])
-        # profiles and probe share one store, merged independently
+        store.record_section("aot_restart", {"compiles": 0})
+        # result sections and profiles share one store, merged
+        # independently
         store.record_profiles({"family:GBT": {"calls": 1,
                                               "wall_seconds": 2.0}})
-        v = store.probe_verdict("jax-x")
-        assert v["healthy"] is False
-        assert v["transcript"] == ["probe 1/3: hung"]
+        store.record_section("fleet", {"replicas": 2})
+        state = store.load()
+        assert state["aot_restart"]["compiles"] == 0
+        assert state["fleet"]["replicas"] == 2
         assert "family:GBT" in store.profiles()
 
-    def test_bench_probe_writer_uses_the_store(self, tmp_path,
-                                               monkeypatch):
+    def test_bench_fails_instead_of_falling_back(self, monkeypatch,
+                                                 capsys):
+        """A measurement that raises ends the bench non-zero with no
+        result line — no CPU fallback, no ``"value": 0.0`` exit 0."""
         import bench
-        path = str(tmp_path / "state.json")
-        monkeypatch.setattr(bench, "_STATE_PATH", path)
-        monkeypatch.setattr(bench, "_probe_cache_path",
-                            lambda: str(tmp_path / "probe.json"))
-        bench._store_probe_verdict(False, "dead tunnel",
-                                   transcript=["probe 1/1: dead"])
-        v = ProfileStore(path).probe_verdict(bench._probe_key())
-        assert v["healthy"] is False
-        assert v["transcript"] == ["probe 1/1: dead"]
-        assert bench._load_probe_verdict() == (False, "dead tunnel")
+
+        def boom():
+            raise RuntimeError("Unable to initialize backend")
+        monkeypatch.setattr(bench, "_measure", boom)
+        with pytest.raises(RuntimeError, match="initialize backend"):
+            bench.main()
+        assert capsys.readouterr().out == ""
+        for gone in ("_force_cpu", "_probe_ambient", "_inner",
+                     "_load_probe_verdict"):
+            assert not hasattr(bench, gone)
 
     def test_gather_normalizes_bucket_labels(self, trained, tmp_path,
                                              monkeypatch):

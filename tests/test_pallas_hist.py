@@ -1,8 +1,9 @@
 """Pallas fused level-histogram kernel (models/pallas_hist.py).
 
-On CPU the kernel runs in Pallas interpret mode; on TPU the same code
-compiles via Mosaic. Reference result is the matmul-strategy einsum
-(models/trees._level_histograms), which these tests reproduce in numpy.
+Here the kernel runs under the Pallas interpreter (``interpret=True``);
+on TPU the same code compiles via Mosaic (chip_smoke.py). Reference
+result is the matmul-strategy einsum (models/trees._level_histograms),
+which these tests reproduce in numpy.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -36,7 +37,8 @@ def test_matches_einsum(n, TB, C, S):
     stats = rng.normal(size=(n, S)).astype(np.float32)
     ref = _reference(bin_oh, slot, stats, C)
     got = np.asarray(pallas_level_hist(
-        jnp.asarray(bin_oh), jnp.asarray(slot), jnp.asarray(stats), C))
+        jnp.asarray(bin_oh), jnp.asarray(slot), jnp.asarray(stats), C,
+        interpret=True))
     np.testing.assert_allclose(got, ref, atol=1e-4, rtol=1e-5)
 
 
@@ -50,6 +52,7 @@ def test_zero_stats_rows_are_inert():
     stats = rng.normal(size=(n, S)).astype(np.float32)
     stats[50:] = 0.0
     got = np.asarray(pallas_level_hist(
-        jnp.asarray(bin_oh), jnp.asarray(slot), jnp.asarray(stats), C))
+        jnp.asarray(bin_oh), jnp.asarray(slot), jnp.asarray(stats), C,
+        interpret=True))
     ref = _reference(bin_oh[:50], slot[:50], stats[:50], C)
     np.testing.assert_allclose(got, ref, atol=1e-5)

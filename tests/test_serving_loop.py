@@ -125,6 +125,30 @@ class TestServerSmoke:
         finally:
             server.stop()
 
+    def test_unlabelled_record_scores_like_score_function(self, trained):
+        """A serving request carries no label. With guardrails on (the
+        CLI default) the response column is a placeholder, and the
+        answer equals ``ScoreFunction`` on the same record."""
+        from transmogrifai_tpu.local import ScoreFunction
+        model, recs, pred = trained
+        record = {k: v for k, v in recs[0].items() if k != "label"}
+        want = ScoreFunction(model)(dict(record))
+        labelled = ScoreFunction(model)(dict(recs[0]))
+        server, client = serve_in_process(
+            {"m": model}, ServeConfig(max_wait_ms=5.0))
+        try:
+            assert server.config.guardrails
+            row = client.score(dict(record))
+        finally:
+            server.stop()
+        assert "_guard" not in row
+        assert set(row) == set(want)
+        assert row[pred].keys() == want[pred].keys()
+        for k, v in want[pred].items():
+            assert row[pred][k] == pytest.approx(v, abs=1e-9)
+            # ... and the label plays no part in the score
+            assert labelled[pred][k] == pytest.approx(v, abs=1e-9)
+
     def test_deadline_or_full(self, trained):
         model, recs, _ = trained
         server, client = serve_in_process(

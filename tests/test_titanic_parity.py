@@ -9,9 +9,8 @@ import numpy as np
 import pytest
 
 pytestmark = pytest.mark.skipif(
-    not os.path.exists("/root/reference/test-data/PassengerDataAll.csv")
-    and not os.environ.get("TITANIC_CSV"),
-    reason="Titanic CSV not available")
+    not os.environ.get("TITANIC_CSV"),
+    reason="Titanic CSV not available (set TITANIC_CSV)")
 
 
 def test_titanic_rf_cv_range_parity():

@@ -81,6 +81,16 @@ def _poison(model_dir: str, why: str) -> None:
           f"live-compiling every bucket", file=sys.stderr)
 
 
+def _execution_devices():
+    """The one device an unsharded plan program is compiled for and
+    dispatched on. ``deserialize_and_load`` otherwise binds the
+    executable to EVERY device of the backend, and a one-device program
+    loaded over N devices fails at dispatch ("expected N shards") —
+    on any multi-chip host."""
+    import jax
+    return jax.devices()[:1]
+
+
 def _tree_defs(plan, bucket: int, n_outputs: int):
     """Recompute the serialized executable's calling-convention pytree
     defs from the plan itself — deterministic, so they are never
@@ -179,7 +189,8 @@ def load_scoring_artifacts(plan, model_dir: str
         try:
             in_tree, out_tree = _tree_defs(plan, bucket, n_outputs)
             execs[bucket] = _se.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=_execution_devices())
         except Exception as e:
             _poison(model_dir,
                     f"deserialize failure on {label}: "
@@ -305,7 +316,9 @@ def seed_prepare_registry(model_dir: str,
             in_tree = jtu.tree_structure(((avals, mask), {}))
             out_tree = jtu.tree_structure(
                 tuple(range(int(entry.get("nOutputs", 0)))))
-            ex = _se.deserialize_and_load(payload, in_tree, out_tree)
+            ex = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=_execution_devices())
         except Exception as e:
             record_aot_fallback("torn", model_dir, entry=label,
                                 error=f"{type(e).__name__}: {e}")

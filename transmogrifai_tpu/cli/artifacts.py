@@ -148,8 +148,6 @@ def _format_text(model_dir: str, manifest: dict, rows, bad: int,
 
 
 def run_artifacts(args) -> int:
-    from ..utils.jax_setup import pin_platform_from_env
-    pin_platform_from_env()
     try:
         from ..artifacts import store as _store
         if args.export:

@@ -105,8 +105,6 @@ def _format_json_doc(audits, findings, stats, model_dir) -> str:
 
 
 def run_audit(args) -> int:
-    from ..utils.jax_setup import pin_platform_from_env
-    pin_platform_from_env()
     try:
         from ..analysis.audit import audit_demo, audit_model, \
             plan_fingerprint

@@ -140,8 +140,6 @@ def _bench(model, records, rows: int) -> dict:
 
 
 def run_score(args) -> int:
-    from ..utils.jax_setup import pin_platform_from_env
-    pin_platform_from_env()
     if args.bench:
         if args.model:
             from ..workflow import WorkflowModel

@@ -68,6 +68,10 @@ from typing import List, Optional
 __all__ = ["add_serve_parser", "run_serve", "run_supervised",
            "serve_forever"]
 
+#: seconds a connection that is mid-answer at shutdown gets to finish
+#: writing before its transport is aborted
+_CLOSE_GRACE_S = 5.0
+
 
 def add_serve_parser(sub) -> None:
     sv = sub.add_parser(
@@ -229,12 +233,22 @@ async def serve_forever(server, host: str, port: int,
                          "incarnation",
                 "kind": "transient"}
 
+    #: open client connections -> "an answer is being produced". At
+    #: shutdown idle ones are closed under their reader; a busy one
+    #: finishes its answer first (Server.wait_closed() waits for every
+    #: accepted connection, so an attached client must not be able to
+    #: keep a stopped server — and the device it owns — alive)
+    conns: dict = {}
+    closing = [False]
+
     async def handle(reader, writer):
         try:
-            while True:
+            while not closing[0]:
+                conns[writer] = False
                 line = await reader.readline()
                 if not line:
                     break
+                conns[writer] = True
                 if server.draining:
                     # refuse the connection with the machine-readable
                     # answer (the reconnecting client backs off and
@@ -301,6 +315,7 @@ async def serve_forever(server, host: str, port: int,
                     done.set()
                     break
         finally:
+            conns.pop(writer, None)
             writer.close()
 
     async def handle_metrics(reader, writer):
@@ -390,10 +405,18 @@ async def serve_forever(server, host: str, port: int,
         if snap_task is not None:
             snap_task.cancel()
         tcp.close()
-        await tcp.wait_closed()
+        closing[0] = True
+        for writer, busy in list(conns.items()):
+            if not busy:
+                writer.close()
+        try:
+            await asyncio.wait_for(tcp.wait_closed(), _CLOSE_GRACE_S)
+        except asyncio.TimeoutError:
+            # a client that stopped reading its answers: cut it off
+            for writer in list(conns):
+                writer.transport.abort()
         if http is not None:
             http.close()
-            await http.wait_closed()
         await server.shutdown()
     final = {"served": answered["n"], **server.describe()}
     if drain_summary is not None:
@@ -407,8 +430,8 @@ def run_serve(args) -> int:
         return run_supervised(args)
     from ..observability import persist_process_profiles, trace
     from ..serving.server import ServeConfig, ServingServer
-    from ..utils.jax_setup import pin_platform_from_env
-    pin_platform_from_env()
+    from ..utils.jax_setup import backend_block, enable_compilation_cache
+    enable_compilation_cache()
     trace.configure_from_env()
     lifecycle = None
     if getattr(args, "auto_retrain", False):
@@ -511,6 +534,10 @@ def run_serve(args) -> int:
             "buckets": [d.chosen for d in server._bucket_decisions]}
     if admission_control is not None:
         banner_extra["admission"] = "on"
+    # the device this process actually holds, and what its boot took
+    # from the compile cache — so whoever started it can tell a server
+    # on the chip from one that is not
+    banner_extra["backend"] = backend_block()
     try:
         return asyncio.run(serve_forever(
             server, args.host, args.port,

@@ -12,9 +12,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 
-from ..utils.jax_setup import shard_map
 from .base import (ClassifierModel, FamilyPreconditionError,
                    Predictor, check_fold_classes, num_classes,
                    subset_grid)

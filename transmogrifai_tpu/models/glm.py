@@ -16,9 +16,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 
-from ..utils.jax_setup import shard_map
 from .base import Predictor, RegressionModel, subset_grid
 
 __all__ = ["GeneralizedLinearRegression",

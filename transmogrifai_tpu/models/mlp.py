@@ -17,12 +17,12 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 
 from .base import (ClassifierModel, Predictor,
                    check_fold_classes, num_classes, subset_grid)
 from .solvers import lbfgs_minimize
-from ..utils.jax_setup import shard_map
 
 __all__ = ["MultilayerPerceptronClassifier",
            "MultilayerPerceptronClassifierModel"]
@@ -100,7 +100,7 @@ def _mlp_batched_fit(X, onehot, mask, key, sizes: Tuple[int, ...],
     O(steps x rows) work where L-BFGS stops early. Mini-batching bounds
     the work to O(steps x batch) row-visits REGARDLESS of n — measured
     comparable validation error to per-fold L-BFGS at a fraction of the
-    wall-clock for wide/tall designs (BASELINE.md config 5). The
+    wall-clock for wide/tall designs (BASELINE.json config 5). The
     sequential fit_arrays keeps MLlib-parity L-BFGS; the CV search only
     uses these fits to RANK hyperparameters."""
     n = X.shape[0]

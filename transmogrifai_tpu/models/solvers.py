@@ -31,9 +31,6 @@ import optax.tree_utils as otu
 
 __all__ = ["lbfgs_minimize", "fista_minimize"]
 
-#: optax < 0.2.4 ships only tree_l2_norm; tree_norm is its later alias
-_tree_norm = getattr(otu, "tree_norm", None) or otu.tree_l2_norm
-
 
 def lbfgs_minimize(loss_fn: Callable, w0, max_iter: int = 100,
                    tol: float = 1e-6):
@@ -56,7 +53,7 @@ def lbfgs_minimize(loss_fn: Callable, w0, max_iter: int = 100,
         _, state = carry
         count = otu.tree_get(state, "count")
         grad = otu.tree_get(state, "grad")
-        err = _tree_norm(grad)
+        err = otu.tree_norm(grad)
         return (count == 0) | ((count < max_iter) & (err >= tol))
 
     final_params, _ = jax.lax.while_loop(

@@ -31,7 +31,10 @@ __all__ = ["METRICS_SCHEMA_VERSION", "LatencyHistogram", "ServeMetrics"]
 #:     measured drain rate, per-tenant weight/admitted/shed counts,
 #:     knob decisions) — {"enabled": false} when the controller is off
 #:     (docs/admission.md)
-METRICS_SCHEMA_VERSION = 4
+#: v5: top-level "backend" block (platform, device_kind, device_count,
+#:     jax version, x64, compile-cache dir + hits/misses) — what the
+#:     process actually runs on (utils/jax_setup.backend_block)
+METRICS_SCHEMA_VERSION = 5
 
 
 class LatencyHistogram:

@@ -1,20 +1,18 @@
 """Persisted performance-profile store: the queryable cost record the
 telemetry-autotuning roadmap item consumes.
 
-One atomic-merge JSON writer over the repo-level ``BENCH_STATE.json``
-(the only file that survives across bench rounds — /tmp does not): the
-bench's ambient-backend probe verdict (+ transcript), and the
-per-(stage, family, bucket) wall/compile/execute records that
-``utils/compile_time`` sections and the validator's family profile
-observe, all merge through the same read-modify-write (temp file +
-``os.replace``) so concurrent writers never tear the store and repeated
-runs ACCUMULATE cost history instead of overwriting it.
+One atomic-merge JSON writer over the checkout-level
+``BENCH_STATE.json`` (untracked — a store is a measurement of ONE
+machine and is never committed): the per-(stage, family, bucket)
+wall/compile/execute records that ``utils/compile_time`` sections and
+the validator's family profile observe merge through one
+read-modify-write (temp file + ``os.replace``) so concurrent writers
+never tear the store and repeated runs ACCUMULATE cost history instead
+of overwriting it.
 
 Layout (top-level keys are independent namespaces)::
 
     {
-      "probe":    {"<jax>-<platform>": {healthy, note, time,
-                                        transcript?}},
       "profiles": {"_schema":          1,
                    "_compacted":       {keys, calls, ...},   # if capped
                    "score:b64":        {calls, wall_seconds,
@@ -117,8 +115,9 @@ def atomic_write_json(path: str, doc: dict, *, indent: int = 1,
 
 
 def default_store_path() -> str:
-    """``TX_PROFILE_STORE`` if set, else the repo-level
-    ``BENCH_STATE.json`` next to bench.py."""
+    """``TX_PROFILE_STORE`` if set, else the checkout-level
+    ``BENCH_STATE.json`` next to bench.py (untracked: whatever this
+    checkout's own runs have recorded, empty on a fresh one)."""
     env = os.environ.get("TX_PROFILE_STORE")
     if env:
         return env
@@ -145,25 +144,6 @@ class ProfileStore:
 
     def _write(self, state: dict) -> bool:
         return atomic_write_json(self.path, state)
-
-    # -- probe verdicts (bench ambient-backend health) ---------------------
-    def record_probe(self, key: str, healthy: bool, note: str,
-                     transcript: Optional[list] = None) -> bool:
-        """Merge one probe verdict under ``probe[key]`` — bench.py's
-        writer, now shared with the profile records (the ROADMAP
-        "hidden prerequisite": the probe's verdict AND its transcript
-        persist across rounds in the same store)."""
-        with _merge_lock(self.path):
-            state = self.load()
-            verdict = {"healthy": bool(healthy), "note": str(note),
-                       "time": time.time()}
-            if transcript is not None:
-                verdict["transcript"] = list(transcript)
-            state.setdefault("probe", {})[key] = verdict
-            return self._write(state)
-
-    def probe_verdict(self, key: str) -> Optional[dict]:
-        return self.load().get("probe", {}).get(key)
 
     # -- cost profiles -----------------------------------------------------
     def record_profiles(self, records: Dict[str, dict]) -> bool:
@@ -340,7 +320,7 @@ class ProfileStore:
         """Persist one named, timestamped bench/diagnostic block (e.g.
         ``aot_restart``) wholesale. Callers own the namespace — pick a
         name that is not one of the structural blocks (``profiles``,
-        ``tuning``, ``autotune``, ``probes``)."""
+        ``tuning``, ``autotune``)."""
         with _merge_lock(self.path):
             state = self.load()
             out = dict(doc)

@@ -1,4 +1,4 @@
-"""Error taxonomy + classifier for the fault-tolerant runtime.
+"""Error classes + classifier for the fault-tolerant runtime.
 
 The Spark reference leans on executor-level fault tolerance: a lost
 worker re-runs its tasks, a sick executor is blacklisted, and the

@@ -135,12 +135,26 @@ class ReplicaProcess:
                         self.port_event.set()
 
     def wait_port(self, timeout: float = 120.0) -> int:
-        if not self.port_event.wait(timeout):
+        """The bound port from the child's banner. A child that EXITS
+        before printing one (it could not load its model, or could not
+        get its device) fails here at once, with its last output,
+        instead of being waited on for the whole timeout."""
+        deadline = time.monotonic() + timeout
+        while not self.port_event.wait(0.1):
             rc = self.proc.poll()
+            if rc is None and time.monotonic() < deadline:
+                continue
+            self._pump.join(1.0)        # collect the child's last words
+            if self.port_event.is_set():
+                break
             tail = "".join(self.output[-20:])
-            raise TimeoutError(
-                f"replica {self.spec.name} printed no serving banner "
-                f"within {timeout}s (exit={rc})\n{tail}")
+            if rc is None:
+                raise TimeoutError(
+                    f"replica {self.spec.name} printed no serving "
+                    f"banner within {timeout}s\n{tail}")
+            raise RuntimeError(
+                f"replica {self.spec.name} exited {rc} before its "
+                f"serving banner\n{tail}")
         return int(self.port)
 
     def alive(self) -> bool:
@@ -245,19 +259,39 @@ class ReplicaManager:
         if self.on_up is not None:
             self.on_up(name, rp.spec.host, port, rp.generation)
 
+    def _first_boot(self, name: str, errors: Dict[str, str]) -> None:
+        try:
+            self._boot(name, resume=False)
+        except (OSError, TimeoutError, RuntimeError) as e:
+            errors[name] = str(e)
+            with self._lock:
+                self.states[name] = "failed"
+            _telemetry.event("fleet_boot_failed", replica=name,
+                             error=str(e)[:200])
+            print(json.dumps({"fleet": "boot_failed", "replica": name,
+                              "error": str(e)[:2000]}), flush=True)
+
     def start(self) -> None:
         """Boot every replica in parallel, barrier on readiness, then
-        start the watch thread."""
-        threads = [threading.Thread(target=self._boot,
-                                    args=(name, False))
+        start the watch thread. A fleet that cannot boot EVERY replica
+        it was asked for does not start: the caller's ``shutdown()``
+        stops the ones that did. (Every replica inherits this process's
+        environment, so on an accelerator they all reach for the same
+        device and only one gets it — docs/fleet.md.)"""
+        errors: Dict[str, str] = {}
+        threads = [threading.Thread(target=self._first_boot,
+                                    args=(name, errors))
                    for name in self.specs]
         for t in threads:
             t.start()
         for t in threads:
             t.join()
-        booted = [n for n, s in self.states.items() if s == "ok"]
-        if not booted:
-            raise RuntimeError("no replica became ready")
+        if errors:
+            raise RuntimeError(
+                f"{len(errors)} of {len(self.specs)} replicas failed "
+                f"to boot: " + "; ".join(
+                    f"{n}: {e.splitlines()[0]}"
+                    for n, e in sorted(errors.items())))
         self._watch = threading.Thread(target=self._watch_loop,
                                        daemon=True)
         self._watch.start()

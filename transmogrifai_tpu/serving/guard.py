@@ -324,9 +324,12 @@ def _boxed_column(f, vals: List[Any]) -> FeatureColumn:
     """Mirror of ``FeatureGeneratorStage.extract_column`` over
     already-admitted values. Numeric columns are built directly
     (placeholder NaNs for quarantined non-nullables must not re-enter
-    boxing, which rejects them); response columns degrade to all-NaN
-    when the label cannot box (label-free scoring, same as
-    ``_generate_raw_data``)."""
+    boxing, which rejects them); a response column degrades to a
+    placeholder when the label is absent or cannot box (label-free
+    scoring): NaN, or 0.0 for a non-nullable label — the answer row
+    boxes every result column, the response included, and a
+    non-nullable type cannot hold NaN. ``ScoreFunction._extract_raw``
+    uses the same ignored 0.0."""
     from ..features.columns import ColumnKind, column_kind
     if column_kind(f.ftype) == ColumnKind.NUMERIC:
         data = np.empty(len(vals), dtype=np.float64)
@@ -339,6 +342,8 @@ def _boxed_column(f, vals: List[Any]) -> FeatureColumn:
                 if not f.is_response:
                     raise
                 data[i] = math.nan   # unboxable label: score label-free
+        if f.is_response and not f.ftype.is_nullable:
+            data[np.isnan(data)] = 0.0
         return FeatureColumn(ftype=f.ftype, data=data)
     try:
         return FeatureColumn.from_values(f.ftype, vals)

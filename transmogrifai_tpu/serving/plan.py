@@ -157,11 +157,8 @@ class ScoringPlan:
         if self._compiled:
             return self
         from ..utils.jax_setup import enable_compilation_cache
-        try:
-            # warm-start serving: persisted XLA artifacts skip compiles
-            enable_compilation_cache()
-        except (OSError, RuntimeError):  # pragma: no cover - cache dir
-            pass                         # not writable
+        # warm-start serving: persisted XLA artifacts skip compiles
+        enable_compilation_cache()
         import jax
 
         self._raw_features = self.model.raw_features()

@@ -739,6 +739,10 @@ class FleetRouter:
             self._poll_task = None
             self._stop_event = None
             server.close()
+            # Server.wait_closed() waits for every accepted connection:
+            # hang up on attached clients or a stopped router never exits
+            for writer in list(self._client_writers.values()):
+                writer.close()
             await server.wait_closed()
             for link in list(self._links.values()):
                 await link.close()

@@ -1306,6 +1306,7 @@ class ServingServer:
         dict reads + fixed-bin quantile interpolation, no device work,
         no I/O."""
         from ..observability.metrics import METRICS_SCHEMA_VERSION
+        from ..utils.jax_setup import backend_block
         from .plan import plan_compiles
         breakers = {}
         sentinels = {}
@@ -1341,12 +1342,13 @@ class ServingServer:
         serving_counters = {
             k: v for k, v in _telemetry.counters().items()
             if k.startswith(("serve_", "serving_", "breaker_",
-                             "drift_", "lifecycle_"))}
+                             "drift_", "lifecycle_", "plan_"))}
         return {
             "schema": METRICS_SCHEMA_VERSION,
             "uptime_seconds": round(self.metrics.uptime_seconds(), 3),
             "running": self._running,
             "process": self.process_block(),
+            "backend": backend_block(),
             "requests": int(self.stats["requests"]),
             "answered": self.metrics.answered,
             "failed_batches": self.metrics.failed,

@@ -25,9 +25,12 @@ listener that accumulates them
   also records wall seconds and call count, giving callers the
   ``execute = wall - compile`` split per label.
 
-Installation is lazy and idempotent; on a JAX without the monitoring
-API everything degrades to zeros (callers must treat 0.0 as "unknown",
-not "free").
+The same listener registration counts the persistent compilation
+cache's hits and misses (``cache_counts()``), so a process can say
+whether its compiles were served from ``utils/jax_setup``'s cache
+directory.
+
+Installation is lazy and idempotent.
 """
 from __future__ import annotations
 
@@ -38,15 +41,19 @@ from contextlib import contextmanager
 from typing import Dict
 
 __all__ = ["install", "compile_seconds", "compile_seconds_by_thread",
-           "section", "seconds_by_section", "reset_sections",
-           "set_section_observer"]
+           "cache_counts", "section", "seconds_by_section",
+           "reset_sections", "set_section_observer"]
 
 _LOCK = threading.Lock()
 _TOTAL = {"seconds": 0.0}
 _BY_THREAD: Dict[str, float] = defaultdict(float)
 #: label -> {"seconds": wall, "compile": event seconds, "calls": n}
 _SECTIONS: Dict[str, Dict[str, float]] = {}
-_STATE = {"installed": False, "available": False}
+#: persistent compilation cache hits / misses seen by this process
+_CACHE = {"hits": 0, "misses": 0}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
+_STATE = {"installed": False}
 _SECTION_STACK = threading.local()
 #: optional callback ``(label, wall_seconds, compile_seconds)`` fired
 #: as each section CLOSES — how the span tracer
@@ -84,19 +91,32 @@ def _on_event_duration(event: str, duration: float, **_kw) -> None:
             rec["compile"] += duration
 
 
-def install() -> bool:
-    """Register the listener once; True when the monitoring API exists."""
-    if _STATE["installed"]:
-        return _STATE["available"]
-    _STATE["installed"] = True
-    try:
-        import jax.monitoring as monitoring
-        monitoring.register_event_duration_secs_listener(
-            _on_event_duration)
-        _STATE["available"] = True
-    except Exception:  # pragma: no cover - older jax without the API
-        _STATE["available"] = False
-    return _STATE["available"]
+def _on_event(event: str, **_kw) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        with _LOCK:
+            _CACHE[key] += 1
+
+
+def install() -> None:
+    """Register the listeners once."""
+    with _LOCK:
+        if _STATE["installed"]:
+            return
+        _STATE["installed"] = True
+    import jax.monitoring as monitoring
+    monitoring.register_event_duration_secs_listener(_on_event_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def cache_counts() -> Dict[str, int]:
+    """``{"hits", "misses"}`` of the persistent compilation cache as
+    seen by this process since :func:`install`. JAX records a miss
+    when it WRITES an entry, i.e. only for compiles that took at least
+    ``jax_persistent_cache_min_compile_time_secs``; quicker programs
+    are recompiled by every process and counted nowhere."""
+    with _LOCK:
+        return dict(_CACHE)
 
 
 def compile_seconds() -> float:

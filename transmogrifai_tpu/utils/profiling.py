@@ -6,7 +6,16 @@ one complete event per XLA op execution — the accelerator-level
 profile the reference delegates to the Spark UI (SURVEY §5.5 aux).
 :func:`summarize_device_trace` reduces it to the top time-sink ops and a
 device-busy figure so benchmarks can report utilization, not just
-wall-clock (VERDICT r4 next-round #1).
+wall-clock.
+
+NOTE (jax 0.9.0 on a TPU v5e, PR 23's chip run): the profiler writes
+both ``<host>.xplane.pb`` and ``<host>.trace.json.gz`` and the device
+lanes are found, but the busy figure OVER-COUNTS — 172.7% of the span
+on one device for a warm flagship search. A ``while`` op is an event
+that contains its body's ops on the same "XLA Ops" lane, and the lane
+filter also keeps "Async XLA Ops". Do not quote ``device_busy_pct``
+until ROADMAP S1 rebuilds the reduction (union of intervals, one
+lane) and checks it on a small recorded trace.
 
 On the CPU backend the trace contains only host python frames (no
 device lanes) — callers fall back to the workflow listener's per-stage

@@ -127,8 +127,7 @@ def _fit_and_transform_layers(
     import time as _time
     fitted: Dict[str, PipelineStage] = {}
     if listener is not None:
-        # per-stage compile/execute split (utils/compile_time.py);
-        # no-op zeros on a jax without the monitoring API
+        # per-stage compile/execute split (utils/compile_time.py)
         from ..utils import compile_time
         compile_time.install()
 
@@ -395,6 +394,11 @@ class Workflow:
             raise ValueError("No result features set")
         if self._input_data is None:
             raise ValueError("No input data set")
+        # train is where a trainer process starts using JAX: the search
+        # programs it compiles are there for the next process from this
+        # checkout (utils/jax_setup placement rule)
+        from ..utils.jax_setup import enable_compilation_cache
+        enable_compilation_cache()
         if resume_from is not None:
             from ..selector.selector import ModelSelector
             selectors = [s for s in self.stages()
