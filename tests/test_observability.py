@@ -164,6 +164,48 @@ class TestTracer:
         assert len(ids) == 100
         assert all(i.startswith("req-") for i in ids)
 
+    def test_enabled_span_reaches_a_running_profiler(self, tmp_path):
+        """An open ``span()`` is also a ``jax.profiler.TraceAnnotation``
+        with the span's scalar attributes: under a running profiler trace
+        the package's spans sit on a host line of the profile. A disabled
+        ``span()`` is still the shared no-op and writes nothing there."""
+        import glob
+        import jax
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("off.span", family="GBT"):
+                pass
+            assert trace.span("off.span") is trace._NOOP
+            trace.configure(True)
+            with trace.span("search.family", family="GBT", lanes=54,
+                            skipped=None):
+                with trace.span("search.fetch"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        seen = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name in ("search.family", "search.fetch",
+                                      "off.span"):
+                        seen[event.name] = (plane.name, dict(event.stats),
+                                            event.start_ns, event.end_ns)
+        assert set(seen) == {"search.family", "search.fetch"}
+        plane, stats, start, end = seen["search.family"]
+        assert plane.startswith("/host:")
+        assert stats["family"] == "GBT" and int(stats["lanes"]) == 54
+        assert "skipped" not in stats       # scalars only
+        assert start <= seen["search.fetch"][2] \
+            and seen["search.fetch"][3] <= end
+        # and the tracer's own record is what it always was
+        family = next(s for s in trace.spans()
+                      if s["name"] == "search.family")
+        assert family["attrs"] == {"family": "GBT", "lanes": 54,
+                                   "skipped": None}
+
 
 class TestSectionSpans:
     def test_section_attaches_to_enclosing_span(self):
@@ -354,6 +396,50 @@ class TestTrainSpans:
         # balanced: every span record is CLOSED (has a duration)
         assert all(s["dur"] is not None
                    for s in first + second + third)
+
+    def test_search_spans_say_where_the_host_waits(self):
+        """One traced tiny train with a tree family: the family's design
+        and its blocking fetch are children of ``search.family``, the
+        winner's refit and training-set evaluation descendants of
+        ``train``, outside every family."""
+        from transmogrifai_tpu.models import GBTClassifier
+        from transmogrifai_tpu.selector import \
+            BinaryClassificationModelSelector
+        label, feats = _features()
+        selector = BinaryClassificationModelSelector.with_cross_validation(
+            num_folds=2, seed=3, models=[
+                (GBTClassifier(num_rounds=2, max_depth=2, max_bins=8),
+                 [{"gamma": 0.0}, {"gamma": 0.1}])])
+        pred = selector.set_input(label, feats).get_output()
+        trace.configure(True)
+        (Workflow().set_result_features(label, pred)
+         .set_input_records(_records(n=80)).train())
+        spans = trace.spans()
+        by_sid = {s["sid"]: s for s in spans}
+
+        def ancestors(span):
+            out = []
+            while span.get("parent") in by_sid:
+                span = by_sid[span["parent"]]
+                out.append(span["name"])
+            return out
+
+        def named(name):
+            found = [s for s in spans if s["name"] == name]
+            assert found, f"no {name} span"
+            return found
+
+        for name in ("search.design", "search.fetch"):
+            for s in named(name):
+                assert by_sid[s["parent"]]["name"] == "search.family"
+                assert ancestors(s)[-1] == "train"
+        for name in ("search.refit", "search.train_eval"):
+            (s,) = named(name)
+            assert "train" in ancestors(s)
+            assert "search.family" not in ancestors(s)
+        (refit,) = named("search.refit")
+        assert refit["attrs"]["family"] == "GBTClassifier"
+        assert all(s["dur"] is not None for s in spans)
 
     def test_scoring_spans_nest_under_guarded(self, trained):
         model, recs, _pred = trained

@@ -58,6 +58,7 @@ _log = logging.getLogger(__name__)
 from ..features.columns import PredictionColumn
 from .base import (ClassifierModel, Predictor, RegressionModel,
                    check_fold_classes, num_classes, subset_grid)
+from ..observability import trace as _trace
 from ..parallel.mesh import to_host
 
 __all__ = [
@@ -69,6 +70,16 @@ __all__ = [
     "GBTClassifierModel", "GBTRegressorModel",
     "GBTMulticlassClassifierModel",
 ]
+
+#: every ``jax.named_scope`` of this module (docs/observability.md "Device
+#: time by scope"): the ``tree.*`` phases of one level of ``_grow_tree``, a
+#: boosting round, the validation metric of the ``*_eval_kernel``s, and one
+#: ``fg.<family>`` around each fold-grid program's body. A scope is a path
+#: component of the ``op_name`` of the ops traced under it and exists only
+#: while JAX traces: it adds no operation and changes no program's name.
+SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
+          "tree.split", "tree.route", "gbt.round", "fg.metric",
+          "fg.gbt", "fg.forest", "fg.gbt_softmax")
 
 
 # ---------------------------------------------------------------------------
@@ -564,7 +575,8 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     if sub_enabled:
         hist_mode = hist_mode[:-len("+sub")]
     if hist_mode == "matmul_bf16":
-        bin_oh = _bin_indicator(packed, TB, jnp.bfloat16, feat_of)
+        with jax.named_scope("tree.indicator"):
+            bin_oh = _bin_indicator(packed, TB, jnp.bfloat16, feat_of)
     elif hist_mode in ("matmul", "pallas"):
         ind_gb = n * TB * jnp.dtype(stats.dtype).itemsize / 2 ** 30
         if ind_gb > 4.0:
@@ -577,7 +589,8 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                 "packed bins, %s); consider TX_TREE_HIST=matmul_chunk "
                 "or matmul_bf16", ind_gb, n, TB,
                 jnp.dtype(stats.dtype).name)
-        bin_oh = _bin_indicator(packed, TB, stats.dtype, feat_of)
+        with jax.named_scope("tree.indicator"):
+            bin_oh = _bin_indicator(packed, TB, stats.dtype, feat_of)
     else:
         bin_oh = None                # scatter / matmul_chunk modes
     key = feat_key
@@ -602,117 +615,125 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
             active = None
         else:
             C = min(2 ** level, cap)               # static slots this level
-            if axis_name:
-                slot, node_of_slot, active = _compress_nodes_global(
-                    node, C, 2 ** level, axis_name)
+            with jax.named_scope("tree.compress"):
+                if axis_name:
+                    slot, node_of_slot, active = _compress_nodes_global(
+                        node, C, 2 ** level, axis_name)
+                else:
+                    slot, node_of_slot, active = _compress_nodes(node, C)
+        with jax.named_scope("tree.hist"):
+            if (sub_enabled and identity and prev_identity
+                    and prev_hist is not None):
+                # histogram subtraction (the LightGBM trick): rows routed
+                # left stayed even-numbered (`node = 2*node + (1-go_left)`),
+                # so build ONLY the left-child histograms — half the
+                # contraction — indexed by parent (slot >> 1); each right
+                # child is parent - left. Stats are level-invariant within
+                # a tree and bins never change, so prev_hist[p] IS the
+                # parent's full histogram. Odd-slot rows park on sentinel
+                # slot C (== 2*C_half): one_hot zeroes it, scatter drops
+                # it, and the Pallas [:num_slots] slice discards it.
+                C_half = C // 2
+                slot_sub = jnp.where((slot & 1) == 0, slot >> 1, C)
+                hist_even = _level_histograms(
+                    packed, slot_sub, stats, C_half, TB, bin_oh,
+                    mode=hist_mode, axis_name=axis_name, feat_of=feat_of)
+                hist = jnp.stack([hist_even, prev_hist - hist_even],
+                                 axis=1).reshape(C, TB, stats.shape[1])
             else:
-                slot, node_of_slot, active = _compress_nodes(node, C)
-        if (sub_enabled and identity and prev_identity
-                and prev_hist is not None):
-            # histogram subtraction (the LightGBM trick): rows routed
-            # left stayed even-numbered (`node = 2*node + (1-go_left)`),
-            # so build ONLY the left-child histograms — half the
-            # contraction — indexed by parent (slot >> 1); each right
-            # child is parent - left. Stats are level-invariant within
-            # a tree and bins never change, so prev_hist[p] IS the
-            # parent's full histogram. Odd-slot rows park on sentinel
-            # slot C (== 2*C_half): one_hot zeroes it, scatter drops
-            # it, and the Pallas [:num_slots] slice discards it.
-            C_half = C // 2
-            slot_sub = jnp.where((slot & 1) == 0, slot >> 1, C)
-            hist_even = _level_histograms(
-                packed, slot_sub, stats, C_half, TB, bin_oh,
-                mode=hist_mode, axis_name=axis_name, feat_of=feat_of)
-            hist = jnp.stack([hist_even, prev_hist - hist_even],
-                             axis=1).reshape(C, TB, stats.shape[1])
-        else:
-            hist = _level_histograms(packed, slot, stats, C, TB, bin_oh,
-                                     mode=hist_mode, axis_name=axis_name,
-                                     feat_of=feat_of)
+                hist = _level_histograms(packed, slot, stats, C, TB, bin_oh,
+                                         mode=hist_mode, axis_name=axis_name,
+                                         feat_of=feat_of)
         prev_hist, prev_identity = hist, identity
-        cs = jnp.cumsum(hist, axis=1)              # packed-axis running sum
-        # per-feature segmented cumsum: subtract the running sum at the
-        # owning block's start; splitting at bin b sends bins<=b left
-        base = jnp.where((block_start > 0)[None, :, None],
-                         cs[:, jnp.maximum(block_start - 1, 0), :], 0.0)
-        left = cs - base
-        if identity:
-            # unlike compression (which only materializes non-empty
-            # slots), identity slots include empty nodes; their all-zero
-            # histograms yield -inf/zero gains under every default gain,
-            # but a user-set gamma<0 with min_child_weight<=0 could make
-            # an empty node's XGB gain positive — so count rows per slot
-            # (folded into the total reduction as an extra ones column)
-            # and mask empty slots out of split_ok below
-            aug = jax.ops.segment_sum(
-                jnp.concatenate(
-                    [stats, jnp.ones((n, 1), stats.dtype)], axis=1),
-                slot, num_segments=C)
-            if axis_name:
-                aug = jax.lax.psum(aug, axis_name)
-            total = aug[:, None, :-1]
-            nonempty = aug[:, -1] > 0
-        else:
-            total = jax.ops.segment_sum(stats, slot,
-                                        num_segments=C)[:, None, :]
-            if axis_name:
-                total = jax.lax.psum(total, axis_name)
-        right = total - left
-        gain = gain_fn(left, right, total)         # (C, TB)
-        gain = jnp.where(not_a_split[None, :], -jnp.inf, gain)
-        if max_features is not None and max_features < d:
-            key, sub = jax.random.split(key)
+        with jax.named_scope("tree.split"):
+            cs = jnp.cumsum(hist, axis=1)          # packed-axis running sum
+            # per-feature segmented cumsum: subtract the running sum at the
+            # owning block's start; splitting at bin b sends bins<=b left
+            base = jnp.where((block_start > 0)[None, :, None],
+                             cs[:, jnp.maximum(block_start - 1, 0), :], 0.0)
+            left = cs - base
+            with jax.named_scope("tree.node_sums"):
+                if identity:
+                    # unlike compression (which only materializes
+                    # non-empty slots), identity slots include empty nodes;
+                    # their all-zero histograms yield -inf/zero gains under
+                    # every default gain, but a user-set gamma<0 with
+                    # min_child_weight<=0 could make an empty node's XGB
+                    # gain positive — so count rows per slot (folded into
+                    # the total reduction as an extra ones column) and mask
+                    # empty slots out of split_ok below
+                    aug = jax.ops.segment_sum(
+                        jnp.concatenate(
+                            [stats, jnp.ones((n, 1), stats.dtype)], axis=1),
+                        slot, num_segments=C)
+                    if axis_name:
+                        aug = jax.lax.psum(aug, axis_name)
+                    total = aug[:, None, :-1]
+                    nonempty = aug[:, -1] > 0
+                else:
+                    total = jax.ops.segment_sum(stats, slot,
+                                                num_segments=C)[:, None, :]
+                    if axis_name:
+                        total = jax.lax.psum(total, axis_name)
+            right = total - left
+            gain = gain_fn(left, right, total)         # (C, TB)
+            gain = jnp.where(not_a_split[None, :], -jnp.inf, gain)
+            if max_features is not None and max_features < d:
+                key, sub = jax.random.split(key)
+                if identity:
+                    # node_of_slot is arange(C) here — the node-keyed
+                    # gather below would be a no-op
+                    u = jax.random.uniform(sub, (C, d))
+                elif 2 ** level <= cap:
+                    # node-keyed draw: invariant to slot numbering, so the
+                    # identity and compressed paths pick identical per-node
+                    # feature subsets. A sentinel (empty) slot clamps onto
+                    # the last node's row — safe not because that row is
+                    # unused but because sentinel-slot outputs never reach
+                    # the heap (mode="drop") or routing
+                    u = jax.random.uniform(sub, (2 ** level, d))[
+                        jnp.clip(node_of_slot, 0, 2 ** level - 1)]
+                else:
+                    u = jax.random.uniform(sub, (C, d))
+                kth = jnp.sort(u, axis=1)[:, max_features - 1:max_features]
+                gain = jnp.where((u <= kth)[:, feat_of], gain, -jnp.inf)
+            best = jnp.argmax(gain, axis=1)            # (C,) packed bin index
+            best_gain = jnp.take_along_axis(gain, best[:, None], axis=1)[:, 0]
+            split_ok = best_gain >= jnp.maximum(min_info_gain, 1e-12)
+            if depth_limit is not None:
+                split_ok &= level < depth_limit
             if identity:
-                # node_of_slot is arange(C) here — the node-keyed
-                # gather below would be a no-op
-                u = jax.random.uniform(sub, (C, d))
-            elif 2 ** level <= cap:
-                # node-keyed draw: invariant to slot numbering, so the
-                # identity and compressed paths pick identical per-node
-                # feature subsets. A sentinel (empty) slot clamps onto
-                # the last node's row — safe not because that row is
-                # unused but because sentinel-slot outputs never reach
-                # the heap (mode="drop") or routing
-                u = jax.random.uniform(sub, (2 ** level, d))[
-                    jnp.clip(node_of_slot, 0, 2 ** level - 1)]
-            else:
-                u = jax.random.uniform(sub, (C, d))
-            kth = jnp.sort(u, axis=1)[:, max_features - 1:max_features]
-            gain = jnp.where((u <= kth)[:, feat_of], gain, -jnp.inf)
-        best = jnp.argmax(gain, axis=1)            # (C,) packed bin index
-        best_gain = jnp.take_along_axis(gain, best[:, None], axis=1)[:, 0]
-        split_ok = best_gain >= jnp.maximum(min_info_gain, 1e-12)
-        if depth_limit is not None:
-            split_ok &= level < depth_limit
-        if identity:
-            split_ok &= nonempty
-        if level + 1 < depth and not identity:
-            # budget mask: next level holds at most min(2^(level+1), cap)
-            # slots; each split adds one net node, so only the first
-            # (budget - active) slots may split. Binds only near capacity
-            # (the identity fast path above is taken exactly when it
-            # cannot bind).
-            budget = min(2 ** (level + 1), cap)
-            split_ok &= jnp.arange(C) < (budget - active)
-        bfeat = jnp.where(split_ok, feat_of[best], 0)
-        thr = jnp.where(split_ok, packed_thr[best], jnp.inf)
-        heap_pos = jnp.where(node_of_slot == _SLOT_SENTINEL,
-                             _SLOT_SENTINEL, 2 ** level - 1 + node_of_slot)
-        # feat_map translates design-local feature ids (e.g. a per-tree
-        # feature pool) back to ORIGINAL column ids for the heap
-        heap_feat = (bfeat if feat_map is None
-                     else jnp.where(split_ok, feat_map[bfeat], 0))
-        feat_heap = feat_heap.at[heap_pos].set(heap_feat, mode="drop")
-        thr_heap = thr_heap.at[heap_pos].set(thr.astype(thr_heap.dtype),
-                                             mode="drop")
+                split_ok &= nonempty
+            if level + 1 < depth and not identity:
+                # budget mask: next level holds at most min(2^(level+1), cap)
+                # slots; each split adds one net node, so only the first
+                # (budget - active) slots may split. Binds only near capacity
+                # (the identity fast path above is taken exactly when it
+                # cannot bind).
+                budget = min(2 ** (level + 1), cap)
+                split_ok &= jnp.arange(C) < (budget - active)
+            bfeat = jnp.where(split_ok, feat_of[best], 0)
+            thr = jnp.where(split_ok, packed_thr[best], jnp.inf)
+            heap_pos = jnp.where(node_of_slot == _SLOT_SENTINEL,
+                                 _SLOT_SENTINEL, 2 ** level - 1 + node_of_slot)
+            # feat_map translates design-local feature ids (e.g. a per-tree
+            # feature pool) back to ORIGINAL column ids for the heap
+            heap_feat = (bfeat if feat_map is None
+                         else jnp.where(split_ok, feat_map[bfeat], 0))
+            feat_heap = feat_heap.at[heap_pos].set(heap_feat, mode="drop")
+            thr_heap = thr_heap.at[heap_pos].set(thr.astype(thr_heap.dtype),
+                                                 mode="drop")
         # route rows: packed[i, f*] <= best_packed  <=>  bin <= b; a
         # denied split routes everything left via the TB sentinel
-        best_r = jnp.where(split_ok, best, TB)
-        go_left = packed[jnp.arange(n), bfeat[slot]] <= best_r[slot]
-        node = 2 * node + (1 - go_left.astype(jnp.int32))  # within-level idx
-    leaf_stats = jax.ops.segment_sum(stats, node, num_segments=2 ** depth)
-    if axis_name:
-        leaf_stats = jax.lax.psum(leaf_stats, axis_name)
+        with jax.named_scope("tree.route"):
+            best_r = jnp.where(split_ok, best, TB)
+            go_left = packed[jnp.arange(n), bfeat[slot]] <= best_r[slot]
+            # within-level index
+            node = 2 * node + (1 - go_left.astype(jnp.int32))
+    with jax.named_scope("tree.node_sums"):
+        leaf_stats = jax.ops.segment_sum(stats, node, num_segments=2 ** depth)
+        if axis_name:
+            leaf_stats = jax.lax.psum(leaf_stats, axis_name)
     return feat_heap, thr_heap, leaf_stats, node
 
 
@@ -1093,25 +1114,28 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
 
     def one_round(carry, rkey):
         margins = carry
-        if objective == "logistic":
-            p = jax.nn.sigmoid(margins)
-            g, h = p - y, jnp.maximum(p * (1 - p), 1e-12)
-        else:
-            g, h = margins - y, jnp.ones_like(y)
-        m = _row_draw(
-            lambda k, mm: jax.random.bernoulli(k, subsample,
-                                               (mm,)).astype(dtype),
-            rkey, n, axis_name, row_total) * mask
-        g, h = g * m, h * m
-        feat, thr, leaf_stats, node = _grow_tree(
-            packed, feat_of, block_start, packed_thr,
-            jnp.stack([g, h], axis=1), depth=depth,
-            gain_fn=gain_fn, min_info_gain=0.0, hist_mode=hist_mode,
-            axis_name=axis_name, row_total=row_total,
-            depth_limit=depth_limit)
-        vals = -step_size * leaf_stats[:, 0] / (leaf_stats[:, 1] + reg_lambda)
-        vals = jnp.where(jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
-        margins = margins + vals[node]
+        with jax.named_scope("gbt.round"):
+            if objective == "logistic":
+                p = jax.nn.sigmoid(margins)
+                g, h = p - y, jnp.maximum(p * (1 - p), 1e-12)
+            else:
+                g, h = margins - y, jnp.ones_like(y)
+            m = _row_draw(
+                lambda k, mm: jax.random.bernoulli(k, subsample,
+                                                   (mm,)).astype(dtype),
+                rkey, n, axis_name, row_total) * mask
+            g, h = g * m, h * m
+            feat, thr, leaf_stats, node = _grow_tree(
+                packed, feat_of, block_start, packed_thr,
+                jnp.stack([g, h], axis=1), depth=depth,
+                gain_fn=gain_fn, min_info_gain=0.0, hist_mode=hist_mode,
+                axis_name=axis_name, row_total=row_total,
+                depth_limit=depth_limit)
+            vals = (-step_size * leaf_stats[:, 0]
+                    / (leaf_stats[:, 1] + reg_lambda))
+            vals = jnp.where(jnp.sum(jnp.abs(leaf_stats), axis=1) > 0,
+                             vals, 0.0)
+            margins = margins + vals[node]
         return margins, (feat, thr, vals)
     _, (feats, thrs, leaves) = jax.lax.scan(
         one_round, margins0, jax.random.split(key, num_rounds))
@@ -1164,30 +1188,32 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
     margins0 = jnp.broadcast_to(base, (n, num_classes)).astype(dtype)
 
     def one_round(margins, rkey):
-        p = jax.nn.softmax(margins, axis=1)
-        g = p - onehot                                  # (n, K)
-        h = jnp.maximum(p * (1.0 - p), 1e-12)
-        m = _row_draw(
-            lambda k, mm: jax.random.bernoulli(k, subsample,
-                                               (mm,)).astype(dtype),
-            rkey, n, axis_name, row_total) * mask
+        with jax.named_scope("gbt.round"):
+            p = jax.nn.softmax(margins, axis=1)
+            g = p - onehot                                  # (n, K)
+            h = jnp.maximum(p * (1.0 - p), 1e-12)
+            m = _row_draw(
+                lambda k, mm: jax.random.bernoulli(k, subsample,
+                                                   (mm,)).astype(dtype),
+                rkey, n, axis_name, row_total) * mask
 
-        def per_class(gk, hk):
-            feat, thr, leaf_stats, node = _grow_tree(
-                packed, feat_of, block_start, packed_thr,
-                jnp.stack([gk * m, hk * m], axis=1), depth=depth,
-                gain_fn=gain_fn, min_info_gain=0.0, hist_mode=hist_mode,
-                axis_name=axis_name, row_total=row_total,
-                depth_limit=depth_limit)
-            vals = (-step_size * leaf_stats[:, 0]
-                    / (leaf_stats[:, 1] + reg_lambda))
-            vals = jnp.where(
-                jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
-            return feat, thr, vals, vals[node]
+            def per_class(gk, hk):
+                feat, thr, leaf_stats, node = _grow_tree(
+                    packed, feat_of, block_start, packed_thr,
+                    jnp.stack([gk * m, hk * m], axis=1), depth=depth,
+                    gain_fn=gain_fn, min_info_gain=0.0,
+                    hist_mode=hist_mode, axis_name=axis_name,
+                    row_total=row_total, depth_limit=depth_limit)
+                vals = (-step_size * leaf_stats[:, 0]
+                        / (leaf_stats[:, 1] + reg_lambda))
+                vals = jnp.where(
+                    jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
+                return feat, thr, vals, vals[node]
 
-        feats, thrs, vals, delta = jax.vmap(per_class, in_axes=(1, 1)
-                                            )(g, h)     # over classes
-        return margins + delta.T, (feats, thrs, vals)
+            feats, thrs, vals, delta = jax.vmap(
+                per_class, in_axes=(1, 1))(g, h)            # over classes
+            margins = margins + delta.T
+        return margins, (feats, thrs, vals)
 
     _, (feats, thrs, leaves) = jax.lax.scan(
         one_round, margins0, jax.random.split(key, num_rounds))
@@ -1246,9 +1272,10 @@ def _forest_fg_kernel(statics: tuple, mesh=None):
 
     def batched(masks, mi, mg, sr, dl, *rest):
         ob = masks.shape[0]     # candidate lanes share the block budget
-        return jax.vmap(functools.partial(one, ob),
-                        in_axes=(0, 0, 0, 0, 0) + (None,) * 10
-                        )(masks, mi, mg, sr, dl, *rest)
+        with jax.named_scope("fg.forest"):
+            return jax.vmap(functools.partial(one, ob),
+                            in_axes=(0, 0, 0, 0, 0) + (None,) * 10
+                            )(masks, mi, mg, sr, dl, *rest)
 
     if mesh is None:
         return jax.jit(batched)
@@ -1275,8 +1302,9 @@ def _gbt_fg_kernel(statics: tuple, mesh=None):
                          hist_mode=hist_mode, depth_limit=dl)
 
     def batched(masks, ss, rl, ga, mcw, sub, dl, *rest):
-        return jax.vmap(one, in_axes=(0,) * 7 + (None,) * 6
-                        )(masks, ss, rl, ga, mcw, sub, dl, *rest)
+        with jax.named_scope("fg.gbt"):
+            return jax.vmap(one, in_axes=(0,) * 7 + (None,) * 6
+                            )(masks, ss, rl, ga, mcw, sub, dl, *rest)
 
     if mesh is None:
         return jax.jit(batched)
@@ -1334,16 +1362,18 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None):
             max_features=max_features, pool_cfg=pool_cfg,
             impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
             outer_batch=ob, budget_mb=budget_mb, depth_limit=dl)
-        scores = _candidate_scores("forest", spec[0], depth, feats, thrs,
-                                   leaves, 0.0, Xv[fi])
-        return mfn(yv[fi], scores)
+        with jax.named_scope("fg.metric"):
+            scores = _candidate_scores("forest", spec[0], depth, feats,
+                                       thrs, leaves, 0.0, Xv[fi])
+            return mfn(yv[fi], scores)
 
     def batched(masks, mi, mg, sr, dl, fi, Xv, yv, *rest):
         ob = masks.shape[0]
-        return jax.vmap(functools.partial(one, ob),
-                        in_axes=(0, 0, 0, 0, 0, 0, None, None)
-                        + (None,) * 10
-                        )(masks, mi, mg, sr, dl, fi, Xv, yv, *rest)
+        with jax.named_scope("fg.forest"):
+            return jax.vmap(functools.partial(one, ob),
+                            in_axes=(0, 0, 0, 0, 0, 0, None, None)
+                            + (None,) * 10
+                            )(masks, mi, mg, sr, dl, fi, Xv, yv, *rest)
 
     if mesh is None:
         return jax.jit(batched)
@@ -1368,15 +1398,17 @@ def _gbt_eval_kernel(statics: tuple, spec: tuple, mesh=None):
             packed, feat_of, block_start, packed_thr, y, key, mask, ss,
             rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
             objective=objective, hist_mode=hist_mode, depth_limit=dl)
-        scores = _candidate_scores("gbt", spec[0], depth, feats, thrs,
-                                   leaves, base, Xv[fi])
-        return mfn(yv[fi], scores)
+        with jax.named_scope("fg.metric"):
+            scores = _candidate_scores("gbt", spec[0], depth, feats, thrs,
+                                       leaves, base, Xv[fi])
+            return mfn(yv[fi], scores)
 
     def batched(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv, *rest):
-        return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
-                        + (None,) * 6
-                        )(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv,
-                          *rest)
+        with jax.named_scope("fg.gbt"):
+            return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
+                            + (None,) * 6
+                            )(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv,
+                              *rest)
 
     if mesh is None:
         return jax.jit(batched)
@@ -1402,8 +1434,9 @@ def _gbt_softmax_fg_kernel(statics: tuple, mesh=None):
             num_classes=num_classes, hist_mode=hist_mode, depth_limit=dl)
 
     def batched(masks, ss, rl, ga, mcw, sub, dl, *rest):
-        return jax.vmap(one, in_axes=(0,) * 7 + (None,) * 6
-                        )(masks, ss, rl, ga, mcw, sub, dl, *rest)
+        with jax.named_scope("fg.gbt_softmax"):
+            return jax.vmap(one, in_axes=(0,) * 7 + (None,) * 6
+                            )(masks, ss, rl, ga, mcw, sub, dl, *rest)
 
     if mesh is None:
         return jax.jit(batched)
@@ -1445,15 +1478,17 @@ def _gbt_softmax_eval_kernel(statics: tuple, spec: tuple, mesh=None):
             packed, feat_of, block_start, packed_thr, y, key, mask, ss,
             rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
             num_classes=num_classes, hist_mode=hist_mode, depth_limit=dl)
-        margins = _softmax_margins(feats, thrs, leaves, base, depth,
-                                   Xv[fi])
-        return mfn(yv[fi], jax.nn.softmax(margins, axis=1))
+        with jax.named_scope("fg.metric"):
+            margins = _softmax_margins(feats, thrs, leaves, base, depth,
+                                       Xv[fi])
+            return mfn(yv[fi], jax.nn.softmax(margins, axis=1))
 
     def batched(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv, *rest):
-        return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
-                        + (None,) * 6
-                        )(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv,
-                          *rest)
+        with jax.named_scope("fg.gbt_softmax"):
+            return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
+                            + (None,) * 6
+                            )(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv,
+                              *rest)
 
     if mesh is None:
         return jax.jit(batched)
@@ -1493,27 +1528,31 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
     for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in \
             _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
                               _GBT_SKEY):
-        design, _ = _design_args(X, cand0.max_bins, edge_rows=edge_rows)
+        with _trace.span("search.design"):
+            design, _ = _design_args(X, cand0.max_bins,
+                                     edge_rows=edge_rows)
         statics = (depth_cap, cand0.num_rounds, num_classes_k,
                    _hist_mode(n, int(design[1].shape[0])))
         _note_compile("gbt_softmax", statics, masks_p.shape)
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
             fn = _gbt_softmax_eval_kernel(statics, spec, mesh)
-            mm = to_host(fn(
-                jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                Xv_j, yv_j, *design[:4], y_j,
-                jax.random.PRNGKey(cand0.seed)))[:count]
+            with _trace.span("search.fetch"):
+                mm = to_host(fn(
+                    jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
+                    Xv_j, yv_j, *design[:4], y_j,
+                    jax.random.PRNGKey(cand0.seed)))[:count]
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
         fn = _gbt_softmax_fg_kernel(statics, mesh)
-        feats, thrs, leaves, base = fn(
-            jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
-            jax.random.PRNGKey(cand0.seed))
-        feats = to_host(feats)[:count]
-        thrs = to_host(thrs)[:count]
-        leaves = to_host(leaves)[:count]
-        base = to_host(base)[:count]
+        with _trace.span("search.fetch"):
+            feats, thrs, leaves, base = fn(
+                jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
+                jax.random.PRNGKey(cand0.seed))
+            feats = to_host(feats)[:count]
+            thrs = to_host(thrs)[:count]
+            leaves = to_host(leaves)[:count]
+            base = to_host(base)[:count]
         for f in range(F):
             for j, (gi, cand) in enumerate(members):
                 c = f * gk + j
@@ -2199,8 +2238,9 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
     for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in \
             _candidate_groups(est, grid, masks, mesh, _FOREST_TRACED,
                               _FOREST_STATIC):
-        design, widths = _design_args(X, cand0.max_bins,
-                                      edge_rows=edge_rows)
+        with _trace.span("search.design"):
+            design, widths = _design_args(X, cand0.max_bins,
+                                          edge_rows=edge_rows)
         mf = _resolve_max_features(cand0.feature_subset_strategy, d,
                                    classification) \
             if cand0.bootstrap else None
@@ -2215,19 +2255,21 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
             fn = _forest_eval_kernel(statics, spec, mesh)
-            mm = to_host(fn(
-                jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                Xv_j, yv_j, *design, narrow, wide, y_j,
-                jax.random.PRNGKey(cand0.seed)))[:count]
+            with _trace.span("search.fetch"):
+                mm = to_host(fn(
+                    jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
+                    Xv_j, yv_j, *design, narrow, wide, y_j,
+                    jax.random.PRNGKey(cand0.seed)))[:count]
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
         fn = _forest_fg_kernel(statics, mesh)
-        feats, thrs, leaves = fn(
-            jnp.asarray(masks_p), *vecs_j, *design, narrow, wide,
-            y_j, jax.random.PRNGKey(cand0.seed))
-        feats = to_host(feats)[:count]
-        thrs = to_host(thrs)[:count]
-        leaves = to_host(leaves)[:count]
+        with _trace.span("search.fetch"):
+            feats, thrs, leaves = fn(
+                jnp.asarray(masks_p), *vecs_j, *design, narrow, wide,
+                y_j, jax.random.PRNGKey(cand0.seed))
+            feats = to_host(feats)[:count]
+            thrs = to_host(thrs)[:count]
+            leaves = to_host(leaves)[:count]
         model_cls = (TreeEnsembleClassifierModel if classification
                      else TreeEnsembleRegressorModel)
         for f in range(F):
@@ -2273,28 +2315,31 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
     for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in \
             _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
                               _GBT_SKEY):
-        design, _ = _design_args(X, cand0.max_bins,
-                                 edge_rows=edge_rows)
+        with _trace.span("search.design"):
+            design, _ = _design_args(X, cand0.max_bins,
+                                     edge_rows=edge_rows)
         statics = (depth_cap, cand0.num_rounds, objective,
                    _hist_mode(n, int(design[1].shape[0])))
         _note_compile("gbt", statics, masks_p.shape)
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
             fn = _gbt_eval_kernel(statics, spec, mesh)
-            mm = to_host(fn(
-                jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                Xv_j, yv_j, *design[:4], y_j,
-                jax.random.PRNGKey(cand0.seed)))[:count]
+            with _trace.span("search.fetch"):
+                mm = to_host(fn(
+                    jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
+                    Xv_j, yv_j, *design[:4], y_j,
+                    jax.random.PRNGKey(cand0.seed)))[:count]
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
         fn = _gbt_fg_kernel(statics, mesh)
-        feats, thrs, leaves, base = fn(
-            jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
-            jax.random.PRNGKey(cand0.seed))
-        feats = to_host(feats)[:count]
-        thrs = to_host(thrs)[:count]
-        leaves = to_host(leaves)[:count]
-        base = to_host(base)[:count]
+        with _trace.span("search.fetch"):
+            feats, thrs, leaves, base = fn(
+                jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
+                jax.random.PRNGKey(cand0.seed))
+            feats = to_host(feats)[:count]
+            thrs = to_host(thrs)[:count]
+            leaves = to_host(leaves)[:count]
+            base = to_host(base)[:count]
         for f in range(F):
             for j, (gi, cand) in enumerate(members):
                 c = f * gk + j

@@ -13,7 +13,11 @@ substrate:
   ``with span(...)`` blocks build the tree for free; cross-thread work
   (the validator's family pool, the serving executors) passes an
   explicit ``parent=current_ref()`` instead — context vars do not cross
-  executor threads, and implicit inheritance there would lie.
+  executor threads, and implicit inheritance there would lie. An open
+  ``span()`` is also a ``jax.profiler.TraceAnnotation`` (name + scalar
+  attributes), so under a running ``jax.profiler`` trace the package's
+  spans sit in the profile on the profiler's clock, beside the device
+  lanes; ``add_span`` is retrospective and cannot annotate.
 - **Off by default, near-zero when off.** ``enabled()`` is one bool
   read; ``span()`` returns a shared no-op context manager and
   allocates NOTHING when tracing is disabled — the serving hot path
@@ -159,11 +163,12 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("rec", "_token")
+    __slots__ = ("rec", "_token", "_annotation")
 
     def __init__(self, rec: dict):
         self.rec = rec
         self._token = None
+        self._annotation = None
 
     def __enter__(self):
         stack = _STACK.get()
@@ -175,12 +180,21 @@ class _Span:
         if rec["trace"] is None:
             rec["trace"] = f"t{rec['sid']}"
         self._token = _STACK.set(stack + (rec,))
+        # the same span, written into a running ``jax.profiler`` trace on
+        # the profiler's own clock, beside the device's lanes (a TraceMe:
+        # nothing is recorded while no profiler session is open)
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(rec["name"], **{
+            k: v for k, v in rec["attrs"].items()
+            if isinstance(v, (str, int, float, bool))})
+        self._annotation.__enter__()
         rec["t0"] = time.monotonic()
         return rec
 
     def __exit__(self, exc_type, exc, tb):
         rec = self.rec
         rec["dur"] = time.monotonic() - rec["t0"]
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             rec["attrs"]["status"] = "error"
             rec["attrs"]["error"] = f"{exc_type.__name__}: {exc}"

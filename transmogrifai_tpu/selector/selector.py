@@ -18,6 +18,7 @@ import numpy as np
 from ..evaluators.base import EvaluationMetrics, Evaluator
 from ..features.columns import PredictionColumn
 from ..models.base import PredictionModel, Predictor
+from ..observability import trace as _trace
 from .splitters import Splitter, SplitterSummary
 from .validator import BestEstimator, CrossValidation, ValidationResult, \
     _ValidatorBase
@@ -354,17 +355,20 @@ class ModelSelector(Predictor):
         retry = (self.retry_policy
                  or getattr(self.validator, "retry_policy", None)
                  or RetryPolicy.from_env())
-        inner = retry.call(lambda: best.estimator.fit_arrays(Xp, yp),
-                           description=f"winner-refit:{best.name}")
+        with _trace.span("search.refit", family=best.name):
+            inner = retry.call(
+                lambda: best.estimator.fit_arrays(Xp, yp),
+                description=f"winner-refit:{best.name}")
 
         # 4. training-set evaluation (reference :172)
         evaluator = self.validator.evaluator
-        train_eval = evaluator.evaluate_arrays(
-            yp, inner.predict_arrays(Xp))
-        holdout_eval = None
-        if X_hold is not None:
-            holdout_eval = evaluator.evaluate_arrays(
-                y_hold, inner.predict_arrays(X_hold))
+        with _trace.span("search.train_eval"):
+            train_eval = evaluator.evaluate_arrays(
+                yp, inner.predict_arrays(Xp))
+            holdout_eval = None
+            if X_hold is not None:
+                holdout_eval = evaluator.evaluate_arrays(
+                    y_hold, inner.predict_arrays(X_hold))
 
         summary = ModelSelectorSummary(
             validation_type=type(self.validator).__name__,
