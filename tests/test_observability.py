@@ -439,6 +439,12 @@ class TestTrainSpans:
             assert "search.family" not in ancestors(s)
         (refit,) = named("search.refit")
         assert refit["attrs"]["family"] == "GBTClassifier"
+        # a fetch says which routing form the tree programs traced so far
+        # hold (trees.tree_route_forms; the CPU's scatter mode gathers),
+        # its own program's included: that one traced inside the span
+        for s in named("search.fetch"):
+            assert s["attrs"]["route_gather"] >= 1
+            assert s["attrs"]["route_dense"] >= 0
         assert all(s["dur"] is not None for s in spans)
 
     def test_scoring_spans_nest_under_guarded(self, trained):

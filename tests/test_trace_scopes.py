@@ -63,7 +63,8 @@ def fit_gbt_hlo():
 @pytest.fixture(scope="module")
 def fold_grid_hlo():
     """The GBT fold-grid program (fit + validation metric) as the selector's
-    driver builds it, captured where ``_gbt_fold_grid`` calls it."""
+    driver builds it, captured where ``_gbt_fold_grid`` calls it, under the
+    accelerator's ``matmul`` histogram mode."""
     rng = np.random.default_rng(1)
     X = rng.normal(size=(64, 4))
     y = (X[:, 1] > 0).astype(np.float64)
@@ -85,6 +86,7 @@ def fold_grid_hlo():
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(trees, "_gbt_eval_kernel", spy)
+        patch.setenv("TX_TREE_HIST", "matmul")
         metrics = GBTClassifier(num_rounds=2, max_depth=2, max_bins=8
                                 ).eval_fold_grid_arrays(
             X, y, masks, [{"gamma": 0.0}, {"gamma": 0.1}], X_val, y_val,
@@ -124,6 +126,37 @@ def test_fold_grid_program_keeps_its_name(fold_grid_hlo):
     families = {c for c in _components(fold_grid_hlo)
                 if c.startswith("fg.") and c != "fg.metric"}
     assert families == {"fg.gbt"}
+
+
+@pytest.mark.parametrize("program", ("fit_gbt_hlo", "fold_grid_hlo"))
+def test_no_gather_under_tree_route(program, request):
+    """ISSUE 27: under the ``matmul`` family a level routes its rows by
+    selects over the slot and the column axis; the scope keeps its name."""
+    hlo = request.getfixturevalue(program)
+    routed = [line for line in hlo.splitlines() if "tree.route" in line]
+    assert any("tree.route/reduce_or" in line for line in routed)
+    assert not [line for line in routed
+                if re.search(r"= \S+ gather\(", line)]
+
+
+def test_route_form_follows_hist_mode_and_retraces(monkeypatch):
+    rng = np.random.default_rng(27)
+    X = rng.normal(size=(80, 4))
+    y = (X[:, 0] > 0).astype(np.float64)
+    seen = []
+    for mode in ("scatter", "matmul", "scatter", "pallas"):
+        monkeypatch.setenv("TX_TREE_HIST", mode)
+        before = trees.tree_route_forms()
+        GBTClassifier(num_rounds=2, max_depth=2, max_bins=8).fit_arrays(X, y)
+        after = trees.tree_route_forms()
+        seen.append({k: after[k] - before[k] for k in after})
+    # a fit traces its tree grower under its own mode's form and no other;
+    # the third fit finds the first one's program and traces nothing
+    assert seen[0]["gather"] >= 1 and seen[0]["dense"] == 0
+    assert seen[1]["dense"] >= 1 and seen[1]["gather"] == 0
+    assert seen[2] == {"dense": 0, "gather": 0}
+    assert seen[3]["dense"] >= 1 and seen[3]["gather"] == 0
+    assert set(trees.tree_route_forms()) == {"dense", "gather"}
 
 
 def test_every_scope_is_used_in_trees():

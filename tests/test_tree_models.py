@@ -299,6 +299,115 @@ class TestHistogramModes:
                                                rtol=1e-5)
 
 
+def _route_case(name):
+    """(args, kwargs, lane masks or None) of one ``_grow_tree`` call. The
+    stats are one-hot class counts times whole-number row weights, so every
+    histogram sum is exact in either histogram mode and the two calls can
+    differ in nothing but the routing form."""
+    import jax
+    import jax.numpy as jnp
+    from transmogrifai_tpu.models import trees as T
+    rng = np.random.default_rng(27)
+    n, d, bins = 600, 10, 16
+    if name == "wide_bins":
+        d, bins = 24, 32            # TB = 768: indices a bf16 pass rounds
+    if name == "pooled":
+        d = 40
+    X = rng.normal(size=(n, d))
+    y = ((X[:, 0] > 0.1) ^ (X[:, 3] < -0.2) | (X[:, 7] > 1.0)).astype(int)
+    design = T._PackedDesign(X, max_bins=bins)
+    packed, feat_of, block_start, thr = (
+        jnp.asarray(design.packed), jnp.asarray(design.feat_of),
+        jnp.asarray(design.block_start), jnp.asarray(design.packed_thr))
+    onehot = jax.nn.one_hot(jnp.asarray(y), 2, dtype=thr.dtype)
+    kwargs = dict(depth=4, gain_fn=T._gini_gain(2.0), min_info_gain=1e-9)
+    masks = None
+    if name == "compressed":        # 2^3 > 7: levels 2-5 rank-compress and
+        kwargs.update(depth=6, node_cap=7)      # the budget mask binds
+    elif name == "depth_limit":     # levels 2-4 are denied: all rows left
+        kwargs.update(depth=5, depth_limit=jnp.asarray(2))
+    elif name == "wide_bins":
+        assert int(feat_of.shape[0]) > 256
+        kwargs.update(depth=5)
+    elif name == "pooled":          # as _forest_body passes a tree's pool
+        (narrow, wide), cfg, mf = T._pool_plan(design.widths, 2)
+        assert cfg is not None
+        pool, packed, feat_of, block_start, thr = T._tree_pool(
+            jax.random.PRNGKey(3), jnp.asarray(design.binned),
+            jnp.asarray(design.col_thr), narrow, wide, cfg)
+        kwargs.update(depth=5, feat_map=pool, max_features=mf,
+                      feat_key=jax.random.PRNGKey(4))
+    elif name == "vmap_lanes":
+        masks = jnp.asarray(rng.integers(0, 3, size=(3, n)), thr.dtype)
+        kwargs.update(depth=6, node_cap=7)
+    return (packed, feat_of, block_start, thr, onehot), kwargs, masks
+
+
+class TestRouteForms:
+    """The routing step of a level has two forms (models/trees._route_form):
+    per-row gathers under the ``scatter`` family, selects over the slot and
+    the column axis under the ``matmul`` family. They must route every row
+    alike: the same integers, not close ones."""
+
+    @pytest.mark.parametrize("case", [
+        "identity", "compressed", "depth_limit", "pooled", "wide_bins",
+        "vmap_lanes"])
+    def test_dense_route_equals_gather_route(self, case):
+        import jax
+        from transmogrifai_tpu.models import trees as T
+        (packed, feat_of, block_start, thr, onehot), kwargs, masks = \
+            _route_case(case)
+        before = T.tree_route_forms()
+
+        def grow(mode):
+            def one(stats):
+                return T._grow_tree(packed, feat_of, block_start, thr,
+                                    stats, hist_mode=mode, **kwargs)
+            if masks is None:
+                return jax.jit(one)(onehot)
+            return jax.jit(jax.vmap(lambda m: one(onehot * m[:, None])))(
+                masks)
+        gathered, dense = grow("scatter"), grow("matmul")
+        after = T.tree_route_forms()
+        assert after["gather"] > before["gather"]
+        assert after["dense"] > before["dense"]
+        for name, a, b in zip(("feat_heap", "thr_heap", "leaf_stats",
+                               "node"), gathered, dense):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"{case}: {name}")
+        _, thr_heap, _, node = (np.asarray(a) for a in dense)
+        assert np.isfinite(thr_heap).sum() >= 2     # a tree was grown
+        if case == "depth_limit":   # the TB sentinel: nobody went right
+            assert (node % 8 == 0).all() and len(np.unique(node)) > 1
+        if case == "vmap_lanes":
+            assert not np.array_equal(node[0], node[1])
+
+    def test_wide_designs_keep_the_gather(self, monkeypatch):
+        import jax
+        from transmogrifai_tpu.models import trees as T
+        assert T._route_form("scatter", 4) == "gather"
+        for base in ("matmul", "pallas", "matmul_bf16", "matmul_chunk"):
+            assert T._route_form(base, T._ROUTE_DENSE_MAX_D) == "dense"
+            assert T._route_form(base, T._ROUTE_DENSE_MAX_D + 1) == "gather"
+        # the rule is read while tracing: the same call takes the other form
+        (packed, feat_of, block_start, thr, onehot), kwargs, _ = \
+            _route_case("identity")
+
+        def grow():     # a new jit each time, so each call traces
+            return jax.jit(lambda stats: T._grow_tree(
+                packed, feat_of, block_start, thr, stats,
+                hist_mode="matmul", **kwargs))(onehot)
+        dense = grow()
+        monkeypatch.setattr(T, "_ROUTE_DENSE_MAX_D", 4)
+        before = T.tree_route_forms()
+        gathered = grow()
+        after = T.tree_route_forms()
+        assert (after["gather"], after["dense"]) \
+            == (before["gather"] + 1, before["dense"])
+        for a, b in zip(dense, gathered):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 class TestPoolPlan:
     """Stratified feature-pool planning edge cases (review findings)."""
 

@@ -41,6 +41,7 @@ device arrays and are safe to call inside ``shard_map``.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import os
@@ -383,7 +384,9 @@ def _hist_mode(n: int = 0, total_bins: int = 0) -> str:
     move near-tie splits, so it is opt-in (TX_TREE_SUB=1) until the
     accuracy audit at scale. The suffix rides the SAME static
     ``hist_mode`` string every jitted entry pins, so toggling it
-    retraces exactly like a base-mode switch.
+    retraces exactly like a base-mode switch. The routing form of a
+    level follows the base mode too (see _route_form): ``scatter``
+    gathers each row's bin, every other mode selects it densely.
 
     TX_TREE_HIST overrides. Decided at trace time (platform only for
     now — the n/total_bins parameters stay in the signature so a
@@ -515,6 +518,74 @@ def _level_histograms(packed: jnp.ndarray, slot: jnp.ndarray,
     return out.reshape(num_slots, total_bins, s_dim)
 
 
+#: widest design (columns) the ``matmul`` family still routes densely
+#: (see _route_form). One level on a v5e, 196,608 rows, 32 slots (builder's
+#: chip run, PR 27, PERF.md section 6): the gather form takes 5.4-6.2 ms
+#: whatever the width (27-31 ns a row), the dense form 0.24 / 0.28 / 1.14 /
+#: 2.18 / 4.26 ms at 100 / 200 / 1,000 / 2,000 / 4,000 columns (0.2 ms +
+#: 1.0 ms per 1,000 columns; 256 slots add 0.3 ms): they meet near 5,600
+#: columns, and at 4,096 the dense form still wins by a quarter
+_ROUTE_DENSE_MAX_D = 4096
+
+#: how many traced ``_grow_tree`` calls took each routing form
+_ROUTE_FORMS = {"dense": 0, "gather": 0}
+
+
+def _route_form(base_mode: str, d: int) -> str:
+    """How a level of ``_grow_tree`` reads each row's split column:
+    "gather" (``packed[rows, bfeat[slot]]``: three per-row gathers, O(n),
+    cheap on a CPU) or "dense" (selects over the slot and the column axis,
+    O(n * (C + d)) elementwise, no gather). Chosen at trace time from the
+    resolved base ``hist_mode`` (``scatter``, the CPU path and the tests'
+    reference, gathers; the ``matmul`` family, the accelerator default,
+    routes densely) and the design's width ``d``. Both forms give the same
+    integers."""
+    if base_mode == "scatter" or d > _ROUTE_DENSE_MAX_D:
+        return "gather"
+    return "dense"
+
+
+def tree_route_forms() -> dict:
+    """Traced ``_grow_tree`` calls so far in this process by routing form,
+    ``{"dense": k, "gather": m}`` (see _route_form): the record of which
+    path the compiled tree programs hold."""
+    return dict(_ROUTE_FORMS)
+
+
+@contextlib.contextmanager
+def _fetch_span():
+    """The ``search.fetch`` span of a fold-grid driver, carrying
+    :func:`tree_route_forms` as the scalar attributes ``route_dense`` and
+    ``route_gather``: read when the span opens (what its profiler
+    annotation keeps) and again when it closes, because a program's first
+    call traces inside the span."""
+    def attrs():
+        return {"route_" + k: v for k, v in tree_route_forms().items()}
+    with _trace.span("search.fetch", **attrs()) as rec:
+        yield
+        if rec is not None:
+            rec["attrs"].update(attrs())
+
+
+def _route_left_dense(packed: jnp.ndarray, slot: jnp.ndarray,
+                      bfeat: jnp.ndarray, best_r: jnp.ndarray
+                      ) -> jnp.ndarray:
+    """``packed[i, bfeat[slot[i]]] <= best_r[slot[i]]`` for every row
+    without a gather: the two per-slot tables (C entries) are selected
+    over the slot axis, the row's bin over the column axis of the resident
+    (n, d) matrix. All integer compares and sums, so the result is exactly
+    the gather's (a float contraction on the chip is a bf16 pass and would
+    round bin indices above 256)."""
+    idt = packed.dtype
+    of_slot = slot[:, None] == jnp.arange(bfeat.shape[0],
+                                          dtype=slot.dtype)[None, :]
+    f_i = jnp.sum(jnp.where(of_slot, bfeat.astype(idt)[None, :], 0), axis=1)
+    b_i = jnp.sum(jnp.where(of_slot, best_r.astype(idt)[None, :], 0), axis=1)
+    cols = jnp.arange(packed.shape[1], dtype=idt)[None, :]
+    return jnp.any((cols == f_i[:, None]) & (packed <= b_i[:, None]),
+                   axis=1)
+
+
 def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                block_start: jnp.ndarray, packed_thr: jnp.ndarray,
                stats: jnp.ndarray, *, depth: int, gain_fn,
@@ -593,6 +664,8 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
             bin_oh = _bin_indicator(packed, TB, stats.dtype, feat_of)
     else:
         bin_oh = None                # scatter / matmul_chunk modes
+    route = _route_form(hist_mode, d)
+    _ROUTE_FORMS[route] += 1
     key = feat_key
     prev_hist = None        # previous level's (C_prev, TB, S) histogram
     prev_identity = False
@@ -727,7 +800,11 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
         # denied split routes everything left via the TB sentinel
         with jax.named_scope("tree.route"):
             best_r = jnp.where(split_ok, best, TB)
-            go_left = packed[jnp.arange(n), bfeat[slot]] <= best_r[slot]
+            if route == "dense":
+                go_left = _route_left_dense(packed, slot, bfeat, best_r)
+            else:
+                go_left = (packed[jnp.arange(n), bfeat[slot]]
+                           <= best_r[slot])
             # within-level index
             node = 2 * node + (1 - go_left.astype(jnp.int32))
     with jax.named_scope("tree.node_sums"):
@@ -1537,7 +1614,7 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
             fn = _gbt_softmax_eval_kernel(statics, spec, mesh)
-            with _trace.span("search.fetch"):
+            with _fetch_span():
                 mm = to_host(fn(
                     jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
                     Xv_j, yv_j, *design[:4], y_j,
@@ -1545,7 +1622,7 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
         fn = _gbt_softmax_fg_kernel(statics, mesh)
-        with _trace.span("search.fetch"):
+        with _fetch_span():
             feats, thrs, leaves, base = fn(
                 jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
                 jax.random.PRNGKey(cand0.seed))
@@ -2255,7 +2332,7 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
             fn = _forest_eval_kernel(statics, spec, mesh)
-            with _trace.span("search.fetch"):
+            with _fetch_span():
                 mm = to_host(fn(
                     jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
                     Xv_j, yv_j, *design, narrow, wide, y_j,
@@ -2263,7 +2340,7 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
         fn = _forest_fg_kernel(statics, mesh)
-        with _trace.span("search.fetch"):
+        with _fetch_span():
             feats, thrs, leaves = fn(
                 jnp.asarray(masks_p), *vecs_j, *design, narrow, wide,
                 y_j, jax.random.PRNGKey(cand0.seed))
@@ -2324,7 +2401,7 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
             fn = _gbt_eval_kernel(statics, spec, mesh)
-            with _trace.span("search.fetch"):
+            with _fetch_span():
                 mm = to_host(fn(
                     jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
                     Xv_j, yv_j, *design[:4], y_j,
@@ -2332,7 +2409,7 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
         fn = _gbt_fg_kernel(statics, mesh)
-        with _trace.span("search.fetch"):
+        with _fetch_span():
             feats, thrs, leaves, base = fn(
                 jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
                 jax.random.PRNGKey(cand0.seed))
