@@ -160,7 +160,14 @@ def test_route_form_follows_hist_mode_and_retraces(monkeypatch):
 
 
 def test_every_scope_is_used_in_trees():
-    source = open(trees.__file__).read()
+    """``trees.SCOPES`` is the one list the scope readers take: every name
+    on it is opened somewhere in the three modules that build the fit and
+    fold-grid programs, and they open no other (ISSUE 28 added the linear
+    family's in ``models/linear.py`` and ``parallel/cv.py``)."""
+    from transmogrifai_tpu.models import linear
+    from transmogrifai_tpu.parallel import cv
+    source = "".join(open(module.__file__).read()
+                     for module in (trees, linear, cv))
     for scope in trees.SCOPES:
         assert f'jax.named_scope("{scope}")' in source
     assert len(set(trees.SCOPES)) == len(trees.SCOPES)

@@ -33,7 +33,6 @@ __all__ = ["LogisticRegression", "LogisticRegressionModel",
            "LinearRegression", "LinearRegressionModel",
            "LinearSVC", "LinearSVCModel"]
 
-
 # ---------------------------------------------------------------------------
 # shared weighted-fit cores
 #
@@ -81,10 +80,11 @@ def _unstandardize_coefs(w: jnp.ndarray, b: jnp.ndarray, mu: jnp.ndarray,
 
 def _prep(X, w, standardize: bool, axis_name):
     n, d = X.shape
-    if standardize:
-        return _weighted_standardize(X, w, axis_name)
-    wsum = jnp.maximum(_psum(jnp.sum(w), axis_name), 1e-12)
-    return X, jnp.zeros(d, X.dtype), jnp.ones(d, X.dtype), wsum
+    with jax.named_scope("lin.standardize"):
+        if standardize:
+            return _weighted_standardize(X, w, axis_name)
+        wsum = jnp.maximum(_psum(jnp.sum(w), axis_name), 1e-12)
+        return X, jnp.zeros(d, X.dtype), jnp.ones(d, X.dtype), wsum
 
 
 def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
@@ -117,16 +117,18 @@ def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
 
     w0 = jnp.zeros(d + 1, Xs.dtype)
     force_fista = solver == "fista" or axis_name is not None
-    if use_l1 or force_fista:
-        mask = jnp.concatenate([jnp.ones(d, Xs.dtype),
-                                jnp.zeros(1, Xs.dtype)])
-        lip = design_lipschitz(Xs, l2, curvature_bound=0.25, w=w,
-                               axis_name=axis_name) + 0.25
-        params = fista_minimize(smooth, l1, w0, lip, max_iter=max_iter * 5,
-                                tol=0.0 if force_fista else 1e-7,
-                                l1_mask=mask, grad_psum_axis=axis_name)
-    else:
-        params = lbfgs_minimize(smooth, w0, max_iter=max_iter)
+    with jax.named_scope("lin.solve"):
+        if use_l1 or force_fista:
+            mask = jnp.concatenate([jnp.ones(d, Xs.dtype),
+                                    jnp.zeros(1, Xs.dtype)])
+            lip = design_lipschitz(Xs, l2, curvature_bound=0.25, w=w,
+                                   axis_name=axis_name) + 0.25
+            params = fista_minimize(smooth, l1, w0, lip,
+                                    max_iter=max_iter * 5,
+                                    tol=0.0 if force_fista else 1e-7,
+                                    l1_mask=mask, grad_psum_axis=axis_name)
+        else:
+            params = lbfgs_minimize(smooth, w0, max_iter=max_iter)
     wv, b = params[:d], jnp.where(fit_intercept, params[d], 0.0)
     return _unstandardize_coefs(wv, b, mu, sigma)
 
@@ -149,9 +151,11 @@ def linear_regression_core(X, y, w, reg, alpha, *, fit_intercept: bool,
     if not use_l1:
         # ridge normal equations on the MXU (reference: MLlib "normal"
         # solver / breeze L-BFGS; one (d,d) psum-reduced solve here)
-        A = (_psum(Xs.T @ (w[:, None] * Xs), axis_name) / wsum
-             + l2 * jnp.eye(d, dtype=Xs.dtype))
-        wv = jnp.linalg.solve(A, _psum(Xs.T @ (w * yc), axis_name) / wsum)
+        with jax.named_scope("lin.solve"):
+            A = (_psum(Xs.T @ (w[:, None] * Xs), axis_name) / wsum
+                 + l2 * jnp.eye(d, dtype=Xs.dtype))
+            wv = jnp.linalg.solve(
+                A, _psum(Xs.T @ (w * yc), axis_name) / wsum)
     else:
         nshards = _psum(jnp.asarray(1.0, Xs.dtype), axis_name)
 
@@ -159,13 +163,15 @@ def linear_regression_core(X, y, w, reg, alpha, *, fit_intercept: bool,
             r = Xs @ wv - yc
             return (jnp.sum(w * r * r) / (2.0 * wsum)
                     + 0.5 * l2 * jnp.sum(wv * wv) / nshards)
-        lip = design_lipschitz(Xs, l2, curvature_bound=1.0, w=w,
-                               axis_name=axis_name) + 1e-3
-        wv = fista_minimize(smooth, l1, jnp.zeros(d, Xs.dtype), lip,
-                            max_iter=max_iter * 5,
-                            tol=0.0 if (solver == "fista"
-                                        or axis_name is not None) else 1e-7,
-                            grad_psum_axis=axis_name)
+        with jax.named_scope("lin.solve"):
+            lip = design_lipschitz(Xs, l2, curvature_bound=1.0, w=w,
+                                   axis_name=axis_name) + 1e-3
+            wv = fista_minimize(smooth, l1, jnp.zeros(d, Xs.dtype), lip,
+                                max_iter=max_iter * 5,
+                                tol=0.0 if (solver == "fista"
+                                            or axis_name is not None)
+                                else 1e-7,
+                                grad_psum_axis=axis_name)
     w_orig = wv / sigma
     b = ybar - w_orig @ mu if fit_intercept else jnp.asarray(0.0, Xs.dtype)
     return w_orig, b
@@ -192,14 +198,16 @@ def linear_svc_core(X, y, w, reg, alpha, *, fit_intercept: bool,
                 + 0.5 * reg * jnp.sum(wv * wv) / nshards)
 
     w0 = jnp.zeros(d + 1, Xs.dtype)
-    if solver == "fista" or axis_name is not None:
-        # squared hinge has phi'' <= 2
-        lip = design_lipschitz(Xs, reg, curvature_bound=2.0, w=w,
-                               axis_name=axis_name) + 2.0
-        params = fista_minimize(loss, 0.0, w0, lip, max_iter=max_iter * 5,
-                                tol=0.0, grad_psum_axis=axis_name)
-    else:
-        params = lbfgs_minimize(loss, w0, max_iter=max_iter)
+    with jax.named_scope("lin.solve"):
+        if solver == "fista" or axis_name is not None:
+            # squared hinge has phi'' <= 2
+            lip = design_lipschitz(Xs, reg, curvature_bound=2.0, w=w,
+                                   axis_name=axis_name) + 2.0
+            params = fista_minimize(loss, 0.0, w0, lip,
+                                    max_iter=max_iter * 5, tol=0.0,
+                                    grad_psum_axis=axis_name)
+        else:
+            params = lbfgs_minimize(loss, w0, max_iter=max_iter)
     wv, b = params[:d], jnp.where(fit_intercept, params[d], 0.0)
     return _unstandardize_coefs(wv, b, mu, sigma)
 

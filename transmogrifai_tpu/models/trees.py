@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import logging
 import os
 import threading
@@ -72,16 +73,20 @@ __all__ = [
     "GBTMulticlassClassifierModel",
 ]
 
-#: every ``jax.named_scope`` of this module (docs/observability.md "Device
-#: time by scope"): the ``tree.*`` phases of one level of ``_grow_tree``, a
-#: boosting round, the validation metric of the ``*_eval_kernel``s, and one
-#: ``fg.<family>`` around each fold-grid program's body. A scope is a path
-#: component of the ``op_name`` of the ops traced under it and exists only
-#: while JAX traces: it adds no operation and changes no program's name.
+#: every ``jax.named_scope`` of the package's fold-grid and fit programs
+#: (docs/observability.md "Device time by scope"): the ``tree.*`` phases of
+#: one level of ``_grow_tree``, a forest tree's bootstrap weights and feature
+#: pool, a boosting round, the validation metric of the ``*_eval_kernel``s
+#: (and of the linear fold-grid programs), one ``fg.<family>`` around each
+#: fold-grid program's body, and the linear cores' ``lin.*``
+#: (``models/linear.py``, ``parallel/cv.py``). The one list of the package:
+#: the benchmark's scope readers take it from this attribute. A scope is a
+#: path component of the ``op_name`` of the ops traced under it and exists
+#: only while JAX traces: it adds no operation and changes no program's name.
 SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
-          "tree.split", "tree.route", "gbt.round", "fg.metric",
-          "fg.gbt", "fg.forest", "fg.gbt_softmax")
-
+          "tree.split", "tree.route", "tree.bootstrap", "tree.pool",
+          "gbt.round", "fg.metric", "fg.gbt", "fg.forest",
+          "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve")
 
 # ---------------------------------------------------------------------------
 # binning — packed variable-width bins
@@ -1061,19 +1066,21 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
 
     def one_tree(tkey):
         pkey, wkey, fkey = jax.random.split(tkey, 3)
-        if bootstrap:
-            w = _row_draw(
-                lambda k, m: jax.random.poisson(k, subsample,
-                                                (m,)).astype(dtype),
-                wkey, n, axis_name, row_total)
-        else:
-            w = jnp.ones((n,), dtype)
-        w = w * mask
-        stats = (onehot * w[:, None] if kind == "cls"
-                 else jnp.stack([w, w * y, w * y * y], axis=1))
+        with jax.named_scope("tree.bootstrap"):
+            if bootstrap:
+                w = _row_draw(
+                    lambda k, m: jax.random.poisson(k, subsample,
+                                                    (m,)).astype(dtype),
+                    wkey, n, axis_name, row_total)
+            else:
+                w = jnp.ones((n,), dtype)
+            w = w * mask
+            stats = (onehot * w[:, None] if kind == "cls"
+                     else jnp.stack([w, w * y, w * y * y], axis=1))
         if pool_cfg is not None:
-            pool, p_sub, fo_sub, bs_sub, thr_sub = _tree_pool(
-                pkey, binned, col_thr, narrow_idx, wide_idx, pool_cfg)
+            with jax.named_scope("tree.pool"):
+                pool, p_sub, fo_sub, bs_sub, thr_sub = _tree_pool(
+                    pkey, binned, col_thr, narrow_idx, wide_idx, pool_cfg)
             feat, thr, leaf_stats, _ = _grow_tree(
                 p_sub, fo_sub, bs_sub, thr_sub, stats, depth=depth,
                 gain_fn=gain_fn, min_info_gain=min_info_gain,
@@ -1347,7 +1354,9 @@ def _forest_fg_kernel(statics: tuple, mesh=None):
             impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
             outer_batch=ob, budget_mb=budget_mb, depth_limit=dl)
 
-    def batched(masks, mi, mg, sr, dl, *rest):
+    # named apart from the boosted programs' ``batched``: the function a
+    # ``jax.jit`` wraps names the program (``jit_forest_batched``)
+    def forest_batched(masks, mi, mg, sr, dl, *rest):
         ob = masks.shape[0]     # candidate lanes share the block budget
         with jax.named_scope("fg.forest"):
             return jax.vmap(functools.partial(one, ob),
@@ -1355,12 +1364,12 @@ def _forest_fg_kernel(statics: tuple, mesh=None):
                             )(masks, mi, mg, sr, dl, *rest)
 
     if mesh is None:
-        return jax.jit(batched)
+        return jax.jit(forest_batched)
     from jax.sharding import PartitionSpec as P
     leaves_spec = (P("models", None, None, None) if kind == "cls"
                    else P("models", None, None))
     return jax.jit(shard_map(
-        batched, mesh=mesh,
+        forest_batched, mesh=mesh,
         in_specs=(P("models", None), P("models"), P("models"),
                   P("models"), P("models")) + (P(),) * 10,
         out_specs=(P("models", None, None), P("models", None, None),
@@ -1444,7 +1453,7 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None):
                                        thrs, leaves, 0.0, Xv[fi])
             return mfn(yv[fi], scores)
 
-    def batched(masks, mi, mg, sr, dl, fi, Xv, yv, *rest):
+    def forest_batched(masks, mi, mg, sr, dl, fi, Xv, yv, *rest):
         ob = masks.shape[0]
         with jax.named_scope("fg.forest"):
             return jax.vmap(functools.partial(one, ob),
@@ -1453,10 +1462,10 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None):
                             )(masks, mi, mg, sr, dl, fi, Xv, yv, *rest)
 
     if mesh is None:
-        return jax.jit(batched)
+        return jax.jit(forest_batched)
     from jax.sharding import PartitionSpec as P
     return jax.jit(shard_map(
-        batched, mesh=mesh,
+        forest_batched, mesh=mesh,
         in_specs=(P("models", None), P("models"), P("models"),
                   P("models"), P("models"), P("models")) + (P(),) * 12,
         out_specs=P("models"), check_vma=False))
@@ -1595,16 +1604,12 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
     F, n = masks.shape
     G = len(grid)
     d = X.shape[1]
-    y_j = jnp.asarray(y)
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    if eval_ctx is not None:
-        Xv_j = jnp.asarray(np.asarray(eval_ctx[0], dtype=np.float64))
-        yv_j = jnp.asarray(np.asarray(eval_ctx[1], dtype=np.float64))
-        spec = eval_ctx[2]
-    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in \
-            _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
-                              _GBT_SKEY):
+    y_j, Xv_j, yv_j, spec, groups = _fold_grid_head(
+        y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
+                                       _GBT_SKEY))
+    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
         with _trace.span("search.design"):
             design, _ = _design_args(X, cand0.max_bins,
                                      edge_rows=edge_rows)
@@ -2249,6 +2254,26 @@ def _candidate_groups(est, grid, masks, mesh, traced_fields, skey_fields):
         yield members, cand0, depth_cap, vecs, masks_p, fidx, count, gk
 
 
+def _fold_grid_head(y, eval_ctx, groups):
+    """What a fold-grid driver does on the host before its first design, under
+    the span ``search.head``: the labels and (with ``eval_ctx``) the stacked
+    validation folds go to the device, and the first candidate group is laid
+    out (the later ones stay lazy: a group's masks are lanes x rows).
+    Returns (y, X_val, y_val on the device, metric spec, the groups); the
+    three validation entries are None without ``eval_ctx``."""
+    with _trace.span("search.head"):
+        y_j = jnp.asarray(y)
+        Xv_j = yv_j = spec = None
+        if eval_ctx is not None:
+            Xv_j = jnp.asarray(np.asarray(eval_ctx[0], dtype=np.float64))
+            yv_j = jnp.asarray(np.asarray(eval_ctx[1], dtype=np.float64))
+            spec = eval_ctx[2]
+        first = next(groups, None)
+        if first is not None:
+            groups = itertools.chain([first], groups)
+        return y_j, Xv_j, yv_j, spec, groups
+
+
 def _scatter_group_metrics(metric_mat, mm, members, F: int, gk: int):
     """Write one group's (padded, fold-major) metric vector back into
     the (F, G) matrix."""
@@ -2305,16 +2330,12 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
     G = len(grid)
     d = X.shape[1]
     k = num_classes(y)
-    y_j = jnp.asarray(y)
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    if eval_ctx is not None:
-        Xv_j = jnp.asarray(np.asarray(eval_ctx[0], dtype=np.float64))
-        yv_j = jnp.asarray(np.asarray(eval_ctx[1], dtype=np.float64))
-        spec = eval_ctx[2]
-    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in \
-            _candidate_groups(est, grid, masks, mesh, _FOREST_TRACED,
-                              _FOREST_STATIC):
+    y_j, Xv_j, yv_j, spec, groups = _fold_grid_head(
+        y, eval_ctx, _candidate_groups(est, grid, masks, mesh,
+                                       _FOREST_TRACED, _FOREST_STATIC))
+    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
         with _trace.span("search.design"):
             design, widths = _design_args(X, cand0.max_bins,
                                           edge_rows=edge_rows)
@@ -2380,18 +2401,14 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
     F, n = masks.shape
     G = len(grid)
     d = X.shape[1]
-    y_j = jnp.asarray(y)
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    if eval_ctx is not None:
-        Xv_j = jnp.asarray(np.asarray(eval_ctx[0], dtype=np.float64))
-        yv_j = jnp.asarray(np.asarray(eval_ctx[1], dtype=np.float64))
-        spec = eval_ctx[2]
     model_cls = (GBTClassifierModel if objective == "logistic"
                  else GBTRegressorModel)
-    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in \
-            _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
-                              _GBT_SKEY):
+    y_j, Xv_j, yv_j, spec, groups = _fold_grid_head(
+        y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
+                                       _GBT_SKEY))
+    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
         with _trace.span("search.design"):
             design, _ = _design_args(X, cand0.max_bins,
                                      edge_rows=edge_rows)

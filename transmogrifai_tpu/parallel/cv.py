@@ -36,6 +36,8 @@ import numpy as np
 from .mesh import to_host
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..observability import trace as _trace
+
 from ..models.linear import (binary_logistic_core, linear_regression_core,
                              linear_svc_core)
 
@@ -176,25 +178,27 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
     Returns (F, G, d+1) parameters, [..., :d] coefficients + [..., d]
     intercept, in the ORIGINAL feature space.
     """
-    X = np.asarray(X, dtype=np.float64)
-    y = np.asarray(y, dtype=np.float64)
-    masks = np.asarray(masks, dtype=np.float64)
-    grid = np.asarray(grid, dtype=np.float64).reshape(-1, 2)
-    F, n = masks.shape
-    G, d = grid.shape[0], X.shape[1]
-    use_l1 = bool(np.any(grid[:, 0] * grid[:, 1] > 0))
-    cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
+    with _trace.span("search.head"):
+        X = np.asarray(X, dtype=np.float64)
+        y = np.asarray(y, dtype=np.float64)
+        masks = np.asarray(masks, dtype=np.float64)
+        grid = np.asarray(grid, dtype=np.float64).reshape(-1, 2)
+        F, n = masks.shape
+        G, d = grid.shape[0], X.shape[1]
+        use_l1 = bool(np.any(grid[:, 0] * grid[:, 1] > 0))
+        cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
 
-    # flatten candidates fold-major: slot f*G + g = (fold f, grid g)
-    regs = np.tile(grid[:, 0], F)
-    alphas = np.tile(grid[:, 1], F)
-    wmat = np.repeat(masks, G, axis=0)            # (F*G, n)
+        # flatten candidates fold-major: slot f*G + g = (fold f, grid g)
+        regs = np.tile(grid[:, 0], F)
+        alphas = np.tile(grid[:, 1], F)
+        wmat = np.repeat(masks, G, axis=0)            # (F*G, n)
 
     if mesh is None:
         fn = _local_kernel(cfg)
-        params = fn(jnp.asarray(wmat), jnp.asarray(regs),
-                    jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
-        return np.asarray(params).reshape(F, G, d + 1)
+        with _trace.span("search.fetch"):
+            params = fn(jnp.asarray(wmat), jnp.asarray(regs),
+                        jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
+            return np.asarray(params).reshape(F, G, d + 1)
 
     m_shards = mesh.shape["models"]
     d_shards = mesh.shape.get("data", 1)
@@ -212,9 +216,10 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
             [wmat, np.zeros((wmat.shape[0], pad_r))], axis=1)
 
     fn = _mesh_kernel(cfg, mesh)
-    params = fn(jnp.asarray(wmat), jnp.asarray(regs),
-                jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
-    return to_host(params)[:FG].reshape(F, G, d + 1)
+    with _trace.span("search.fetch"):
+        params = fn(jnp.asarray(wmat), jnp.asarray(regs),
+                    jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
+        return to_host(params)[:FG].reshape(F, G, d + 1)
 
 
 def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
@@ -240,27 +245,30 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
     spec  : (kind, metric) for evaluators.device_metrics.metric_fn —
             "binary" uses decision margins, "regression" raw values.
     """
-    X = np.asarray(X, dtype=np.float64)
-    y = np.asarray(y, dtype=np.float64)
-    masks = np.asarray(masks, dtype=np.float64)
-    grid = np.asarray(grid, dtype=np.float64).reshape(-1, 2)
-    F, n = masks.shape
-    G, d = grid.shape[0], X.shape[1]
-    use_l1 = bool(np.any(grid[:, 0] * grid[:, 1] > 0))
-    cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
+    with _trace.span("search.head"):
+        X = np.asarray(X, dtype=np.float64)
+        y = np.asarray(y, dtype=np.float64)
+        masks = np.asarray(masks, dtype=np.float64)
+        grid = np.asarray(grid, dtype=np.float64).reshape(-1, 2)
+        F, n = masks.shape
+        G, d = grid.shape[0], X.shape[1]
+        use_l1 = bool(np.any(grid[:, 0] * grid[:, 1] > 0))
+        cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
 
-    regs = np.tile(grid[:, 0], F)
-    alphas = np.tile(grid[:, 1], F)
-    wmat = np.repeat(masks, G, axis=0)            # (F*G, n)
-    fidx = np.repeat(np.arange(F, dtype=np.int32), G)
-    Xv = jnp.asarray(np.asarray(X_val, dtype=np.float64))
-    yv = jnp.asarray(np.asarray(y_val, dtype=np.float64))
+        regs = np.tile(grid[:, 0], F)
+        alphas = np.tile(grid[:, 1], F)
+        wmat = np.repeat(masks, G, axis=0)            # (F*G, n)
+        fidx = np.repeat(np.arange(F, dtype=np.int32), G)
+        Xv = jnp.asarray(np.asarray(X_val, dtype=np.float64))
+        yv = jnp.asarray(np.asarray(y_val, dtype=np.float64))
 
     if mesh is None:
         fn = _local_eval_kernel(cfg, spec)
-        mm = fn(jnp.asarray(wmat), jnp.asarray(regs), jnp.asarray(alphas),
-                jnp.asarray(fidx), jnp.asarray(X), jnp.asarray(y), Xv, yv)
-        return np.asarray(mm).reshape(F, G)
+        with _trace.span("search.fetch"):
+            mm = fn(jnp.asarray(wmat), jnp.asarray(regs),
+                    jnp.asarray(alphas), jnp.asarray(fidx), jnp.asarray(X),
+                    jnp.asarray(y), Xv, yv)
+            return np.asarray(mm).reshape(F, G)
 
     m_shards = mesh.shape["models"]
     d_shards = mesh.shape.get("data", 1)
@@ -278,9 +286,10 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         wmat = np.concatenate(
             [wmat, np.zeros((wmat.shape[0], pad_r))], axis=1)
     fn = _mesh_eval_kernel(cfg, spec, mesh)
-    mm = fn(jnp.asarray(wmat), jnp.asarray(regs), jnp.asarray(alphas),
-            jnp.asarray(fidx), jnp.asarray(X), jnp.asarray(y), Xv, yv)
-    return to_host(mm)[:FG].reshape(F, G)
+    with _trace.span("search.fetch"):
+        mm = fn(jnp.asarray(wmat), jnp.asarray(regs), jnp.asarray(alphas),
+                jnp.asarray(fidx), jnp.asarray(X), jnp.asarray(y), Xv, yv)
+        return to_host(mm)[:FG].reshape(F, G)
 
 
 def _candidate_eval(cfg, spec, params, fi, Xv, yv):
@@ -292,41 +301,52 @@ def _candidate_eval(cfg, spec, params, fi, Xv, yv):
     from ..evaluators.device_metrics import (binary_from_raw_pair,
                                              metric_fn)
     d = Xv.shape[-1]
-    m = Xv[fi] @ params[:d] + params[d]
-    if spec[0] == "binary":
-        if cfg[0] == "svc":
-            scores = (m, (m > 0).astype(m.dtype))
+    with jax.named_scope("fg.metric"):
+        m = Xv[fi] @ params[:d] + params[d]
+        if spec[0] == "binary":
+            if cfg[0] == "svc":
+                scores = (m, (m > 0).astype(m.dtype))
+            else:
+                scores = binary_from_raw_pair(jnp.stack([-m, m], axis=1))
         else:
-            scores = binary_from_raw_pair(jnp.stack([-m, m], axis=1))
-    else:
-        scores = m
-    return metric_fn(*spec)(yv[fi], scores)
+            scores = m
+        return metric_fn(*spec)(yv[fi], scores)
 
+
+# The four programs below are named ``jit_linear_batched`` (the function a
+# ``jax.jit`` wraps names the program), so a profile tells the linear
+# fold-grid programs from the tree families' ``jit_batched`` and
+# ``jit_forest_batched``; their bodies trace under the scope ``fg.linear``.
 
 @functools.lru_cache(maxsize=32)
 def _local_eval_kernel(cfg, spec):
     def one(w, r, a, fi, X_, y_, Xv, yv):
         params = _candidate_fit(cfg, w, r, a, X_, y_)
         return _candidate_eval(cfg, spec, params, fi, Xv, yv)
-    return jax.jit(jax.vmap(
-        one, in_axes=(0, 0, 0, 0, None, None, None, None)))
+
+    def linear_batched(*args):
+        with jax.named_scope("fg.linear"):
+            return jax.vmap(
+                one, in_axes=(0, 0, 0, 0, None, None, None, None))(*args)
+    return jax.jit(linear_batched)
 
 
 @functools.lru_cache(maxsize=32)
 def _mesh_eval_kernel(cfg, spec, mesh):
     data_ax = "data" if "data" in mesh.axis_names else None
 
-    def shard_body(w_loc, r_loc, a_loc, fi_loc, X_loc, y_loc, Xv, yv):
+    def linear_batched(w_loc, r_loc, a_loc, fi_loc, X_loc, y_loc, Xv, yv):
         def one(w, r, a, fi):
             params = _candidate_fit(cfg, w, r, a, X_loc, y_loc,
                                     axis_name=data_ax)
             # params are psum-complete (identical on every data shard),
             # and Xv/yv replicate — the metric is data-axis-invariant
             return _candidate_eval(cfg, spec, params, fi, Xv, yv)
-        return jax.vmap(one)(w_loc, r_loc, a_loc, fi_loc)
+        with jax.named_scope("fg.linear"):
+            return jax.vmap(one)(w_loc, r_loc, a_loc, fi_loc)
 
     return jax.jit(shard_map(
-        shard_body, mesh=mesh,
+        linear_batched, mesh=mesh,
         in_specs=(P("models", data_ax), P("models"), P("models"),
                   P("models"), P(data_ax, None), P(data_ax), P(), P()),
         out_specs=P("models"), check_vma=False))
@@ -353,9 +373,12 @@ def _candidate_fit(cfg, w, reg, alpha, X_, y_, axis_name=None):
 
 @functools.lru_cache(maxsize=32)
 def _local_kernel(cfg):
-    return jax.jit(jax.vmap(
-        lambda w, r, a, X_, y_: _candidate_fit(cfg, w, r, a, X_, y_),
-        in_axes=(0, 0, 0, None, None)))
+    def linear_batched(*args):
+        with jax.named_scope("fg.linear"):
+            return jax.vmap(
+                lambda w, r, a, X_, y_: _candidate_fit(cfg, w, r, a, X_, y_),
+                in_axes=(0, 0, 0, None, None))(*args)
+    return jax.jit(linear_batched)
 
 
 @functools.lru_cache(maxsize=32)
@@ -364,19 +387,20 @@ def _mesh_kernel(cfg, mesh):
     # unsharded and the fit cores run without a psum axis
     data_ax = "data" if "data" in mesh.axis_names else None
 
-    def shard_body(w_loc, r_loc, a_loc, X_loc, y_loc):
+    def linear_batched(w_loc, r_loc, a_loc, X_loc, y_loc):
         # w_loc: (FG_local, n_local) — vmap candidates, psum row shards
-        return jax.vmap(
-            lambda w, r, a: _candidate_fit(cfg, w, r, a, X_loc, y_loc,
-                                           axis_name=data_ax)
-        )(w_loc, r_loc, a_loc)
+        with jax.named_scope("fg.linear"):
+            return jax.vmap(
+                lambda w, r, a: _candidate_fit(cfg, w, r, a, X_loc, y_loc,
+                                               axis_name=data_ax)
+            )(w_loc, r_loc, a_loc)
 
     # check_vma=False because solver state inits (zeros) are axis-
     # invariant; gradient correctness under it comes from the SHARD-LOCAL
     # objective + explicit grad psum in fista_minimize — autodiff never
     # transposes a collective (silently wrong with vma checking off)
     return jax.jit(shard_map(
-        shard_body, mesh=mesh,
+        linear_batched, mesh=mesh,
         in_specs=(P("models", data_ax), P("models"), P("models"),
                   P(data_ax, None), P(data_ax)),
         out_specs=P("models", None), check_vma=False))

@@ -454,10 +454,7 @@ class _ValidatorBase:
                 rec["calls"] += 1
                 th.name = prev
 
-        # family spans run on pool worker threads where the context-var
-        # stack is empty: parent them explicitly to whatever span was
-        # open at dispatch time (the train root / rung span)
-        span_parent = _trace.current_ref()
+        span_parent = None      # the dispatch span's ref, set below
 
         def run_task(name, key, cands, thunk):
             with _trace.span("search.family", parent=span_parent,
@@ -530,9 +527,19 @@ class _ValidatorBase:
         workers = self._dispatch_workers(len(tasks))
         if deadline is not None:
             workers = min(len(tasks), max(2, workers))
-        if (len(tasks) > 1 and workers > 1 and spec is not None
-                and dispatch_bytes <= async_cap
-                and os.environ.get("TX_ASYNC_FAMILIES", "1") != "0"):
+        threaded = (len(tasks) > 1 and workers > 1 and spec is not None
+                    and dispatch_bytes <= async_cap
+                    and os.environ.get("TX_ASYNC_FAMILIES", "1") != "0")
+        with _trace.span("search.dispatch", families=len(tasks),
+                         workers=workers if threaded else 1,
+                         threaded=int(threaded),
+                         dispatch_bytes=dispatch_bytes):
+            # family spans run on pool worker threads where the
+            # context-var stack is empty: parent them explicitly to the
+            # dispatch span
+            span_parent = _trace.current_ref()
+            if not threaded:
+                return [run_task(*t) for t in tasks]
             from concurrent.futures import ThreadPoolExecutor
             from concurrent.futures import TimeoutError as _FutTimeout
             from concurrent.futures import wait as _fut_wait
@@ -569,7 +576,6 @@ class _ValidatorBase:
             # do not join it — the whole point is not to wait forever
             ex.shutdown(wait=deadline is None)
             return results
-        return [run_task(*t) for t in tasks]
 
     def _device_matrices(self, models, X, y, masks, X_val_st, y_val_st,
                          spec, ctx: Optional[RuntimeContext] = None):
