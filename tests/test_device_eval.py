@@ -297,3 +297,363 @@ class TestGLMDeviceSearch:
         assert np.all(np.isfinite(coefs)), coefs
         seq = est.fit_arrays(X[1:], y[1:])
         np.testing.assert_allclose(coefs, seq.coefficients, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: the fused kernels score the validation rows where the fit put
+# them ("in_fit" form, ``val_rows`` given) and walk the raw validation
+# matrix only for rows foreign to the fitted table ("traverse" form)
+# ---------------------------------------------------------------------------
+
+def _infit_table(family, seed=29):
+    """A noisy table whose values float32 holds exactly (device binning
+    compares float32 with float32; the suite runs float64), wide enough
+    for a pooled forest, with a label of the family's kind."""
+    r = np.random.default_rng(seed)
+    n, d = 360, (24 if family == "forest_pooled" else 10)
+    X = r.normal(size=(n, d))
+    X[:, 4:] = (X[:, 4:] > 0.8)
+    X = X.astype(np.float32).astype(np.float64)
+    z = X[:, 0] - 0.5 * X[:, 1] + X[:, 5] + r.normal(size=n)
+    if family in ("forest_reg", "gbt_reg"):
+        y = z
+    elif family == "gbt_softmax":
+        y = np.digitize(z, [-0.7, 0.7]).astype(np.float64)
+    else:
+        y = (z > 0).astype(np.float64)
+    return X, y
+
+
+def _infit_family(family):
+    """(estimator, grid, evaluator) of a family: depth 12 and a shallower
+    lane, so that under ``TX_TREE_DEPTH=mask`` one program at the cap runs
+    ``_compress_nodes``, the budget mask and a traced depth limit."""
+    from transmogrifai_tpu.models import XGBoostClassifier
+    deep = dict(max_depth=12, max_bins=16)
+    if family in ("forest_cls", "forest_pooled"):
+        est = RandomForestClassifier(
+            num_trees=3, feature_subset_strategy=(
+                "auto" if family == "forest_pooled" else "all"), **deep)
+        grid = [{"min_instances_per_node": 1}, {"max_depth": 3}]
+        return est, grid, BinaryClassificationEvaluator()
+    if family == "forest_reg":
+        est = RandomForestRegressor(num_trees=3,
+                                    feature_subset_strategy="all", **deep)
+        return (est, [{"min_instances_per_node": 1}, {"max_depth": 3}],
+                RegressionEvaluator())
+    grid = [{"min_child_weight": 0.0}, {"max_depth": 3}]
+    if family == "gbt_bin":
+        return (GBTClassifier(num_rounds=3, **deep), grid,
+                BinaryClassificationEvaluator())
+    if family == "gbt_reg":
+        return (GBTRegressor(num_rounds=3, **deep), grid,
+                RegressionEvaluator())
+    assert family == "gbt_softmax"
+    return (XGBoostClassifier(num_round=2, eta=0.3, **deep), grid,
+            MultiClassificationEvaluator())
+
+
+INFIT_FAMILIES = ("forest_cls", "forest_reg", "forest_pooled", "gbt_bin",
+                  "gbt_reg", "gbt_softmax")
+
+
+@pytest.fixture
+def infit_env(monkeypatch):
+    from transmogrifai_tpu.models import trees
+
+    def configure(binning):
+        monkeypatch.setenv("TX_TREE_BINNING", binning)
+        monkeypatch.setenv("TX_TREE_DEPTH", "mask")
+        trees.clear_design_cache()
+    yield configure
+    trees.clear_design_cache()
+
+
+def _fold_arrays(X, y, evaluator):
+    cv = CrossValidation(evaluator, num_folds=3, seed=7, mesh=None)
+    _, masks, _, spec, X_val, y_val, val_rows = cv._build_fold_arrays(X, y)
+    for f in range(3):
+        np.testing.assert_array_equal(X_val[f], X[val_rows[f]])
+        assert not masks[f, val_rows[f]].any()
+    return masks, spec, X_val, y_val, val_rows
+
+
+@pytest.mark.parametrize("binning", ("host", "device"))
+@pytest.mark.parametrize("family", INFIT_FAMILIES)
+def test_in_fit_leaves_are_the_heaps_leaves(infit_env, family, binning):
+    """(a) What the in-fit form reads for the held-out rows is what
+    ``_traverse`` finds for them in the finished heaps: leaf for leaf in
+    every tree of a forest, and in every round of a boosted fit (the
+    margin the fit carries for EVERY row of the table is the walked
+    one)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.models import trees
+    infit_env(binning)
+    X, y = _infit_table(family)
+    est, _, evaluator = _infit_family(family)
+    masks, _, _, _, val_rows = _fold_arrays(X, y, evaluator)
+    mask, va = jnp.asarray(masks[0]), val_rows[0]
+    design, widths = trees._design_args(X, est.max_bins)
+    hist = trees._hist_mode(X.shape[0], int(design[1].shape[0]))
+    key = jax.random.PRNGKey(3)
+    limit = jnp.asarray(12.0)          # traced, as a mask-depth lane's is
+    Xa = jnp.asarray(X)
+
+    def walked(feats, thrs):
+        return np.asarray(jax.vmap(
+            lambda f, t: trees._traverse(Xa, f, t, 12))(feats, thrs))
+
+    if family.startswith("forest"):
+        cls = family != "forest_reg"
+        mf = trees._resolve_max_features(est.feature_subset_strategy,
+                                         X.shape[1], cls)
+        (narrow, wide), pool_cfg, mf = trees._pool_plan(widths, mf)
+        assert (pool_cfg is not None) == (family == "forest_pooled")
+        body = jax.jit(functools.partial(
+            trees._forest_body, kind="cls" if cls else "reg", depth=12,
+            num_classes=2 if cls else 0, num_trees=3, max_features=mf,
+            pool_cfg=pool_cfg, impurity="gini", bootstrap=True,
+            hist_mode=hist))
+        feats, thrs, leaves, leaf = body(
+            *design, narrow, wide, jnp.asarray(y), key, mask, 1.0, 0.0,
+            1.0, depth_limit=limit, val_rows=jnp.asarray(va))
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      walked(feats, thrs)[:, va])
+        assert len(np.unique(np.asarray(leaf))) > 8
+        # and without the rows the body returns what it always did
+        assert len(body(*design, narrow, wide, jnp.asarray(y), key, mask,
+                        1.0, 0.0, 1.0, depth_limit=limit)) == 3
+    elif family == "gbt_softmax":
+        body = jax.jit(functools.partial(
+            trees._gbt_softmax_body, depth=12, num_rounds=2,
+            num_classes=3, hist_mode=hist))
+        feats, thrs, leaves, base, margins = body(
+            *design[:4], jnp.asarray(y), key, mask, 0.3, 1.0, 0.0, 0.0,
+            1.0, depth_limit=limit)
+        want = np.asarray(trees._softmax_margins(
+            feats, thrs, leaves, base, 12, Xa))
+        np.testing.assert_allclose(np.asarray(margins), want, atol=1e-9)
+        feats, thrs = (np.asarray(a).reshape(6, -1) for a in (feats, thrs))
+    else:
+        body = jax.jit(functools.partial(
+            trees._gbt_body, depth=12, num_rounds=3, hist_mode=hist,
+            objective="logistic" if family == "gbt_bin" else "squared"))
+        feats, thrs, leaves, base, margins = body(
+            *design[:4], jnp.asarray(y), key, mask, 0.1, 1.0, 0.0, 0.0,
+            1.0, depth_limit=limit)
+        leaf = walked(feats, thrs)
+        want = np.asarray(base) + np.asarray(leaves)[
+            np.arange(3)[:, None], leaf].sum(axis=0)
+        # every row of the table, the masked ones included
+        np.testing.assert_allclose(np.asarray(margins), want, atol=1e-9)
+    # the trees are deep enough for the compressed levels to have run
+    assert np.isfinite(np.asarray(thrs)[..., 2 ** 9 - 1:]).any()
+
+
+@pytest.mark.parametrize("binning", ("host", "device"))
+@pytest.mark.parametrize("design", ("packed", "pooled"))
+def test_in_fit_leaf_index_is_traverse(infit_env, design, binning):
+    """(a), by index: ``_grow_tree``'s final node of EVERY row, weighted
+    or not, is ``_traverse``'s leaf in the heap it returns: with the slot
+    compression and the budget mask of a depth-12 tree, on a pooled
+    sub-design (``feat_map``) as on the packed one."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.models import trees
+    infit_env(binning)
+    X, y = _infit_table("forest_pooled")
+    masks, _, _, _, val_rows = _fold_arrays(
+        X, y, BinaryClassificationEvaluator())
+    (packed, feat_of, block_start, packed_thr, binned, col_thr), widths = \
+        trees._design_args(X, 16)
+    stats = jnp.asarray(np.eye(2)[y.astype(int)] * masks[0][:, None])
+    grow = functools.partial(
+        trees._grow_tree, depth=12, gain_fn=trees._gini_gain(1.0),
+        min_info_gain=0.0, hist_mode=trees._hist_mode(*X.shape))
+    if design == "pooled":
+        (narrow, wide), pool_cfg, _ = trees._pool_plan(widths, 5)
+        pool, *sub = trees._tree_pool(jax.random.PRNGKey(1), binned,
+                                      col_thr, narrow, wide, pool_cfg)
+        feat, thr, _, node = jax.jit(grow)(*sub, stats, feat_map=pool)
+    else:
+        feat, thr, _, node = jax.jit(grow)(packed, feat_of, block_start,
+                                           packed_thr, stats)
+    want = trees._traverse(jnp.asarray(X), feat, thr, 12)
+    np.testing.assert_array_equal(np.asarray(node), np.asarray(want))
+    assert len(np.unique(np.asarray(node)[val_rows[0]])) > 8
+    assert np.isfinite(np.asarray(thr)[2 ** 9 - 1:]).any()
+
+
+@pytest.mark.parametrize("binning", ("host", "device"))
+@pytest.mark.parametrize("family", INFIT_FAMILIES)
+def test_val_rows_give_the_same_metric_matrix(infit_env, family, binning):
+    """(b) The (F, G) matrix with ``val_rows`` is the one without: a
+    forest's bit for bit, a boosted family's to 1e-6 (the carried margin
+    is a sequential sum); the validation matrix is not even looked at."""
+    from transmogrifai_tpu.models import trees
+    infit_env(binning)
+    X, y = _infit_table(family)
+    est, grid, evaluator = _infit_family(family)
+    masks, spec, X_val, y_val, val_rows = _fold_arrays(X, y, evaluator)
+    before = trees.tree_eval_forms()
+    walked = est.eval_fold_grid_arrays(X, y, masks, grid, X_val, y_val,
+                                       spec)
+    middle = trees.tree_eval_forms()
+    in_fit = est.eval_fold_grid_arrays(X, y, masks, grid, None, y_val,
+                                       spec, val_rows=val_rows)
+    after = trees.tree_eval_forms()
+    assert walked.shape == (3, len(grid)) and np.isfinite(walked).all()
+    if family.startswith("forest"):
+        np.testing.assert_array_equal(in_fit, walked)
+    else:
+        np.testing.assert_allclose(in_fit, walked, atol=1e-6, rtol=0)
+    # each call traced its own form, if it traced at all (an earlier case
+    # of the same shapes may have left the program behind)
+    assert middle["in_fit"] == before["in_fit"]
+    assert after["traverse"] == middle["traverse"]
+    with pytest.raises(ValueError, match="val_rows"):
+        est.eval_fold_grid_arrays(X, y, masks, grid, X_val, y_val, spec,
+                                  val_rows=val_rows[:, :-1])
+
+
+@pytest.mark.parametrize("family", ("forest_cls", "gbt_bin"))
+def test_racing_mask_needs_explicit_rows(infit_env, family):
+    """(c) A racing rung zeroes training rows that are no validation rows:
+    the rows ``masks == 0`` are then not the fold's validation rows, so the
+    form follows what the caller says, not the mask. Without ``val_rows``
+    the kernel walks ``X_val`` as it always did; with them it gives the
+    same matrix."""
+    from transmogrifai_tpu.models import trees
+    infit_env("host")
+    X, y = _infit_table(family)
+    est, grid, evaluator = _infit_family(family)
+    masks, spec, X_val, y_val, val_rows = _fold_arrays(X, y, evaluator)
+    rung = masks.copy()
+    r = np.random.default_rng(5)
+    for f in range(3):
+        train = np.nonzero(masks[f] > 0)[0]
+        rung[f, r.choice(train, size=len(train) // 2, replace=False)] = 0.0
+    assert ((rung == 0).sum(axis=1) > val_rows.shape[1]).all()
+    before = trees.tree_eval_forms()
+    walked = est.eval_fold_grid_arrays(X, y, rung, grid, X_val, y_val, spec)
+    after = trees.tree_eval_forms()
+    assert after["in_fit"] == before["in_fit"]
+    in_fit = est.eval_fold_grid_arrays(X, y, rung, grid, X_val, y_val,
+                                       spec, val_rows=val_rows)
+    np.testing.assert_allclose(in_fit, walked, atol=1e-6, rtol=0)
+    full = est.eval_fold_grid_arrays(X, y, masks, grid, X_val, y_val, spec)
+    assert np.abs(full - walked).max() > 1e-6      # the rung did thin the fit
+
+
+def test_validate_prepared_still_walks(infit_env):
+    """(c) Workflow-level CV hands over validation rows that are NOT in the
+    fitted table: no ``val_rows``, the "traverse" form, today's matrix
+    (the host evaluator's)."""
+    from transmogrifai_tpu.models import trees
+    infit_env("host")
+    X, y = _infit_table("gbt_bin")
+    idx = np.random.default_rng(0).permutation(len(y))
+    folds = [(X[idx[:240]], y[idx[:240]], X[idx[240:]], y[idx[240:]]),
+             (X[idx[120:]], y[idx[120:]], X[idx[:120]], y[idx[:120]])]
+    pool = [(GBTClassifier(num_rounds=3, max_depth=4, max_bins=16),
+             [{"step_size": 0.1}, {"step_size": 0.3}]),
+            (RandomForestClassifier(num_trees=3, max_depth=4, max_bins=16),
+             [{"min_instances_per_node": 1}])]
+    ev = BinaryClassificationEvaluator()
+    before = trees.tree_eval_forms()
+    dev = CrossValidation(ev, num_folds=2).validate_prepared(pool, folds)
+    after = trees.tree_eval_forms()
+    assert after["in_fit"] == before["in_fit"]
+    assert after["traverse"] >= before["traverse"] + 2
+    host = CrossValidation(_host_only(ev), num_folds=2).validate_prepared(
+        pool, folds)
+    for rd, rh in zip(dev.results, host.results):
+        np.testing.assert_allclose(rd.metric_values, rh.metric_values,
+                                   atol=1e-9)
+
+
+@pytest.mark.parametrize("form", ("in_fit", "traverse"))
+def test_eval_form_is_counted_once_a_kernel_and_in_the_program(
+        infit_env, form):
+    """(d) ``tree_eval_forms()`` counts one form per traced kernel, and the
+    compiled program shows it: the in-fit program has no validation matrix
+    among its parameters (the (F, nv) row indices stand in its place), the
+    walking one has."""
+    from transmogrifai_tpu.models import trees
+    infit_env("host")
+    X, y = _infit_table("gbt_bin", seed=31)
+    X = X[:300, :7]                     # shapes no other case compiles
+    y = y[:300]
+    est = GBTClassifier(num_rounds=2, max_depth=3, max_bins=8)
+    masks, spec, X_val, y_val, val_rows = _fold_arrays(
+        X, y, BinaryClassificationEvaluator())
+    captured = []
+    real = trees._gbt_eval_kernel
+
+    def spy(*key):
+        fn = real(*key)
+
+        def call(*args):
+            captured.append(fn.lower(*args).compile().as_text())
+            return fn(*args)
+        return call
+
+    kwargs = {"val_rows": val_rows} if form == "in_fit" else {}
+    before = trees.tree_eval_forms()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(trees, "_gbt_eval_kernel", spy)
+        est.eval_fold_grid_arrays(X, y, masks, [{"gamma": 0.0}], X_val,
+                                  y_val, spec, **kwargs)
+    after = trees.tree_eval_forms()
+    other = "traverse" if form == "in_fit" else "in_fit"
+    # the spy's lower() and the call itself share one trace cache entry or
+    # trace twice: either way only this form moved
+    assert after[form] > before[form] and after[other] == before[other]
+    (hlo,) = captured
+    entry = hlo[hlo.index("ENTRY"):]
+    params = [ln for ln in entry.splitlines() if " parameter(" in ln]
+    matrix = [ln for ln in params if "f64[3,100,7]" in ln]
+    rows = [ln for ln in params if "s32[3,100]" in ln]
+    assert (len(matrix), len(rows)) == ((0, 1) if form == "in_fit"
+                                        else (1, 0))
+    assert "fg.metric" in hlo
+
+
+def test_search_fetch_span_says_which_eval_form(infit_env):
+    """(d) ``Validator.validate`` names the validation rows, so its tree
+    programs are the in-fit ones, and every ``search.fetch`` span carries
+    the counts next to the routing forms."""
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.observability import trace
+    infit_env("host")
+    X, y = _infit_table("gbt_bin", seed=37)
+    X, y = X[:270, :6], y[:270]
+    pool = [(GBTClassifier(num_rounds=2, max_depth=3, max_bins=8),
+             [{"gamma": 0.0}, {"gamma": 0.1}]),
+            (RandomForestClassifier(num_trees=2, max_depth=3, max_bins=8),
+             [{"min_instances_per_node": 2}]),
+            (LogisticRegression(), [{"reg_param": 0.1}])]
+    before = trees.tree_eval_forms()
+    trace.configure(True)
+    try:
+        CrossValidation(BinaryClassificationEvaluator(), num_folds=3,
+                        seed=3).validate(pool, X, y)
+        spans = [s for s in trace.spans() if s["name"] == "search.fetch"
+                 and "eval_in_fit" in s["attrs"]]
+    finally:
+        trace.configure(False)
+    after = trees.tree_eval_forms()
+    assert after["traverse"] == before["traverse"]
+    assert after["in_fit"] >= before["in_fit"] + 2
+    assert len(spans) == 2                       # the two tree families
+    for s in spans:
+        assert s["attrs"]["eval_in_fit"] >= 1
+        assert s["attrs"]["eval_traverse"] == after["traverse"]
+        assert "route_gather" in s["attrs"]

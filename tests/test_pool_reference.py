@@ -235,7 +235,8 @@ def test_fold_grid_program_matches_fold_by_fold_fits(family):
     evaluator = BinaryClassificationEvaluator()
     cv = CrossValidation(evaluator, num_folds=3, seed=5, stratify=True,
                          mesh=None)
-    splits, masks, fold_data, spec, X_val, y_val = cv._build_fold_arrays(X, y)
+    splits, masks, fold_data, spec, X_val, y_val, _rows = \
+        cv._build_fold_arrays(X, y)
     got = est.eval_fold_grid_arrays(X, y, masks, grid, X_val, y_val, spec)
     assert got.shape == (3, len(grid)) and np.isfinite(got).all()
     for f, (X_tr, y_tr, X_va, y_va) in enumerate(fold_data):

@@ -76,8 +76,8 @@ def fold_grid_hlo():
     captured = {}
     real = trees._gbt_eval_kernel
 
-    def spy(statics, spec, mesh=None):
-        fn = real(statics, spec, mesh)
+    def spy(*key):
+        fn = real(*key)
 
         def call(*args):
             captured["hlo"] = fn.lower(*args).compile().as_text()

@@ -557,15 +557,43 @@ def tree_route_forms() -> dict:
     return dict(_ROUTE_FORMS)
 
 
+#: how many traced fused fit+metric kernels (``*_eval_kernel``) took each
+#: source of the validation rows' leaves (see _eval_form)
+_EVAL_FORMS = {"in_fit": 0, "traverse": 0}
+
+
+def _eval_form(in_fit: bool) -> None:
+    """Count one traced fused fit+metric kernel under the form it holds,
+    i.e. where it finds each validation row's leaf: "in_fit" (the caller
+    named the validation rows' positions in the fitted table,
+    ``val_rows``: ``_grow_tree`` routed them with every other row, so the
+    kernel reads the leaf, or the boosted margin, the fit already holds
+    and no second walk is traced) or "traverse" (the validation rows are
+    foreign to the fitted table: ``_traverse`` walks the raw ``X_val``
+    down the finished heaps). Both forms give the same leaf index (see
+    _candidate_scores)."""
+    _EVAL_FORMS["in_fit" if in_fit else "traverse"] += 1
+
+
+def tree_eval_forms() -> dict:
+    """Traced fused fit+metric tree kernels so far in this process by
+    form, ``{"in_fit": k, "traverse": m}`` (see _eval_form): the record
+    of which path the compiled fold-grid programs hold."""
+    return dict(_EVAL_FORMS)
+
+
 @contextlib.contextmanager
 def _fetch_span():
     """The ``search.fetch`` span of a fold-grid driver, carrying
-    :func:`tree_route_forms` as the scalar attributes ``route_dense`` and
-    ``route_gather``: read when the span opens (what its profiler
+    :func:`tree_route_forms` and :func:`tree_eval_forms` as the scalar
+    attributes ``route_dense`` / ``route_gather`` and ``eval_in_fit`` /
+    ``eval_traverse``: read when the span opens (what its profiler
     annotation keeps) and again when it closes, because a program's first
     call traces inside the span."""
     def attrs():
-        return {"route_" + k: v for k, v in tree_route_forms().items()}
+        out = {"route_" + k: v for k, v in tree_route_forms().items()}
+        out.update(("eval_" + k, v) for k, v in tree_eval_forms().items())
+        return out
     with _trace.span("search.fetch", **attrs()) as rec:
         yield
         if rec is not None:
@@ -1043,7 +1071,7 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
                  row_total: Optional[int] = None,
                  outer_batch: int = 1,
                  budget_mb: Optional[int] = None,
-                 depth_limit=None):
+                 depth_limit=None, val_rows=None):
     """Shared forest program: ``mask`` (n,) row weights let one body
     serve the single fit (mask=ones), the fold x grid batched kernel
     (mask = fold membership, traced per-candidate hyperparams), and the
@@ -1053,7 +1081,16 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
     over that mesh axis (see _grow_tree) and bootstrap draws slice a
     global-shaped sample (_row_draw). Independent trees are fit in
     vmapped blocks (see _tree_block_size); ``outer_batch`` tells the
-    budget how many of these bodies an enclosing vmap runs at once."""
+    budget how many of these bodies an enclosing vmap runs at once.
+
+    ``val_rows`` ((nv,) int32 positions in the fitted table; the fused
+    fit+metric kernel's in-fit form, see _eval_form) adds a fourth
+    output: the leaf ``_grow_tree`` routed each of those rows to in every
+    tree, (T, nv) int32: ``_traverse``'s leaf, without the walk. Without
+    it the body returns (feats, thrs, leaves) and traces what it always
+    did."""
+    assert val_rows is None or axis_name is None, \
+        "val_rows index the whole table: not under row sharding"
     n, d = packed.shape
     dtype = packed_thr.dtype
     if kind == "cls":
@@ -1081,14 +1118,14 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
             with jax.named_scope("tree.pool"):
                 pool, p_sub, fo_sub, bs_sub, thr_sub = _tree_pool(
                     pkey, binned, col_thr, narrow_idx, wide_idx, pool_cfg)
-            feat, thr, leaf_stats, _ = _grow_tree(
+            feat, thr, leaf_stats, node = _grow_tree(
                 p_sub, fo_sub, bs_sub, thr_sub, stats, depth=depth,
                 gain_fn=gain_fn, min_info_gain=min_info_gain,
                 feat_key=fkey, max_features=max_features, feat_map=pool,
                 hist_mode=hist_mode, axis_name=axis_name,
                 row_total=row_total, depth_limit=depth_limit)
         else:
-            feat, thr, leaf_stats, _ = _grow_tree(
+            feat, thr, leaf_stats, node = _grow_tree(
                 packed, feat_of, block_start, packed_thr, stats,
                 depth=depth, gain_fn=gain_fn,
                 min_info_gain=min_info_gain, feat_key=fkey,
@@ -1101,7 +1138,9 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
                              1.0 / num_classes)
         else:
             leaf = leaf_stats[:, 1] / jnp.maximum(leaf_stats[:, 0], 1e-12)
-        return feat, thr, leaf
+        if val_rows is None:
+            return feat, thr, leaf
+        return feat, thr, leaf, node[val_rows]
 
     keys = jax.random.split(key, num_trees)
     # full-design TB is a safe upper bound for the pooled design's
@@ -1118,11 +1157,10 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
         return outs
     pad = (-num_trees) % tb
     keys_p = jnp.concatenate([keys, keys[:pad]], axis=0)
-    _, (feats, thrs, leaves) = jax.lax.scan(
+    _, outs = jax.lax.scan(
         lambda c, kb: (c, jax.vmap(one_tree)(kb)), None,
         keys_p.reshape(-1, tb, *keys.shape[1:]))
-    flat = lambda a: a.reshape((-1,) + a.shape[2:])[:num_trees]
-    return flat(feats), flat(thrs), flat(leaves)
+    return tuple(a.reshape((-1,) + a.shape[2:])[:num_trees] for a in outs)
 
 
 @functools.partial(
@@ -1179,7 +1217,16 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
     """Shared boosting program with row-mask semantics (see
     _forest_body): masked rows get zero grad/hess weight; the base
     margin is the mask-weighted mean. ``axis_name`` row-shards the fit
-    (psum'd histograms/means, global-sliced subsampling)."""
+    (psum'd histograms/means, global-sliced subsampling).
+
+    Returns (feats, thrs, leaves, base, margins): ``margins`` (n,) is the
+    scan's final carry, the finished margin of EVERY row of the table,
+    masked or not (``_grow_tree`` routes them all, and each round adds
+    its leaf value to all of them): what the in-fit form of the fused
+    fit+metric kernel scores the held-out rows from (see _eval_form).
+    It is the sequential float32 sum over rounds, not ``base +
+    sum(vals)``: equal to a few ulp. A caller that drops it compiles
+    what it compiled without it."""
     n, d = packed.shape
     dtype = packed_thr.dtype
     gain_fn = _xgb_gain(reg_lambda, gamma, min_child_weight)
@@ -1221,9 +1268,9 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
                              vals, 0.0)
             margins = margins + vals[node]
         return margins, (feat, thr, vals)
-    _, (feats, thrs, leaves) = jax.lax.scan(
+    margins, (feats, thrs, leaves) = jax.lax.scan(
         one_round, margins0, jax.random.split(key, num_rounds))
-    return feats, thrs, leaves, base
+    return feats, thrs, leaves, base, margins
 
 
 @functools.partial(
@@ -1237,7 +1284,7 @@ def _fit_gbt(packed, feat_of, block_start, packed_thr, y, key, *, depth: int,
                      jnp.ones_like(y), step_size, reg_lambda, gamma,
                      min_child_weight, subsample, depth=depth,
                      num_rounds=num_rounds, objective=objective,
-                     hist_mode=hist_mode)
+                     hist_mode=hist_mode)[:4]
 
 
 def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
@@ -1256,7 +1303,8 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
     fixed margins, so they vmap as one batched program (histogram width
     x K, sequential depth unchanged). Base margins are the log class
     priors. Returns (feats (R,K,H), thrs (R,K,H), leaves (R,K,L),
-    base (K,))."""
+    base (K,), margins (n,K)): the last is the scan's final carry, every
+    row's finished margins (see _gbt_body)."""
     n, d = packed.shape
     dtype = packed_thr.dtype
     gain_fn = _xgb_gain(reg_lambda, gamma, min_child_weight)
@@ -1299,9 +1347,9 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
             margins = margins + delta.T
         return margins, (feats, thrs, vals)
 
-    _, (feats, thrs, leaves) = jax.lax.scan(
+    margins, (feats, thrs, leaves) = jax.lax.scan(
         one_round, margins0, jax.random.split(key, num_rounds))
-    return feats, thrs, leaves, base
+    return feats, thrs, leaves, base, margins
 
 
 @functools.partial(
@@ -1316,7 +1364,7 @@ def _fit_gbt_softmax(packed, feat_of, block_start, packed_thr, y, key, *,
         packed, feat_of, block_start, packed_thr, y, key,
         jnp.ones_like(y), step_size, reg_lambda, gamma, min_child_weight,
         subsample, depth=depth, num_rounds=num_rounds,
-        num_classes=num_classes, hist_mode=hist_mode)
+        num_classes=num_classes, hist_mode=hist_mode)[:4]
 
 
 @functools.partial(jax.jit, static_argnames=("depth",))
@@ -1385,7 +1433,7 @@ def _gbt_fg_kernel(statics: tuple, mesh=None):
         return _gbt_body(packed, feat_of, block_start, packed_thr, y,
                          key, mask, ss, rl, ga, mcw, sub, depth=depth,
                          num_rounds=num_rounds, objective=objective,
-                         hist_mode=hist_mode, depth_limit=dl)
+                         hist_mode=hist_mode, depth_limit=dl)[:4]
 
     def batched(masks, ss, rl, ga, mcw, sub, dl, *rest):
         with jax.named_scope("fg.gbt"):
@@ -1403,23 +1451,46 @@ def _gbt_fg_kernel(statics: tuple, mesh=None):
         check_vma=False))
 
 
+def _gbt_scores(spec_kind, margin):
+    """A boosted candidate's validation scores from its (nv,) margins:
+    the HOST model's exact score transform (evaluators/device_metrics.py
+    host twins), so the device metric ranks candidates identically to
+    the host evaluator."""
+    from ..evaluators.device_metrics import binary_from_sigmoid
+    if spec_kind == "binary":
+        return binary_from_sigmoid(margin)
+    return margin                           # regression values
+
+
 def _candidate_scores(kind, spec_kind, depth, feats, thrs, leaves, base,
-                      Xv):
-    """Validation scores for ONE fitted tree candidate, on device:
-    traversal + leaf gather + tree reduction, then the HOST model's
-    exact score transform (evaluators/device_metrics.py host twins:
-    vote normalization for forests, sigmoid for GBT classifiers) so the
-    device metric ranks candidates identically to the host evaluator."""
-    from ..evaluators.device_metrics import (binary_from_sigmoid,
-                                             binary_from_votes,
+                      Xv, leaf=None):
+    """Validation scores for ONE fitted tree candidate, on device: each
+    validation row's leaf in every tree, leaf gather + tree reduction,
+    then the host model's score transform (_gbt_scores; the forests' vote
+    normalization, evaluators/device_metrics.py host twins).
+
+    The leaf has two sources (see _eval_form). "traverse", ``leaf`` None:
+    the raw validation matrix ``Xv`` (nv, d) is READ here and walked down
+    every finished heap (``_traverse``: three per-row gathers a level);
+    the form for validation rows that are not rows of the fitted table
+    (``validate_prepared``, direct callers). "in_fit", ``leaf`` (T, nv)
+    given (_forest_body ``val_rows``): ``Xv`` is not read; the rows were
+    routed by the fit itself. The same leaf, because a row's bin is the
+    count of its column's edges strictly below ``x``, so ``packed[i, f]
+    <= b`` is ``X[i, f] <= packed_thr[b]`` (exact where table and heap
+    share a dtype: device binning, or host binning under x64; with
+    float64 HOST binning and a float32 heap a value within one float32
+    ulp above an edge bins right and walks left, and the in-fit leaf is
+    the one the training rows got. A NaN at a denied split walks right;
+    in the fit it stays left)."""
+    from ..evaluators.device_metrics import (binary_from_votes,
                                              vote_probability)
-    leaf = jax.vmap(lambda fh, th: _traverse(Xv, fh, th, depth))(feats, thrs)
+    if leaf is None:
+        leaf = jax.vmap(lambda fh, th: _traverse(Xv, fh, th, depth)
+                        )(feats, thrs)
     vals = leaves[jnp.arange(leaves.shape[0])[:, None], leaf]
     if kind == "gbt":
-        margin = base + jnp.sum(vals, axis=0)
-        if spec_kind == "binary":
-            return binary_from_sigmoid(margin)
-        return margin                       # regression values
+        return _gbt_scores(spec_kind, base + jnp.sum(vals, axis=0))
     agg = jnp.mean(vals, axis=0)            # (nv, K) votes or (nv,) values
     if spec_kind == "binary":
         return binary_from_votes(agg)
@@ -1429,37 +1500,46 @@ def _candidate_scores(kind, spec_kind, depth, feats, thrs, leaves, base,
 
 
 @functools.lru_cache(maxsize=32)
-def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None):
+def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
+                        in_fit: bool = False):
     """Fit + validation-metric fusion of _forest_fg_kernel: candidates
     never materialize on host — the program returns one metric scalar
-    per candidate (see evaluators/device_metrics.py for why)."""
+    per candidate (see evaluators/device_metrics.py for why).
+
+    ``val`` is the stacked validation matrix (F, nv, d) in the "traverse"
+    form and, with ``in_fit``, the (F, nv) int32 positions of the
+    validation rows in the fitted table instead (see _eval_form): the
+    program then holds no ``_traverse`` and never sees ``X_val``."""
     (kind, depth, num_classes, num_trees, max_features, pool_cfg,
      impurity, bootstrap, hist_mode, budget_mb) = statics
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
-    def one(ob, mask, mi, mg, sr, dl, fi, Xv, yv, packed, feat_of,
+    def one(ob, mask, mi, mg, sr, dl, fi, val, yv, packed, feat_of,
             block_start, packed_thr, binned, col_thr, narrow, wide, y,
             key):
-        feats, thrs, leaves = _forest_body(
+        feats, thrs, leaves, *in_fit_leaf = _forest_body(
             packed, feat_of, block_start, packed_thr, binned, col_thr,
             narrow, wide, y, key, mask, mi, mg, sr, kind=kind,
             depth=depth, num_classes=num_classes, num_trees=num_trees,
             max_features=max_features, pool_cfg=pool_cfg,
             impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
-            outer_batch=ob, budget_mb=budget_mb, depth_limit=dl)
+            outer_batch=ob, budget_mb=budget_mb, depth_limit=dl,
+            val_rows=val[fi] if in_fit else None)
         with jax.named_scope("fg.metric"):
-            scores = _candidate_scores("forest", spec[0], depth, feats,
-                                       thrs, leaves, 0.0, Xv[fi])
+            scores = _candidate_scores(
+                "forest", spec[0], depth, feats, thrs, leaves, 0.0,
+                None if in_fit else val[fi], *in_fit_leaf)
             return mfn(yv[fi], scores)
 
-    def forest_batched(masks, mi, mg, sr, dl, fi, Xv, yv, *rest):
+    def forest_batched(masks, mi, mg, sr, dl, fi, val, yv, *rest):
         ob = masks.shape[0]
+        _eval_form(in_fit)
         with jax.named_scope("fg.forest"):
             return jax.vmap(functools.partial(one, ob),
                             in_axes=(0, 0, 0, 0, 0, 0, None, None)
                             + (None,) * 10
-                            )(masks, mi, mg, sr, dl, fi, Xv, yv, *rest)
+                            )(masks, mi, mg, sr, dl, fi, val, yv, *rest)
 
     if mesh is None:
         return jax.jit(forest_batched)
@@ -1472,28 +1552,35 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None):
 
 
 @functools.lru_cache(maxsize=32)
-def _gbt_eval_kernel(statics: tuple, spec: tuple, mesh=None):
-    """Fit + validation-metric fusion of _gbt_fg_kernel."""
+def _gbt_eval_kernel(statics: tuple, spec: tuple, mesh=None,
+                     in_fit: bool = False):
+    """Fit + validation-metric fusion of _gbt_fg_kernel; ``val`` and
+    ``in_fit`` as in _forest_eval_kernel (the in-fit form scores the
+    validation rows from the fit's final margins, _gbt_body)."""
     depth, num_rounds, objective, hist_mode = statics
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
-    def one(mask, ss, rl, ga, mcw, sub, dl, fi, Xv, yv, packed, feat_of,
+    def one(mask, ss, rl, ga, mcw, sub, dl, fi, val, yv, packed, feat_of,
             block_start, packed_thr, y, key):
-        feats, thrs, leaves, base = _gbt_body(
+        feats, thrs, leaves, base, margins = _gbt_body(
             packed, feat_of, block_start, packed_thr, y, key, mask, ss,
             rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
             objective=objective, hist_mode=hist_mode, depth_limit=dl)
         with jax.named_scope("fg.metric"):
-            scores = _candidate_scores("gbt", spec[0], depth, feats, thrs,
-                                       leaves, base, Xv[fi])
+            if in_fit:
+                scores = _gbt_scores(spec[0], margins[val[fi]])
+            else:
+                scores = _candidate_scores("gbt", spec[0], depth, feats,
+                                           thrs, leaves, base, val[fi])
             return mfn(yv[fi], scores)
 
-    def batched(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv, *rest):
+    def batched(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv, *rest):
+        _eval_form(in_fit)
         with jax.named_scope("fg.gbt"):
             return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
                             + (None,) * 6
-                            )(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv,
+                            )(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv,
                               *rest)
 
     if mesh is None:
@@ -1517,7 +1604,8 @@ def _gbt_softmax_fg_kernel(statics: tuple, mesh=None):
         return _gbt_softmax_body(
             packed, feat_of, block_start, packed_thr, y, key, mask, ss,
             rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
-            num_classes=num_classes, hist_mode=hist_mode, depth_limit=dl)
+            num_classes=num_classes, hist_mode=hist_mode,
+            depth_limit=dl)[:4]
 
     def batched(masks, ss, rl, ga, mcw, sub, dl, *rest):
         with jax.named_scope("fg.gbt_softmax"):
@@ -1550,30 +1638,36 @@ def _softmax_margins(feats, thrs, leaves, base, depth: int, Xv):
 
 
 @functools.lru_cache(maxsize=32)
-def _gbt_softmax_eval_kernel(statics: tuple, spec: tuple, mesh=None):
+def _gbt_softmax_eval_kernel(statics: tuple, spec: tuple, mesh=None,
+                             in_fit: bool = False):
     """Fit + validation-metric fusion of _gbt_softmax_fg_kernel: the
     multiclass metric consumes softmax probabilities, matching the host
-    ClassifierModel.raw_to_probability ranking exactly."""
+    ClassifierModel.raw_to_probability ranking exactly. ``val`` and
+    ``in_fit`` as in _forest_eval_kernel."""
     depth, num_rounds, num_classes, hist_mode = statics
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
-    def one(mask, ss, rl, ga, mcw, sub, dl, fi, Xv, yv, packed, feat_of,
+    def one(mask, ss, rl, ga, mcw, sub, dl, fi, val, yv, packed, feat_of,
             block_start, packed_thr, y, key):
-        feats, thrs, leaves, base = _gbt_softmax_body(
+        feats, thrs, leaves, base, margins = _gbt_softmax_body(
             packed, feat_of, block_start, packed_thr, y, key, mask, ss,
             rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
             num_classes=num_classes, hist_mode=hist_mode, depth_limit=dl)
         with jax.named_scope("fg.metric"):
-            margins = _softmax_margins(feats, thrs, leaves, base, depth,
-                                       Xv[fi])
+            if in_fit:
+                margins = margins[val[fi]]
+            else:
+                margins = _softmax_margins(feats, thrs, leaves, base,
+                                           depth, val[fi])
             return mfn(yv[fi], jax.nn.softmax(margins, axis=1))
 
-    def batched(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv, *rest):
+    def batched(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv, *rest):
+        _eval_form(in_fit)
         with jax.named_scope("fg.gbt_softmax"):
             return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
                             + (None,) * 6
-                            )(masks, ss, rl, ga, mcw, sub, dl, fi, Xv, yv,
+                            )(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv,
                               *rest)
 
     if mesh is None:
@@ -1606,7 +1700,7 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
     d = X.shape[1]
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    y_j, Xv_j, yv_j, spec, groups = _fold_grid_head(
+    y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
         y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
                                        _GBT_SKEY))
     for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
@@ -1618,11 +1712,11 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
         _note_compile("gbt_softmax", statics, masks_p.shape)
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
-            fn = _gbt_softmax_eval_kernel(statics, spec, mesh)
+            fn = _gbt_softmax_eval_kernel(statics, spec, mesh, in_fit)
             with _fetch_span():
                 mm = to_host(fn(
                     jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                    Xv_j, yv_j, *design[:4], y_j,
+                    val_j, yv_j, *design[:4], y_j,
                     jax.random.PRNGKey(cand0.seed)))[:count]
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
@@ -1695,7 +1789,7 @@ def _gbt_sharded_kernel(statics: tuple, mesh, axis: str):
                          key, mask, ss, rl, ga, mcw, sub, depth=depth,
                          num_rounds=num_rounds, objective=objective,
                          hist_mode=hist_mode, axis_name=axis,
-                         row_total=row_total)
+                         row_total=row_total)[:4]
 
     return jax.jit(shard_map(
         body, mesh=mesh,
@@ -2254,24 +2348,49 @@ def _candidate_groups(est, grid, masks, mesh, traced_fields, skey_fields):
         yield members, cand0, depth_cap, vecs, masks_p, fidx, count, gk
 
 
+def _eval_ctx_parts(eval_ctx):
+    """(X_val, y_val, spec, val_rows) of a fold-grid driver's ``eval_ctx``:
+    ``(X_val (F, nv, d), y_val (F, nv), spec[, val_rows])``. ``val_rows``
+    ((F, nv) positions of each fold's validation rows in the fitted table;
+    absent or None: they are foreign to it) selects the fused kernels' form
+    (see _eval_form): given, ``X_val`` is never read (and may be None);
+    else ``X_val`` is uploaded and walked."""
+    X_val, y_val, spec, *rest = eval_ctx
+    val_rows = rest[0] if rest else None
+    if val_rows is not None:
+        val_rows = np.asarray(val_rows, dtype=np.int32)
+        if val_rows.shape != np.shape(y_val):
+            raise ValueError(
+                f"val_rows {val_rows.shape} must name one row of the "
+                f"fitted table per validation label {np.shape(y_val)}")
+    return X_val, y_val, spec, val_rows
+
+
 def _fold_grid_head(y, eval_ctx, groups):
     """What a fold-grid driver does on the host before its first design, under
     the span ``search.head``: the labels and (with ``eval_ctx``) the stacked
     validation folds go to the device, and the first candidate group is laid
     out (the later ones stay lazy: a group's masks are lanes x rows).
-    Returns (y, X_val, y_val on the device, metric spec, the groups); the
-    three validation entries are None without ``eval_ctx``."""
+    Of the validation folds the labels always go; the matrix ``X_val`` only
+    in the "traverse" form (see _eval_ctx_parts): with ``val_rows`` the
+    (F, nv) int32 row indices go in its place and the matrix is not read.
+    Returns (y, X_val or val_rows, y_val on the device, metric spec, whether
+    the form is in-fit, the groups); the validation entries are None
+    without ``eval_ctx``."""
     with _trace.span("search.head"):
         y_j = jnp.asarray(y)
-        Xv_j = yv_j = spec = None
+        val_j = yv_j = spec = None
+        in_fit = False
         if eval_ctx is not None:
-            Xv_j = jnp.asarray(np.asarray(eval_ctx[0], dtype=np.float64))
-            yv_j = jnp.asarray(np.asarray(eval_ctx[1], dtype=np.float64))
-            spec = eval_ctx[2]
+            X_val, y_val, spec, val_rows = _eval_ctx_parts(eval_ctx)
+            in_fit = val_rows is not None
+            val_j = (jnp.asarray(val_rows) if in_fit else
+                     jnp.asarray(np.asarray(X_val, dtype=np.float64)))
+            yv_j = jnp.asarray(np.asarray(y_val, dtype=np.float64))
         first = next(groups, None)
         if first is not None:
             groups = itertools.chain([first], groups)
-        return y_j, Xv_j, yv_j, spec, groups
+        return y_j, val_j, yv_j, spec, in_fit, groups
 
 
 def _scatter_group_metrics(metric_mat, mm, members, F: int, gk: int):
@@ -2296,8 +2415,12 @@ def _fold_edge_recurse(fold_grid_fn, est, X, y, masks, grid, mesh,
         rows = np.nonzero(masks[f] > 0)[0]
         sub_eval = None
         if eval_ctx is not None:
-            sub_eval = (eval_ctx[0][f:f + 1], eval_ctx[1][f:f + 1],
-                        eval_ctx[2])
+            # one fold of the SAME table a call: its val_rows stay valid
+            X_val, y_val, spec, val_rows = _eval_ctx_parts(eval_ctx)
+            sub_eval = (X_val if X_val is None else X_val[f:f + 1],
+                        y_val[f:f + 1], spec,
+                        val_rows if val_rows is None
+                        else val_rows[f:f + 1])
         outs.append(fold_grid_fn(est, X, y, masks[f:f + 1], grid, mesh,
                                  eval_ctx=sub_eval, edge_rows=rows, **kw))
     if eval_ctx is not None:
@@ -2311,9 +2434,12 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
     per static shape group), optionally sharded over a mesh ``models``
     axis — see the kernel docstrings for the bin-edge deviation.
 
-    With ``eval_ctx = (X_val (F,nv,d), y_val (F,nv), spec)`` the fused
-    fit+metric kernels run instead and the return value is the (F, G)
-    validation-metric matrix — fitted trees never reach the host."""
+    With ``eval_ctx`` (see _eval_ctx_parts: ``(X_val (F,nv,d), y_val
+    (F,nv), spec[, val_rows])``) the fused fit+metric kernels run instead
+    and the return value is the (F, G) validation-metric matrix — fitted
+    trees never reach the host. ``val_rows`` given: only those row indices are
+    read, the validation scores come from the leaves the fit put the rows
+    in; None: ``X_val`` is uploaded and walked down the finished trees."""
     masks = np.asarray(masks, dtype=np.float64)
     if edge_rows is None and _fold_edges_mode():
         return _fold_edge_recurse(
@@ -2332,7 +2458,7 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
     k = num_classes(y)
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    y_j, Xv_j, yv_j, spec, groups = _fold_grid_head(
+    y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
         y, eval_ctx, _candidate_groups(est, grid, masks, mesh,
                                        _FOREST_TRACED, _FOREST_STATIC))
     for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
@@ -2352,11 +2478,11 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
         _note_compile("forest", statics, masks_p.shape)
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
-            fn = _forest_eval_kernel(statics, spec, mesh)
+            fn = _forest_eval_kernel(statics, spec, mesh, in_fit)
             with _fetch_span():
                 mm = to_host(fn(
                     jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                    Xv_j, yv_j, *design, narrow, wide, y_j,
+                    val_j, yv_j, *design, narrow, wide, y_j,
                     jax.random.PRNGKey(cand0.seed)))[:count]
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
@@ -2405,7 +2531,7 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
     metric_mat = np.full((F, G), np.nan)
     model_cls = (GBTClassifierModel if objective == "logistic"
                  else GBTRegressorModel)
-    y_j, Xv_j, yv_j, spec, groups = _fold_grid_head(
+    y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
         y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
                                        _GBT_SKEY))
     for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
@@ -2417,11 +2543,11 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
         _note_compile("gbt", statics, masks_p.shape)
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
-            fn = _gbt_eval_kernel(statics, spec, mesh)
+            fn = _gbt_eval_kernel(statics, spec, mesh, in_fit)
             with _fetch_span():
                 mm = to_host(fn(
                     jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                    Xv_j, yv_j, *design[:4], y_j,
+                    val_j, yv_j, *design[:4], y_j,
                     jax.random.PRNGKey(cand0.seed)))[:count]
             _scatter_group_metrics(metric_mat, mm, members, F, gk)
             continue
@@ -2457,12 +2583,21 @@ class _ForestClassifierBase(Predictor):
         return _forest_fold_grid(self, X, y, masks, grid, mesh, True)
 
     def eval_fold_grid_arrays(self, X, y, masks, grid, X_val, y_val,
-                              spec, mesh=None, cand_idx=None):
+                              spec, mesh=None, cand_idx=None,
+                              val_rows=None):
         """Device-resident search: fused fit + validation metric, (F, G)
         matrix out (see _forest_fold_grid eval_ctx). ``cand_idx``
         (racing rungs) restricts to a candidate subset — traced
         hyperparameters stay dynamic lanes; static groups a rung prunes
-        entirely simply stop being compiled."""
+        entirely simply stop being compiled.
+
+        ``val_rows`` ((F, nv) int, optional): where ``X_val[f]`` is
+        ``X[val_rows[f]]``, rows of the fitted table itself, say so here
+        and the validation matrix is NOT read: each candidate is scored
+        from the leaves its fit already routed those rows to (the same
+        leaves, no second walk; see trees._eval_form). Without it
+        ``X_val`` is read, uploaded and walked down the finished trees —
+        the form for validation rows foreign to ``X``."""
         if spec[0] == "binary" and num_classes(y) != 2:
             raise NotImplementedError(
                 "binary device eval needs binary labels")
@@ -2470,9 +2605,9 @@ class _ForestClassifierBase(Predictor):
             raise NotImplementedError(
                 "forest-classifier device eval needs a classification "
                 "metric")
-        return _forest_fold_grid(self, X, y, masks,
-                                 subset_grid(grid, cand_idx), mesh, True,
-                                 eval_ctx=(X_val, y_val, spec))
+        return _forest_fold_grid(
+            self, X, y, masks, subset_grid(grid, cand_idx), mesh, True,
+            eval_ctx=(X_val, y_val, spec, val_rows))
 
     def fit_arrays_sharded(self, X, y, mesh, axis: str = "data"
                            ) -> TreeEnsembleClassifierModel:
@@ -2542,14 +2677,16 @@ class _ForestRegressorBase(Predictor):
         return _forest_fold_grid(self, X, y, masks, grid, mesh, False)
 
     def eval_fold_grid_arrays(self, X, y, masks, grid, X_val, y_val,
-                              spec, mesh=None, cand_idx=None):
-        """See _ForestClassifierBase.eval_fold_grid_arrays."""
+                              spec, mesh=None, cand_idx=None,
+                              val_rows=None):
+        """See _ForestClassifierBase.eval_fold_grid_arrays (``val_rows``
+        given: ``X_val`` is not read, only those row indices are)."""
         if spec[0] != "regression":
             raise NotImplementedError(
                 "forest-regressor device eval needs a regression metric")
-        return _forest_fold_grid(self, X, y, masks,
-                                 subset_grid(grid, cand_idx), mesh, False,
-                                 eval_ctx=(X_val, y_val, spec))
+        return _forest_fold_grid(
+            self, X, y, masks, subset_grid(grid, cand_idx), mesh, False,
+            eval_ctx=(X_val, y_val, spec, val_rows))
 
     def fit_arrays_sharded(self, X, y, mesh, axis: str = "data"
                            ) -> TreeEnsembleRegressorModel:
@@ -2722,9 +2859,13 @@ class GBTClassifier(Predictor):
         return _gbt_fold_grid(self, X, y, masks, grid, mesh, "logistic")
 
     def eval_fold_grid_arrays(self, X, y, masks, grid, X_val, y_val,
-                              spec, mesh=None, cand_idx=None):
+                              spec, mesh=None, cand_idx=None,
+                              val_rows=None):
         """Device-resident search: fused fit + validation metric, (F, G)
-        matrix out (see _gbt_fold_grid eval_ctx)."""
+        matrix out (see _gbt_fold_grid eval_ctx). ``val_rows`` as in
+        _ForestClassifierBase.eval_fold_grid_arrays: given, ``X_val`` is
+        not read and the held-out rows are scored from the margins the
+        fit already carries for them; without it ``X_val`` is walked."""
         if spec[0] != "binary":
             raise NotImplementedError(
                 "GBT-classifier device eval is binary-only")
@@ -2732,9 +2873,9 @@ class GBTClassifier(Predictor):
         if bad.size:
             raise NotImplementedError(
                 "batched GBT kernel requires binary labels {0, 1}")
-        return _gbt_fold_grid(self, X, y, masks,
-                              subset_grid(grid, cand_idx), mesh,
-                              "logistic", eval_ctx=(X_val, y_val, spec))
+        return _gbt_fold_grid(
+            self, X, y, masks, subset_grid(grid, cand_idx), mesh,
+            "logistic", eval_ctx=(X_val, y_val, spec, val_rows))
 
     def fit_arrays_sharded(self, X, y, mesh, axis: str = "data"
                            ) -> GBTClassifierModel:
@@ -2792,14 +2933,16 @@ class GBTRegressor(Predictor):
         return _gbt_fold_grid(self, X, y, masks, grid, mesh, "squared")
 
     def eval_fold_grid_arrays(self, X, y, masks, grid, X_val, y_val,
-                              spec, mesh=None, cand_idx=None):
-        """See GBTClassifier.eval_fold_grid_arrays."""
+                              spec, mesh=None, cand_idx=None,
+                              val_rows=None):
+        """See GBTClassifier.eval_fold_grid_arrays (``val_rows`` given:
+        ``X_val`` is not read, only those row indices are)."""
         if spec[0] != "regression":
             raise NotImplementedError(
                 "GBT-regressor device eval needs a regression metric")
-        return _gbt_fold_grid(self, X, y, masks,
-                              subset_grid(grid, cand_idx), mesh,
-                              "squared", eval_ctx=(X_val, y_val, spec))
+        return _gbt_fold_grid(
+            self, X, y, masks, subset_grid(grid, cand_idx), mesh,
+            "squared", eval_ctx=(X_val, y_val, spec, val_rows))
 
     def fit_arrays_sharded(self, X, y, mesh, axis: str = "data"
                            ) -> GBTRegressorModel:
@@ -2862,22 +3005,25 @@ class XGBoostClassifier(GBTClassifier):
         return _gbt_softmax_fold_grid(self, X, y, masks, grid, mesh, k)
 
     def eval_fold_grid_arrays(self, X, y, masks, grid, X_val, y_val,
-                              spec, mesh=None, cand_idx=None):
+                              spec, mesh=None, cand_idx=None,
+                              val_rows=None):
         """Device-resident multiclass search: fused softmax fit +
-        metric, (F, G) matrix out (_gbt_softmax_eval_kernel)."""
+        metric, (F, G) matrix out (_gbt_softmax_eval_kernel).
+        ``val_rows`` as in GBTClassifier.eval_fold_grid_arrays: given,
+        ``X_val`` is not read, only those row indices are."""
         k = num_classes(y)
         if k <= 2:
             return GBTClassifier.eval_fold_grid_arrays(
                 self, X, y, masks, grid, X_val, y_val, spec, mesh=mesh,
-                cand_idx=cand_idx)
+                cand_idx=cand_idx, val_rows=val_rows)
         if spec[0] != "multiclass":
             raise NotImplementedError(
                 "softmax-GBT device eval needs a multiclass metric")
         self._check_multiclass_labels(y, k)
         check_fold_classes(y, masks)
-        return _gbt_softmax_fold_grid(self, X, y, masks,
-                                      subset_grid(grid, cand_idx), mesh,
-                                      k, eval_ctx=(X_val, y_val, spec))
+        return _gbt_softmax_fold_grid(
+            self, X, y, masks, subset_grid(grid, cand_idx), mesh, k,
+            eval_ctx=(X_val, y_val, spec, val_rows))
 
     def fit_arrays(self, X: np.ndarray, y: np.ndarray):
         k = num_classes(y)

@@ -179,7 +179,8 @@ class RacingCrossValidation(CrossValidation):
         return budgets
 
     def _eval_rung_cands(self, est, grid, X_r, y_r, rung_masks, Xv_r,
-                         yv_r, spec, alive: Sequence[int], shards: int):
+                         yv_r, spec, alive: Sequence[int], shards: int,
+                         val_rows=None):
         """One family's rung evaluation with the candidate axis padded
         to a multiple of the mesh's ``models`` shard count
         (models/base.pad_cand_idx): rung program SHAPES stay on the
@@ -192,7 +193,8 @@ class RacingCrossValidation(CrossValidation):
         padded, n_valid = pad_cand_idx(alive, shards)
         mm = self._try_device_eval(
             est, grid, X_r, y_r, rung_masks, Xv_r, yv_r, spec,
-            cand_idx=np.asarray(padded, dtype=np.int64))
+            cand_idx=np.asarray(padded, dtype=np.int64),
+            val_rows=val_rows)
         if mm is None:
             return None
         return np.asarray(mm, dtype=np.float64)[:, :n_valid]
@@ -266,7 +268,7 @@ class RacingCrossValidation(CrossValidation):
                  X: np.ndarray, y: np.ndarray) -> BestEstimator:
         t0 = time.perf_counter()
         models = [(est, list(grid) or [{}]) for est, grid in models]
-        _, masks, fold_data, spec, X_val_st, y_val_st = \
+        _, masks, fold_data, spec, X_val_st, y_val_st, val_rows = \
             self._build_fold_arrays(X, y)
         F = masks.shape[0]
         budgets = self._rung_budgets()
@@ -292,13 +294,14 @@ class RacingCrossValidation(CrossValidation):
         try:
             return self._validate_raced(models, X, y, masks, fold_data,
                                         spec, X_val_st, y_val_st,
-                                        budgets, n_total, ctx, t0)
+                                        budgets, n_total, ctx, t0,
+                                        val_rows)
         finally:
             ctx.close_journal()
 
     def _validate_raced(self, models, X, y, masks, fold_data, spec,
-                        X_val_st, y_val_st, budgets, n_total, ctx, t0
-                        ) -> BestEstimator:
+                        X_val_st, y_val_st, budgets, n_total, ctx, t0,
+                        val_rows=None) -> BestEstimator:
         from ..parallel.cv import mesh_model_shards
         shards = mesh_model_shards(self.mesh)
         F = masks.shape[0]
@@ -341,6 +344,11 @@ class RacingCrossValidation(CrossValidation):
                     X_r, y_r = X[kept], y[kept]
                     rung_masks = np.ones((1, len(kept)))
             Xv_r, yv_r = X_val_st[:folds_r], y_val_st[:folds_r]
+            # a rung that fits on X itself (masks edited, rows kept)
+            # still holds every validation row where val_rows says; a
+            # sliced screening table does not
+            vr_r = (val_rows[:folds_r]
+                    if val_rows is not None and X_r is X else None)
             fam_idx: List[Tuple[int, List[int]]] = []
             for fi, (est, grid) in enumerate(models):
                 if fi in host_fams:
@@ -367,7 +375,7 @@ class RacingCrossValidation(CrossValidation):
                     tuple(alive),
                     lambda e=est, g=grid, a=alive: self._eval_rung_cands(
                         e, g, X_r, y_r, rung_masks, Xv_r, yv_r, spec,
-                        a, shards)))
+                        a, shards, vr_r)))
             # one span per racing rung: the family dispatches below
             # parent to it, so a trace shows rung -> family -> compile
             # sections (docs/observability.md)
@@ -435,7 +443,8 @@ class RacingCrossValidation(CrossValidation):
                 continue
             try:
                 mm = self._try_device_eval(est, grid, X, y, masks,
-                                           X_val_st, y_val_st, spec)
+                                           X_val_st, y_val_st, spec,
+                                           val_rows=val_rows)
                 host_results[fi] = (
                     self._results_from_matrix(est, grid, mm)
                     if mm is not None else

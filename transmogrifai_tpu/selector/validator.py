@@ -19,6 +19,7 @@ reference's per-fold / per-family ``Future`` task parallelism maps to:
 """
 from __future__ import annotations
 
+import inspect
 import logging
 import os
 import time
@@ -315,19 +316,29 @@ class _ValidatorBase:
         return hasattr(estimator, "fit_fold_grid_arrays")
 
     def _try_device_eval(self, estimator, grid, X, y, masks,
-                         X_val_st, y_val_st, spec, cand_idx=None):
+                         X_val_st, y_val_st, spec, cand_idx=None,
+                         val_rows=None):
         """(F, G) metric matrix from the family's fused fit+metric
         device kernel, or None to fall through to the host paths.
         This is the device-resident search: candidates' fitted
         parameters never reach the host — only these floats do (the
         winner is refit from scratch by the selector afterwards).
         ``cand_idx`` (racing rungs) evaluates only that candidate
-        subset: the returned matrix is then (F, len(cand_idx))."""
+        subset: the returned matrix is then (F, len(cand_idx)).
+        ``val_rows`` ((F, nv) positions with ``X_val_st[f] ==
+        X[val_rows[f]]``, from _build_fold_arrays; None where the
+        validation rows are not rows of ``X``) goes to the families
+        whose kernel takes it (the tree families score the held-out
+        rows where their fit already put them, models/trees._eval_form);
+        the others are called as ever."""
         if (X_val_st is None or spec is None
                 or not hasattr(estimator, "eval_fold_grid_arrays")
                 or not self._use_batched_kernel(estimator)):
             return None
         kwargs = {} if cand_idx is None else {"cand_idx": cand_idx}
+        if val_rows is not None and "val_rows" in inspect.signature(
+                estimator.eval_fold_grid_arrays).parameters:
+            kwargs["val_rows"] = val_rows
         try:
             return estimator.eval_fold_grid_arrays(
                 X, y, masks, grid, X_val_st, y_val_st, spec,
@@ -357,8 +368,11 @@ class _ValidatorBase:
 
     # -- shared fold/array preparation -------------------------------------
     def _build_fold_arrays(self, X: np.ndarray, y: np.ndarray):
-        """(splits, masks, fold_data, spec, X_val_st, y_val_st) — the
-        arrays every validation strategy (exact and racing) shares.
+        """(splits, masks, fold_data, spec, X_val_st, y_val_st,
+        val_rows) — the arrays every validation strategy (exact and
+        racing) shares. ``val_rows`` (F, nv) int32 stacks the folds'
+        validation indices beside the ``X_val_st`` they select
+        (``X_val_st[f]`` is ``X[val_rows[f]]``), None where that is.
         fold_data is materialized ONCE per search; stable array identity
         also lets the tree family's host-side binning memoize per
         fold. This is also where the search mesh resolves: from here on
@@ -382,11 +396,12 @@ class _ValidatorBase:
         # stacked validation folds for the device-resident fast path
         # (fold sizes are equal by _assignments construction)
         spec = self.evaluator.device_metric_spec()
-        X_val_st = y_val_st = None
+        X_val_st = y_val_st = val_rows = None
         if spec is not None and len({len(va) for _, va in splits}) == 1:
             X_val_st = xp.stack([fd[2] for fd in fold_data])
             y_val_st = np.stack([fd[3] for fd in fold_data])
-        return splits, masks, fold_data, spec, X_val_st, y_val_st
+            val_rows = np.stack([va for _, va in splits]).astype(np.int32)
+        return splits, masks, fold_data, spec, X_val_st, y_val_st, val_rows
 
     def _dispatch_device_evals(self, tasks, X, masks, X_val_st, y_val_st,
                                spec, ctx: Optional[RuntimeContext] = None,
@@ -578,7 +593,8 @@ class _ValidatorBase:
             return results
 
     def _device_matrices(self, models, X, y, masks, X_val_st, y_val_st,
-                         spec, ctx: Optional[RuntimeContext] = None):
+                         spec, ctx: Optional[RuntimeContext] = None,
+                         val_rows=None):
         """Per-family (F, G) device metric matrices (None entries fall
         through to the host paths; ``_QUARANTINED`` entries are out of
         the search)."""
@@ -586,7 +602,8 @@ class _ValidatorBase:
             (type(est).__name__, self._family_key(fi, est),
              tuple(range(len(grid))),
              (lambda e=est, g=grid: self._try_device_eval(
-                 e, g, X, y, masks, X_val_st, y_val_st, spec)))
+                 e, g, X, y, masks, X_val_st, y_val_st, spec,
+                 val_rows=val_rows)))
             for fi, (est, grid) in enumerate(models)]
         return self._dispatch_device_evals(tasks, X, masks, X_val_st,
                                            y_val_st, spec, ctx=ctx)
@@ -698,12 +715,12 @@ class _ValidatorBase:
         models = [(est, list(grid) or [{}]) for est, grid in models]
         ctx = self._begin_runtime(models, X, y)
         try:
-            _, masks, fold_data, spec, X_val_st, y_val_st = \
+            _, masks, fold_data, spec, X_val_st, y_val_st, val_rows = \
                 self._build_fold_arrays(X, y)
             results: List[ValidationResult] = []
             device_mm = self._device_matrices(models, X, y, masks,
                                               X_val_st, y_val_st, spec,
-                                              ctx=ctx)
+                                              ctx=ctx, val_rows=val_rows)
             for fi, ((estimator, grid), mm) in enumerate(
                     zip(models, device_mm)):
                 if mm is _QUARANTINED:
