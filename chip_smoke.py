@@ -7,9 +7,7 @@ Drives the flagship path once through the entry points a user calls:
    ``jax.clear_caches()`` (the persistent compile cache must serve the
    recompiles) on the seeded synthetic Titanic with the whole default binary pool (LR 8, RF 18,
    GBT 18, SVC 4 grid points x 3 folds = 144 models x folds, float32),
-   ``score_and_evaluate`` on the holdout, ``model.save()`` with AOT export,
-   and the Pallas level-histogram kernel compiled for real and compared with
-   the einsum;
+   ``score_and_evaluate`` on the holdout, ``model.save()`` with AOT export;
 2. a SERVER process: ``python -m transmogrifai_tpu.cli serve`` with its
    defaults, a ``{"ready": true}`` barrier, a few UNLABELLED records through
    ``TcpServingClient``, one ``{"metrics": true}``, then SIGTERM with the
@@ -136,49 +134,6 @@ def _peak_hbm(jax) -> list:
     return out
 
 
-def _pallas_check(jax, name: str, n: int, d: int, bins: int, C: int,
-                  S: int, interpret: bool) -> None:
-    """Compile the level-histogram kernel at one shape and compare it
-    with the einsum it replaces, both against a highest-precision
-    reference (on a TPU a float32 matmul is a bf16 pass by default)."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from transmogrifai_tpu.models.pallas_hist import pallas_level_hist
-    from transmogrifai_tpu.models.trees import _bin_indicator
-    TB = d * bins
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(SEED), 3)
-    packed = (jax.random.randint(k1, (n, d), 0, bins, jnp.int32)
-              + jnp.arange(d, dtype=jnp.int32)[None, :] * bins)
-    feat_of = jnp.repeat(jnp.arange(d, dtype=jnp.int32), bins)
-    bin_oh = _bin_indicator(packed, TB, jnp.float32, feat_of)
-    slot = jax.random.randint(k2, (n,), 0, C, jnp.int32)
-    stats = jax.random.normal(k3, (n, S), jnp.float32)
-    t0 = time.perf_counter()
-    got = pallas_level_hist(bin_oh, slot, stats, C, interpret=interpret)
-    got.block_until_ready()
-    first_call_s = time.perf_counter() - t0
-    slot_oh = jax.nn.one_hot(slot, C, dtype=jnp.float32)
-    einsum = jnp.einsum("nc,ns,nb->cbs", slot_oh, stats, bin_oh)
-    exact = jnp.einsum("nc,ns,nb->cbs", slot_oh, stats, bin_oh,
-                       precision=jax.lax.Precision.HIGHEST)
-    scale = float(jnp.max(jnp.abs(exact)))
-    err_pallas = float(jnp.max(jnp.abs(got - exact))) / scale
-    err_einsum = float(jnp.max(jnp.abs(einsum - exact))) / scale
-    row = {"shape": name, "n": n, "TB": TB, "C": C, "S": S,
-           "interpret": interpret,
-           "first_call_seconds": round(first_call_s, 2),
-           "pallas_vs_exact": err_pallas, "einsum_vs_exact": err_einsum,
-           "finite": bool(np.isfinite(np.asarray(got)).all())}
-    say(f"pallas {json.dumps(row)}")
-    check(row["finite"], f"pallas kernel produced non-finite values ({name})")
-    # the kernel must be at least as close to the exact contraction as
-    # the einsum it replaces (floor: float32 summation noise)
-    check(err_pallas <= max(2.0 * err_einsum, 1e-5),
-          f"pallas kernel differs from the einsum at {name}: "
-          f"{err_pallas:.3g} vs einsum {err_einsum:.3g} (relative to max)")
-
-
 def _feature_width(model, pred_name: str) -> int:
     """Width of the feature vector the selected model consumes."""
     stage = next(s for s in model.stages()
@@ -272,7 +227,10 @@ def phase_train(mode: str) -> int:
           f"did not serve the recompiles: cold {cold}, now {restart}")
     mesh = resolve_search_mesh("auto")
     peaks = _peak_hbm(jax)
-    say(f"hist_mode: {_hist_mode()}  depth_mode: {_depth_mode()}  "
+    # the tree kernels' paths as the package's resolvers choose them for
+    # this table (32 bins a column bounds the packed bin count)
+    hist_mode = _hist_mode(len(train), 32 * _feature_width(model, pred_name))
+    say(f"hist_mode: {hist_mode}  depth_mode: {_depth_mode()}  "
         f"search mesh: {None if mesh is None else dict(mesh.shape)}  "
         f"peak HBM bytes per device: {peaks}")
     check(all(b is None or b > 0 for b in peaks),
@@ -355,22 +313,6 @@ def phase_train(mode: str) -> int:
           == 3 * EXPECTED_PREPARE_FIT_FALLBACKS,
           f"prepare_fit_fallbacks = {counters.get('prepare_fit_fallbacks', 0)}"
           f" over three trains, expected 3 x {EXPECTED_PREPARE_FIT_FALLBACKS}")
-
-    # the opt-in Pallas level-histogram kernel, compiled for real
-    if tiny:
-        _pallas_check(jax, "tiny", 256, 4, 8, C=4, S=2, interpret=True)
-    else:
-        # deepest flagship level: c_max = min(2^(12-1), node cap 256)
-        _pallas_check(jax, "flagship", len(train),
-                      _feature_width(model, pred_name), 32, C=256, S=2,
-                      interpret=mode != "chip")
-        if mode == "chip":
-            # one level of the 1M x 100 design (C=32, TB=3200, S=2). The
-            # tiling depends on (C, S, TB) only; rows are cut to 131072
-            # because the (n, TB) float32 indicator is 12.8 GB at 1M rows
-            # and the kernel's TB padding copies it
-            _pallas_check(jax, "1Mx100-level", 131072, 100, 32, C=32, S=2,
-                          interpret=False)
 
     with open(os.path.join(WORK, "expected.json"), "w") as fh:
         json.dump({"device": dev, "pred_name": pred_name, "records": sample,

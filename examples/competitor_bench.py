@@ -5,9 +5,10 @@ Spark + native XGBoost stack, but this build host exposes ONE physical
 core (``nproc`` = 1), so a real multi-core run is impossible here.
 This harness produces the honest substitute: scikit-learn's
 HistGradientBoosting / RandomForest (C/Cython cores, the same
-histogram-tree algorithm class as LightGBM/XGBoost) on the SAME
-synthetic matrix ``examples/scale_bench.py`` measures, pinned to ONE
-thread on every host. Comparing a TPU row against
+histogram-tree algorithm class as LightGBM/XGBoost) on the synthetic
+table of the ``synth100_gbt`` benchmark configuration (BASELINE.json
+config 4: a fifth of the columns standard normal, the rest binary at
+15 %), pinned to ONE thread on every host. Comparing a TPU row against
 ``single_thread_seconds / 32`` bounds a PERFECT-scaling 32-core run of
 the competitor — a denominator that can only flatter the competitor,
 never this framework.
@@ -22,7 +23,20 @@ import os
 import sys
 import time
 
+import numpy as np
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def make_data(rows: int, cols: int, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    n_num = max(cols // 5, 1)
+    X_num = rng.normal(size=(rows, n_num))
+    X_bin = (rng.uniform(size=(rows, cols - n_num)) < 0.15).astype(float)
+    X = np.concatenate([X_num, X_bin], axis=1)
+    logits = X_num[:, 0] + X_bin[:, :3].sum(axis=1) - 0.5
+    y = (logits + rng.logistic(size=rows) * 0.5 > 0).astype(float)
+    return X, y
 
 
 def main() -> None:
@@ -37,17 +51,14 @@ def main() -> None:
     ap.add_argument("--cols", type=int, default=100)
     args = ap.parse_args()
 
-    import numpy as np
     from sklearn.ensemble import (HistGradientBoostingClassifier,
                                   RandomForestClassifier)
-
-    from examples.scale_bench import make_data
 
     X, y = make_data(args.rows, args.cols)
     cores = len(os.sched_getaffinity(0))
 
-    # shape-matched to scale_bench's GBT(20 rounds, d6, 32 bins,
-    # step 0.1) and RF(50 trees, d6, min 10 rows/leaf-split)
+    # shape-matched to the synth100_gbt.fit cell's GBT(20 rounds, d6, 32
+    # bins, step 0.1) and RF(50 trees, d6, min 10 rows/leaf-split)
     for name, est in [
         ("sklearn_histgbt_20iter_d6",
          HistGradientBoostingClassifier(

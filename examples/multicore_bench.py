@@ -11,9 +11,9 @@ number on any machine that has the cores:
 
 It provisions one XLA-CPU device PER CORE (``jax_num_cpu_devices``),
 builds the production ("models", "data") mesh, and runs the SAME
-Titanic default-pool search bench.py measures, so the printed
-models x folds/s is directly comparable to the single-core and TPU
-rows. On a 1-core host it still runs but clearly labels the result
+Titanic default-pool search ``chip_smoke.py`` trains. The printed
+models x folds/s is a CPU figure: it says nothing about the chip (the
+benchmark's cells do, ``PERF.md``). On a 1-core host it still runs but clearly labels the result
 single-core (no false multi-core claim).
 """
 from __future__ import annotations

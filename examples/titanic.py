@@ -280,7 +280,7 @@ if __name__ == "__main__":
         csv_path=sys.argv[1] if len(sys.argv) > 1 else None)
     # the reference helloworld's full story: persist the trained
     # selector model and serve single records from the saved dir
-    # (kept OUT of run() so bench.py wall-clocks stay train+eval only)
+    # (kept OUT of run(), which callers time as train + eval only)
     import tempfile
     path = os.path.join(tempfile.mkdtemp(prefix="titanic_"), "model")
     served = demo_serve(model, path)

@@ -89,6 +89,19 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture
+def retired_tree_switches(monkeypatch):
+    """Every environment variable that used to select a tree-kernel path
+    (ISSUE 30), set to a value that would flip the choice if anything
+    still read it. The resolvers in ``models/trees.py`` work their answer
+    out from the platform and the sizes alone."""
+    for name, value in (("TREE_HIST", "matmul_bf16"), ("TREE_SUB", "1"),
+                        ("TREE_DEPTH", "bogus"), ("TREE_BINNING", "bogus"),
+                        ("TREE_BLOCK_MB", "1"),
+                        ("DEVICE_BIN_MIN_ELEMS", "1")):
+        monkeypatch.setenv("TX_" + name, value)
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running parity tests (TX_RUN_SLOW=1)")

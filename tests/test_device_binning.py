@@ -37,7 +37,7 @@ def _assert_designs_equal(a: _PackedDesign, b: _PackedDesign):
 def test_device_matches_host(monkeypatch):
     X = _data()
     host = _PackedDesign(X, 32)
-    monkeypatch.setenv("TX_TREE_BINNING", "device")
+    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
     dev = _PackedDesign(X, 32)
     _assert_designs_equal(host, dev)
 
@@ -47,7 +47,7 @@ def test_device_matches_host_edge_rows(monkeypatch):
     X = _data()
     edge_rows = np.arange(0, X.shape[0], 2)[:1601]  # m-1 = 1600
     host = _PackedDesign(X, 32, edge_rows=edge_rows)
-    monkeypatch.setenv("TX_TREE_BINNING", "device")
+    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
     dev = _PackedDesign(X, 32, edge_rows=edge_rows)
     _assert_designs_equal(host, dev)
 
@@ -56,19 +56,34 @@ def test_device_digitize_chunked(monkeypatch):
     """Row-chunk padding path: force tiny chunks and a ragged tail."""
     X = _data(n=777)
     host = _PackedDesign(X, 32)
-    monkeypatch.setenv("TX_TREE_BINNING", "device")
+    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
     monkeypatch.setattr(trees, "_HIST_CHUNK_ELEMS", 10_000)
     dev = _PackedDesign(X, 32)
     np.testing.assert_array_equal(np.asarray(host.binned),
                                   np.asarray(dev.binned))
 
 
-def test_auto_mode_stays_host_on_cpu(monkeypatch):
-    """auto must not switch small/CPU fits off the bit-exact path."""
-    monkeypatch.delenv("TX_TREE_BINNING", raising=False)
+def test_cpu_fits_stay_on_the_host():
+    """The rule must not switch small/CPU fits off the bit-exact path."""
     X = _data(n=64)
     d = _PackedDesign(X, 32)
     assert isinstance(d.binned, np.ndarray)
+
+
+@pytest.mark.parametrize("backend, elems, on_device", [
+    ("cpu", 100_000_000, False),
+    ("tpu", trees._DEVICE_BIN_MIN_ELEMS - 1, False),
+    ("tpu", trees._DEVICE_BIN_MIN_ELEMS, True),
+])
+def test_binning_place_follows_backend_and_size(
+        monkeypatch, retired_tree_switches, backend, elems, on_device):
+    """Where a design is binned is worked out from the backend and the
+    edge matrix's size alone; the variables that used to override it
+    (``retired_tree_switches``) are not read."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert trees._DEVICE_BIN_MIN_ELEMS == 4_000_000
+    assert trees._bin_on_device(elems) is on_device
 
 
 def test_device_fit_quality(monkeypatch):
@@ -83,7 +98,7 @@ def test_device_fit_quality(monkeypatch):
     est = GBTClassifier(num_rounds=5, max_depth=3)
     acc_host = float(np.mean(
         est.fit_arrays(X, y).predict_arrays(X).data == y))
-    monkeypatch.setenv("TX_TREE_BINNING", "device")
+    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
     trees._DESIGN_CACHE.clear()
     acc_dev = float(np.mean(
         est.fit_arrays(X, y).predict_arrays(X).data == y))

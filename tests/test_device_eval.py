@@ -326,7 +326,7 @@ def _infit_table(family, seed=29):
 
 def _infit_family(family):
     """(estimator, grid, evaluator) of a family: depth 12 and a shallower
-    lane, so that under ``TX_TREE_DEPTH=mask`` one program at the cap runs
+    lane, so that under the ``mask`` depth mode one program at the cap runs
     ``_compress_nodes``, the budget mask and a traced depth limit."""
     from transmogrifai_tpu.models import XGBoostClassifier
     deep = dict(max_depth=12, max_bins=16)
@@ -362,8 +362,9 @@ def infit_env(monkeypatch):
     from transmogrifai_tpu.models import trees
 
     def configure(binning):
-        monkeypatch.setenv("TX_TREE_BINNING", binning)
-        monkeypatch.setenv("TX_TREE_DEPTH", "mask")
+        monkeypatch.setattr(trees, "_bin_on_device",
+                            lambda elems: binning == "device")
+        monkeypatch.setattr(trees, "_depth_mode", lambda: "mask")
         trees.clear_design_cache()
     yield configure
     trees.clear_design_cache()

@@ -799,40 +799,6 @@ class TestResolveImportableFnNoExec:
         assert resolve_importable_fn(extract) is None
 
 
-class TestHistModeSuffix:
-    def test_bad_suffix_honors_valid_base(self, monkeypatch, caplog):
-        from transmogrifai_tpu.models.trees import _hist_mode
-        monkeypatch.setenv("TX_TREE_HIST", "pallas+sb")   # the typo
-        monkeypatch.delenv("TX_TREE_SUB", raising=False)
-        with caplog.at_level("WARNING"):
-            assert _hist_mode() == "pallas"
-        assert "suffix" in caplog.text
-
-    def test_bad_suffix_still_composes_tx_tree_sub(self, monkeypatch):
-        from transmogrifai_tpu.models.trees import _hist_mode
-        monkeypatch.setenv("TX_TREE_HIST", "matmul+subb")
-        monkeypatch.setenv("TX_TREE_SUB", "1")
-        assert _hist_mode() == "matmul+sub"
-
-    def test_valid_modes_unchanged(self, monkeypatch):
-        from transmogrifai_tpu.models.trees import _hist_mode
-        monkeypatch.setenv("TX_TREE_HIST", "matmul+sub")
-        monkeypatch.delenv("TX_TREE_SUB", raising=False)
-        assert _hist_mode() == "matmul+sub"
-        monkeypatch.setenv("TX_TREE_HIST", "scatter")
-        assert _hist_mode() == "scatter"
-
-    def test_unknown_base_falls_back_with_warning(
-            self, monkeypatch, caplog):
-        from transmogrifai_tpu.models.trees import _hist_mode
-        monkeypatch.setenv("TX_TREE_HIST", "bogus")
-        monkeypatch.delenv("TX_TREE_SUB", raising=False)
-        with caplog.at_level("WARNING"):
-            mode = _hist_mode()
-        assert mode in ("scatter", "matmul")
-        assert "not a recognized" in caplog.text
-
-
 class TestAsyncDispatchGuard:
     def test_counts_stacked_validation_folds_and_masks(self):
         from transmogrifai_tpu.selector.validator import \

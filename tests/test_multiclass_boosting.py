@@ -158,15 +158,16 @@ class TestSoftmaxFoldGrid:
         np.testing.assert_array_equal(ms[1][0].leaves, seq.leaves)
 
     def test_mask_depth_models_match_static(self, monkeypatch):
-        """Softmax lanes under TX_TREE_DEPTH=mask trim back to their own
-        depth bit-exactly (leaf_axis=2 stride)."""
+        """Softmax lanes under the ``mask`` depth mode trim back to their
+        own depth bit-exactly (leaf_axis=2 stride)."""
+        from transmogrifai_tpu.models import trees
         from transmogrifai_tpu.models.trees import XGBoostClassifier
         X, y, masks, _, _ = self._data()
         est = XGBoostClassifier(num_round=3)
         grid = [{"max_depth": 2}, {"max_depth": 4}]
-        monkeypatch.setenv("TX_TREE_DEPTH", "static")
+        monkeypatch.setattr(trees, "_depth_mode", lambda: "static")
         ms = est.fit_fold_grid_arrays(X, y, masks[:1], grid)
-        monkeypatch.setenv("TX_TREE_DEPTH", "mask")
+        monkeypatch.setattr(trees, "_depth_mode", lambda: "mask")
         mk = est.fit_fold_grid_arrays(X, y, masks[:1], grid)
         for gi in range(2):
             np.testing.assert_array_equal(ms[0][gi].feats, mk[0][gi].feats)

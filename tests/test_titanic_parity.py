@@ -1,8 +1,8 @@
 """Titanic AuPR parity (VERDICT r2 item 4): the reference's holdout AuPR
 is 0.8225 (README.md:88, Spark BinaryClassificationModelSelector).
 A reduced LR+GBT pool reproduces the full default search's winner (GBT
-depth 6) in seconds; the full pool's number is recorded by bench.py
-(r3: 0.8333). Asserted loosely here so metric jitter doesn't flake."""
+depth 6) in seconds; the full pool's number is pinned by chip_smoke.py
+(``CPU_REFERENCE_AUPR``). Asserted loosely here so metric jitter doesn't flake."""
 import os
 
 import numpy as np

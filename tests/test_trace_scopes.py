@@ -86,7 +86,7 @@ def fold_grid_hlo():
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(trees, "_gbt_eval_kernel", spy)
-        patch.setenv("TX_TREE_HIST", "matmul")
+        patch.setattr(trees, "_hist_mode", lambda n, tb: "matmul")
         metrics = GBTClassifier(num_rounds=2, max_depth=2, max_bins=8
                                 ).eval_fold_grid_arrays(
             X, y, masks, [{"gamma": 0.0}, {"gamma": 0.1}], X_val, y_val,
@@ -144,8 +144,8 @@ def test_route_form_follows_hist_mode_and_retraces(monkeypatch):
     X = rng.normal(size=(80, 4))
     y = (X[:, 0] > 0).astype(np.float64)
     seen = []
-    for mode in ("scatter", "matmul", "scatter", "pallas"):
-        monkeypatch.setenv("TX_TREE_HIST", mode)
+    for mode in ("scatter", "matmul", "scatter", "matmul_chunk"):
+        monkeypatch.setattr(trees, "_hist_mode", lambda n, tb, m=mode: m)
         before = trees.tree_route_forms()
         GBTClassifier(num_rounds=2, max_depth=2, max_bins=8).fit_arrays(X, y)
         after = trees.tree_route_forms()

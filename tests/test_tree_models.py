@@ -153,28 +153,30 @@ class TestGridSupport:
 
 
 class TestHistogramModes:
-    """scatter / matmul / pallas histogram strategies must produce
-    IDENTICAL trees (models/trees._hist_mode; matmul and pallas ride
-    the MXU on TPU). The mode is threaded as a STATIC jit argument —
-    switching TX_TREE_HIST between fits in one process must retrace,
-    not silently reuse the previous mode's program."""
+    """scatter / matmul / matmul_chunk histogram paths must produce
+    IDENTICAL trees (models/trees._hist_mode chooses among them from the
+    platform and the indicator's size; the matmul pair rides the MXU on
+    TPU). The resolved mode is threaded as a STATIC jit argument — another
+    answer of the resolver between fits in one process must retrace, not
+    silently reuse the previous mode's program."""
 
     def test_modes_agree(self, rng, monkeypatch):
         import numpy as np
+        import transmogrifai_tpu.models.trees as T
         from transmogrifai_tpu.models.trees import (GBTClassifier,
                                                     RandomForestClassifier)
         X = rng.normal(size=(300, 12))
         X[:, 6:] = (X[:, 6:] > 0).astype(float)   # binary block
         y = (X[:, 0] + X[:, 6] > 0.3).astype(float)
         fits = {}
-        for mode in ("scatter", "matmul", "pallas", "matmul_chunk"):
-            monkeypatch.setenv("TX_TREE_HIST", mode)
+        for mode in ("scatter", "matmul", "matmul_chunk"):
+            monkeypatch.setattr(T, "_hist_mode", lambda n, tb, m=mode: m)
             fits[mode] = (
                 GBTClassifier(num_rounds=8, max_depth=4).fit_arrays(X, y),
                 RandomForestClassifier(num_trees=4, max_depth=6,
                                        min_instances_per_node=5
                                        ).fit_arrays(X, y))
-        for other in ("matmul", "pallas", "matmul_chunk"):
+        for other in ("matmul", "matmul_chunk"):
             for a, b in zip(fits["scatter"], fits[other]):
                 np.testing.assert_allclose(a.thrs, b.thrs, rtol=1e-6,
                                            err_msg=other)
@@ -184,20 +186,22 @@ class TestHistogramModes:
                                            err_msg=other)
 
     def test_hist_subtraction_matches_direct(self, rng, monkeypatch):
-        """LightGBM-style histogram subtraction (``+sub`` suffix,
-        models/trees._grow_tree): identity levels build LEFT-child
-        histograms only and derive right = parent - left. On data
-        without exact gain ties the trees are identical to the direct
-        build (ties may legitimately resolve to a different equal-gain
-        split — the documented opt-in caveat)."""
+        """LightGBM-style histogram subtraction (a ``+sub`` suffix on the
+        static ``hist_mode``, which only tests pass: models/trees.
+        _grow_tree): identity levels build LEFT-child histograms only and
+        derive right = parent - left. On data without exact gain ties the
+        trees are identical to the direct build (ties may legitimately
+        resolve to a different equal-gain split — the documented
+        caveat)."""
         import numpy as np
+        import transmogrifai_tpu.models.trees as T
         from transmogrifai_tpu.models.trees import (GBTClassifier,
                                                     RandomForestClassifier)
         X = rng.normal(size=(300, 12))
         y = (X[:, 0] * 2 - X[:, 1] > 0.2).astype(float)
         fits = {}
         for mode in ("scatter", "scatter+sub", "matmul", "matmul+sub"):
-            monkeypatch.setenv("TX_TREE_HIST", mode)
+            monkeypatch.setattr(T, "_hist_mode", lambda n, tb, m=mode: m)
             fits[mode] = (
                 # shallow + few rounds keeps every node large and every
                 # residual strong: tiny nodes / flattened late-round
@@ -224,8 +228,7 @@ class TestHistogramModes:
         hist(node >> 1) - hist_even) up to float reassociation."""
         import jax.numpy as jnp
         import numpy as np
-        from transmogrifai_tpu.models.trees import (_bin_indicator,
-                                                    _design_args,
+        from transmogrifai_tpu.models.trees import (_design_args,
                                                     _level_histograms)
         rng = np.random.default_rng(7)
         X = rng.normal(size=(500, 5))
@@ -243,39 +246,31 @@ class TestHistogramModes:
         sub = jnp.stack([even, prev - even], axis=1).reshape(8, TB, 2)
         np.testing.assert_allclose(np.asarray(full), np.asarray(sub),
                                    atol=1e-10)
-        # the Pallas kernel must tolerate the sentinel slot (== C) the
-        # sub path parks odd rows on: C < C_pad contamination lands in
-        # accumulator rows the [:num_slots] slice discards
-        even_pl = _level_histograms(
-            packed, jnp.where((node & 1) == 0, node >> 1, 8), stats, 4,
-            TB, _bin_indicator(packed, TB, stats.dtype,
-                               jnp.asarray(feat_of)),
-            mode="pallas", feat_of=feat_of)
-        np.testing.assert_allclose(np.asarray(even_pl), np.asarray(even),
-                                   atol=1e-6)
 
     def test_mode_switch_retraces(self, rng, monkeypatch):
-        """Regression test: TX_TREE_HIST used to be read at trace time
+        """Regression test: the mode used to be read at trace time
         only, so the second fit in a process silently reused the first
-        mode's compiled program (making in-process comparisons vacuous)."""
+        mode's compiled program (making in-process comparisons vacuous).
+        The routing form follows the mode, so a retrace shows as one more
+        traced ``_grow_tree`` of the other form."""
         import transmogrifai_tpu.models.trees as T
-        seen = []
-        orig = T._hist_mode
-        monkeypatch.setattr(
-            T, "_hist_mode",
-            lambda n=0, tb=0: seen.append(orig(n, tb)) or seen[-1])
-        X = rng.normal(size=(80, 4))
+        X = rng.normal(size=(83, 4))     # a shape no other test fits
         y = (X[:, 0] > 0).astype(float)
-        monkeypatch.setenv("TX_TREE_HIST", "scatter")
-        T.GBTClassifier(num_rounds=2, max_depth=2).fit_arrays(X, y)
-        monkeypatch.setenv("TX_TREE_HIST", "matmul")
-        T.GBTClassifier(num_rounds=2, max_depth=2).fit_arrays(X, y)
-        assert "scatter" in seen and "matmul" in seen
+        forms = []
+        for mode in ("scatter", "matmul"):
+            monkeypatch.setattr(T, "_hist_mode", lambda n, tb, m=mode: m)
+            before = T.tree_route_forms()
+            T.GBTClassifier(num_rounds=2, max_depth=2).fit_arrays(X, y)
+            after = T.tree_route_forms()
+            forms.append({k: after[k] - before[k] for k in after})
+        assert forms[0]["gather"] > 0 and forms[0]["dense"] == 0
+        assert forms[1]["dense"] > 0 and forms[1]["gather"] == 0
 
     def test_fold_grid_kernel_modes_agree(self, rng, monkeypatch):
         """The batched fold x grid kernels pin the mode into their
         static key too."""
         import numpy as np
+        import transmogrifai_tpu.models.trees as T
         from transmogrifai_tpu.models.trees import GBTClassifier
         X = rng.normal(size=(200, 8))
         y = (X[:, 0] > 0).astype(float)
@@ -284,12 +279,12 @@ class TestHistogramModes:
         masks[1, 100:] = 0.0
         grid = [{"max_depth": 3}, {"max_depth": 3, "step_size": 0.3}]
         outs = {}
-        for mode in ("scatter", "matmul", "pallas"):
-            monkeypatch.setenv("TX_TREE_HIST", mode)
+        for mode in ("scatter", "matmul", "matmul_chunk"):
+            monkeypatch.setattr(T, "_hist_mode", lambda n, tb, m=mode: m)
             models = GBTClassifier(num_rounds=4).fit_fold_grid_arrays(
                 X, y, masks, grid)
             outs[mode] = models
-        for other in ("matmul", "pallas"):
+        for other in ("matmul", "matmul_chunk"):
             for f in range(2):
                 for g in range(2):
                     a, b = outs["scatter"][f][g], outs[other][f][g]
@@ -297,6 +292,27 @@ class TestHistogramModes:
                     np.testing.assert_allclose(a.feats, b.feats)
                     np.testing.assert_allclose(a.leaves, b.leaves,
                                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend, x64, n, total_bins, expected", [
+    ("cpu", False, 10 ** 9, 4096, "scatter"),      # a CPU never contracts
+    ("tpu", False, 1_000_000, 800, "matmul"),      # the 1M-row fit: 2.98 GiB
+    ("tpu", False, 2 ** 18, 4096, "matmul"),       # 4 GiB to the byte
+    ("tpu", False, 2 ** 18 + 1, 4096, "matmul_chunk"),
+    ("tpu", True, 2 ** 17 + 1, 4096, "matmul_chunk"),      # float64 stats
+])
+def test_hist_mode_is_worked_out_from_platform_and_size(
+        monkeypatch, retired_tree_switches, backend, x64, n, total_bins,
+        expected):
+    """``_hist_mode`` owns the choice of histogram path: the platform
+    picks the family, the (n, total_bins) indicator's bytes in the stats
+    dtype pick ``matmul_chunk`` past 4 GiB. The variables that used to
+    override it (``retired_tree_switches`` sets them all) are not read."""
+    import jax
+    import transmogrifai_tpu.models.trees as T
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with jax.enable_x64(x64):
+        assert T._hist_mode(n, total_bins) == expected
 
 
 def _route_case(name):
@@ -386,7 +402,7 @@ class TestRouteForms:
         import jax
         from transmogrifai_tpu.models import trees as T
         assert T._route_form("scatter", 4) == "gather"
-        for base in ("matmul", "pallas", "matmul_bf16", "matmul_chunk"):
+        for base in ("matmul", "matmul_chunk"):
             assert T._route_form(base, T._ROUTE_DENSE_MAX_D) == "dense"
             assert T._route_form(base, T._ROUTE_DENSE_MAX_D + 1) == "gather"
         # the rule is read while tracing: the same call takes the other form
@@ -592,7 +608,8 @@ class TestFoldEdges:
 
 
 class TestDepthMask:
-    """TX_TREE_DEPTH=mask (VERDICT r4 #3): one program per tree family —
+    """The ``mask`` depth mode (VERDICT r4 #3; models/trees._depth_mode,
+    the accelerator's side of the rule): one program per tree family —
     depth becomes a traced per-lane limit at the grid's max depth.
     Metrics must be BIT-identical to the per-depth static programs
     (masked levels deny splits; a denied split routes all rows left)."""
@@ -600,6 +617,7 @@ class TestDepthMask:
     def test_mask_mode_metrics_identical(self, monkeypatch):
         from transmogrifai_tpu.evaluators import \
             BinaryClassificationEvaluator
+        import transmogrifai_tpu.models.trees as T
         from transmogrifai_tpu.models.trees import (
             GBTClassifier, RandomForestClassifier, _forest_fold_grid,
             _gbt_fold_grid)
@@ -617,14 +635,14 @@ class TestDepthMask:
                    for dd in (2, 4) for m in (5, 20)]
         grid_gbt = [{"max_depth": dd} for dd in (2, 4)]
 
-        monkeypatch.setenv("TX_TREE_DEPTH", "static")
+        monkeypatch.setattr(T, "_depth_mode", lambda: "static")
         mm_s_rf = _forest_fold_grid(
             RandomForestClassifier(num_trees=5), X, y, masks, grid_rf,
             None, True, eval_ctx=(Xv, yv, spec))
         mm_s_gbt = _gbt_fold_grid(
             GBTClassifier(num_rounds=3), X, y, masks, grid_gbt, None,
             "logistic", eval_ctx=(Xv, yv, spec))
-        monkeypatch.setenv("TX_TREE_DEPTH", "mask")
+        monkeypatch.setattr(T, "_depth_mode", lambda: "mask")
         mm_m_rf = _forest_fold_grid(
             RandomForestClassifier(num_trees=5), X, y, masks, grid_rf,
             None, True, eval_ctx=(Xv, yv, spec))
@@ -638,6 +656,7 @@ class TestDepthMask:
         """The non-eval (model-materializing) path agrees too: a
         depth-2 lane grown under a depth-4 cap predicts exactly like
         the static depth-2 program."""
+        import transmogrifai_tpu.models.trees as T
         from transmogrifai_tpu.models.trees import (
             RandomForestClassifier, _forest_fold_grid)
         rng = np.random.default_rng(6)
@@ -646,10 +665,10 @@ class TestDepthMask:
         y = (X[:, 0] > 0).astype(float)
         masks = np.ones((1, n))
         grid = [{"max_depth": dd} for dd in (2, 4)]
-        monkeypatch.setenv("TX_TREE_DEPTH", "static")
+        monkeypatch.setattr(T, "_depth_mode", lambda: "static")
         ms = _forest_fold_grid(RandomForestClassifier(num_trees=4),
                                X, y, masks, grid, None, True)
-        monkeypatch.setenv("TX_TREE_DEPTH", "mask")
+        monkeypatch.setattr(T, "_depth_mode", lambda: "mask")
         mk = _forest_fold_grid(RandomForestClassifier(num_trees=4),
                                X, y, masks, grid, None, True)
         Xt = rng.normal(size=(50, 3))
@@ -659,37 +678,8 @@ class TestDepthMask:
             np.testing.assert_array_equal(ps.data, pk.data)
 
 
-class TestBf16Histograms:
-    """TX_TREE_HIST=matmul_bf16 (VERDICT r4 #2): bf16 operands, fp32
-    accumulation — the MXU-native contraction. Indicators are exact in
-    bf16; only per-row stat rounding can flip near-tie splits, so the
-    contract is agreement within tolerance + accuracy parity, not
-    bit-equality."""
-
-    def test_bf16_mode_close_to_exact(self, rng, monkeypatch):
-        from transmogrifai_tpu.models.trees import (GBTClassifier,
-                                                    RandomForestClassifier)
-        X = rng.normal(size=(400, 8))
-        X[:, 4:] = (X[:, 4:] > 0).astype(float)
-        y = (X[:, 0] + X[:, 4] > 0.3).astype(float)
-        fits = {}
-        for mode in ("scatter", "matmul_bf16"):
-            monkeypatch.setenv("TX_TREE_HIST", mode)
-            fits[mode] = (
-                GBTClassifier(num_rounds=8, max_depth=4).fit_arrays(X, y),
-                RandomForestClassifier(num_trees=6, max_depth=5,
-                                       min_instances_per_node=5
-                                       ).fit_arrays(X, y))
-        for a, b in zip(fits["scatter"], fits["matmul_bf16"]):
-            # near-tie splits may differ; the vast majority must agree
-            assert np.mean(a.feats == b.feats) > 0.95
-            acc_a = np.mean(a.predict_arrays(X).data == y)
-            acc_b = np.mean(b.predict_arrays(X).data == y)
-            assert abs(acc_a - acc_b) < 0.02
-
-
 class TestMatmulChunk:
-    """TX_TREE_HIST=matmul_chunk: the MXU contraction with the bin
+    """The ``matmul_chunk`` path: the MXU contraction with the bin
     indicator rebuilt per bin block by gather+compare — exact vs the
     whole-matrix modes even when multiple blocks are forced."""
 
@@ -697,9 +687,9 @@ class TestMatmulChunk:
         import transmogrifai_tpu.models.trees as T
         X = rng.normal(size=(300, 10))
         y = (X[:, 0] + 0.3 * X[:, 1] > 0).astype(float)
-        monkeypatch.setenv("TX_TREE_HIST", "scatter")
+        monkeypatch.setattr(T, "_hist_mode", lambda n, tb: "scatter")
         ref = T.GBTClassifier(num_rounds=6, max_depth=4).fit_arrays(X, y)
-        monkeypatch.setenv("TX_TREE_HIST", "matmul_chunk")
+        monkeypatch.setattr(T, "_hist_mode", lambda n, tb: "matmul_chunk")
         # force many bin blocks: step = max(8, 1000//300) = 8 bins per
         # block -> dozens of blocks over this design's packed bins
         monkeypatch.setattr(T, "_HIST_CHUNK_ELEMS", 1000)

@@ -107,25 +107,39 @@ class TestShardedGBTParity:
 
 class TestVmappedTreeBlocks:
     def test_blocks_equal_scan(self, monkeypatch):
-        """TX_TREE_BLOCK_MB forces the vmapped-block forest path (the
-        accelerator default) on CPU; trees must equal the lax.scan
-        path's (same per-tree keys, independent lanes)."""
+        """The vmapped-block forest path (what _tree_block_size gives an
+        accelerator) against the lax.scan path a CPU takes; trees must
+        be equal (same per-tree keys, independent lanes). The block size
+        is read while tracing, so the jitted fit's cache is dropped on
+        either side of the switch."""
+        from transmogrifai_tpu.models import trees
         X, yc, _ = _data(n=320)
         est = RandomForestClassifier(num_trees=12, max_depth=4, seed=9)
         scan_model = est.fit_arrays(X, yc)
-        monkeypatch.setenv("TX_TREE_BLOCK_MB", "256")
-        block_model = est.fit_arrays(X, yc)
+        monkeypatch.setattr(trees, "_tree_block_size",
+                            lambda *args: 5)        # 12 trees: a ragged tail
+        trees._fit_forest_classifier.clear_cache()
+        try:
+            block_model = est.fit_arrays(X, yc)
+        finally:
+            trees._fit_forest_classifier.clear_cache()
         np.testing.assert_array_equal(block_model.feats,
                                       scan_model.feats)
         np.testing.assert_allclose(block_model.leaves,
                                    scan_model.leaves, atol=1e-12)
 
-    def test_cpu_defaults_to_scan(self):
-        from transmogrifai_tpu.models.trees import (_tree_block_size,
-                                                    _tree_budget_mb)
-        assert _tree_budget_mb() is None
-        assert _tree_block_size(10_000, 500, 6, 2, 50, "matmul",
-                                False) == 1
-        # explicit budget enables blocks regardless of platform
-        assert _tree_block_size(1_000, 100, 4, 2, 50, "matmul", False,
-                                budget_mb=256) > 1
+    @pytest.mark.parametrize("backend, blocks", [("cpu", False),
+                                                 ("tpu", True)])
+    def test_block_size_follows_the_platform(
+            self, monkeypatch, retired_tree_switches, backend, blocks):
+        """A CPU scans tree by tree; an accelerator batches as many trees
+        as the fixed budget holds. No variable overrides either
+        (``retired_tree_switches``)."""
+        import jax
+        from transmogrifai_tpu.models.trees import _tree_block_size
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        size = _tree_block_size(1_000, 100, 4, 2, 50, "matmul", False)
+        assert (size > 1) is blocks
+        if blocks:      # the budget is shared by an enclosing vmap's lanes
+            assert _tree_block_size(1_000, 100, 4, 2, 50, "matmul", False,
+                                    54) < size

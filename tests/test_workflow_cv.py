@@ -247,16 +247,16 @@ def test_insearch_balancing_flips_winner():
 
 
 def test_r5_tree_flags_compose_end_to_end(rng, monkeypatch):
-    """The r5 tree flags — TX_TREE_DEPTH=mask, TX_TREE_EDGES=fold,
-    TX_TREE_SUB=1 — must compose: one end-to-end search with ALL of
-    them on, plus an in-search balancer, still trains, scores and
-    reaches sane quality. Combinations are where flag interactions
-    regress (each flag's own parity is covered by its unit tests)."""
-    from transmogrifai_tpu.models import GBTClassifier
+    """The r5 tree paths — ``mask`` depth, TX_TREE_EDGES=fold, histogram
+    subtraction (``+sub``) — must compose: one end-to-end search with
+    ALL of them on, plus an in-search balancer, still trains, scores and
+    reaches sane quality. Combinations are where path interactions
+    regress (each path's own parity is covered by its unit tests)."""
+    from transmogrifai_tpu.models import GBTClassifier, trees
     from transmogrifai_tpu.selector.splitters import DataBalancer
-    monkeypatch.setenv("TX_TREE_DEPTH", "mask")
+    monkeypatch.setattr(trees, "_depth_mode", lambda: "mask")
     monkeypatch.setenv("TX_TREE_EDGES", "fold")
-    monkeypatch.setenv("TX_TREE_SUB", "1")
+    monkeypatch.setattr(trees, "_hist_mode", lambda n, tb: "scatter+sub")
     recs = []
     for i in range(400):
         y = float(rng.random() < 0.25)

@@ -103,26 +103,24 @@ SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
 # packed axis.
 
 
-#: edge-matrix element count (edge rows x features) above which the
-#: auto binning mode moves quantile binning onto the accelerator: the
-#: per-feature host loop (np.unique + np.quantile + searchsorted, all
-#: f64 sorts) dwarfs the device fit it feeds at 1M x 100
-#: (builder-reported; ROADMAP S4 re-measures it)
-_DEVICE_BIN_MIN_ELEMS = int(os.environ.get("TX_DEVICE_BIN_MIN_ELEMS",
-                                           "4000000"))
+#: edge-matrix element count (edge rows x features) from which quantile
+#: binning runs on the accelerator: the per-feature host loop (np.unique
+#: + np.quantile + searchsorted, all f64 sorts) dwarfs the device fit it
+#: feeds at 1M x 100 (builder-reported; ROADMAP S4 re-measures it)
+_DEVICE_BIN_MIN_ELEMS = 4_000_000
 
 
-def _binning_mode() -> str:
-    """Where quantile bin edges + digitization run: "host" (the exact
-    f64 numpy per-feature loop), "device" (f32 column sorts + quantile
-    gathers + compare-sum digitize, one XLA program set), or "auto"
-    (default): device when an accelerator backend is active and the
-    edge matrix is >= _DEVICE_BIN_MIN_ELEMS elements. The device path
-    deviates from host only in f32 arithmetic (edges can shift ~1 ulp
-    around ties); small fits and CPU runs keep host binning bit-exact.
-    TX_TREE_BINNING overrides."""
-    mode = os.environ.get("TX_TREE_BINNING", "auto")
-    return mode if mode in ("host", "device") else "auto"
+def _bin_on_device(edge_elems: int) -> bool:
+    """Where quantile bin edges + digitization run: on the device (f32
+    column sorts + quantile gathers + compare-sum digitize, one XLA
+    program set) when an accelerator backend is active and the edge
+    matrix holds >= _DEVICE_BIN_MIN_ELEMS elements, else on the host (the
+    exact f64 numpy per-feature loop). The device path deviates from the
+    host's only in f32 arithmetic (edges can shift ~1 ulp around ties);
+    small fits and CPU runs keep host binning bit-exact. Pure in the
+    matrix's shape and the backend."""
+    return (edge_elems >= _DEVICE_BIN_MIN_ELEMS
+            and jax.default_backend() != "cpu")
 
 
 @jax.jit
@@ -185,11 +183,7 @@ class _PackedDesign:
         influence where the splits can fall."""
         n, d = X.shape          # numpy or device array — never download
         e_rows = n if edge_rows is None else len(edge_rows)
-        mode = _binning_mode()
-        use_device = mode == "device" or (
-            mode == "auto" and e_rows * d >= _DEVICE_BIN_MIN_ELEMS
-            and jax.default_backend() != "cpu")
-        if use_device:
+        if _bin_on_device(e_rows * d):
             thr_parts, widths, binned = self._bin_device(
                 X, max_bins, edge_rows)
         else:
@@ -358,67 +352,53 @@ _DEFAULT_NODE_CAP = 256
 _HIST_CHUNK_ELEMS = 32_000_000
 
 
-def _hist_mode(n: int = 0, total_bins: int = 0) -> str:
-    """Histogram strategy: "scatter" (fused segment_sum), "matmul"
-    (one-hot contractions that ride the MXU), or "pallas" (fused VMEM-
-    resident accumulation kernel, models/pallas_hist.py). Auto: matmul
-    on accelerators (XLA scatters serialize there); scatter on CPU —
-    r4 re-measured the flagship search ~10% faster under scatter even
-    at small n*TB, retiring r3's small-problem matmul threshold (the
-    fused eval kernels changed the balance). "matmul_bf16" is the
-    MXU-native variant: both one-hot indicators AND the per-row stats
-    cast to bfloat16, contraction accumulates in float32
-    (preferred_element_type) — 0/1 indicators are exact in bf16, so the
-    only approximation is ~3-decimal-digit rounding of individual
-    grad/hess/count contributions before the fp32 accumulation; split
-    decisions can flip on near-ties, which is why it is opt-in rather
-    than the TPU default until measured. (On a TPU the plain
-    "matmul" einsum is itself a default-precision, i.e. bf16-pass,
-    contraction: chip_smoke.py measures it ~2e-3 of max off the
-    highest-precision result — see PERF.md.)
-    "matmul_chunk" is exact like "matmul" but rebuilds the bin
-    indicator per bin block (gather+compare, scatter-free) every level
-    instead of holding the whole (n, TB) matrix — the big-n mode where
-    that matrix would blow HBM.
-    A ``+sub`` suffix (any base mode) additionally enables LightGBM-
-    style histogram SUBTRACTION inside the level loop: identity levels
-    > 0 build histograms for LEFT children only (half the slots) and
-    derive each right child as parent - left — the parent histogram is
-    the previous level's, and the per-row stats are level-invariant
-    within a tree. Mathematically identical; float cancellation can
-    move near-tie splits, so it is opt-in (TX_TREE_SUB=1) until the
-    accuracy audit at scale. The suffix rides the SAME static
-    ``hist_mode`` string every jitted entry pins, so toggling it
-    retraces exactly like a base-mode switch. The routing form of a
-    level follows the base mode too (see _route_form): ``scatter``
-    gathers each row's bin, every other mode selects it densely.
+#: largest (n, total_bins) bin indicator, in bytes of the stats dtype,
+#: that the ``matmul`` path still holds whole: it is re-read at every
+#: level, and past this it crowds a 16 GB chip's HBM (12.8 GB at 1M x
+#: 3,200 bins in float32). The largest a benchmark cell builds is the
+#: 1M-row fit's, 1,000,000 x 800 x 4 B = 2.98 GiB
+_INDICATOR_MAX_BYTES = 4 * 2 ** 30
 
-    TX_TREE_HIST overrides. Decided at trace time (platform only for
-    now — the n/total_bins parameters stay in the signature so a
-    size-based policy can return without touching every call site), so
-    all modes stay available side by side."""
-    base_modes = ("scatter", "matmul", "pallas", "matmul_bf16",
-                  "matmul_chunk")
-    sub = os.environ.get("TX_TREE_SUB", "0") == "1"
-    mode = os.environ.get("TX_TREE_HIST")
-    if mode:
-        base, plus, suffix = mode.partition("+")
-        if base in base_modes:
-            if plus and suffix != "sub":
-                # a typo'd suffix ("pallas+sb") must not silently throw
-                # away the user's explicit, valid base-mode choice
-                _log.warning(
-                    "TX_TREE_HIST=%r has unrecognized suffix %r "
-                    "(only '+sub' exists); honoring base mode %r",
-                    mode, suffix, base)
-                return base + "+sub" if sub else base
-            # TX_TREE_SUB composes with an explicit base mode too
-            return mode if suffix == "sub" or not sub else mode + "+sub"
-        _log.warning(
-            "TX_TREE_HIST=%r is not a recognized histogram mode %s; "
-            "falling back to the platform default", mode, base_modes)
-    mode = "matmul" if jax.default_backend() != "cpu" else "scatter"
-    return mode + "+sub" if sub else mode
+
+def _hist_mode(n: int, total_bins: int) -> str:
+    """The one owner of the level histograms' path, worked out from what
+    the code can observe (no environment variable, no option):
+
+    - "scatter" on a CPU: fused segment_sum, the tests' reference (r4
+      measured the flagship search ~10% faster under it there even at
+      small n*TB);
+    - "matmul" on an accelerator (XLA scatters serialize there): one-hot
+      contractions on the MXU over the (n, total_bins) bin indicator built
+      once per tree. On a TPU the einsum is a default-precision, i.e.
+      bf16-pass, contraction: chip_smoke.py measures it ~2e-3 of max off
+      the highest-precision result (PERF.md);
+    - "matmul_chunk" on an accelerator where that indicator, in the stats
+      dtype, would pass _INDICATOR_MAX_BYTES: the same exact contraction
+      with the indicator rebuilt per bin block (gather + compare,
+      scatter-free) every level instead of held whole.
+
+    Not returned, but still a value of the static that tests pass:
+    "<mode>+sub", LightGBM-style histogram SUBTRACTION inside the level
+    loop of _grow_tree: identity levels > 0 build histograms for LEFT
+    children only (half the slots) and derive each right child as parent
+    - left (the parent histogram is the previous level's, and the per-row
+    stats are level-invariant within a tree). Mathematically identical;
+    float cancellation can move near-tie splits. One look at the chip
+    (PERF.md section 6, PR 30) read +2.6 % on the one-chip search cell and
+    +0.4 % on the 1M-row fit: making it the accelerator's default is
+    ROADMAP S2 (e), a ``perf_opt`` with a claim.
+
+    ``n`` is the rows one device holds (a row-sharded fit passes its
+    shard's). Decided at trace time; every jitted entry pins the result
+    as its static ``hist_mode``, so another answer retraces. The routing
+    form of a level follows it (see _route_form): ``scatter`` gathers each
+    row's bin, the other two select it densely."""
+    if jax.default_backend() == "cpu":
+        return "scatter"
+    itemsize = np.dtype(jax.dtypes.canonicalize_dtype(float)).itemsize
+    if n * total_bins * itemsize > _INDICATOR_MAX_BYTES:
+        return "matmul_chunk"
+    return "matmul"
 
 
 def _bin_indicator(packed: jnp.ndarray, total_bins: int, dtype,
@@ -454,7 +434,7 @@ def _level_histograms(packed: jnp.ndarray, slot: jnp.ndarray,
     - scatter (bin_oh None): fused segment_sum per feature block
       (segment id = slot*TB + packed bin), blocks bounding the
       broadcasted (n x d_block x S) scatter input to _HIST_CHUNK_ELEMS;
-    - matmul / matmul_bf16 (bin_oh given): hist[c,b,s] =
+    - matmul (bin_oh given): hist[c,b,s] =
       sum_i 1[slot_i=c] * binOH[i,b] * stats[i,s] — S dense
       contractions on the MXU, no per-level scatters. Peak memory is
       the (n, TB) indicator built once per tree;
@@ -462,9 +442,7 @@ def _level_histograms(packed: jnp.ndarray, slot: jnp.ndarray,
       contraction with the indicator REBUILT per bin block by gather +
       compare, bounding the transient to ~_HIST_CHUNK_ELEMS — the
       big-n mode where the whole (n, TB) indicator would blow HBM
-      (12.8 GB at 1M x 3200 in float32);
-    - pallas (bin_oh given): same contraction as one fused Pallas
-      kernel with the accumulator VMEM-resident (models/pallas_hist.py).
+      (12.8 GB at 1M x 3200 in float32).
     """
     n, d = packed.shape
     s_dim = stats.shape[1]
@@ -485,23 +463,8 @@ def _level_histograms(packed: jnp.ndarray, slot: jnp.ndarray,
         hist = jnp.concatenate(parts, axis=1)
         return (jax.lax.psum(hist, axis_name) if axis_name else hist)
     if bin_oh is not None:
-        if mode == "pallas":
-            from transmogrifai_tpu.models.pallas_hist import (
-                pallas_level_hist)
-            hist = pallas_level_hist(bin_oh, slot, stats, num_slots)
-        elif mode == "matmul_bf16":
-            # MXU-native: bf16 operands, fp32 accumulation. bin_oh is
-            # already bf16 (built once per tree); the per-row stats
-            # round to bf16 here — the one approximation of this mode
-            # (see _hist_mode docstring).
-            slot_oh = jax.nn.one_hot(slot, num_slots, dtype=jnp.bfloat16)
-            hist = jnp.einsum(
-                "nc,ns,nb->cbs", slot_oh, stats.astype(jnp.bfloat16),
-                bin_oh, preferred_element_type=jnp.float32
-            ).astype(stats.dtype)
-        else:
-            slot_oh = jax.nn.one_hot(slot, num_slots, dtype=stats.dtype)
-            hist = jnp.einsum("nc,ns,nb->cbs", slot_oh, stats, bin_oh)
+        slot_oh = jax.nn.one_hot(slot, num_slots, dtype=stats.dtype)
+        hist = jnp.einsum("nc,ns,nb->cbs", slot_oh, stats, bin_oh)
         # histograms are linear in rows: the data-parallel reduction is
         # one psum over ICI — the Rabit-allreduce role (SURVEY §2.9)
         return (jax.lax.psum(hist, axis_name) if axis_name else hist)
@@ -637,7 +600,7 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     ``depth_limit`` (optional TRACED scalar <= depth) truncates growth:
     levels >= depth_limit are denied splits, so one compiled program at
     the grid's max depth serves every depth candidate as a vmapped lane
-    (TX_TREE_DEPTH=mask — the compile-count reduction; a denied split
+    (the ``mask`` depth mode — the compile-count reduction; a denied split
     routes all rows left, so shallower trees are exact, just stored in
     a deeper heap of +inf thresholds).
 
@@ -675,24 +638,11 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     # resolved here only when the caller did not pin it; jitted entry
     # points MUST pin it (static arg) or mode switches won't retrace
     hist_mode = hist_mode or _hist_mode(n, TB)
-    sub_enabled = hist_mode.endswith("+sub")
-    if sub_enabled:
-        hist_mode = hist_mode[:-len("+sub")]
-    if hist_mode == "matmul_bf16":
-        with jax.named_scope("tree.indicator"):
-            bin_oh = _bin_indicator(packed, TB, jnp.bfloat16, feat_of)
-    elif hist_mode in ("matmul", "pallas"):
-        ind_gb = n * TB * jnp.dtype(stats.dtype).itemsize / 2 ** 30
-        if ind_gb > 4.0:
-            # the (n, TB) indicator is re-read every level; at this
-            # size it dominates HBM —
-            # matmul_chunk rebuilds it per bin block instead, and bf16
-            # operands halve it
-            _log.warning(
-                "matmul histogram indicator is %.1f GiB (%d rows x %d "
-                "packed bins, %s); consider TX_TREE_HIST=matmul_chunk "
-                "or matmul_bf16", ind_gb, n, TB,
-                jnp.dtype(stats.dtype).name)
+    # "<mode>+sub": a value of the static that only tests pass (the
+    # resolver never returns it): histogram subtraction, see _hist_mode
+    hist_mode, _, suffix = hist_mode.partition("+")
+    sub_enabled = suffix == "sub"
+    if hist_mode == "matmul":
         with jax.named_scope("tree.indicator"):
             bin_oh = _bin_indicator(packed, TB, stats.dtype, feat_of)
     else:
@@ -737,8 +687,7 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                 # child is parent - left. Stats are level-invariant within
                 # a tree and bins never change, so prev_hist[p] IS the
                 # parent's full histogram. Odd-slot rows park on sentinel
-                # slot C (== 2*C_half): one_hot zeroes it, scatter drops
-                # it, and the Pallas [:num_slots] slice discards it.
+                # slot C (== 2*C_half): one_hot zeroes it, scatter drops it.
                 C_half = C // 2
                 slot_sub = jnp.where((slot & 1) == 0, slot >> 1, C)
                 hist_even = _level_histograms(
@@ -1009,8 +958,8 @@ def _row_draw(draw_fn, wkey, n: int, axis_name: Optional[str],
     return jax.lax.dynamic_slice(full, (start,), (n,))
 
 
-#: transient-memory budget for batching independent forest trees with
-#: vmap (bytes); TX_TREE_BLOCK_MB overrides. Trees of a bagged forest
+#: transient-memory budget (MB) for batching independent forest trees
+#: with vmap on an accelerator. Trees of a bagged forest
 #: are embarrassingly parallel — a lax.scan over them serializes
 #: hundreds of tiny per-level ops (the dominant cost of small-data
 #: selector searches, where dispatch/latency beats FLOPs), so trees are
@@ -1020,35 +969,21 @@ def _row_draw(draw_fn, wkey, n: int, axis_name: Optional[str],
 _TREE_BLOCK_BUDGET_MB = 256
 
 
-def _tree_budget_mb() -> Optional[int]:
-    """Resolved tree-block budget in MB, or None for platform-auto
-    (accelerators: default budget; CPU: no tree batching — measured a
-    ~9% Titanic regression from batching on one core, where the blocks'
-    dispatch-latency win doesn't exist). Callers must thread this into
-    their kernel cache keys / jit statics — reading the env var inside
-    an already-compiled program would silently ignore changes."""
-    import os
-    v = int(os.environ.get("TX_TREE_BLOCK_MB", "0"))
-    return v or None
-
-
 def _tree_block_size(n: int, total_bins: int, depth: int, s_dim: int,
                      num_trees: int, hist_mode: str, pooled: bool,
-                     outer_batch: int = 1,
-                     budget_mb: Optional[int] = None) -> int:
-    if budget_mb is None:
-        # platform-auto (decided at trace time, like _hist_mode): vmap
-        # blocks pay on accelerators where a lax.scan of tiny per-level
-        # ops is launch-latency-bound; on CPU the scan wins
-        if jax.default_backend() == "cpu":
-            return 1
-        budget_mb = _TREE_BLOCK_BUDGET_MB
-    budget = budget_mb * 1024 * 1024
+                     outer_batch: int = 1) -> int:
+    """How many forest trees one vmapped block fits (decided at trace
+    time, like _hist_mode): as many as _TREE_BLOCK_BUDGET_MB holds on an
+    accelerator, where a lax.scan of tiny per-level ops is launch-
+    latency-bound; 1 (the plain scan) on a CPU, where batching measured
+    a ~9% Titanic regression on one core."""
+    if jax.default_backend() == "cpu":
+        return 1
+    budget = _TREE_BLOCK_BUDGET_MB * 1024 * 1024
     cap = min(n, _DEFAULT_NODE_CAP)
     c_max = min(2 ** max(depth - 1, 0), cap)
     per_tree = 2 * n * 8 + 2 * c_max * total_bins * s_dim * 8
-    if hist_mode and hist_mode.split("+")[0] in (
-            "matmul", "pallas", "matmul_bf16", "matmul_chunk"):
+    if hist_mode.partition("+")[0] != "scatter":
         # the (n, c_max) slot one-hot is the dominant per-tree transient
         # of the einsum strategy at depth
         per_tree += n * c_max * 8
@@ -1070,7 +1005,6 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
                  axis_name: Optional[str] = None,
                  row_total: Optional[int] = None,
                  outer_batch: int = 1,
-                 budget_mb: Optional[int] = None,
                  depth_limit=None, val_rows=None):
     """Shared forest program: ``mask`` (n,) row weights let one body
     serve the single fit (mask=ones), the fold x grid batched kernel
@@ -1148,8 +1082,7 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
         row_total if row_total is not None else n,
         int(feat_of.shape[0]), depth,
         num_classes if kind == "cls" else 3, num_trees,
-        hist_mode or "scatter", pool_cfg is not None, outer_batch,
-        budget_mb=budget_mb)
+        hist_mode or "scatter", pool_cfg is not None, outer_batch)
     if tb >= num_trees:
         return jax.vmap(one_tree)(keys)
     if tb == 1:
@@ -1166,7 +1099,7 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
 @functools.partial(
     jax.jit, static_argnames=("depth", "num_classes", "num_trees",
                               "max_features", "pool_cfg", "impurity",
-                              "bootstrap", "hist_mode", "budget_mb"))
+                              "bootstrap", "hist_mode"))
 def _fit_forest_classifier(packed, feat_of, block_start, packed_thr,
                            binned, col_thr, narrow_idx, wide_idx, y, key,
                            *, depth: int, num_classes: int, num_trees: int,
@@ -1174,21 +1107,19 @@ def _fit_forest_classifier(packed, feat_of, block_start, packed_thr,
                            pool_cfg: Optional[tuple], impurity: str,
                            min_instances: float, min_info_gain: float,
                            subsample: float, bootstrap: bool,
-                           hist_mode: Optional[str],
-                           budget_mb: Optional[int] = None):
+                           hist_mode: Optional[str]):
     return _forest_body(
         packed, feat_of, block_start, packed_thr, binned, col_thr,
         narrow_idx, wide_idx, y, key, jnp.ones_like(y), min_instances,
         min_info_gain, subsample, kind="cls", depth=depth,
         num_classes=num_classes, num_trees=num_trees,
         max_features=max_features, pool_cfg=pool_cfg, impurity=impurity,
-        bootstrap=bootstrap, hist_mode=hist_mode, budget_mb=budget_mb)
+        bootstrap=bootstrap, hist_mode=hist_mode)
 
 
 @functools.partial(
     jax.jit, static_argnames=("depth", "num_trees", "max_features",
-                              "pool_cfg", "bootstrap", "hist_mode",
-                              "budget_mb"))
+                              "pool_cfg", "bootstrap", "hist_mode"))
 def _fit_forest_regressor(packed, feat_of, block_start, packed_thr,
                           binned, col_thr, narrow_idx, wide_idx, y, key,
                           *, depth: int, num_trees: int,
@@ -1196,15 +1127,13 @@ def _fit_forest_regressor(packed, feat_of, block_start, packed_thr,
                           pool_cfg: Optional[tuple],
                           min_instances: float, min_info_gain: float,
                           subsample: float, bootstrap: bool,
-                          hist_mode: Optional[str],
-                          budget_mb: Optional[int] = None):
+                          hist_mode: Optional[str]):
     return _forest_body(
         packed, feat_of, block_start, packed_thr, binned, col_thr,
         narrow_idx, wide_idx, y, key, jnp.ones_like(y), min_instances,
         min_info_gain, subsample, kind="reg", depth=depth, num_classes=0,
         num_trees=num_trees, max_features=max_features, pool_cfg=pool_cfg,
-        impurity="", bootstrap=bootstrap, hist_mode=hist_mode,
-        budget_mb=budget_mb)
+        impurity="", bootstrap=bootstrap, hist_mode=hist_mode)
 
 
 def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
@@ -1390,7 +1319,7 @@ def _predict_leaves(X, feats, thrs, depth: int):
 @functools.lru_cache(maxsize=32)
 def _forest_fg_kernel(statics: tuple, mesh=None):
     (kind, depth, num_classes, num_trees, max_features, pool_cfg,
-     impurity, bootstrap, hist_mode, budget_mb) = statics
+     impurity, bootstrap, hist_mode) = statics
 
     def one(ob, mask, mi, mg, sr, dl, packed, feat_of, block_start,
             packed_thr, binned, col_thr, narrow, wide, y, key):
@@ -1400,7 +1329,7 @@ def _forest_fg_kernel(statics: tuple, mesh=None):
             depth=depth, num_classes=num_classes, num_trees=num_trees,
             max_features=max_features, pool_cfg=pool_cfg,
             impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
-            outer_batch=ob, budget_mb=budget_mb, depth_limit=dl)
+            outer_batch=ob, depth_limit=dl)
 
     # named apart from the boosted programs' ``batched``: the function a
     # ``jax.jit`` wraps names the program (``jit_forest_batched``)
@@ -1511,7 +1440,7 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
     validation rows in the fitted table instead (see _eval_form): the
     program then holds no ``_traverse`` and never sees ``X_val``."""
     (kind, depth, num_classes, num_trees, max_features, pool_cfg,
-     impurity, bootstrap, hist_mode, budget_mb) = statics
+     impurity, bootstrap, hist_mode) = statics
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
@@ -1524,7 +1453,7 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
             depth=depth, num_classes=num_classes, num_trees=num_trees,
             max_features=max_features, pool_cfg=pool_cfg,
             impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
-            outer_batch=ob, budget_mb=budget_mb, depth_limit=dl,
+            outer_batch=ob, depth_limit=dl,
             val_rows=val[fi] if in_fit else None)
         with jax.named_scope("fg.metric"):
             scores = _candidate_scores(
@@ -1756,7 +1685,7 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
 @functools.lru_cache(maxsize=32)
 def _forest_sharded_kernel(statics: tuple, mesh, axis: str):
     (kind, depth, num_classes, num_trees, max_features, pool_cfg,
-     impurity, bootstrap, hist_mode, row_total, budget_mb) = statics
+     impurity, bootstrap, hist_mode, row_total) = statics
     from jax.sharding import PartitionSpec as P
 
     def body(packed, binned, y, mask, feat_of, block_start, packed_thr,
@@ -1767,7 +1696,7 @@ def _forest_sharded_kernel(statics: tuple, mesh, axis: str):
             depth=depth, num_classes=num_classes, num_trees=num_trees,
             max_features=max_features, pool_cfg=pool_cfg,
             impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
-            axis_name=axis, row_total=row_total, budget_mb=budget_mb)
+            axis_name=axis, row_total=row_total)
 
     # outputs replicate: every shard reaches identical split decisions
     # from the psum'd reductions
@@ -1807,7 +1736,8 @@ def _gbt_fit_sharded(est, X, y, mesh, axis: str, objective: str):
         [np.asarray(packed), np.asarray(y)], shards)
     row_total = len(mask)
     statics = (est.max_depth, est.num_rounds, objective,
-               _hist_mode(row_total, int(feat_of.shape[0])), row_total)
+               _hist_mode(row_total // shards, int(feat_of.shape[0])),
+               row_total)
     fn = _gbt_sharded_kernel(statics, mesh, axis)
     feats, thrs, leaves, base = fn(
         jnp.asarray(packed_p), jnp.asarray(y_p), jnp.asarray(mask),
@@ -2180,13 +2110,10 @@ def _design_args(X: np.ndarray, max_bins: int,
     """Host-bin X and return ((packed, feat_of, block_start, packed_thr,
     binned, col_thr) device arrays, widths host array). ``edge_rows``
     restricts quantile-edge estimation (TX_TREE_EDGES=fold)."""
-    # the binning-mode env var joins the key: a TX_TREE_BINNING toggle
-    # between fits on the same matrix must not serve the other mode's
-    # cached design (the auto decision is pure in X/backend, so the
-    # env value is the only extra degree of freedom)
+    # where the design is binned (_bin_on_device) is pure in X's shape
+    # and the backend, so it needs no place in the key
     key = (id(X), getattr(X, "shape", None), max_bins,
-           None if edge_rows is None else id(edge_rows),
-           _binning_mode())
+           None if edge_rows is None else id(edge_rows))
     with _DESIGN_LOCK:
         hit = _DESIGN_CACHE.get(key)
         if hit is not None and hit[0] is X and hit[1] is edge_rows:
@@ -2216,25 +2143,21 @@ def _fold_edges_mode() -> bool:
 def _depth_mode() -> str:
     """How the fold×grid search handles the max_depth sweep:
 
-    - "static" (default): one program per distinct depth — lanes do
-      exactly their own work.
+    - "static": one program per distinct depth — lanes do exactly their
+      own work.
     - "mask": ONE compiled program per tree family at the grid's
       deepest depth; each candidate's depth is a traced per-lane limit
       (_grow_tree depth_limit). Cuts tree-family compile count 3x on
       the default grids (flagship: 6 -> 2 programs) at the price of
       shallow lanes running the deep lane's masked levels.
 
-    Auto default: mask on accelerators, static on CPU (same split
+    Chosen by platform: mask on accelerators, static on CPU (same split
     _hist_mode uses). The choice rests on builder-reported runs that no
     driver record holds (mask ~2x faster warm on a v5e, where the search
     is dispatch-bound; ~4x slower on one CPU core, where the masked
-    levels are real work); ROADMAP S2/S4 re-measure both sides on
-    chip cells. chip_smoke.py runs the flagship under mask: 2 tree
-    programs instead of 6, same winner and holdout AuPR as static on CPU.
-    TX_TREE_DEPTH overrides."""
-    mode = os.environ.get("TX_TREE_DEPTH")
-    if mode in ("mask", "static"):
-        return mode
+    levels are real work); ROADMAP S3 measures both sides on the chip
+    cells. chip_smoke.py runs the flagship under mask: 2 tree programs
+    instead of 6, same winner and holdout AuPR as static on CPU."""
     return "static" if jax.default_backend() == "cpu" else "mask"
 
 
@@ -2250,7 +2173,7 @@ def _note_compile(kind: str, statics: tuple, shape: tuple) -> None:
 
 def tree_kernel_compiles() -> int:
     """Distinct compiled fold×grid tree programs so far in this process
-    (the compile-count diagnostic bench.py reports)."""
+    (the compile-count diagnostic chip_smoke.py reports)."""
     return len(_COMPILE_KEYS)
 
 
@@ -2295,7 +2218,7 @@ _GBT_SKEY = ("max_depth", "num_rounds", "max_bins", "seed")
 def _trim_tree_arrays(feats, thrs, leaves, depth_cap: int, depth: int,
                       leaf_axis: int = 1):
     """Slice a depth_cap-shaped (heap, leaves) candidate back to its own
-    ``depth`` (TX_TREE_DEPTH=mask materialization): levels >= depth hold
+    ``depth`` (``mask`` depth mode materialization): levels >= depth hold
     only (0, +inf) denied splits, and a truncated node ``l``'s rows all
     sit in its leftmost descendant leaf ``l << (cap - depth)`` — so the
     heap prefix plus a strided leaf gather reproduce the static-depth
@@ -2318,7 +2241,7 @@ def _candidate_groups(est, grid, masks, mesh, traced_fields, skey_fields):
     fold×grid drivers (forest / binary-GBT / softmax-GBT): partition
     grid points into static shape groups, flatten (fold, candidate)
     lanes fold-major, tile the traced hyperparameter vectors (plus the
-    trailing depth-limit lane for TX_TREE_DEPTH=mask), and pad to the
+    trailing depth-limit lane for the ``mask`` depth mode), and pad to the
     mesh shard count.
 
     Yields (members, cand0, depth_cap, traced_vecs, masks_p, fidx,
@@ -2473,8 +2396,7 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
                    k if classification else 0, cand0.num_trees, mf,
                    pool_cfg, getattr(cand0, "impurity", ""),
                    cand0.bootstrap,
-                   _hist_mode(n, int(design[1].shape[0])),
-                   _tree_budget_mb())
+                   _hist_mode(n, int(design[1].shape[0])))
         _note_compile("forest", statics, masks_p.shape)
         vecs_j = [jnp.asarray(v) for v in vecs]
         if eval_ctx is not None:
@@ -2630,8 +2552,8 @@ class _ForestClassifierBase(Predictor):
         row_total = len(mask)
         statics = ("cls", self.max_depth, k, self.num_trees, mf,
                    pool_cfg, self.impurity, self.bootstrap,
-                   _hist_mode(row_total, int(feat_of.shape[0])),
-                   row_total, _tree_budget_mb())
+                   _hist_mode(row_total // shards, int(feat_of.shape[0])),
+                   row_total)
         fn = _forest_sharded_kernel(statics, mesh, axis)
         feats, thrs, leaves = fn(
             jnp.asarray(packed_p), jnp.asarray(binned_p),
@@ -2661,8 +2583,7 @@ class _ForestClassifierBase(Predictor):
             min_instances=float(self.min_instances_per_node),
             min_info_gain=self.min_info_gain,
             subsample=self.subsampling_rate, bootstrap=self.bootstrap,
-            hist_mode=_hist_mode(X.shape[0], int(design[1].shape[0])),
-            budget_mb=_tree_budget_mb())
+            hist_mode=_hist_mode(X.shape[0], int(design[1].shape[0])))
         return TreeEnsembleClassifierModel(feats, thrs, leaves,
                                            depth=self.max_depth,
                                            n_features=d)
@@ -2704,8 +2625,8 @@ class _ForestRegressorBase(Predictor):
         row_total = len(mask)
         statics = ("reg", self.max_depth, 0, self.num_trees, mf,
                    pool_cfg, "", self.bootstrap,
-                   _hist_mode(row_total, int(feat_of.shape[0])),
-                   row_total, _tree_budget_mb())
+                   _hist_mode(row_total // shards, int(feat_of.shape[0])),
+                   row_total)
         fn = _forest_sharded_kernel(statics, mesh, axis)
         feats, thrs, leaves = fn(
             jnp.asarray(packed_p), jnp.asarray(binned_p),
@@ -2734,8 +2655,7 @@ class _ForestRegressorBase(Predictor):
             min_instances=float(self.min_instances_per_node),
             min_info_gain=self.min_info_gain,
             subsample=self.subsampling_rate, bootstrap=self.bootstrap,
-            hist_mode=_hist_mode(X.shape[0], int(design[1].shape[0])),
-            budget_mb=_tree_budget_mb())
+            hist_mode=_hist_mode(X.shape[0], int(design[1].shape[0])))
         return TreeEnsembleRegressorModel(feats, thrs, leaves,
                                           depth=self.max_depth,
                                           n_features=d)

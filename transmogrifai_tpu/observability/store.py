@@ -23,7 +23,7 @@ Layout (top-level keys are independent namespaces)::
                    "placement:...":    {...},
                    "prepare:seg:...":  {...}},
       "tuning":   {"overrides": {"serving.target_batch": 32, ...}},
-      "autotune": {...}    # TX_BENCH_MODE=autotune decision trail
+      "autotune": {...}    # record_autotune: a decision trail
     }
 
 Reserved ``profiles`` keys start with ``_`` (real labels are
@@ -116,7 +116,7 @@ def atomic_write_json(path: str, doc: dict, *, indent: int = 1,
 
 def default_store_path() -> str:
     """``TX_PROFILE_STORE`` if set, else the checkout-level
-    ``BENCH_STATE.json`` next to bench.py (untracked: whatever this
+    ``BENCH_STATE.json`` at its root (untracked: whatever this
     checkout's own runs have recorded, empty on a fresh one)."""
     env = os.environ.get("TX_PROFILE_STORE")
     if env:
@@ -315,9 +315,9 @@ class ProfileStore:
             state["tuning"] = block
             return self._write(state)
 
-    # -- named bench blocks (TX_BENCH_MODE=restart_aot, ...) ---------------
+    # -- named diagnostic blocks -------------------------------------------
     def record_section(self, name: str, doc: dict) -> bool:
-        """Persist one named, timestamped bench/diagnostic block (e.g.
+        """Persist one named, timestamped diagnostic block (e.g.
         ``aot_restart``) wholesale. Callers own the namespace — pick a
         name that is not one of the structural blocks (``profiles``,
         ``tuning``, ``autotune``)."""
@@ -328,11 +328,11 @@ class ProfileStore:
             state[str(name)] = out
             return self._write(state)
 
-    # -- autotune bench trail (TX_BENCH_MODE=autotune) ---------------------
+    # -- autotune decision trail -------------------------------------------
     def record_autotune(self, doc: dict) -> bool:
-        """Persist the bench's full TuningDecision list + tuned-vs-
-        static deltas, so the perf trajectory records WHY a knob moved,
-        not just that it did."""
+        """Persist a full TuningDecision list + tuned-vs-static deltas,
+        so the perf trajectory records WHY a knob moved, not just that
+        it did."""
         with _merge_lock(self.path):
             state = self.load()
             out = dict(doc)

@@ -2,8 +2,8 @@
 event stream.
 
 The same idiom as ``serving.plan_compiles()`` / ``racing
-.search_compiles()``: module-level accumulators that bench.py and the
-resilience tests read to prove runtime behavior (zero re-dispatch of
+.search_compiles()``: module-level accumulators that chip_smoke.py, the
+benchmark (``benchmark/``) and the resilience tests read to prove runtime behavior (zero re-dispatch of
 journaled work, retry counts, quarantine counts) rather than infer it
 from timing. ``WorkflowListener`` snapshots the event stream into
 ``AppMetrics.fault_events`` so one training run's retries and

@@ -146,7 +146,7 @@ class RacingCrossValidation(CrossValidation):
         self.min_fidelity = mf
         #: telemetry of the last validate() call (rungs, budgets,
         #: pruned counts) — the selector copies it into
-        #: ModelSelectorSummary.racing; bench.py emits it
+        #: ModelSelectorSummary.racing
         self.last_report: Dict = {}
 
     @classmethod

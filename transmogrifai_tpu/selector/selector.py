@@ -198,8 +198,9 @@ class SelectedModel(PredictionModel):
 def models_x_folds(model) -> int:
     """Total (candidate, fold) evaluations recorded by the selector(s)
     in a fitted workflow model — the unit of the north-star throughput
-    metric (BASELINE.json). Shared by bench.py and
-    examples/multicore_bench.py so their rows stay comparable."""
+    metric (BASELINE.json). Shared by chip_smoke.py, the benchmark's
+    search jobs and examples/multicore_bench.py so their rows stay
+    comparable."""
     return sum(
         len(r.metric_values)
         for s in model.stages()

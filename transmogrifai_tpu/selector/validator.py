@@ -31,9 +31,10 @@ import numpy as np
 _log = logging.getLogger(__name__)
 
 #: per-family dispatch accounting (wall + compile seconds, call count),
-#: accumulated across every search in this process — bench.py reads it
-#: to tell a compile-bound search from a compute-bound one family by
-#: family (the thread is named ``tx-family-<Name>`` while the family's
+#: accumulated across every search in this process — the benchmark's
+#: ``slowest_family_s`` reads it (``family_profile()``) to tell a
+#: compile-bound search from a compute-bound one family by family (the
+#: thread is named ``tx-family-<Name>`` while the family's
 #: kernels run, so profiler lanes carry the same attribution)
 _FAMILY_PROFILE: Dict[str, Dict[str, float]] = {}
 

@@ -29,7 +29,7 @@ loop (local/scoring.py) — a plan:
    backends.
 
 ``plan_compiles()`` counts distinct (plan, bucket) programs — the
-compile diagnostic bench.py reports (same idiom as
+compile diagnostic (same idiom as
 models/trees.tree_kernel_compiles): a repeated same-bucket batch adds
 zero.
 """
@@ -74,7 +74,7 @@ __all__ = ["ScoringPlan", "EncodedScoreBatch", "PlanCoverage",
 
 def plan_compiles() -> int:
     """Distinct compiled scoring programs so far in this process (the
-    compile-count diagnostic bench.py's score mode reports)."""
+    compile-count diagnostic of the scoring path)."""
     return compiles("score")
 
 

@@ -43,7 +43,7 @@ programs, so steady state pays ZERO compiles (asserted); per-request
 results are bitwise identical to offline ``score_guarded()`` on the
 same rows (asserted). Entry points: ``python -m transmogrifai_tpu.cli
 serve`` (JSON-lines over TCP, cli/serve.py) and the in-process
-:class:`ServingClient` for tests/bench (``TX_BENCH_MODE=serve_loop``).
+:class:`ServingClient` for tests and in-process load generators.
 Blocking calls are banned from the async handlers by lint rule TX-J10
 (docs/lint.md); everything blocking runs in a named executor.
 """
@@ -1395,7 +1395,7 @@ class ServingServer:
 
 class ServingClient:
     """Synchronous in-process facade over a background-thread
-    :class:`ServingServer` — what tests and ``TX_BENCH_MODE=serve_loop``
+    :class:`ServingServer` — what tests and in-process load generators
     drive. ``submit`` returns a concurrent future for open-loop load
     generation; ``score`` blocks for one row."""
 

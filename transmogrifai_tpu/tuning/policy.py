@@ -543,12 +543,12 @@ class TuningPolicy:
         return self._static(
             name, "waste tolerance is a policy choice, not learnable")
 
-    # -- the full decision table (tx tune, bench) --------------------------
+    # -- the full decision table (tx tune) ---------------------------------
     def decisions(self, max_wait_ms: float = 5.0,
                   max_batch: int = 256) -> List[TuningDecision]:
         """Every knob's resolution under the given serving context —
-        the table ``tx tune`` renders and ``TX_BENCH_MODE=autotune``
-        persists."""
+        the table ``tx tune`` renders (``ProfileStore.record_autotune``
+        persists one)."""
         out = [self.target_batch(max_wait_ms, max_batch)]
         out.extend(self.bucket_range(max_batch))
         out.append(self.prewarm_buckets(max_batch))

@@ -6,7 +6,7 @@ persistent compilation cache amortizes those compiles across processes
 — the same mechanism production JAX training jobs use.
 :func:`enable_compilation_cache` runs once where a process starts
 using JAX: ``Workflow.train``, ``ScoringPlan.compile``, the CLI entry
-point, bench.py and the examples all call it, and it is idempotent.
+point, the benchmark (``benchmark/run.py``) and the examples all call it, and it is idempotent.
 """
 from __future__ import annotations
 
