@@ -445,6 +445,10 @@ class TestTrainSpans:
         for s in named("search.fetch"):
             assert s["attrs"]["route_gather"] >= 1
             assert s["attrs"]["route_dense"] >= 0
+            # and which form their node sums took (trees.tree_sum_forms;
+            # the CPU's scatter mode keeps segment_sum)
+            assert s["attrs"]["sums_scatter"] >= 1
+            assert s["attrs"]["sums_dense"] >= 0
         assert all(s["dur"] is not None for s in spans)
 
     def test_scoring_spans_nest_under_guarded(self, trained):

@@ -159,6 +159,50 @@ def test_route_form_follows_hist_mode_and_retraces(monkeypatch):
     assert set(trees.tree_route_forms()) == {"dense", "gather"}
 
 
+@pytest.mark.parametrize("program, rows, placements", [
+    ("fit_gbt_hlo", 24, 1), ("fold_grid_hlo", 64, 0)])
+def test_no_row_scatter_under_tree_node_sums(program, rows, placements,
+                                             request):
+    """ISSUE 31: under the ``matmul`` family the per-slot totals and the
+    per-leaf sums are selects over the slot axis reduced over the rows; the
+    scope keeps its name. No scatter-add is left, and the one scatter allowed
+    is a tree's placement of the 2 * C leaf sums of a compressed last level
+    (the fit fixture: 24 rows at depth 6, so level 5 has 24 slots): its
+    updates run over the (slot, side) columns, never over the rows."""
+    hlo = request.getfixturevalue(program)
+    summed = [line for line in hlo.splitlines() if "tree.node_sums" in line]
+    assert any("tree.node_sums/reduce_sum" in line for line in summed)
+    assert not [line for line in summed if "scatter-add" in line]
+    scatters = [line for line in summed
+                if re.search(r"= \S+ scatter\(", line)]
+    assert len(scatters) == placements      # the rounds are one scan body
+    for line in scatters:
+        updates = re.search(r"scatter\(\S+, \S+, (%[\w.\-]+)\)", line).group(1)
+        (shape,) = re.findall(
+            re.escape(updates) + r" = \w+\[([\d,]*)\]", hlo)
+        assert int(shape.split(",")[0]) == 2 * 24 != rows
+
+
+def test_sum_form_follows_hist_mode_and_retraces(monkeypatch):
+    rng = np.random.default_rng(31)
+    X = rng.normal(size=(80, 4))
+    y = (X[:, 1] > 0).astype(np.float64)
+    seen = []
+    for mode in ("scatter", "matmul", "scatter", "matmul_chunk"):
+        monkeypatch.setattr(trees, "_hist_mode", lambda n, tb, m=mode: m)
+        before = trees.tree_sum_forms()
+        GBTClassifier(num_rounds=3, max_depth=2, max_bins=8).fit_arrays(X, y)
+        after = trees.tree_sum_forms()
+        seen.append({k: after[k] - before[k] for k in after})
+    # a fit traces its tree grower under its own mode's form and no other;
+    # the third fit finds the first one's program and traces nothing
+    assert seen[0]["scatter"] >= 1 and seen[0]["dense"] == 0
+    assert seen[1]["dense"] >= 1 and seen[1]["scatter"] == 0
+    assert seen[2] == {"dense": 0, "scatter": 0}
+    assert seen[3]["dense"] >= 1 and seen[3]["scatter"] == 0
+    assert set(trees.tree_sum_forms()) == {"dense", "scatter"}
+
+
 def test_every_scope_is_used_in_trees():
     """``trees.SCOPES`` is the one list the scope readers take: every name
     on it is opened somewhere in the three modules that build the fit and

@@ -359,6 +359,21 @@ def _route_case(name):
     return (packed, feat_of, block_start, thr, onehot), kwargs, masks
 
 
+def _grow(case_args, kwargs, masks, mode):
+    """One ``_grow_tree`` call under ``hist_mode=mode``, freshly jitted (so
+    it traces), vmapped over the lanes' row masks where the case has any."""
+    import jax
+    from transmogrifai_tpu.models import trees as T
+    packed, feat_of, block_start, thr, stats = case_args
+
+    def one(st):
+        return T._grow_tree(packed, feat_of, block_start, thr, st,
+                            hist_mode=mode, **kwargs)
+    if masks is None:
+        return jax.jit(one)(stats)
+    return jax.jit(jax.vmap(lambda m: one(stats * m[:, None])))(masks)
+
+
 class TestRouteForms:
     """The routing step of a level has two forms (models/trees._route_form):
     per-row gathers under the ``scatter`` family, selects over the slot and
@@ -369,21 +384,11 @@ class TestRouteForms:
         "identity", "compressed", "depth_limit", "pooled", "wide_bins",
         "vmap_lanes"])
     def test_dense_route_equals_gather_route(self, case):
-        import jax
         from transmogrifai_tpu.models import trees as T
-        (packed, feat_of, block_start, thr, onehot), kwargs, masks = \
-            _route_case(case)
+        args, kwargs, masks = _route_case(case)
         before = T.tree_route_forms()
-
-        def grow(mode):
-            def one(stats):
-                return T._grow_tree(packed, feat_of, block_start, thr,
-                                    stats, hist_mode=mode, **kwargs)
-            if masks is None:
-                return jax.jit(one)(onehot)
-            return jax.jit(jax.vmap(lambda m: one(onehot * m[:, None])))(
-                masks)
-        gathered, dense = grow("scatter"), grow("matmul")
+        gathered = _grow(args, kwargs, masks, "scatter")
+        dense = _grow(args, kwargs, masks, "matmul")
         after = T.tree_route_forms()
         assert after["gather"] > before["gather"]
         assert after["dense"] > before["dense"]
@@ -422,6 +427,164 @@ class TestRouteForms:
             == (before["gather"] + 1, before["dense"])
         for a, b in zip(dense, gathered):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _sums_case(name):
+    """``_route_case`` plus two with S = 3 real-valued regression statistics
+    (weights, w*y, w*y*y under the variance gain): every level an identity
+    level, and a ``node_cap=7`` tree whose last level is compressed, so its
+    leaves are summed by (slot, side) and placed by the leaf ids read off
+    the rows. Returns the case and whether
+    its sums are exact in any order (whole-number statistics)."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu.models import trees as T
+    if not name.startswith("real_"):
+        return _route_case(name) + (True,)
+    (packed, feat_of, block_start, thr, _), kwargs, masks = _route_case(
+        "compressed" if name == "real_compressed" else "identity")
+    rng = np.random.default_rng(31)
+    n = packed.shape[0]
+    w = rng.uniform(0.5, 2.0, size=n)
+    target = (np.asarray(packed[:, 0]) / 4.0 - np.asarray(packed[:, 3]) % 5
+              + rng.normal(size=n))
+    stats = jnp.asarray(np.stack([w, w * target, w * target ** 2], axis=1),
+                        thr.dtype)
+    # a node of 20 rows a side has no second split that mirrors its best
+    kwargs.update(gain_fn=T._variance_gain(20.0))
+    return (packed, feat_of, block_start, thr, stats), kwargs, masks, False
+
+
+class TestSumForms:
+    """The per-slot totals of a level and the per-leaf sums of a tree have
+    two forms (models/trees._sums_form): ``segment_sum`` under the
+    ``scatter`` family, a select over the slot axis reduced over the rows
+    (``_slot_sums``) under the ``matmul`` family. The same sums: bit-equal
+    where the statistics are whole numbers, summation order apart where
+    they are real."""
+
+    @pytest.mark.parametrize("case", [
+        "identity", "compressed", "depth_limit", "pooled", "wide_bins",
+        "vmap_lanes", "real_stats", "real_compressed"])
+    def test_dense_sums_equal_scatter_sums(self, case):
+        from transmogrifai_tpu.models import trees as T
+        args, kwargs, masks, exact = _sums_case(case)
+        before = T.tree_sum_forms()
+        scattered = _grow(args, kwargs, masks, "scatter")
+        middle = T.tree_sum_forms()
+        dense = _grow(args, kwargs, masks, "matmul")
+        after = T.tree_sum_forms()
+        assert (middle["scatter"] - before["scatter"],
+                middle["dense"] - before["dense"]) == (1, 0)
+        assert (after["scatter"] - middle["scatter"],
+                after["dense"] - middle["dense"]) == (0, 1)
+        for name, a, b in zip(("feat_heap", "thr_heap", "leaf_stats",
+                               "node"), scattered, dense):
+            a, b = np.asarray(a), np.asarray(b)
+            if exact or name != "leaf_stats":
+                np.testing.assert_array_equal(a, b, err_msg=f"{case}: {name}")
+            else:
+                np.testing.assert_allclose(a, b, rtol=1e-6, atol=0,
+                                           err_msg=f"{case}: {name}")
+        _, thr_heap, leaf_stats, node = (np.asarray(a) for a in dense)
+        assert np.isfinite(thr_heap).sum() >= 2     # a tree was grown
+        # every row is in a leaf, and the leaves hold all of the statistics
+        stats = np.asarray(args[4])
+        if masks is None:
+            np.testing.assert_allclose(leaf_stats.sum(axis=0),
+                                       stats.sum(axis=0), rtol=1e-9)
+            for leaf in np.unique(node)[:4]:
+                np.testing.assert_allclose(
+                    leaf_stats[leaf], stats[node == leaf].sum(axis=0),
+                    rtol=1e-9)
+            assert not leaf_stats[np.setdiff1d(
+                np.arange(len(leaf_stats)), node)].any()
+        if "compressed" in case:    # the last level outgrew the slot cap
+            assert kwargs["node_cap"] < 2 ** (kwargs["depth"] - 1)
+
+    def test_slot_sums_drop_rows_outside_the_slots(self):
+        """A row whose slot is outside [0, C) adds nothing, as
+        ``segment_sum`` drops it."""
+        import jax
+        import jax.numpy as jnp
+        from transmogrifai_tpu.models import trees as T
+        rng = np.random.default_rng(5)
+        stats = jnp.asarray(rng.integers(0, 9, size=(200, 3)), jnp.float64)
+        slot = jnp.asarray(rng.integers(-2, 9, size=200), jnp.int32)
+        slot = slot.at[:3].set(T._SLOT_SENTINEL)
+        np.testing.assert_array_equal(
+            np.asarray(T._slot_sums(stats, slot, 6)),
+            np.asarray(jax.ops.segment_sum(
+                jnp.where(((slot >= 0) & (slot < 6))[:, None], stats, 0),
+                jnp.clip(slot, 0, 5), num_segments=6)))
+
+    def test_many_slots_keep_the_scatter(self, monkeypatch):
+        import jax
+        from transmogrifai_tpu.models import trees as T
+        assert T._sums_form("scatter", 2) == "scatter"
+        for base in ("matmul", "matmul_chunk"):
+            assert T._sums_form(base, T._SUMS_DENSE_MAX_SLOTS) == "dense"
+            assert T._sums_form(base, T._SUMS_DENSE_MAX_SLOTS + 1) \
+                == "scatter"
+        # the default cap's widest sum (two leaves under each of 256 slots)
+        # is summed densely, by a wide margin
+        assert 2 * T._DEFAULT_NODE_CAP * 4 <= T._SUMS_DENSE_MAX_SLOTS
+        args, kwargs, _, _ = _sums_case("compressed")
+        packed, feat_of, block_start, thr, onehot = args
+
+        def adds(mode):     # the scatter-adds of a freshly traced grower
+            jaxpr = jax.make_jaxpr(lambda st: T._grow_tree(
+                packed, feat_of, block_start, thr, st, hist_mode=mode,
+                **kwargs))(onehot)
+            return str(jaxpr).count("scatter-add")
+        # ``scatter`` keeps segment_sum (a level's histogram, its totals,
+        # the leaves); the matmul family holds no scatter-add at all
+        assert adds("scatter") >= 2 * kwargs["depth"] + 1
+        assert adds("matmul") == 0
+        dense = _grow(args, kwargs, None, "matmul")
+        # the rule is read while tracing: the same call takes the other
+        # form (the widest sum of this tree has 2 * 7 columns)
+        monkeypatch.setattr(T, "_SUMS_DENSE_MAX_SLOTS", 13)
+        before = T.tree_sum_forms()
+        assert adds("matmul") == kwargs["depth"] + 1
+        scattered = _grow(args, kwargs, None, "matmul")
+        after = T.tree_sum_forms()
+        assert (after["scatter"], after["dense"]) \
+            == (before["scatter"] + 2, before["dense"])
+        for a, b in zip(dense, scattered):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("case", ["identity", "compressed"])
+    def test_row_sharded_sums_equal_unsharded(self, case):
+        """Under ``axis_name`` each shard sums its own rows densely and the
+        ``psum`` adds the shards' (slots, S) tables; a compressed last
+        level places the leaves by the ``pmax`` of the leaf ids each shard
+        read off its rows (a shard may hold no row of a leaf)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+        from transmogrifai_tpu.models import trees as T
+        from transmogrifai_tpu.parallel import make_mesh
+        (packed, feat_of, block_start, thr, onehot), kwargs, _, _ = \
+            _sums_case(case)
+        n = packed.shape[0]
+        mesh = make_mesh({"data": 8})
+        assert n % 8 == 0
+
+        def shard_fn(pk, st):
+            return T._grow_tree(pk, feat_of, block_start, thr, st,
+                                hist_mode="matmul", axis_name="data",
+                                row_total=n, **kwargs)
+        sharded = jax.jit(shard_map(
+            shard_fn, mesh=mesh, in_specs=(P("data", None), P("data", None)),
+            out_specs=(P(), P(), P(), P("data")), check_vma=False))(
+            packed, onehot)
+        whole = _grow((packed, feat_of, block_start, thr, onehot), kwargs,
+                      None, "scatter")
+        for name, a, b in zip(("feat_heap", "thr_heap", "leaf_stats",
+                               "node"), whole, sharded):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"{case}: {name}")
 
 
 class TestPoolPlan:
@@ -683,17 +846,30 @@ class TestMatmulChunk:
     indicator rebuilt per bin block by gather+compare — exact vs the
     whole-matrix modes even when multiple blocks are forced."""
 
-    def test_multi_block_exact(self, rng, monkeypatch):
+    @pytest.mark.parametrize("family, reference", [
+        ("gbt", "matmul"), ("tree", "scatter")])
+    def test_multi_block_exact(self, rng, monkeypatch, family, reference):
+        """The boosted fit against the whole-matrix ``matmul`` mode: real
+        gradients, and against ``scatter`` its node totals differ in
+        summation order (``_sums_form``), which flips the exact ties of
+        mirrored splits in the small nodes of 300 rows. The classification
+        tree against ``scatter``: class counts, exact in any order."""
         import transmogrifai_tpu.models.trees as T
         X = rng.normal(size=(300, 10))
         y = (X[:, 0] + 0.3 * X[:, 1] > 0).astype(float)
-        monkeypatch.setattr(T, "_hist_mode", lambda n, tb: "scatter")
-        ref = T.GBTClassifier(num_rounds=6, max_depth=4).fit_arrays(X, y)
+
+        def fit():
+            if family == "gbt":
+                return T.GBTClassifier(num_rounds=6, max_depth=4
+                                       ).fit_arrays(X, y)
+            return T.DecisionTreeClassifier(max_depth=4).fit_arrays(X, y)
+        monkeypatch.setattr(T, "_hist_mode", lambda n, tb: reference)
+        ref = fit()
         monkeypatch.setattr(T, "_hist_mode", lambda n, tb: "matmul_chunk")
         # force many bin blocks: step = max(8, 1000//300) = 8 bins per
         # block -> dozens of blocks over this design's packed bins
         monkeypatch.setattr(T, "_HIST_CHUNK_ELEMS", 1000)
-        chk = T.GBTClassifier(num_rounds=6, max_depth=4).fit_arrays(X, y)
+        chk = fit()
         np.testing.assert_allclose(ref.thrs, chk.thrs, rtol=1e-6)
         np.testing.assert_array_equal(ref.feats, chk.feats)
         np.testing.assert_allclose(ref.leaves, chk.leaves, rtol=1e-5)

@@ -392,7 +392,11 @@ def _hist_mode(n: int, total_bins: int) -> str:
     shard's). Decided at trace time; every jitted entry pins the result
     as its static ``hist_mode``, so another answer retraces. The routing
     form of a level follows it (see _route_form): ``scatter`` gathers each
-    row's bin, the other two select it densely."""
+    row's bin, the other two select it densely. So does the form of the
+    node sums (see _sums_form): ``scatter`` keeps ``segment_sum``, the
+    other two reduce a select over the slot axis. Those sums (a level's
+    ``total``, the tree's ``leaf_stats``) are float32-exact under every
+    mode: the bf16 pass above is the histogram's alone."""
     if jax.default_backend() == "cpu":
         return "scatter"
     itemsize = np.dtype(jax.dtypes.canonicalize_dtype(float)).itemsize
@@ -520,6 +524,62 @@ def tree_route_forms() -> dict:
     return dict(_ROUTE_FORMS)
 
 
+#: most columns (a level's slots, or the two leaves under each slot of the
+#: last one) the ``matmul`` family still sums densely (see _sums_form). One
+#: sum on a v5e, float32, median of five (builder's chip run, PR 31,
+#: PERF.md section 6), ``segment_sum`` against ``_slot_sums``: 1,000,000
+#: rows x 3 statistics, 32 slots, 8.42 ms against 1.02 ms; 54 vmapped lanes
+#: x 49,152 rows x 2 statistics, 20.8 ms at 32, 256 and 512 slots (7.8 ns a
+#: row whatever the slots; 43 ms from 1,024 up) against 1.47 / 1.78 / 2.47 /
+#: 3.82 / 7.90 / 21.7 ms at 32 / 256 / 512 / 1,024 / 2,048 / 4,096 slots;
+#: 1,000,000 rows x 2 statistics unbatched, 8.3-8.4 ms against 2.51 ms at
+#: 1,024 and 6.63 ms at 4,096 slots: they would meet near 5,200 slots, and
+#: at 4,096 the dense form still wins by a fifth (by half under ``vmap``).
+#: The other dense candidates of that run: the same selects with the rows
+#: on the major axis, 4.8 ms at 256 and 512 slots; ``einsum("nc,ns->cs",
+#: precision=HIGHEST)``, 3.0 / 3.7 ms, and further from the float64 sums
+#: (2-3e-6 of the largest at 1,000,000 rows, as the scatter's sequential
+#: adds, 2-5e-6; the selects' tree reduction 1-3e-7); a 3-D select over
+#: (n, slots, S) stores the (lanes, n, slots) one-hot (648 MB at 256 slots)
+_SUMS_DENSE_MAX_SLOTS = 4096
+
+#: how many traced ``_grow_tree`` calls summed their nodes in each form
+_SUM_FORMS = {"dense": 0, "scatter": 0}
+
+
+def _sums_form(base_mode: str, num_slots: int) -> str:
+    """How ``_grow_tree`` adds the rows' statistics up by node (each level's
+    per-slot totals and the tree's per-leaf sums): "scatter"
+    (``jax.ops.segment_sum``: O(n), cheap on a CPU, a serialized per-row
+    scatter-add on the chip) or "dense" (``_slot_sums``: the slot one-hot
+    selected against each statistic and reduced over the rows, O(n * slots)
+    elementwise, no scatter and no gather). Chosen at trace time, once a
+    tree, from the resolved base ``hist_mode`` (``scatter``, the CPU path
+    and the tests' reference, keeps ``segment_sum`` and its bits; the
+    ``matmul`` family, the accelerator default, sums densely) and the
+    widest reduction the tree makes, ``num_slots`` (its leaf sum's columns:
+    twice the last level's slots), because the dense form grows with the
+    slots and the scatter does not. Both forms are float32 sums of float32
+    statistics; they differ in summation order only.
+
+    Why the sums are not read from the level histogram, which holds them
+    (any one feature's bins of ``hist`` add up to ``total``): on the chip the
+    histogram is a default-precision einsum, a bf16 pass over ``stats``
+    (see _hist_mode), while ``total`` and ``leaf_stats`` (the model's leaf
+    values) are float32-exact. Taking them from ``hist`` would state float32
+    and deliver bf16-rounded gradients in every leaf."""
+    if base_mode == "scatter" or num_slots > _SUMS_DENSE_MAX_SLOTS:
+        return "scatter"
+    return "dense"
+
+
+def tree_sum_forms() -> dict:
+    """Traced ``_grow_tree`` calls so far in this process by the form of
+    their node sums, ``{"dense": k, "scatter": m}`` (see _sums_form): the
+    record of which path the compiled tree programs hold."""
+    return dict(_SUM_FORMS)
+
+
 #: how many traced fused fit+metric kernels (``*_eval_kernel``) took each
 #: source of the validation rows' leaves (see _eval_form)
 _EVAL_FORMS = {"in_fit": 0, "traverse": 0}
@@ -548,13 +608,15 @@ def tree_eval_forms() -> dict:
 @contextlib.contextmanager
 def _fetch_span():
     """The ``search.fetch`` span of a fold-grid driver, carrying
-    :func:`tree_route_forms` and :func:`tree_eval_forms` as the scalar
-    attributes ``route_dense`` / ``route_gather`` and ``eval_in_fit`` /
-    ``eval_traverse``: read when the span opens (what its profiler
+    :func:`tree_route_forms`, :func:`tree_sum_forms` and
+    :func:`tree_eval_forms` as the scalar attributes ``route_dense`` /
+    ``route_gather``, ``sums_dense`` / ``sums_scatter`` and ``eval_in_fit``
+    / ``eval_traverse``: read when the span opens (what its profiler
     annotation keeps) and again when it closes, because a program's first
     call traces inside the span."""
     def attrs():
         out = {"route_" + k: v for k, v in tree_route_forms().items()}
+        out.update(("sums_" + k, v) for k, v in tree_sum_forms().items())
         out.update(("eval_" + k, v) for k, v in tree_eval_forms().items())
         return out
     with _trace.span("search.fetch", **attrs()) as rec:
@@ -580,6 +642,24 @@ def _route_left_dense(packed: jnp.ndarray, slot: jnp.ndarray,
     cols = jnp.arange(packed.shape[1], dtype=idt)[None, :]
     return jnp.any((cols == f_i[:, None]) & (packed <= b_i[:, None]),
                    axis=1)
+
+
+def _slot_sums(stats: jnp.ndarray, slot: jnp.ndarray,
+               num_slots: int) -> jnp.ndarray:
+    """``jax.ops.segment_sum(stats, slot, num_segments=num_slots)`` without
+    a scatter: (num_slots, S) sums of the rows' statistics by slot. Each
+    statistic column is selected against the (num_slots, n) slot one-hot
+    and reduced over the rows, one fused compare-select-reduce a column on
+    the VPU (rows along the lanes, as ``slot`` and a column of ``stats``
+    lie; the one-hot is never stored, which a 3-D select over (n, slots,
+    S) would do). A row whose slot is outside [0, num_slots) matches no
+    slot and adds nothing, as ``segment_sum`` drops it. Sums in the
+    statistics' dtype: no contraction, so no bf16 pass on the chip."""
+    of_slot = slot[None, :] == jnp.arange(num_slots,
+                                          dtype=slot.dtype)[:, None]
+    return jnp.stack(
+        [jnp.sum(jnp.where(of_slot, stats[:, s][None, :], 0), axis=1)
+         for s in range(stats.shape[1])], axis=1)
 
 
 def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
@@ -623,6 +703,15 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     allreduce, SURVEY §2.9). ``row_total`` must then carry the GLOBAL
     row count (slot caps must not depend on the shard-local count).
 
+    The node sums (scope ``tree.node_sums``: each level's per-slot
+    ``total``, with the row count of an identity level as one more
+    column, and the per-leaf ``leaf_stats``) are sums of ``stats`` in its
+    own dtype, in one form a tree (see _sums_form): ``segment_sum``
+    under the ``scatter`` mode, ``_slot_sums`` (a select over the slot
+    axis reduced over the rows; no per-row scatter-add) under the
+    ``matmul`` family. They are never read from ``hist``, which holds
+    them but is a bf16-pass contraction on the chip.
+
     Returns (feat_heap (2^depth - 1,), thr_heap (2^depth - 1,),
     leaf_stats (2^depth, S), final node assignment (n,)).
     """
@@ -649,6 +738,17 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
         bin_oh = None                # scatter / matmul_chunk modes
     route = _route_form(hist_mode, d)
     _ROUTE_FORMS[route] += 1
+    # one form of the node sums a tree, by its widest: the leaf sum's
+    # columns, two under each slot of the last level
+    sums = _sums_form(hist_mode, 2 * min(2 ** max(depth - 1, 0), cap))
+    _SUM_FORMS[sums] += 1
+
+    def node_sums(values, slots, num_slots):
+        if sums == "dense":
+            out = _slot_sums(values, slots, num_slots)
+        else:
+            out = jax.ops.segment_sum(values, slots, num_segments=num_slots)
+        return jax.lax.psum(out, axis_name) if axis_name else out
     key = feat_key
     prev_hist = None        # previous level's (C_prev, TB, S) histogram
     prev_identity = False
@@ -717,19 +817,14 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                     # gain positive — so count rows per slot (folded into
                     # the total reduction as an extra ones column) and mask
                     # empty slots out of split_ok below
-                    aug = jax.ops.segment_sum(
+                    aug = node_sums(
                         jnp.concatenate(
                             [stats, jnp.ones((n, 1), stats.dtype)], axis=1),
-                        slot, num_segments=C)
-                    if axis_name:
-                        aug = jax.lax.psum(aug, axis_name)
+                        slot, C)
                     total = aug[:, None, :-1]
                     nonempty = aug[:, -1] > 0
                 else:
-                    total = jax.ops.segment_sum(stats, slot,
-                                                num_segments=C)[:, None, :]
-                    if axis_name:
-                        total = jax.lax.psum(total, axis_name)
+                    total = node_sums(stats, slot, C)[:, None, :]
             right = total - left
             gain = gain_fn(left, right, total)         # (C, TB)
             gain = jnp.where(not_a_split[None, :], -jnp.inf, gain)
@@ -788,11 +883,33 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                 go_left = (packed[jnp.arange(n), bfeat[slot]]
                            <= best_r[slot])
             # within-level index
-            node = 2 * node + (1 - go_left.astype(jnp.int32))
+            went_right = 1 - go_left.astype(jnp.int32)
+            node = 2 * node + went_right
     with jax.named_scope("tree.node_sums"):
-        leaf_stats = jax.ops.segment_sum(stats, node, num_segments=2 ** depth)
-        if axis_name:
-            leaf_stats = jax.lax.psum(leaf_stats, axis_name)
+        if sums == "scatter" or depth == 0 or identity:
+            # the last level's slots were its node ids (or there is none):
+            # a leaf's column is its id
+            leaf_stats = node_sums(stats, node, 2 ** depth)
+        else:
+            # a leaf is (last level's slot, side): summed over 2 * C columns,
+            # not 2^depth segments, then placed by the columns' leaf ids (a
+            # column no row reached lands nowhere: its leaf stays zero, as
+            # an empty segment does). The ids are read off the rows by the
+            # same select, reduced by min over a column's rows (all in one
+            # leaf; 2^depth, out of the table, where there is none):
+            # ``node_of_slot`` would keep the last level's n-update scatter
+            # of ``tree.compress`` alive in the programs that return no heap
+            column = 2 * slot + went_right
+            by_column = node_sums(stats, column, 2 * C)
+            of_column = column[None, :] == jnp.arange(
+                2 * C, dtype=column.dtype)[:, None]
+            leaf_of = jnp.min(
+                jnp.where(of_column, node[None, :], 2 ** depth), axis=1)
+            if axis_name:
+                leaf_of = jax.lax.pmin(leaf_of, axis_name)
+            leaf_stats = jnp.zeros((2 ** depth, stats.shape[1]),
+                                   stats.dtype).at[leaf_of].set(
+                by_column, mode="drop")
     return feat_heap, thr_heap, leaf_stats, node
 
 
