@@ -205,13 +205,14 @@ def test_sum_form_follows_hist_mode_and_retraces(monkeypatch):
 
 def test_every_scope_is_used_in_trees():
     """``trees.SCOPES`` is the one list the scope readers take: every name
-    on it is opened somewhere in the three modules that build the fit and
+    on it is opened somewhere in the four modules that build the fit and
     fold-grid programs, and they open no other (ISSUE 28 added the linear
-    family's in ``models/linear.py`` and ``parallel/cv.py``)."""
-    from transmogrifai_tpu.models import linear
+    family's in ``models/linear.py`` and ``parallel/cv.py``, ISSUE 32 the
+    naive Bayes program's in ``models/bayes.py``)."""
+    from transmogrifai_tpu.models import bayes, linear
     from transmogrifai_tpu.parallel import cv
     source = "".join(open(module.__file__).read()
-                     for module in (trees, linear, cv))
+                     for module in (trees, linear, cv, bayes))
     for scope in trees.SCOPES:
         assert f'jax.named_scope("{scope}")' in source
     assert len(set(trees.SCOPES)) == len(trees.SCOPES)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax import shard_map
 import numpy as np
 
+from ..observability import trace as _trace
 from .base import (ClassifierModel, FamilyPreconditionError,
                    Predictor, check_fold_classes, num_classes,
                    subset_grid)
@@ -65,24 +66,25 @@ def _nb_masked_body(X, y, masks, smoothing, *, num_classes: int,
         return _nb_closed_form(X, labels, mask, sm, num_classes,
                                model_type)
 
-    return jax.vmap(one)(masks, smoothing)
-
-
-@functools.partial(jax.jit, static_argnames=("num_classes", "model_type"))
-def _fit_nb_masked(X, y, masks, smoothing, *, num_classes: int,
-                   model_type: str):
-    return _nb_masked_body(X, y, masks, smoothing,
-                           num_classes=num_classes, model_type=model_type)
+    with jax.named_scope("fg.bayes"):
+        return jax.vmap(one)(masks, smoothing)
 
 
 def _nb_raw(pi, theta, Xv, model_type: str):
     """(nv, K) log-joint scores — the device twin of
-    NaiveBayesModel.predict_raw."""
+    NaiveBayesModel.predict_raw. The products are float32 in full on a
+    TPU too: a log-joint is a sum of raw feature values times log-shares
+    (thousands times ten), and the one bf16 pass of the chip's default
+    rounds a feature of 2,960 to a multiple of 16, which moved 0.7 % of a
+    Covertype-shaped fold's argmaxes (PERF.md, PR 32)."""
+    def product(a, b):
+        return jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+
     if model_type == "bernoulli":
         Xb = (Xv != 0).astype(theta.dtype)
         neg = jnp.log1p(-jnp.minimum(jnp.exp(theta), 1 - 1e-12))
-        return pi + Xb @ theta.T + (1.0 - Xb) @ neg.T
-    return pi + Xv @ theta.T
+        return pi + product(Xb, theta) + product(1.0 - Xb, neg)
+    return pi + product(Xv, theta)
 
 
 def _nb_eval_body(X, y, masks, smoothing, fidx, Xv, yv, *,
@@ -100,54 +102,56 @@ def _nb_eval_body(X, y, masks, smoothing, fidx, Xv, yv, *,
     def one(mask, sm, fi):
         pi, theta = _nb_closed_form(Xf, labels, mask, sm, num_classes,
                                     model_type)
-        raw = _nb_raw(pi, theta, Xv[fi], model_type)
-        # host NaiveBayesModel ranks by the softmax of the log-joints
-        scores = (binary_from_raw_pair(raw) if spec[0] == "binary"
-                  else softmax_probability(raw))
-        return mfn(yv[fi], scores)
+        with jax.named_scope("fg.metric"):
+            raw = _nb_raw(pi, theta, Xv[fi], model_type)
+            # host NaiveBayesModel ranks by the softmax of the log-joints
+            scores = (binary_from_raw_pair(raw) if spec[0] == "binary"
+                      else softmax_probability(raw))
+            return mfn(yv[fi], scores)
 
-    return jax.vmap(one)(masks, smoothing, fidx)
+    with jax.named_scope("fg.bayes"):
+        return jax.vmap(one)(masks, smoothing, fidx)
 
 
-@functools.partial(jax.jit, static_argnames=("num_classes", "model_type",
-                                             "spec"))
-def _eval_nb_masked(X, y, masks, smoothing, fidx, Xv, yv, *,
-                    num_classes: int, model_type: str, spec: tuple):
-    return _nb_eval_body(X, y, masks, smoothing, fidx, Xv, yv,
-                         num_classes=num_classes, model_type=model_type,
-                         spec=spec)
-
+# The two kernels below are programs named ``jit_bayes_batched`` (the
+# function a ``jax.jit`` wraps names the program) whose bodies trace under
+# the scope ``fg.bayes``, as the other families' fold-grid programs have a
+# name and a scope of their own (docs/observability.md).
 
 @functools.lru_cache(maxsize=32)
-def _nb_eval_mesh_kernel(num_classes: int, model_type: str, spec: tuple,
-                         mesh):
-    from jax.sharding import PartitionSpec as P
-
-    def batched(masks, smoothing, fidx, X, y, Xv, yv):
+def _nb_eval_kernel(num_classes: int, model_type: str, spec: tuple,
+                    mesh=None):
+    """Fit + metric of every candidate; with a mesh the candidate axis is
+    sharded over its ``models`` axis and X/y replicate."""
+    def bayes_batched(masks, smoothing, fidx, X, y, Xv, yv):
         return _nb_eval_body(X, y, masks, smoothing, fidx, Xv, yv,
                              num_classes=num_classes,
                              model_type=model_type, spec=spec)
 
+    if mesh is None:
+        return jax.jit(bayes_batched)
+    from jax.sharding import PartitionSpec as P
     return jax.jit(shard_map(
-        batched, mesh=mesh,
+        bayes_batched, mesh=mesh,
         in_specs=(P("models", None), P("models"), P("models"),
                   P(), P(), P(), P()),
         out_specs=P("models"), check_vma=False))
 
 
 @functools.lru_cache(maxsize=32)
-def _nb_mesh_kernel(num_classes: int, model_type: str, mesh):
-    """Candidate axis sharded over the mesh ``models`` axis (same
-    mapping as the other family kernels); X/y replicate."""
-    from jax.sharding import PartitionSpec as P
-
-    def batched(masks, smoothing, X, y):
+def _nb_fit_kernel(num_classes: int, model_type: str, mesh=None):
+    """(pi, theta) of every candidate (same mapping onto a mesh as the
+    other family kernels)."""
+    def bayes_batched(masks, smoothing, X, y):
         return _nb_masked_body(X, y, masks, smoothing,
                                num_classes=num_classes,
                                model_type=model_type)
 
+    if mesh is None:
+        return jax.jit(bayes_batched)
+    from jax.sharding import PartitionSpec as P
     return jax.jit(shard_map(
-        batched, mesh=mesh,
+        bayes_batched, mesh=mesh,
         in_specs=(P("models", None), P("models"), P(), P()),
         out_specs=(P("models", None), P("models", None, None)),
         check_vma=False))
@@ -197,14 +201,8 @@ class NaiveBayes(Predictor):
             masks_c = np.repeat(masks, gk, axis=0)   # fold-major
             (masks_c, sm), _ = _pad_candidates(
                 mesh, [masks_c, sm], masks_c.shape[1])
-            if mesh is not None:
-                fn = _nb_mesh_kernel(k, model_type, mesh)
-                pi, theta = fn(jnp.asarray(masks_c), jnp.asarray(sm),
-                               X_j, y_j)
-            else:
-                pi, theta = _fit_nb_masked(
-                    X_j, y_j, jnp.asarray(masks_c), jnp.asarray(sm),
-                    num_classes=k, model_type=model_type)
+            pi, theta = _nb_fit_kernel(k, model_type, mesh)(
+                jnp.asarray(masks_c), jnp.asarray(sm), X_j, y_j)
             pi, theta = to_host(pi), to_host(theta)
             for f in range(F):
                 for j, (gi, _) in enumerate(members):
@@ -256,16 +254,10 @@ class NaiveBayes(Predictor):
                 mesh, [masks_c, sm], masks_c.shape[1])
             fidx = np.concatenate(
                 [fidx, np.zeros(len(sm) - count, dtype=np.int32)])
-            if mesh is not None:
-                fn = _nb_eval_mesh_kernel(k, model_type, spec, mesh)
-                mm = fn(jnp.asarray(masks_c), jnp.asarray(sm),
-                        jnp.asarray(fidx), X_j, y_j, Xv_j, yv_j)
-            else:
-                mm = _eval_nb_masked(
-                    X_j, y_j, jnp.asarray(masks_c), jnp.asarray(sm),
-                    jnp.asarray(fidx), Xv_j, yv_j, num_classes=k,
-                    model_type=model_type, spec=spec)
-            mm = to_host(mm)[:count]
+            with _trace.span("search.fetch"):
+                mm = to_host(_nb_eval_kernel(k, model_type, spec, mesh)(
+                    jnp.asarray(masks_c), jnp.asarray(sm),
+                    jnp.asarray(fidx), X_j, y_j, Xv_j, yv_j))[:count]
             for f in range(F):
                 for j, (gi, _) in enumerate(members):
                     metric_mat[f, gi] = mm[f * gk + j]
@@ -300,11 +292,5 @@ class NaiveBayesModel(ClassifierModel):
         return self.pi + X @ self.theta.T
 
     def raw_arrays(self, X):
-        import jax.numpy as jnp
-        pi = jnp.asarray(self.pi, X.dtype)
-        theta = jnp.asarray(self.theta, X.dtype)
-        if self.model_type == "bernoulli":
-            Xb = (X != 0).astype(X.dtype)
-            neg = jnp.log1p(-jnp.minimum(jnp.exp(theta), 1 - 1e-12))
-            return pi + Xb @ theta.T + (1.0 - Xb) @ neg.T
-        return pi + X @ theta.T
+        return _nb_raw(jnp.asarray(self.pi, X.dtype),
+                       jnp.asarray(self.theta, X.dtype), X, self.model_type)

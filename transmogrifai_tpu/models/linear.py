@@ -133,6 +133,63 @@ def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
     return _unstandardize_coefs(wv, b, mu, sigma)
 
 
+#: precision of the multinomial (n, d) x (d, k) products: the core's logits
+#: (its gradient's product inherits it), the lanes' validation logits and a
+#: K-class model's scores. Float32 in full on the chip too, whose default is
+#: one bf16 pass, coarser than a float16 table: with it the refit read 2e-3 to
+#: 5e-3 from the same steps in float64 where this reads 2e-5 to 1e-4
+#: (PERF.md, PR 32). The binary cores' products are matrix-vector and never
+#: reach the multiplier
+_SOFTMAX_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def multinomial_logistic_core(X, y, w, reg, alpha, *, k: int,
+                              fit_intercept: bool, standardize: bool,
+                              max_iter: int, use_l1: bool,
+                              axis_name: Optional[str] = None,
+                              solver: str = "auto"):
+    """Weighted multinomial (softmax) logistic fit over ``k`` classes ->
+    (coefficients (k, d), intercepts (k,)). The objective is
+    ``-sum_i w_i log softmax(Xs_i W^T + b)[y_i] / sum(w) + 0.5 l2 ||W||^2
+    (+ l1 ||W||_1 by the proximal step)``, the intercepts unpenalised: with
+    ``l2 > 0`` its minimiser is unique, so no centring of the rows of ``W``
+    is needed. Solver choice, the shard-local objective and the static trip
+    count under a mesh or solver="fista" are ``binary_logistic_core``'s."""
+    d = X.shape[1]
+    Xs, mu, sigma, wsum = _prep(X, w, standardize, axis_name)
+    onehot = jax.nn.one_hot(y.astype(jnp.int32), k, dtype=Xs.dtype)
+    l2 = reg * (1.0 - alpha)
+    l1 = reg * alpha
+    nshards = _psum(jnp.asarray(1.0, Xs.dtype), axis_name)
+
+    def smooth(params):     # shard-local; the solver psums the gradient
+        W = params[:, :d]
+        logits = jnp.matmul(Xs, W.T, precision=_SOFTMAX_PRECISION) \
+            + (params[:, d] if fit_intercept else 0.0)
+        ll = jnp.sum(w * jnp.sum(onehot * jax.nn.log_softmax(logits),
+                                 axis=1)) / wsum
+        return -ll + 0.5 * l2 * jnp.sum(W * W) / nshards
+
+    W0 = jnp.zeros((k, d + 1), Xs.dtype)
+    force_fista = solver == "fista" or axis_name is not None
+    with jax.named_scope("lin.solve"):
+        if use_l1 or force_fista:
+            mask = jnp.concatenate([jnp.ones((k, d), Xs.dtype),
+                                    jnp.zeros((k, 1), Xs.dtype)], axis=1)
+            # the softmax Hessian in the logits, diag(p) - p p^T, is <= 1/2
+            lip = design_lipschitz(Xs, l2, curvature_bound=0.5, w=w,
+                                   axis_name=axis_name) + 0.5
+            params = fista_minimize(smooth, l1, W0, lip,
+                                    max_iter=max_iter * 5,
+                                    tol=0.0 if force_fista else 1e-7,
+                                    l1_mask=mask, grad_psum_axis=axis_name)
+        else:
+            params = lbfgs_minimize(smooth, W0, max_iter=max_iter)
+    W = params[:, :d]
+    b = params[:, d] if fit_intercept else jnp.zeros(k, Xs.dtype)
+    return _unstandardize_coefs(W, b, mu, sigma)
+
+
 def linear_regression_core(X, y, w, reg, alpha, *, fit_intercept: bool,
                            standardize: bool, max_iter: int, use_l1: bool,
                            axis_name: Optional[str] = None,
@@ -231,31 +288,10 @@ def _fit_binary_logistic(X, y, reg, alpha, *, fit_intercept: bool,
 def _fit_multinomial_logistic(X, y, reg, alpha, *, k: int,
                               fit_intercept: bool, standardize: bool,
                               max_iter: int, use_l1: bool):
-    n, d = X.shape
-    Xs, mu, sigma, _ = _prep(X, jnp.ones(n, X.dtype), standardize, None)
-    onehot = jax.nn.one_hot(y.astype(jnp.int32), k, dtype=Xs.dtype)
-    l2 = reg * (1.0 - alpha)
-    l1 = reg * alpha
-
-    def smooth(params):
-        W = params[:, :d]
-        b = params[:, d] if fit_intercept else 0.0
-        logits = Xs @ W.T + b
-        ll = jnp.mean(jnp.sum(onehot * jax.nn.log_softmax(logits), axis=1))
-        return -ll + 0.5 * l2 * jnp.sum(W * W)
-
-    W0 = jnp.zeros((k, d + 1), Xs.dtype)
-    if use_l1:
-        mask = jnp.concatenate(
-            [jnp.ones((k, d), Xs.dtype), jnp.zeros((k, 1), Xs.dtype)], axis=1)
-        lip = design_lipschitz(Xs, l2, curvature_bound=0.5) + 0.5
-        params = fista_minimize(smooth, l1, W0, lip, max_iter=max_iter * 5,
-                                l1_mask=mask)
-    else:
-        params = lbfgs_minimize(smooth, W0, max_iter=max_iter)
-    W = params[:, :d]
-    b = params[:, d] if fit_intercept else jnp.zeros(k, Xs.dtype)
-    return _unstandardize_coefs(W, b, mu, sigma)
+    return multinomial_logistic_core(
+        X, y, jnp.ones(X.shape[0], X.dtype), reg, alpha, k=k,
+        fit_intercept=fit_intercept, standardize=standardize,
+        max_iter=max_iter, use_l1=use_l1)
 
 
 def _grid_to_reg_alpha(estimator, grid,
@@ -315,40 +351,53 @@ class LogisticRegression(Predictor):
         return LogisticRegressionModel(coefficients=np.asarray(w),
                                        intercept=np.asarray(b))
 
+    def _fold_grid_kind(self, y) -> dict:
+        """The fold-grid kernel of these labels (parallel/cv.py
+        LINEAR_KERNELS): the binomial core for two classes, as
+        ``fit_arrays`` takes it, else the multinomial one over ``k``."""
+        k = int(np.max(y)) + 1 if len(y) else 2
+        return ({"kind": "logistic"} if k <= 2
+                else {"kind": "softmax", "k": k})
+
     def fit_fold_grid_arrays(self, X, y, masks, grid, mesh=None):
         """All (fold, grid point) candidates in one batched XLA program
         (optionally sharded over a ("models", "data") mesh) — reference
-        OpValidator.scala:270-310 task parallelism. Binary only."""
-        if len(y) and int(np.max(y)) + 1 > 2:
-            raise NotImplementedError("batched kernel is binary-only")
+        OpValidator.scala:270-310 task parallelism. More than two classes
+        fit the multinomial lanes (``jit_softmax_batched``)."""
         from ..parallel.cv import fit_linear_fold_grid
         ga = _grid_to_reg_alpha(self, grid)
         params = fit_linear_fold_grid(
-            "logistic", X, y, masks, ga, mesh=mesh,
+            X=X, y=y, masks=masks, grid=ga, mesh=mesh,
             fit_intercept=self.fit_intercept,
-            standardize=self.standardization, max_iter=self.max_iter)
+            standardize=self.standardization, max_iter=self.max_iter,
+            **self._fold_grid_kind(y))
         d = X.shape[1]
-        return [[LogisticRegressionModel(p[:d], p[d]) for p in row]
+        return [[LogisticRegressionModel(p[..., :d], p[..., d]) for p in row]
                 for row in params]
 
     def eval_fold_grid_arrays(self, X, y, masks, grid, X_val, y_val,
                               spec, mesh=None, cand_idx=None):
         """Device-resident search: fit + validation metric for every
         candidate in one program, (F, G) metric matrix out (see
-        parallel/cv.eval_linear_fold_grid). Binary margins.
+        parallel/cv.eval_linear_fold_grid): binary margins, or the
+        softmax of K logits under a multiclass metric.
         ``cand_idx`` (racing rungs) restricts to a candidate subset —
         the (reg, alpha) vectors stay traced values, so subsetting is a
         shape change, never a retrace of new statics."""
-        if spec[0] != "binary":
-            raise NotImplementedError("logistic device eval is binary-only")
-        if len(y) and int(np.max(y)) + 1 > 2:
-            raise NotImplementedError("batched kernel is binary-only")
+        kind = self._fold_grid_kind(y)
+        if spec[0] not in ("binary", "multiclass"):
+            raise NotImplementedError(
+                "logistic device eval needs a classification metric")
+        if spec[0] == "binary" and "k" in kind:
+            raise NotImplementedError(
+                "binary device eval needs binary labels")
         from ..parallel.cv import eval_linear_fold_grid
         ga = _grid_to_reg_alpha(self, subset_grid(grid, cand_idx))
         return eval_linear_fold_grid(
-            "logistic", X, y, masks, ga, X_val, y_val, spec, mesh=mesh,
-            fit_intercept=self.fit_intercept,
-            standardize=self.standardization, max_iter=self.max_iter)
+            X=X, y=y, masks=masks, grid=ga, X_val=X_val, y_val=y_val,
+            spec=spec, mesh=mesh, fit_intercept=self.fit_intercept,
+            standardize=self.standardization, max_iter=self.max_iter,
+            **kind)
 
 
 class LogisticRegressionModel(ClassifierModel):
@@ -368,7 +417,8 @@ class LogisticRegressionModel(ClassifierModel):
         if self.coefficients.ndim == 1:
             m = X @ c + float(self.intercept)
             return jnp.stack([-m, m], axis=1)
-        return X @ c.T + jnp.asarray(self.intercept, X.dtype)
+        return (jnp.matmul(X, c.T, precision=_SOFTMAX_PRECISION)
+                + jnp.asarray(self.intercept, X.dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -78,7 +78,8 @@ __all__ = [
 #: one level of ``_grow_tree``, a forest tree's bootstrap weights and feature
 #: pool, a boosting round, the validation metric of the ``*_eval_kernel``s
 #: (and of the linear fold-grid programs), one ``fg.<family>`` around each
-#: fold-grid program's body, and the linear cores' ``lin.*``
+#: fold-grid program's body (``fg.softmax``: the multinomial logistic lanes,
+#: ``fg.bayes``: ``models/bayes.py``), and the linear cores' ``lin.*``
 #: (``models/linear.py``, ``parallel/cv.py``). The one list of the package:
 #: the benchmark's scope readers take it from this attribute. A scope is a
 #: path component of the ``op_name`` of the ops traced under it and exists
@@ -86,7 +87,8 @@ __all__ = [
 SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
           "tree.split", "tree.route", "tree.bootstrap", "tree.pool",
           "gbt.round", "fg.metric", "fg.gbt", "fg.forest",
-          "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve")
+          "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve",
+          "fg.softmax", "fg.bayes")
 
 # ---------------------------------------------------------------------------
 # binning — packed variable-width bins

@@ -38,8 +38,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..observability import trace as _trace
 
-from ..models.linear import (binary_logistic_core, linear_regression_core,
-                             linear_svc_core)
+from ..models.linear import (_SOFTMAX_PRECISION, binary_logistic_core,
+                             linear_regression_core, linear_svc_core,
+                             multinomial_logistic_core)
 
 __all__ = ["fold_masks", "fit_linear_fold_grid", "eval_linear_fold_grid",
            "models_mesh", "resolve_search_mesh", "mesh_model_shards",
@@ -47,11 +48,13 @@ __all__ = ["fold_masks", "fit_linear_fold_grid", "eval_linear_fold_grid",
 
 #: kind -> weighted fit core (all share the signature
 #: (X, y, w, reg, alpha, *, fit_intercept, standardize, max_iter,
-#:  use_l1, axis_name) -> (coefficients, intercept))
+#:  use_l1, axis_name) -> (coefficients, intercept)); "softmax" takes the
+#: class count ``k`` besides and hands back (k, d) and (k,)
 LINEAR_KERNELS = {
     "logistic": binary_logistic_core,
     "squared": linear_regression_core,
     "svc": linear_svc_core,
+    "softmax": multinomial_logistic_core,
 }
 
 
@@ -165,10 +168,12 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
                          mesh: Optional[Mesh] = None,
                          fit_intercept: bool = True,
                          standardize: bool = True,
-                         max_iter: int = 100) -> np.ndarray:
+                         max_iter: int = 100,
+                         k: Optional[int] = None) -> np.ndarray:
     """Fit every (fold, grid point) candidate of one linear family.
 
-    kind   : "logistic" | "squared" | "svc" (see LINEAR_KERNELS)
+    kind   : "logistic" | "squared" | "svc" | "softmax" (see
+             LINEAR_KERNELS); "softmax" needs the class count ``k``
     masks  : (F, n) 0/1 train-row masks (1 = row in the fold's train set)
     grid   : (G, 2) columns (reg_param, elastic_net_param)
     mesh   : optional ("models", "data") mesh — without one, the whole
@@ -176,7 +181,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
              the local device.
 
     Returns (F, G, d+1) parameters, [..., :d] coefficients + [..., d]
-    intercept, in the ORIGINAL feature space.
+    intercept, in the ORIGINAL feature space; (F, G, k, d+1) for "softmax".
     """
     with _trace.span("search.head"):
         X = np.asarray(X, dtype=np.float64)
@@ -186,7 +191,9 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         F, n = masks.shape
         G, d = grid.shape[0], X.shape[1]
         use_l1 = bool(np.any(grid[:, 0] * grid[:, 1] > 0))
-        cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
+        cfg = _kernel_cfg(kind, use_l1, fit_intercept, standardize,
+                          max_iter, k)
+        lane = (d + 1,) if k is None else (k, d + 1)
 
         # flatten candidates fold-major: slot f*G + g = (fold f, grid g)
         regs = np.tile(grid[:, 0], F)
@@ -198,7 +205,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         with _trace.span("search.fetch"):
             params = fn(jnp.asarray(wmat), jnp.asarray(regs),
                         jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
-            return np.asarray(params).reshape(F, G, d + 1)
+            return np.asarray(params).reshape(F, G, *lane)
 
     m_shards = mesh.shape["models"]
     d_shards = mesh.shape.get("data", 1)
@@ -219,7 +226,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
     with _trace.span("search.fetch"):
         params = fn(jnp.asarray(wmat), jnp.asarray(regs),
                     jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
-        return to_host(params)[:FG].reshape(F, G, d + 1)
+        return to_host(params)[:FG].reshape(F, G, *lane)
 
 
 def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
@@ -229,7 +236,8 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
                           mesh: Optional[Mesh] = None,
                           fit_intercept: bool = True,
                           standardize: bool = True,
-                          max_iter: int = 100) -> np.ndarray:
+                          max_iter: int = 100,
+                          k: Optional[int] = None) -> np.ndarray:
     """Fit AND evaluate every (fold, grid point) candidate in ONE device
     program, returning only the (F, G) validation-metric matrix.
 
@@ -243,7 +251,8 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
             see _ValidatorBase._assignments)
     y_val : (F, nv) validation labels
     spec  : (kind, metric) for evaluators.device_metrics.metric_fn —
-            "binary" uses decision margins, "regression" raw values.
+            "binary" uses decision margins, "regression" raw values,
+            "multiclass" the softmax of the lane's logits.
     """
     with _trace.span("search.head"):
         X = np.asarray(X, dtype=np.float64)
@@ -253,7 +262,8 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         F, n = masks.shape
         G, d = grid.shape[0], X.shape[1]
         use_l1 = bool(np.any(grid[:, 0] * grid[:, 1] > 0))
-        cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
+        cfg = _kernel_cfg(kind, use_l1, fit_intercept, standardize,
+                          max_iter, k)
 
         regs = np.tile(grid[:, 0], F)
         alphas = np.tile(grid[:, 1], F)
@@ -292,18 +302,39 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         return to_host(mm)[:FG].reshape(F, G)
 
 
+def _kernel_cfg(kind, use_l1, fit_intercept, standardize, max_iter, k):
+    """The statics a fold-grid kernel is cached by; ``k`` (classes) is part
+    of them for the "softmax" kind and of no other."""
+    if (kind == "softmax") != (k is not None):
+        raise ValueError(f"kind {kind!r} with k={k!r}: the class count "
+                         f"belongs to the softmax kind, and only to it")
+    cfg = (kind, use_l1, fit_intercept, standardize, max_iter)
+    return cfg if k is None else cfg + (k,)
+
+
 def _candidate_eval(cfg, spec, params, fi, Xv, yv):
     """Validation metric for one fitted candidate against its fold's
     validation rows, using the host model's exact score semantics:
     logistic ranks by softmax probability of the [-m, m] raw pair, SVC
     by the raw margin (no probability, as in MLlib), regression by the
-    predicted values."""
+    predicted values; a multiclass metric takes the softmax of the
+    lane's raw scores (K logits, or the [-m, m] pair of a two-class
+    lane), as ``LogisticRegressionModel.predict_raw`` hands them on."""
     from ..evaluators.device_metrics import (binary_from_raw_pair,
-                                             metric_fn)
+                                             metric_fn,
+                                             softmax_probability)
     d = Xv.shape[-1]
     with jax.named_scope("fg.metric"):
-        m = Xv[fi] @ params[:d] + params[d]
-        if spec[0] == "binary":
+        if cfg[0] == "softmax":
+            W = params.reshape(cfg[5], d + 1)
+            raw = jnp.matmul(Xv[fi], W[:, :d].T,
+                             precision=_SOFTMAX_PRECISION) + W[:, d]
+        else:
+            m = Xv[fi] @ params[:d] + params[d]
+        if spec[0] == "multiclass":
+            scores = softmax_probability(
+                raw if cfg[0] == "softmax" else jnp.stack([-m, m], axis=1))
+        elif spec[0] == "binary":
             if cfg[0] == "svc":
                 scores = (m, (m > 0).astype(m.dtype))
             else:
@@ -317,6 +348,23 @@ def _candidate_eval(cfg, spec, params, fi, Xv, yv):
 # ``jax.jit`` wraps names the program), so a profile tells the linear
 # fold-grid programs from the tree families' ``jit_batched`` and
 # ``jit_forest_batched``; their bodies trace under the scope ``fg.linear``.
+# The multinomial lanes' are ``jit_softmax_batched`` under ``fg.softmax``:
+# what reads the binary kinds' programs by name goes on reading only those.
+
+def _program(cfg, body):
+    """``body`` under its kind's scope, in a function of its program's
+    name."""
+    if cfg[0] == "softmax":
+        def softmax_batched(*args):
+            with jax.named_scope("fg.softmax"):
+                return body(*args)
+        return softmax_batched
+
+    def linear_batched(*args):
+        with jax.named_scope("fg.linear"):
+            return body(*args)
+    return linear_batched
+
 
 @functools.lru_cache(maxsize=32)
 def _local_eval_kernel(cfg, spec):
@@ -324,42 +372,44 @@ def _local_eval_kernel(cfg, spec):
         params = _candidate_fit(cfg, w, r, a, X_, y_)
         return _candidate_eval(cfg, spec, params, fi, Xv, yv)
 
-    def linear_batched(*args):
-        with jax.named_scope("fg.linear"):
-            return jax.vmap(
-                one, in_axes=(0, 0, 0, 0, None, None, None, None))(*args)
-    return jax.jit(linear_batched)
+    return jax.jit(_program(cfg, jax.vmap(
+        one, in_axes=(0, 0, 0, 0, None, None, None, None))))
 
 
 @functools.lru_cache(maxsize=32)
 def _mesh_eval_kernel(cfg, spec, mesh):
     data_ax = "data" if "data" in mesh.axis_names else None
 
-    def linear_batched(w_loc, r_loc, a_loc, fi_loc, X_loc, y_loc, Xv, yv):
+    def body(w_loc, r_loc, a_loc, fi_loc, X_loc, y_loc, Xv, yv):
         def one(w, r, a, fi):
             params = _candidate_fit(cfg, w, r, a, X_loc, y_loc,
                                     axis_name=data_ax)
             # params are psum-complete (identical on every data shard),
             # and Xv/yv replicate — the metric is data-axis-invariant
             return _candidate_eval(cfg, spec, params, fi, Xv, yv)
-        with jax.named_scope("fg.linear"):
-            return jax.vmap(one)(w_loc, r_loc, a_loc, fi_loc)
+        return jax.vmap(one)(w_loc, r_loc, a_loc, fi_loc)
 
     return jax.jit(shard_map(
-        linear_batched, mesh=mesh,
+        _program(cfg, body), mesh=mesh,
         in_specs=(P("models", data_ax), P("models"), P("models"),
                   P("models"), P(data_ax, None), P(data_ax), P(), P()),
         out_specs=P("models"), check_vma=False))
 
 
 def _candidate_fit(cfg, w, reg, alpha, X_, y_, axis_name=None):
-    kind, use_l1, fit_intercept, standardize, max_iter = cfg
+    """One lane's parameters, flat: ``(d + 1,)`` coefficients then
+    intercept, or the softmax kind's ``(k, d + 1)`` rows one after another
+    (``(k * (d + 1),)``)."""
+    kind, use_l1, fit_intercept, standardize, max_iter = cfg[:5]
+    more = {"k": cfg[5]} if kind == "softmax" else {}
     # solver="fista": static trip count so the mesh and local batched
     # paths are bit-identical and collectives stay in lockstep
     coef, b = LINEAR_KERNELS[kind](
         X_, y_, w, reg, alpha, fit_intercept=fit_intercept,
         standardize=standardize, max_iter=max_iter,
-        use_l1=use_l1, axis_name=axis_name, solver="fista")
+        use_l1=use_l1, axis_name=axis_name, solver="fista", **more)
+    if kind == "softmax":
+        return jnp.concatenate([coef, b[:, None]], axis=1).reshape(-1)
     return jnp.concatenate([jnp.reshape(coef, (-1,)),
                             jnp.reshape(b, (1,))])
 
@@ -373,12 +423,9 @@ def _candidate_fit(cfg, w, reg, alpha, X_, y_, axis_name=None):
 
 @functools.lru_cache(maxsize=32)
 def _local_kernel(cfg):
-    def linear_batched(*args):
-        with jax.named_scope("fg.linear"):
-            return jax.vmap(
-                lambda w, r, a, X_, y_: _candidate_fit(cfg, w, r, a, X_, y_),
-                in_axes=(0, 0, 0, None, None))(*args)
-    return jax.jit(linear_batched)
+    return jax.jit(_program(cfg, jax.vmap(
+        lambda w, r, a, X_, y_: _candidate_fit(cfg, w, r, a, X_, y_),
+        in_axes=(0, 0, 0, None, None))))
 
 
 @functools.lru_cache(maxsize=32)
@@ -387,20 +434,19 @@ def _mesh_kernel(cfg, mesh):
     # unsharded and the fit cores run without a psum axis
     data_ax = "data" if "data" in mesh.axis_names else None
 
-    def linear_batched(w_loc, r_loc, a_loc, X_loc, y_loc):
+    def body(w_loc, r_loc, a_loc, X_loc, y_loc):
         # w_loc: (FG_local, n_local) — vmap candidates, psum row shards
-        with jax.named_scope("fg.linear"):
-            return jax.vmap(
-                lambda w, r, a: _candidate_fit(cfg, w, r, a, X_loc, y_loc,
-                                               axis_name=data_ax)
-            )(w_loc, r_loc, a_loc)
+        return jax.vmap(
+            lambda w, r, a: _candidate_fit(cfg, w, r, a, X_loc, y_loc,
+                                           axis_name=data_ax)
+        )(w_loc, r_loc, a_loc)
 
     # check_vma=False because solver state inits (zeros) are axis-
     # invariant; gradient correctness under it comes from the SHARD-LOCAL
     # objective + explicit grad psum in fista_minimize — autodiff never
     # transposes a collective (silently wrong with vma checking off)
     return jax.jit(shard_map(
-        linear_batched, mesh=mesh,
+        _program(cfg, body), mesh=mesh,
         in_specs=(P("models", data_ax), P("models"), P("models"),
                   P(data_ax, None), P(data_ax)),
         out_specs=P("models", None), check_vma=False))

@@ -255,6 +255,9 @@ class _ValidatorBase:
         mis-replayed."""
         ctx = RuntimeContext(retry=self.retry_policy,
                              family_deadline=self.family_deadline)
+        # a search that sends no family down the host path still shows the
+        # counter, at 0 (what reads it tells "none" from "not counted")
+        _telemetry.count("host_path_families", 0)
         if self.checkpoint_dir and X is not None:
             from ..runtime.journal import search_fingerprint
             params = dict(self.get_params(),
@@ -546,16 +549,8 @@ class _ValidatorBase:
         threaded = (len(tasks) > 1 and workers > 1 and spec is not None
                     and dispatch_bytes <= async_cap
                     and os.environ.get("TX_ASYNC_FAMILIES", "1") != "0")
-        with _trace.span("search.dispatch", families=len(tasks),
-                         workers=workers if threaded else 1,
-                         threaded=int(threaded),
-                         dispatch_bytes=dispatch_bytes):
-            # family spans run on pool worker threads where the
-            # context-var stack is empty: parent them explicitly to the
-            # dispatch span
-            span_parent = _trace.current_ref()
-            if not threaded:
-                return [run_task(*t) for t in tasks]
+
+        def pooled():
             from concurrent.futures import ThreadPoolExecutor
             from concurrent.futures import TimeoutError as _FutTimeout
             from concurrent.futures import wait as _fut_wait
@@ -593,6 +588,24 @@ class _ValidatorBase:
             ex.shutdown(wait=deadline is None)
             return results
 
+        with _trace.span("search.dispatch", families=len(tasks),
+                         workers=workers if threaded else 1,
+                         threaded=int(threaded),
+                         dispatch_bytes=dispatch_bytes) as rec:
+            # family spans run on pool worker threads where the
+            # context-var stack is empty: parent them explicitly to the
+            # dispatch span
+            span_parent = _trace.current_ref()
+            results = (pooled() if threaded
+                       else [run_task(*t) for t in tasks])
+            if rec is not None:
+                # the families with no device program for this search:
+                # the caller evaluates them on the host, one fit after
+                # another (counter ``host_path_families``)
+                rec["attrs"]["host_path"] = ",".join(
+                    t[0] for t, r in zip(tasks, results) if r is None)
+            return results
+
     def _device_matrices(self, models, X, y, masks, X_val_st, y_val_st,
                          spec, ctx: Optional[RuntimeContext] = None,
                          val_rows=None):
@@ -612,7 +625,10 @@ class _ValidatorBase:
     def _family_host_results(self, estimator, grid, X, y, masks,
                              fold_data) -> List[ValidationResult]:
         """Host evaluation of one family: batched fold x grid kernel when
-        available, per-candidate sequential fits otherwise."""
+        available, per-candidate sequential fits otherwise. Counted once
+        a family a search (``host_path_families``): a default pool that
+        counts any has a family without a fold-grid device program."""
+        _telemetry.count("host_path_families")
         results: List[ValidationResult] = []
         # fast path: families exposing a fold x grid kernel train all
         # candidates in ONE batched XLA program (mesh-sharded when
