@@ -433,8 +433,8 @@ def _sums_case(name):
     """``_route_case`` plus two with S = 3 real-valued regression statistics
     (weights, w*y, w*y*y under the variance gain): every level an identity
     level, and a ``node_cap=7`` tree whose last level is compressed, so its
-    leaves are summed by (slot, side) and placed by the leaf ids read off
-    the rows. Returns the case and whether
+    leaves are summed by (slot, side) and placed by the slots' node ids.
+    Returns the case and whether
     its sums are exact in any order (whole-number statistics)."""
     import jax.numpy as jnp
     from transmogrifai_tpu.models import trees as T
@@ -545,7 +545,9 @@ class TestSumForms:
         # form (the widest sum of this tree has 2 * 7 columns)
         monkeypatch.setattr(T, "_SUMS_DENSE_MAX_SLOTS", 13)
         before = T.tree_sum_forms()
-        assert adds("matmul") == kwargs["depth"] + 1
+        # (a level's totals and the leaves, and the row counts of the
+        # columns of the four carried levels, 2 to 5: ``_carry_slots``)
+        assert adds("matmul") == kwargs["depth"] + 1 + 4
         scattered = _grow(args, kwargs, None, "matmul")
         after = T.tree_sum_forms()
         assert (after["scatter"], after["dense"]) \
@@ -557,8 +559,8 @@ class TestSumForms:
     def test_row_sharded_sums_equal_unsharded(self, case):
         """Under ``axis_name`` each shard sums its own rows densely and the
         ``psum`` adds the shards' (slots, S) tables; a compressed last
-        level places the leaves by the ``pmax`` of the leaf ids each shard
-        read off its rows (a shard may hold no row of a leaf)."""
+        level places the leaves by its slots' node ids, the same on every
+        shard (the carried slots rank the ``psum`` of the occupancy)."""
         import jax
         import jax.numpy as jnp
         from jax import shard_map
@@ -585,6 +587,217 @@ class TestSumForms:
                                "node"), whole, sharded):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=f"{case}: {name}")
+
+
+def _carry_case(name):
+    """(args, kwargs, lane masks or None, traced kwargs) of a ``_grow_tree``
+    call whose deep levels carry their slots (``_carry_slots``)."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu.models import trees as T
+    args, kwargs, masks = _route_case(
+        "vmap_lanes" if name == "vmap_lanes" else "compressed")
+    kwargs = dict(kwargs, gain_fn=T._gini_gain(1.0))
+    traced = {}
+    if name == "after_identity":    # levels 0-2 identity, 3 and 4 carried
+        kwargs.update(depth=5, node_cap=8)
+    elif name == "depth_limit":     # traced: levels 3-5 are denied splits
+        traced["depth_limit"] = jnp.asarray(3)
+    elif name == "cap_one":         # level 0 is no identity level either
+        kwargs.update(depth=3, node_cap=1)
+    elif name == "zero_weight":     # a third of the rows weigh nothing
+        packed, feat_of, block_start, thr, onehot = args
+        weight = (np.arange(packed.shape[0]) % 3 > 0).astype(float)
+        args = (packed, feat_of, block_start, thr,
+                onehot * jnp.asarray(weight)[:, None])
+    else:
+        assert name in ("budget_mask", "vmap_lanes"), name
+    return args, kwargs, masks, traced
+
+
+class TestCarriedSlots:
+    """A level wider than the slot cap takes its slots from the level before
+    (models/trees._carry_slots): the ranks of the occupied node ids in
+    ascending order, worked out on the 2 * C (slot, side) columns and not
+    on the rows. The same integers a sort of the rows' node ids gives."""
+
+    @staticmethod
+    def _grow_levels(monkeypatch, args, kwargs, masks, traced, mode):
+        """The tree and what every call of ``_carry_slots`` took and gave,
+        returned out of the jitted (and vmapped) grower as outputs."""
+        import jax
+        from transmogrifai_tpu.models import trees as T
+        packed, feat_of, block_start, thr, stats = args
+        real = T._carry_slots
+        seen = []
+
+        def spy(slot, node_of_slot, went_right, *rest):
+            out = real(slot, node_of_slot, went_right, *rest)
+            seen.append((slot, node_of_slot, went_right) + tuple(out))
+            return out
+        monkeypatch.setattr(T, "_carry_slots", spy)
+
+        def one(st, tr):
+            del seen[:]
+            tree = T._grow_tree(packed, feat_of, block_start, thr, st,
+                                hist_mode=mode, **kwargs, **tr)
+            return tree, list(seen)
+        if masks is None:
+            tree, levels = jax.jit(one)(stats, traced)
+            lanes = [(tree, levels)]
+        else:
+            tree, levels = jax.jit(jax.vmap(
+                lambda m: one(stats * m[:, None], traced)))(masks)
+            lanes = [jax.tree_util.tree_map(lambda a: a[i], (tree, levels))
+                     for i in range(masks.shape[0])]
+        return [(tuple(np.asarray(a) for a in t),
+                 [tuple(np.asarray(a) for a in lv) for lv in ls])
+                for t, ls in lanes]
+
+    @pytest.mark.parametrize("mode", ["scatter", "matmul"])
+    @pytest.mark.parametrize("case", [
+        "after_identity", "budget_mask", "depth_limit", "cap_one",
+        "vmap_lanes", "zero_weight"])
+    def test_carried_slots_equal_a_ranking_of_the_rows(
+            self, monkeypatch, case, mode):
+        from transmogrifai_tpu.models import trees as T
+        args, kwargs, masks, traced = _carry_case(case)
+        depth, node_cap = kwargs["depth"], kwargs["node_cap"]
+        lanes = self._grow_levels(monkeypatch, args, kwargs, masks, traced,
+                                  mode)
+        carried = [lv for lv in range(1, depth)
+                   if not (2 ** lv <= node_cap
+                           and (lv + 1 == depth or 2 ** (lv + 1) <= node_cap))]
+        most = 0
+        for (_, _, _, final_node), levels in lanes:
+            assert len(levels) == len(carried)
+            for i, (lv, (slot, node_of_slot, went_right, next_slot,
+                         next_node_of_slot, active)) in enumerate(
+                    zip(carried, levels)):
+                C, C_next = (min(2 ** (lv - 1), node_cap),
+                             min(2 ** lv, node_cap))
+                assert node_of_slot.shape == (C,)
+                assert next_node_of_slot.shape == (C_next,)
+                if i == 0 and case != "cap_one":    # after an identity level
+                    np.testing.assert_array_equal(node_of_slot, np.arange(C))
+                if i > 0:       # a carried level hands on what it was given
+                    np.testing.assert_array_equal(slot, levels[i - 1][3])
+                    np.testing.assert_array_equal(node_of_slot,
+                                                  levels[i - 1][4])
+                # every row counts, whatever it weighs
+                node = 2 * node_of_slot[slot] + went_right
+                ids, ranks = np.unique(node, return_inverse=True)
+                np.testing.assert_array_equal(next_slot, ranks,
+                                              err_msg=f"level {lv}")
+                assert int(active) == len(ids) <= C_next
+                np.testing.assert_array_equal(next_node_of_slot[:len(ids)],
+                                              ids)
+                assert (next_node_of_slot[len(ids):]
+                        == T._SLOT_SENTINEL).all()
+                most = max(most, len(ids))
+            # the last level's rows went on from the nodes it was handed
+            np.testing.assert_array_equal(
+                final_node >> 1, levels[-1][4][levels[-1][3]])
+        if case == "cap_one":
+            assert most == 1
+        elif case == "depth_limit":
+            # denied levels route every row left: the slots stop growing
+            assert all(int(lv[5]) == int(levels[1][5]) for lv in levels[1:])
+            assert 1 < most < node_cap
+        else:           # the budget mask binds: a level filled its slots
+            assert most == node_cap
+
+    @pytest.mark.parametrize("case", ["compressed", "vmap_lanes",
+                                      "real_compressed"])
+    @pytest.mark.parametrize("mode", ["scatter", "matmul"])
+    def test_compressed_trees_are_the_parents_bit_for_bit(self, case, mode):
+        """Heaps, leaf sums and final nodes of the compressed cases against
+        digests taken from the commit before the slots were carried (PR 32,
+        70f468a: a sort of the rows' node ids and two n-update scatters a
+        level): the same integers, so the same tree to the last bit. The
+        dense leaf sums of real-valued statistics are left to
+        TestSumForms: their order of summation is the machine's."""
+        import hashlib
+        parent = {
+            "compressed": ("2272c2a39ba0a78f", "72274102b81a71a9",
+                           "38a673f73c47c907", "51e7d986991c8a40"),
+            "vmap_lanes": ("42373553326e7e78", "cea6c946b36c138f",
+                           "5d52f23c9a4e047a", "4a8e926c37aa7782"),
+            "real_compressed": ("5533eac042618848", "7f1fd0bc25a76f14",
+                                "1e0d09119b361401", "60b1544cfb992a8b"),
+        }[case]
+        args, kwargs, masks, exact = _sums_case(case)
+        out = _grow(args, kwargs, masks, mode)
+        for name, a, want in zip(("feat_heap", "thr_heap", "leaf_stats",
+                                  "node"), out, parent):
+            if name == "leaf_stats" and mode == "matmul" and not exact:
+                continue
+            a = np.ascontiguousarray(np.asarray(a))
+            got = hashlib.sha256(str((a.dtype.str, a.shape)).encode()
+                                 + a.tobytes()).hexdigest()[:16]
+            assert got == want, f"{case} {mode}: {name}"
+
+    def test_no_sort_and_no_per_row_scatter_at_depth_12(self):
+        """The traced program of a depth-12 tree at the default cap (levels
+        8-11 carried) under the accelerator's mode: no ``sort`` anywhere,
+        and no scatter with a row's worth of updates. The CPU's mode keeps
+        ``segment_sum``, which is what the walk finds there."""
+        import jax
+        from transmogrifai_tpu.models import trees as T
+        (packed, feat_of, block_start, thr, onehot), kwargs, _ = \
+            _route_case("identity")
+        n = packed.shape[0]
+        kwargs = dict(kwargs, depth=12)
+
+        def eqns(jaxpr):
+            for e in jaxpr.eqns:
+                yield e
+                for v in e.params.values():
+                    for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from eqns(sub)
+
+        def per_row(mode):
+            before = T.tree_compress_levels()["carried"]
+            jaxpr = jax.make_jaxpr(lambda st: T._grow_tree(
+                packed, feat_of, block_start, thr, st, hist_mode=mode,
+                **kwargs))(onehot).jaxpr
+            assert T.tree_compress_levels()["carried"] == before + 4
+            found = list(eqns(jaxpr))
+            names = {e.primitive.name for e in found}
+            assert "cumsum" in names            # the walk reaches the ranks
+            assert "sort" not in names
+            return [e.primitive.name for e in found
+                    if e.primitive.name.startswith("scatter")
+                    and n in e.invars[2].aval.shape]
+        assert n not in (feat_of.shape[0], 2 ** 12, 2 ** 12 - 1, 256, 512)
+        assert per_row("matmul") == []
+        assert set(per_row("scatter")) == {"scatter-add"}
+
+    def test_compress_levels_ride_on_the_fetch_span(self):
+        """``tree_compress_levels()`` counts the carried levels a traced
+        grower holds, and the ``search.fetch`` span carries the count as
+        ``compress_carried``, read again when it closes: the program
+        traced inside it."""
+        from transmogrifai_tpu.models import trees as T
+        from transmogrifai_tpu.observability import trace
+        args, kwargs, _ = _route_case("compressed")     # levels 2-5
+        flat, flat_kwargs, _ = _route_case("identity")
+        before = T.tree_compress_levels()
+        assert set(before) == {"carried"}
+        _grow(flat, flat_kwargs, None, "matmul")
+        assert T.tree_compress_levels() == before
+        trace.configure(True)
+        try:
+            with T._fetch_span():
+                _grow(args, kwargs, None, "matmul")
+            (span,) = [s for s in trace.spans()
+                       if s["name"] == "search.fetch"]
+        finally:
+            trace.configure(False)
+            trace.reset()
+        assert T.tree_compress_levels() == {"carried": before["carried"] + 4}
+        assert span["attrs"]["compress_carried"] == before["carried"] + 4
 
 
 class TestPoolPlan:

@@ -50,12 +50,15 @@ class TestShardedForestParity:
             sharded.predict_values(X), local.predict_values(X),
             atol=1e-9)
 
-    def test_rf_deep_tree_compressed_slots(self, mesh):
-        # depth > 9 exercises _compress_nodes_global (the identity
-        # fast path stops covering every level past the slot cap)
+    @pytest.mark.parametrize("max_depth", [11, 12])
+    def test_rf_deep_tree_compressed_slots(self, mesh, max_depth):
+        # depth > 9 carries compressed slots from level to level (the
+        # identity fast path stops covering every level past the slot
+        # cap): the columns' occupancy is psum-ed over the row shards
+        # (trees._carry_slots), so every shard gives a node the same slot
         X, yc, _ = _data(n=960)
-        est = RandomForestClassifier(num_trees=3, max_depth=11, seed=2,
-                                     min_instances_per_node=1)
+        est = RandomForestClassifier(num_trees=3, max_depth=max_depth,
+                                     seed=2, min_instances_per_node=1)
         local = est.fit_arrays(X, yc)
         sharded = est.fit_arrays_sharded(X, yc, mesh)
         np.testing.assert_array_equal(sharded.feats, local.feats)

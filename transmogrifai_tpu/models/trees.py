@@ -298,48 +298,84 @@ class _PackedDesign:
 # generic level-wise tree builder
 # ---------------------------------------------------------------------------
 
-def _compress_nodes(node: jnp.ndarray, cap: int):
-    """Rank-compress true node ids (n,) into dense slots [0, cap).
-
-    Deep levels of a level-wise tree are mostly empty (at most ``n`` of
-    the ``2^level`` nodes can hold rows, and min-instances constraints
-    shrink that further), so histograms/gains are computed per *active
-    slot*, not per node. Sort-based ranking is O(n log n), all static
-    shapes. Returns (slot_per_row (n,), node_of_slot (cap,) int32 with
-    ``_SLOT_SENTINEL`` for unused slots, active_count scalar).
-    """
-    snode, order = jax.lax.sort_key_val(node, jnp.arange(node.shape[0],
-                                                         dtype=jnp.int32))
-    is_new = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32),
-         (snode[1:] != snode[:-1]).astype(jnp.int32)])
-    rank = jnp.cumsum(is_new) - 1                       # slot of sorted rows
-    slot = jnp.zeros_like(node).at[order].set(rank.astype(node.dtype))
-    node_of_slot = jnp.full((cap,), _SLOT_SENTINEL, jnp.int32).at[
-        rank].set(snode.astype(jnp.int32), mode="drop")
-    return slot, node_of_slot, rank[-1] + 1
+#: how many compressed levels the traced ``_grow_tree`` calls of this process
+#: hold (each one a call of ``_carry_slots``)
+_COMPRESS_LEVELS = {"carried": 0}
 
 
-def _compress_nodes_global(node: jnp.ndarray, cap: int, level_size: int,
-                           axis_name: str):
-    """Rank-compress node ids CONSISTENTLY across row shards.
+def tree_compress_levels() -> dict:
+    """Traced compressed levels of ``_grow_tree`` so far in this process,
+    ``{"carried": k}``: the levels whose slots were carried over from the
+    level before (see _carry_slots), beside :func:`tree_route_forms` and
+    :func:`tree_sum_forms`. An identity level counts nothing."""
+    return dict(_COMPRESS_LEVELS)
 
-    The sort-based :func:`_compress_nodes` ranks whatever nodes the
-    local rows happen to occupy — under row sharding different shards
-    would assign different slots to the same node, and the psum'd
-    histograms would mix nodes. This variant ranks against the GLOBAL
-    occupancy bitmap (one psum of a (2^level,) int vector — the same
-    ICI hop the histograms take), producing the identical
-    ascending-node-id slot order the sort produces on one device.
-    """
-    occ = jnp.zeros((level_size,), jnp.int32).at[node].set(1, mode="drop")
-    occ = (jax.lax.psum(occ, axis_name) > 0).astype(jnp.int32)
-    rank = jnp.cumsum(occ) - 1                      # slot per node id
-    slot = rank[node].astype(node.dtype)
-    node_of_slot = jnp.full((cap,), _SLOT_SENTINEL, jnp.int32).at[
-        jnp.where(occ > 0, rank, cap)].set(
-        jnp.arange(level_size, dtype=jnp.int32), mode="drop")
-    return slot, node_of_slot, jnp.sum(occ)
+
+def _carry_slots(slot: jnp.ndarray, node_of_slot: jnp.ndarray,
+                 went_right: jnp.ndarray, next_slots: int, node_sums,
+                 route: str):
+    """The next level's dense slots from this level's: the rank compression
+    of a level wider than the slot cap, without a sort and without a
+    per-row scatter or gather.
+
+    Deep levels of a level-wise tree are mostly empty, so histograms and
+    gains are computed per *active slot*, not per node: a compressed level's
+    slots are the ranks of its occupied node ids in ascending order. This
+    level's slots (``slot`` (n,); ``node_of_slot`` (C,) holds their node
+    ids, ``_SLOT_SENTINEL`` for an unused one) already ascend with the node
+    id, and a row's next node is ``2 * node + went_right``: so ``column =
+    2 * slot + went_right`` ascends with the next level's node id too, and a
+    row's next slot is the number of occupied columns below its own. The
+    ranking is done on the 2 * C columns, not on the n rows:
+
+    - the columns' row counts come from the tree's ``node_sums`` (its
+      ``_sums_form``, and the ``psum`` of a row-sharded fit: the occupancy
+      is then global and every shard gives a node the same slot). Rows
+      count, not weights: a zero-weight bootstrap row occupies its node;
+    - a row reads its column's rank in the tree's ``route`` form (see
+      _route_form): "gather" indexes the 2 * C ranks; "dense" selects, over
+      the slot axis, one C-entry table keyed by the slot the row already
+      has (a right column has its left sibling's rank, plus one where the
+      sibling is occupied): all integers, rows along the lanes, the slots
+      reduced on the major axis;
+    - the next ``node_of_slot`` takes 2 * C updates.
+
+    One level on a v5e, 54 vmapped lanes x 49,152 rows, 256 slots
+    (builder's chip run, PR 33, PERF.md section 6): 2.6 ms, against 28.5 ms
+    for a sort of the rows' node ids and the two n-update scatters that
+    put the ranks back, and 29 ms with the gather as the read. The read
+    alone 1.3 ms; with the slots on the minor axis, as the routing's
+    tables lie, 2.8 ms, and two tables twice that; the row counts 1.6 ms.
+
+    Returns (slot (n,), node_of_slot (next_slots,), active count). The
+    budget mask of ``_grow_tree`` keeps the count within ``next_slots``."""
+    _COMPRESS_LEVELS["carried"] += 1
+    C = node_of_slot.shape[0]
+    column = 2 * slot + went_right
+    rows = node_sums(jnp.ones((slot.shape[0], 1), jnp.int32), column, 2 * C)
+    occ = (rows[:, 0] > 0).astype(jnp.int32)
+    below = jnp.cumsum(occ) - occ       # an occupied column's rank
+    if route == "dense":
+        table = 2 * below[0::2] + occ[0::2]
+        of_slot = slot[None, :] == jnp.arange(C, dtype=slot.dtype)[:, None]
+        mine = jnp.sum(jnp.where(of_slot, table[:, None], 0), axis=0)
+        next_slot = (mine >> 1) + went_right * (mine & 1)
+    else:
+        next_slot = below[column]
+    next_node_of_slot = jnp.full((next_slots,), _SLOT_SENTINEL, jnp.int32).at[
+        jnp.where(occ > 0, below, next_slots)].set(
+        _child_ids(node_of_slot, _SLOT_SENTINEL), mode="drop")
+    return next_slot, next_node_of_slot, jnp.sum(occ)
+
+
+def _child_ids(node_of_slot: jnp.ndarray, unused: int) -> jnp.ndarray:
+    """(2 * C,) int32 node ids one level down of the two children of every
+    slot, left then right: column ``2 * c + side`` of slot ``c`` holds
+    ``2 * node_of_slot[c] + side``, and ``unused`` under a slot that holds
+    ``_SLOT_SENTINEL``."""
+    parent = jnp.repeat(node_of_slot, 2)
+    side = jnp.arange(parent.shape[0], dtype=jnp.int32) & 1
+    return jnp.where(parent == _SLOT_SENTINEL, unused, 2 * parent + side)
 
 
 _SLOT_SENTINEL = jnp.iinfo(jnp.int32).max
@@ -610,17 +646,18 @@ def tree_eval_forms() -> dict:
 @contextlib.contextmanager
 def _fetch_span():
     """The ``search.fetch`` span of a fold-grid driver, carrying
-    :func:`tree_route_forms`, :func:`tree_sum_forms` and
-    :func:`tree_eval_forms` as the scalar attributes ``route_dense`` /
-    ``route_gather``, ``sums_dense`` / ``sums_scatter`` and ``eval_in_fit``
-    / ``eval_traverse``: read when the span opens (what its profiler
+    :func:`tree_route_forms`, :func:`tree_sum_forms`,
+    :func:`tree_eval_forms` and :func:`tree_compress_levels` as the scalar
+    attributes ``route_dense`` / ``route_gather``, ``sums_dense`` /
+    ``sums_scatter``, ``eval_in_fit`` / ``eval_traverse`` and
+    ``compress_carried``: read when the span opens (what its profiler
     annotation keeps) and again when it closes, because a program's first
     call traces inside the span."""
     def attrs():
-        out = {"route_" + k: v for k, v in tree_route_forms().items()}
-        out.update(("sums_" + k, v) for k, v in tree_sum_forms().items())
-        out.update(("eval_" + k, v) for k, v in tree_eval_forms().items())
-        return out
+        return {prefix + k: v for prefix, counts in (
+            ("route_", tree_route_forms()), ("sums_", tree_sum_forms()),
+            ("eval_", tree_eval_forms()),
+            ("compress_", tree_compress_levels())) for k, v in counts.items()}
     with _trace.span("search.fetch", **attrs()) as rec:
         yield
         if rec is not None:
@@ -705,6 +742,14 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     allreduce, SURVEY §2.9). ``row_total`` must then carry the GLOBAL
     row count (slot caps must not depend on the shard-local count).
 
+    A level wider than the cap (or whose next level is) is a COMPRESSED
+    level: its slots are the ranks of the occupied node ids in ascending
+    order, carried over from the level before at that level's end (scope
+    ``tree.compress``, see _carry_slots: the occupancy of the 2 * C
+    (slot, side) columns in the node sums' form, a rank table read in the
+    routing's form; no sort, no per-row scatter, the same slots on every
+    row shard). Every other level is an identity level: slots are node ids.
+
     The node sums (scope ``tree.node_sums``: each level's per-slot
     ``total``, with the row count of an identity level as one more
     column, and the per-leaf ``leaf_stats``) are sums of ``stats`` in its
@@ -754,31 +799,28 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     key = feat_key
     prev_hist = None        # previous level's (C_prev, TB, S) histogram
     prev_identity = False
-    for level in range(depth):
+
+    def is_identity(level):
         # identity fast path: while every within-level node id fits the
         # slot cap AND the next level's budget mask cannot bind
         # (2^(level+1) <= cap, or this is the last level), slots ARE
-        # node ids — the O(n log n) rank-compression sort is skipped
-        # entirely. Empty nodes produce all-zero histograms -> -inf
-        # gains -> they write the already-initialized (0, inf) heap
-        # entries, so results are bit-identical to the compressed path.
-        # With the default cap (256) this covers every level of trees up
-        # to depth 9; only deeper trees pay for compression.
-        identity = 2 ** level <= cap and (
+        # node ids and nothing is ranked. Empty nodes produce all-zero
+        # histograms -> -inf gains -> they write the already-initialized
+        # (0, inf) heap entries, so results are bit-identical to the
+        # compressed path. With the default cap (256) this covers every
+        # level of trees up to depth 9; only deeper trees carry compressed
+        # slots from level to level (_carry_slots), and once a level is
+        # compressed every later one is.
+        return 2 ** level <= cap and (
             level + 1 == depth or 2 ** (level + 1) <= cap)
+    # a level 0 that is no identity level (cap == 1) has one slot
+    slot, node_of_slot, active = node, jnp.zeros((1,), jnp.int32), 1
+    for level in range(depth):
+        identity = is_identity(level)
+        C = min(2 ** level, cap)                   # static slots this level
         if identity:
-            C = 2 ** level
             slot = node
             node_of_slot = jnp.arange(C, dtype=jnp.int32)
-            active = None
-        else:
-            C = min(2 ** level, cap)               # static slots this level
-            with jax.named_scope("tree.compress"):
-                if axis_name:
-                    slot, node_of_slot, active = _compress_nodes_global(
-                        node, C, 2 ** level, axis_name)
-                else:
-                    slot, node_of_slot, active = _compress_nodes(node, C)
         with jax.named_scope("tree.hist"):
             if (sub_enabled and identity and prev_identity
                     and prev_hist is not None):
@@ -887,6 +929,11 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
             # within-level index
             went_right = 1 - go_left.astype(jnp.int32)
             node = 2 * node + went_right
+        if level + 1 < depth and not is_identity(level + 1):
+            with jax.named_scope("tree.compress"):
+                slot, node_of_slot, active = _carry_slots(
+                    slot, node_of_slot, went_right,
+                    min(2 ** (level + 1), cap), node_sums, route)
     with jax.named_scope("tree.node_sums"):
         if sums == "scatter" or depth == 0 or identity:
             # the last level's slots were its node ids (or there is none):
@@ -894,23 +941,14 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
             leaf_stats = node_sums(stats, node, 2 ** depth)
         else:
             # a leaf is (last level's slot, side): summed over 2 * C columns,
-            # not 2^depth segments, then placed by the columns' leaf ids (a
-            # column no row reached lands nowhere: its leaf stays zero, as
-            # an empty segment does). The ids are read off the rows by the
-            # same select, reduced by min over a column's rows (all in one
-            # leaf; 2^depth, out of the table, where there is none):
-            # ``node_of_slot`` would keep the last level's n-update scatter
-            # of ``tree.compress`` alive in the programs that return no heap
-            column = 2 * slot + went_right
-            by_column = node_sums(stats, column, 2 * C)
-            of_column = column[None, :] == jnp.arange(
-                2 * C, dtype=column.dtype)[:, None]
-            leaf_of = jnp.min(
-                jnp.where(of_column, node[None, :], 2 ** depth), axis=1)
-            if axis_name:
-                leaf_of = jax.lax.pmin(leaf_of, axis_name)
+            # not 2^depth segments, then placed by the columns' leaf ids,
+            # which the slots' node ids give (a column no row reached adds
+            # zeros to its leaf, as an empty segment does; an unused slot's
+            # two columns land nowhere)
+            by_column = node_sums(stats, 2 * slot + went_right, 2 * C)
             leaf_stats = jnp.zeros((2 ** depth, stats.shape[1]),
-                                   stats.dtype).at[leaf_of].set(
+                                   stats.dtype).at[
+                _child_ids(node_of_slot, 2 ** depth)].set(
                 by_column, mode="drop")
     return feat_heap, thr_heap, leaf_stats, node
 
