@@ -208,11 +208,13 @@ def test_every_scope_is_used_in_trees():
     on it is opened somewhere in the four modules that build the fit and
     fold-grid programs, and they open no other (ISSUE 28 added the linear
     family's in ``models/linear.py`` and ``parallel/cv.py``, ISSUE 32 the
-    naive Bayes program's in ``models/bayes.py``)."""
-    from transmogrifai_tpu.models import bayes, linear
+    naive Bayes program's in ``models/bayes.py``, ISSUE 34 the IRLS
+    program's ``fg.glm``, ``glm.gram`` and ``glm.solve`` in
+    ``models/glm.py``)."""
+    from transmogrifai_tpu.models import bayes, glm, linear
     from transmogrifai_tpu.parallel import cv
     source = "".join(open(module.__file__).read()
-                     for module in (trees, linear, cv, bayes))
+                     for module in (trees, linear, cv, bayes, glm))
     for scope in trees.SCOPES:
         assert f'jax.named_scope("{scope}")' in source
     assert len(set(trees.SCOPES)) == len(trees.SCOPES)

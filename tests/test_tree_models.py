@@ -431,7 +431,8 @@ class TestRouteForms:
 
 def _sums_case(name):
     """``_route_case`` plus two with S = 3 real-valued regression statistics
-    (weights, w*y, w*y*y under the variance gain): every level an identity
+    (``_variance_stats``: weights and w*y in two pieces, under the variance
+    gain): every level an identity
     level, and a ``node_cap=7`` tree whose last level is compressed, so its
     leaves are summed by (slot, side) and placed by the slots' node ids.
     Returns the case and whether
@@ -447,8 +448,8 @@ def _sums_case(name):
     w = rng.uniform(0.5, 2.0, size=n)
     target = (np.asarray(packed[:, 0]) / 4.0 - np.asarray(packed[:, 3]) % 5
               + rng.normal(size=n))
-    stats = jnp.asarray(np.stack([w, w * target, w * target ** 2], axis=1),
-                        thr.dtype)
+    stats = T._variance_stats(jnp.asarray(w, thr.dtype),
+                              jnp.asarray(target, thr.dtype))
     # a node of 20 rows a side has no second split that mirrors its best
     kwargs.update(gain_fn=T._variance_gain(20.0))
     return (packed, feat_of, block_start, thr, stats), kwargs, masks, False
@@ -714,8 +715,10 @@ class TestCarriedSlots:
         digests taken from the commit before the slots were carried (PR 32,
         70f468a: a sort of the rows' node ids and two n-update scatters a
         level): the same integers, so the same tree to the last bit. The
-        dense leaf sums of real-valued statistics are left to
-        TestSumForms: their order of summation is the machine's."""
+        leaf sums of real-valued statistics are left to TestSumForms: the
+        dense form's order of summation is the machine's, and since PR 34
+        the regression columns are ``_variance_stats``'s (w*y in two pieces,
+        no squares), not the parent's; heaps and nodes still are."""
         import hashlib
         parent = {
             "compressed": ("2272c2a39ba0a78f", "72274102b81a71a9",
@@ -729,7 +732,7 @@ class TestCarriedSlots:
         out = _grow(args, kwargs, masks, mode)
         for name, a, want in zip(("feat_heap", "thr_heap", "leaf_stats",
                                   "node"), out, parent):
-            if name == "leaf_stats" and mode == "matmul" and not exact:
+            if name == "leaf_stats" and not exact:
                 continue
             a = np.ascontiguousarray(np.asarray(a))
             got = hashlib.sha256(str((a.dtype.str, a.shape)).encode()

@@ -79,8 +79,10 @@ __all__ = [
 #: pool, a boosting round, the validation metric of the ``*_eval_kernel``s
 #: (and of the linear fold-grid programs), one ``fg.<family>`` around each
 #: fold-grid program's body (``fg.softmax``: the multinomial logistic lanes,
-#: ``fg.bayes``: ``models/bayes.py``), and the linear cores' ``lin.*``
-#: (``models/linear.py``, ``parallel/cv.py``). The one list of the package:
+#: ``fg.bayes``: ``models/bayes.py``; ``fg.glm``: the IRLS lanes of
+#: ``models/glm.py``, with ``glm.gram`` and ``glm.solve`` inside an
+#: iteration), and the linear cores' ``lin.*`` (``models/linear.py``,
+#: ``parallel/cv.py``). The one list of the package:
 #: the benchmark's scope readers take it from this attribute. A scope is a
 #: path component of the ``op_name`` of the ops traced under it and exists
 #: only while JAX traces: it adds no operation and changes no program's name.
@@ -88,7 +90,7 @@ SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
           "tree.split", "tree.route", "tree.bootstrap", "tree.pool",
           "gbt.round", "fg.metric", "fg.gbt", "fg.forest",
           "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve",
-          "fg.softmax", "fg.bayes")
+          "fg.softmax", "fg.bayes", "fg.glm", "glm.gram", "glm.solve")
 
 # ---------------------------------------------------------------------------
 # binning — packed variable-width bins
@@ -1018,13 +1020,44 @@ def _entropy_gain(min_instances: float):
     return gain
 
 
+def _variance_stats(w: jnp.ndarray, yc: jnp.ndarray) -> jnp.ndarray:
+    """(n, 3) statistics of a regression tree, ``[w, hi, lo]`` with ``hi +
+    lo == w * yc`` exactly: the rows' weights and their weighted CENTRED
+    labels (``yc``: the label less the lane's masked mean, see
+    _forest_body), the real-valued column in two pieces of which each
+    survives the chip's level histogram. That histogram is a
+    default-precision einsum, one bf16 pass over the statistics (see
+    _hist_mode), which keeps 8 significant bits of a value: small integers
+    (weights, class indicators) pass through it exactly, a real number does
+    not. ``hi`` is ``w * yc`` rounded to those 8 bits (``reduce_precision``,
+    an operation of its own: a float32 -> bfloat16 -> float32 round trip is
+    one XLA may drop), ``lo`` the remainder, itself rounded by the pass to
+    its own 8 bits: together 16 bits, an error of 2^-17 of a row's value
+    where the single column's is 2^-9. On a CPU, whose histogram adds in
+    the statistics' own dtype, both pieces are exact and their sums differ
+    from the one column's in summation order only.
+
+    No column of squares: with ``right = total - left`` the squares'
+    sums cancel out of a split's gain (see _variance_gain), and a leaf's
+    value needs none."""
+    v = w * yc
+    hi = jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+    return jnp.stack([w, hi, v - hi], axis=1)
+
+
 def _variance_gain(min_instances: float):
-    """SSE-reduction gain (stats = [w, wy, wyy]); MLlib 'variance'."""
-    def sse(s):
-        return s[..., 2] - s[..., 1] ** 2 / jnp.maximum(s[..., 0], 1e-12)
+    """SSE-reduction gain over ``_variance_stats``; MLlib 'variance'. The
+    gain of a split, ``(SSE(total) - SSE(left) - SSE(right)) / w(total)``
+    with ``SSE(s) = sum(w y^2) - sum(w y)^2 / sum(w)``, is ``(sum_l^2 / w_l
+    + sum_r^2 / w_r - sum_t^2 / w_t) / w_t`` in the sums of ``w * y``
+    alone, the sums of squares of the two children adding up to the
+    parent's; it is invariant under a shift of the label, which is what
+    lets the statistics carry the centred one."""
+    def score(s):
+        return (s[..., 1] + s[..., 2]) ** 2 / jnp.maximum(s[..., 0], 1e-12)
     def gain(left, right, total):
         wp = jnp.maximum(total[..., 0], 1e-12)
-        g = (sse(total) - sse(left) - sse(right)) / wp
+        g = (score(left) + score(right) - score(total)) / wp
         ok = ((left[..., 0] >= min_instances)
               & (right[..., 0] >= min_instances))
         return jnp.where(ok, g, -jnp.inf)
@@ -1191,6 +1224,15 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
                    else _entropy_gain(min_instances))
     else:
         gain_fn = _variance_gain(min_instances)
+        # a regression lane's statistics carry the label CENTRED on the
+        # lane's masked mean, which re-enters at the leaves: the gain does
+        # not see the shift (see _variance_gain), and a label of 1998 +- 11
+        # would spend its significant bits on the 1998 (_variance_stats)
+        def _gsum(v):
+            return jax.lax.psum(v, axis_name) if axis_name else v
+        y_mean = _gsum(jnp.sum(mask * y)) / jnp.maximum(
+            _gsum(jnp.sum(mask)), 1.0)
+        y_centred = y - y_mean
 
     def one_tree(tkey):
         pkey, wkey, fkey = jax.random.split(tkey, 3)
@@ -1204,7 +1246,7 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
                 w = jnp.ones((n,), dtype)
             w = w * mask
             stats = (onehot * w[:, None] if kind == "cls"
-                     else jnp.stack([w, w * y, w * y * y], axis=1))
+                     else _variance_stats(w, y_centred))
         if pool_cfg is not None:
             with jax.named_scope("tree.pool"):
                 pool, p_sub, fo_sub, bs_sub, thr_sub = _tree_pool(
@@ -1228,7 +1270,8 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
             leaf = jnp.where(lw > 0, leaf_stats / jnp.maximum(lw, 1e-12),
                              1.0 / num_classes)
         else:
-            leaf = leaf_stats[:, 1] / jnp.maximum(leaf_stats[:, 0], 1e-12)
+            leaf = y_mean + (leaf_stats[:, 1] + leaf_stats[:, 2]
+                             ) / jnp.maximum(leaf_stats[:, 0], 1e-12)
         if val_rows is None:
             return feat, thr, leaf
         return feat, thr, leaf, node[val_rows]
