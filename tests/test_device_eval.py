@@ -326,8 +326,9 @@ def _infit_table(family, seed=29):
 
 def _infit_family(family):
     """(estimator, grid, evaluator) of a family: depth 12 and a shallower
-    lane, so that under the ``mask`` depth mode one program at the cap runs
-    ``_compress_nodes``, the budget mask and a traced depth limit."""
+    lane, so that under the ``blocks`` depth mode one program runs a
+    depth-3 block beside a depth-12 one with its carried slots
+    (``_carry_slots``) and the budget mask."""
     from transmogrifai_tpu.models import XGBoostClassifier
     deep = dict(max_depth=12, max_bins=16)
     if family in ("forest_cls", "forest_pooled"):
@@ -364,7 +365,7 @@ def infit_env(monkeypatch):
     def configure(binning):
         monkeypatch.setattr(trees, "_bin_on_device",
                             lambda elems: binning == "device")
-        monkeypatch.setattr(trees, "_depth_mode", lambda: "mask")
+        monkeypatch.setattr(trees, "_depth_mode", lambda: "blocks")
         trees.clear_design_cache()
     yield configure
     trees.clear_design_cache()
@@ -401,7 +402,6 @@ def test_in_fit_leaves_are_the_heaps_leaves(infit_env, family, binning):
     design, widths = trees._design_args(X, est.max_bins)
     hist = trees._hist_mode(X.shape[0], int(design[1].shape[0]))
     key = jax.random.PRNGKey(3)
-    limit = jnp.asarray(12.0)          # traced, as a mask-depth lane's is
     Xa = jnp.asarray(X)
 
     def walked(feats, thrs):
@@ -421,20 +421,20 @@ def test_in_fit_leaves_are_the_heaps_leaves(infit_env, family, binning):
             hist_mode=hist))
         feats, thrs, leaves, leaf = body(
             *design, narrow, wide, jnp.asarray(y), key, mask, 1.0, 0.0,
-            1.0, depth_limit=limit, val_rows=jnp.asarray(va))
+            1.0, val_rows=jnp.asarray(va))
         np.testing.assert_array_equal(np.asarray(leaf),
                                       walked(feats, thrs)[:, va])
         assert len(np.unique(np.asarray(leaf))) > 8
         # and without the rows the body returns what it always did
         assert len(body(*design, narrow, wide, jnp.asarray(y), key, mask,
-                        1.0, 0.0, 1.0, depth_limit=limit)) == 3
+                        1.0, 0.0, 1.0)) == 3
     elif family == "gbt_softmax":
         body = jax.jit(functools.partial(
             trees._gbt_softmax_body, depth=12, num_rounds=2,
             num_classes=3, hist_mode=hist))
         feats, thrs, leaves, base, margins = body(
             *design[:4], jnp.asarray(y), key, mask, 0.3, 1.0, 0.0, 0.0,
-            1.0, depth_limit=limit)
+            1.0)
         want = np.asarray(trees._softmax_margins(
             feats, thrs, leaves, base, 12, Xa))
         np.testing.assert_allclose(np.asarray(margins), want, atol=1e-9)
@@ -445,7 +445,7 @@ def test_in_fit_leaves_are_the_heaps_leaves(infit_env, family, binning):
             objective="logistic" if family == "gbt_bin" else "squared"))
         feats, thrs, leaves, base, margins = body(
             *design[:4], jnp.asarray(y), key, mask, 0.1, 1.0, 0.0, 0.0,
-            1.0, depth_limit=limit)
+            1.0)
         leaf = walked(feats, thrs)
         want = np.asarray(base) + np.asarray(leaves)[
             np.arange(3)[:, None], leaf].sum(axis=0)

@@ -157,9 +157,10 @@ class TestSoftmaxFoldGrid:
         np.testing.assert_array_equal(ms[1][0].feats, seq.feats)
         np.testing.assert_array_equal(ms[1][0].leaves, seq.leaves)
 
-    def test_mask_depth_models_match_static(self, monkeypatch):
-        """Softmax lanes under the ``mask`` depth mode trim back to their
-        own depth bit-exactly (leaf_axis=2 stride)."""
+    def test_depth_block_models_match_static(self, monkeypatch):
+        """Softmax lanes under the ``blocks`` depth mode come back at their
+        own depth, bit-equal to the per-depth ``static`` programs' (the
+        (R, K, H) heaps and (R, K, L) leaves of each block)."""
         from transmogrifai_tpu.models import trees
         from transmogrifai_tpu.models.trees import XGBoostClassifier
         X, y, masks, _, _ = self._data()
@@ -167,10 +168,11 @@ class TestSoftmaxFoldGrid:
         grid = [{"max_depth": 2}, {"max_depth": 4}]
         monkeypatch.setattr(trees, "_depth_mode", lambda: "static")
         ms = est.fit_fold_grid_arrays(X, y, masks[:1], grid)
-        monkeypatch.setattr(trees, "_depth_mode", lambda: "mask")
+        monkeypatch.setattr(trees, "_depth_mode", lambda: "blocks")
         mk = est.fit_fold_grid_arrays(X, y, masks[:1], grid)
         for gi in range(2):
             np.testing.assert_array_equal(ms[0][gi].feats, mk[0][gi].feats)
             np.testing.assert_array_equal(ms[0][gi].leaves,
                                           mk[0][gi].leaves)
-            assert ms[0][gi].depth == mk[0][gi].depth
+            assert ms[0][gi].depth == mk[0][gi].depth == grid[gi]["max_depth"]
+            assert mk[0][gi].feats.shape[-1] == 2 ** mk[0][gi].depth - 1

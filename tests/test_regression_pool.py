@@ -382,10 +382,10 @@ def test_only_the_boosted_program_is_named_jit_batched():
     """Of the pool's four families' fold-grid kernels, the boosted one alone
     bears the name every ``search_*_s`` / ``pool_gbt_s`` reader sums."""
     names = {
-        "gbt": trees._gbt_eval_kernel((3, 2, "squared", "scatter"), SPEC,
+        "gbt": trees._gbt_eval_kernel(((3,), 2, "squared", "scatter"), SPEC,
                                       None, True).__name__,
         "forest": trees._forest_eval_kernel(
-            ("reg", 3, 0, 2, None, None, "", True, "scatter"), SPEC, None,
+            ("reg", (3,), 0, 2, None, None, "", True, "scatter"), SPEC, None,
             True).__name__,
         "linear": cv._local_eval_kernel(cv._kernel_cfg(
             "squared", True, True, True, 50, None), SPEC).__name__,

@@ -134,7 +134,10 @@ def test_no_gather_under_tree_route(program, request):
     selects over the slot and the column axis; the scope keeps its name."""
     hlo = request.getfixturevalue(program)
     routed = [line for line in hlo.splitlines() if "tree.route" in line]
-    assert any("tree.route/reduce_or" in line for line in routed)
+    # ``vmap(tree.route)``: the scope is the first opened under the ``vmap``
+    # over the lanes still growing (trees._grow_blocks)
+    assert any(re.search(r"tree\.route\)?/reduce_or", line)
+               for line in routed)
     assert not [line for line in routed
                 if re.search(r"= \S+ gather\(", line)]
 

@@ -340,8 +340,9 @@ def _route_case(name):
     masks = None
     if name == "compressed":        # 2^3 > 7: levels 2-5 rank-compress and
         kwargs.update(depth=6, node_cap=7)      # the budget mask binds
-    elif name == "depth_limit":     # levels 2-4 are denied: all rows left
-        kwargs.update(depth=5, depth_limit=jnp.asarray(2))
+    elif name == "denied_levels":   # a child holds 151 rows or more, so
+        # no node of level 2 (298 rows at most) splits: all rows go left
+        kwargs.update(depth=5, gain_fn=T._gini_gain(151.0))
     elif name == "wide_bins":
         assert int(feat_of.shape[0]) > 256
         kwargs.update(depth=5)
@@ -381,7 +382,7 @@ class TestRouteForms:
     alike: the same integers, not close ones."""
 
     @pytest.mark.parametrize("case", [
-        "identity", "compressed", "depth_limit", "pooled", "wide_bins",
+        "identity", "compressed", "denied_levels", "pooled", "wide_bins",
         "vmap_lanes"])
     def test_dense_route_equals_gather_route(self, case):
         from transmogrifai_tpu.models import trees as T
@@ -398,7 +399,7 @@ class TestRouteForms:
                                           err_msg=f"{case}: {name}")
         _, thr_heap, _, node = (np.asarray(a) for a in dense)
         assert np.isfinite(thr_heap).sum() >= 2     # a tree was grown
-        if case == "depth_limit":   # the TB sentinel: nobody went right
+        if case == "denied_levels":  # the TB sentinel: nobody went right
             assert (node % 8 == 0).all() and len(np.unique(node)) > 1
         if case == "vmap_lanes":
             assert not np.array_equal(node[0], node[1])
@@ -464,7 +465,7 @@ class TestSumForms:
     they are real."""
 
     @pytest.mark.parametrize("case", [
-        "identity", "compressed", "depth_limit", "pooled", "wide_bins",
+        "identity", "compressed", "denied_levels", "pooled", "wide_bins",
         "vmap_lanes", "real_stats", "real_compressed"])
     def test_dense_sums_equal_scatter_sums(self, case):
         from transmogrifai_tpu.models import trees as T
@@ -591,18 +592,18 @@ class TestSumForms:
 
 
 def _carry_case(name):
-    """(args, kwargs, lane masks or None, traced kwargs) of a ``_grow_tree``
-    call whose deep levels carry their slots (``_carry_slots``)."""
+    """(args, kwargs, lane masks or None) of a ``_grow_tree`` call whose
+    deep levels carry their slots (``_carry_slots``)."""
     import jax.numpy as jnp
     from transmogrifai_tpu.models import trees as T
     args, kwargs, masks = _route_case(
         "vmap_lanes" if name == "vmap_lanes" else "compressed")
     kwargs = dict(kwargs, gain_fn=T._gini_gain(1.0))
-    traced = {}
     if name == "after_identity":    # levels 0-2 identity, 3 and 4 carried
         kwargs.update(depth=5, node_cap=8)
-    elif name == "depth_limit":     # traced: levels 3-5 are denied splits
-        traced["depth_limit"] = jnp.asarray(3)
+    elif name == "denied_levels":   # a child holds 130 rows or more, so no
+        # node of levels 3-5 (210 rows at most) splits
+        kwargs.update(gain_fn=T._gini_gain(130.0))
     elif name == "cap_one":         # level 0 is no identity level either
         kwargs.update(depth=3, node_cap=1)
     elif name == "zero_weight":     # a third of the rows weigh nothing
@@ -612,7 +613,7 @@ def _carry_case(name):
                 onehot * jnp.asarray(weight)[:, None])
     else:
         assert name in ("budget_mask", "vmap_lanes"), name
-    return args, kwargs, masks, traced
+    return args, kwargs, masks
 
 
 class TestCarriedSlots:
@@ -622,7 +623,7 @@ class TestCarriedSlots:
     on the rows. The same integers a sort of the rows' node ids gives."""
 
     @staticmethod
-    def _grow_levels(monkeypatch, args, kwargs, masks, traced, mode):
+    def _grow_levels(monkeypatch, args, kwargs, masks, mode):
         """The tree and what every call of ``_carry_slots`` took and gave,
         returned out of the jitted (and vmapped) grower as outputs."""
         import jax
@@ -637,17 +638,17 @@ class TestCarriedSlots:
             return out
         monkeypatch.setattr(T, "_carry_slots", spy)
 
-        def one(st, tr):
+        def one(st):
             del seen[:]
             tree = T._grow_tree(packed, feat_of, block_start, thr, st,
-                                hist_mode=mode, **kwargs, **tr)
+                                hist_mode=mode, **kwargs)
             return tree, list(seen)
         if masks is None:
-            tree, levels = jax.jit(one)(stats, traced)
+            tree, levels = jax.jit(one)(stats)
             lanes = [(tree, levels)]
         else:
             tree, levels = jax.jit(jax.vmap(
-                lambda m: one(stats * m[:, None], traced)))(masks)
+                lambda m: one(stats * m[:, None])))(masks)
             lanes = [jax.tree_util.tree_map(lambda a: a[i], (tree, levels))
                      for i in range(masks.shape[0])]
         return [(tuple(np.asarray(a) for a in t),
@@ -656,15 +657,14 @@ class TestCarriedSlots:
 
     @pytest.mark.parametrize("mode", ["scatter", "matmul"])
     @pytest.mark.parametrize("case", [
-        "after_identity", "budget_mask", "depth_limit", "cap_one",
+        "after_identity", "budget_mask", "denied_levels", "cap_one",
         "vmap_lanes", "zero_weight"])
     def test_carried_slots_equal_a_ranking_of_the_rows(
             self, monkeypatch, case, mode):
         from transmogrifai_tpu.models import trees as T
-        args, kwargs, masks, traced = _carry_case(case)
+        args, kwargs, masks = _carry_case(case)
         depth, node_cap = kwargs["depth"], kwargs["node_cap"]
-        lanes = self._grow_levels(monkeypatch, args, kwargs, masks, traced,
-                                  mode)
+        lanes = self._grow_levels(monkeypatch, args, kwargs, masks, mode)
         carried = [lv for lv in range(1, depth)
                    if not (2 ** lv <= node_cap
                            and (lv + 1 == depth or 2 ** (lv + 1) <= node_cap))]
@@ -700,7 +700,7 @@ class TestCarriedSlots:
                 final_node >> 1, levels[-1][4][levels[-1][3]])
         if case == "cap_one":
             assert most == 1
-        elif case == "depth_limit":
+        elif case == "denied_levels":
             # denied levels route every row left: the slots stop growing
             assert all(int(lv[5]) == int(levels[1][5]) for lv in levels[1:])
             assert 1 < most < node_cap
@@ -986,56 +986,67 @@ class TestFoldEdges:
         assert abs(mm_fold_gbt.mean() - mm_default_gbt.mean()) < 0.2
 
 
-class TestDepthMask:
-    """The ``mask`` depth mode (VERDICT r4 #3; models/trees._depth_mode,
-    the accelerator's side of the rule): one program per tree family —
-    depth becomes a traced per-lane limit at the grid's max depth.
-    Metrics must be BIT-identical to the per-depth static programs
-    (masked levels deny splits; a denied split routes all rows left)."""
+def _depth_blocks_table(seed=4, n=150, d=4, F=2):
+    """A small binary table, F folds that hold out every F-th row, and the
+    validation folds in both forms: the rows' values and their positions."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d))
+    y = (X[:, 0] + 0.5 * rng.normal(size=n) > 0).astype(float)
+    masks = np.ones((F, n))
+    for f in range(F):
+        masks[f, f::F] = 0.0
+    nv = min(int((masks[f] == 0).sum()) for f in range(F))
+    rows = np.stack([np.nonzero(masks[f] == 0)[0][:nv] for f in range(F)])
+    return X, y, masks, X[rows], y[rows], rows
 
-    def test_mask_mode_metrics_identical(self, monkeypatch):
+
+class TestDepthBlocks:
+    """The ``blocks`` depth mode (models/trees._depth_mode, the
+    accelerator's side of the rule): one program per tree family, its
+    lanes in static depth blocks, one a distinct ``max_depth`` of the grid
+    (``_candidate_groups``, ``_vmap_blocks``). A block's body is
+    what the per-depth ``static`` program traces, so metrics and fitted
+    models must be BIT-identical to ``static`` on a CPU."""
+
+    @staticmethod
+    def _both(monkeypatch, call):
+        import transmogrifai_tpu.models.trees as T
+        out = []
+        for mode in ("static", "blocks"):
+            monkeypatch.setattr(T, "_depth_mode", lambda mode=mode: mode)
+            out.append(call())
+        return out
+
+    @pytest.mark.parametrize("form", ["traverse", "in_fit"])
+    def test_blocks_mode_metrics_identical(self, monkeypatch, form):
+        """The fused fit+metric kernels, in both forms of ``fg.metric``."""
         from transmogrifai_tpu.evaluators import \
             BinaryClassificationEvaluator
-        import transmogrifai_tpu.models.trees as T
         from transmogrifai_tpu.models.trees import (
             GBTClassifier, RandomForestClassifier, _forest_fold_grid,
             _gbt_fold_grid)
-        rng = np.random.default_rng(4)
-        n, d, F = 150, 4, 2
-        X = rng.normal(size=(n, d))
-        y = (X[:, 0] + 0.5 * rng.normal(size=n) > 0).astype(float)
-        masks = np.ones((F, n))
-        for f in range(F):
-            masks[f, f::F] = 0.0
-        Xv = np.stack([X[masks[f] == 0][:70] for f in range(F)])
-        yv = np.stack([y[masks[f] == 0][:70] for f in range(F)])
+        X, y, masks, Xv, yv, rows = _depth_blocks_table()
         spec = BinaryClassificationEvaluator().device_metric_spec()
+        ctx = ((Xv, yv, spec) if form == "traverse"
+               else (None, yv, spec, rows))
         grid_rf = [{"max_depth": dd, "min_instances_per_node": m}
                    for dd in (2, 4) for m in (5, 20)]
         grid_gbt = [{"max_depth": dd} for dd in (2, 4)]
-
-        monkeypatch.setattr(T, "_depth_mode", lambda: "static")
-        mm_s_rf = _forest_fold_grid(
+        mm_s_rf, mm_b_rf = self._both(monkeypatch, lambda: _forest_fold_grid(
             RandomForestClassifier(num_trees=5), X, y, masks, grid_rf,
-            None, True, eval_ctx=(Xv, yv, spec))
-        mm_s_gbt = _gbt_fold_grid(
+            None, True, eval_ctx=ctx))
+        mm_s_gbt, mm_b_gbt = self._both(monkeypatch, lambda: _gbt_fold_grid(
             GBTClassifier(num_rounds=3), X, y, masks, grid_gbt, None,
-            "logistic", eval_ctx=(Xv, yv, spec))
-        monkeypatch.setattr(T, "_depth_mode", lambda: "mask")
-        mm_m_rf = _forest_fold_grid(
-            RandomForestClassifier(num_trees=5), X, y, masks, grid_rf,
-            None, True, eval_ctx=(Xv, yv, spec))
-        mm_m_gbt = _gbt_fold_grid(
-            GBTClassifier(num_rounds=3), X, y, masks, grid_gbt, None,
-            "logistic", eval_ctx=(Xv, yv, spec))
-        np.testing.assert_array_equal(mm_s_rf, mm_m_rf)
-        np.testing.assert_array_equal(mm_s_gbt, mm_m_gbt)
+            "logistic", eval_ctx=ctx))
+        assert np.isfinite(mm_s_rf).all() and np.isfinite(mm_s_gbt).all()
+        assert len(np.unique(mm_s_rf)) > 4
+        np.testing.assert_array_equal(mm_s_rf, mm_b_rf)
+        np.testing.assert_array_equal(mm_s_gbt, mm_b_gbt)
 
-    def test_mask_mode_fitted_models_identical(self, monkeypatch):
-        """The non-eval (model-materializing) path agrees too: a
-        depth-2 lane grown under a depth-4 cap predicts exactly like
-        the static depth-2 program."""
-        import transmogrifai_tpu.models.trees as T
+    def test_blocks_mode_fitted_models_identical(self, monkeypatch):
+        """The non-eval (model-materializing) path agrees too: a block's
+        heaps come back at its lanes' own depth, and a depth-2 lane beside
+        a depth-4 one predicts exactly like the static depth-2 program."""
         from transmogrifai_tpu.models.trees import (
             RandomForestClassifier, _forest_fold_grid)
         rng = np.random.default_rng(6)
@@ -1044,17 +1055,301 @@ class TestDepthMask:
         y = (X[:, 0] > 0).astype(float)
         masks = np.ones((1, n))
         grid = [{"max_depth": dd} for dd in (2, 4)]
-        monkeypatch.setattr(T, "_depth_mode", lambda: "static")
-        ms = _forest_fold_grid(RandomForestClassifier(num_trees=4),
-                               X, y, masks, grid, None, True)
-        monkeypatch.setattr(T, "_depth_mode", lambda: "mask")
-        mk = _forest_fold_grid(RandomForestClassifier(num_trees=4),
-                               X, y, masks, grid, None, True)
+        ms, mk = self._both(monkeypatch, lambda: _forest_fold_grid(
+            RandomForestClassifier(num_trees=4), X, y, masks, grid, None,
+            True))
         Xt = rng.normal(size=(50, 3))
-        for gi in range(2):
+        for gi, dd in enumerate((2, 4)):
+            assert mk[0][gi].depth == dd
+            assert mk[0][gi].feats.shape == (4, 2 ** dd - 1)
+            np.testing.assert_array_equal(ms[0][gi].feats, mk[0][gi].feats)
+            np.testing.assert_array_equal(ms[0][gi].leaves,
+                                          mk[0][gi].leaves)
             ps = ms[0][gi].predict_arrays(Xt)
             pk = mk[0][gi].predict_arrays(Xt)
             np.testing.assert_array_equal(ps.data, pk.data)
+
+    def test_scrambled_depths_land_in_their_own_cells(self, monkeypatch):
+        """A grid whose depths come in no order (and repeat): the blocks
+        are laid out ascending, each fold-major over its own members, and
+        every metric still lands in its own (fold, grid point) cell."""
+        import transmogrifai_tpu.models.trees as T
+        from transmogrifai_tpu.evaluators import \
+            BinaryClassificationEvaluator
+        X, y, masks, Xv, yv, _ = _depth_blocks_table(seed=9, F=3)
+        spec = BinaryClassificationEvaluator().device_metric_spec()
+        grid = [{"max_depth": dd, "min_child_weight": w} for dd, w in (
+            (4, 0.0), (2, 3.0), (3, 0.0), (2, 0.0), (4, 3.0), (3, 3.0),
+            (2, 8.0))]
+        est = T.GBTClassifier(num_rounds=3)
+        monkeypatch.setattr(T, "_depth_mode", lambda: "blocks")
+        (_, blocks), = list(T._candidate_groups(
+            est, grid, masks, None, T._GBT_TILED, T._GBT_SKEY))
+        assert [b.depth for b in blocks] == [2, 3, 4]
+        assert [[gi for gi, _ in b.members] for b in blocks] \
+            == [[1, 3, 6], [2, 5], [0, 4]]
+        assert [b.count for b in blocks] == [9, 6, 6]
+        lanes = blocks[0].lanes
+        np.testing.assert_array_equal(blocks[0].fidx,
+                                      np.repeat(np.arange(3), 3))
+        np.testing.assert_array_equal(      # a traced vector, fold-major
+            lanes[1 + T._GBT_TILED.index("min_child_weight")],
+            [3.0, 0.0, 8.0] * 3)
+        np.testing.assert_array_equal(lanes[0], np.repeat(masks, 3, axis=0))
+        mm = T._gbt_fold_grid(est, X, y, masks, grid, None, "logistic",
+                              eval_ctx=(Xv, yv, spec))
+        # each grid point alone: one block of one depth, its own column
+        for gi, point in enumerate(grid):
+            alone = T._gbt_fold_grid(est, X, y, masks, [point], None,
+                                     "logistic", eval_ctx=(Xv, yv, spec))
+            np.testing.assert_array_equal(alone[:, 0], mm[:, gi],
+                                          err_msg=str(point))
+        assert len(np.unique(mm)) > 12
+
+    def test_one_depth_grid_is_one_block_and_blocks_share_levels(
+            self, monkeypatch):
+        """A grid with one depth is one block whichever way
+        ``_depth_mode`` answers: the same lanes, the same statics (so the
+        same cached kernel). And a program of several blocks traces ONE
+        grower and the levels of its deepest block only
+        (``_grow_blocks``): a level runs once, over the lanes of every
+        block still growing, so depths (2, 3, 4) trace 4 levels, not 9."""
+        import jax
+        import transmogrifai_tpu.models.trees as T
+        X, y, masks, _, _, _ = _depth_blocks_table()
+        grid = [{"max_depth": 3, "gamma": g} for g in (0.0, 0.5, 1.0)]
+        est = T.GBTClassifier(num_rounds=2)
+        laid = self._both(monkeypatch, lambda: list(T._candidate_groups(
+            est, grid, masks, None, T._GBT_TILED, T._GBT_SKEY)))
+        for (_, blocks), in laid:
+            assert [b.depth for b in blocks] == [3]
+            assert T.tree_depth_blocks(blocks) == {
+                "blocks": 1, "lane_levels": 2 * 3 * 3}
+        for a, b in zip(laid[0][0][1][0].lanes, laid[1][0][1][0].lanes):
+            np.testing.assert_array_equal(a, b)
+        T._gbt_fg_kernel.cache_clear()      # so that each lowering traces
+        design, _ = T._design_args(X, est.max_bins)
+        lanes = laid[1][0][1][0].lanes          # no fold index: not fused
+        args = ((tuple(jax.numpy.asarray(a) for a in lanes),), *design[:4],
+                jax.numpy.asarray(y), jax.random.PRNGKey(0))
+        level_lanes = []
+        hist = T._level_histograms
+        monkeypatch.setattr(T, "_level_histograms", lambda *a, **kw: (
+            level_lanes.append(a[1].aval.shape), hist(*a, **kw))[1])
+
+        def traced(depths):
+            before = T.tree_route_forms()["gather"]
+            level_lanes.clear()
+            T._gbt_fg_kernel((depths, 2, "logistic", "scatter")).lower(
+                *((args[0] * len(depths),) + args[1:]))
+            return (T.tree_route_forms()["gather"] - before,
+                    len(level_lanes))
+        assert traced((3,)) == (1, 3)       # one grower, three levels
+        assert traced((2, 3, 4)) == (1, 4)  # one grower, the deepest's
+
+    def test_blocks_are_padded_one_by_one_on_a_mesh(self, monkeypatch):
+        """Under a 4-device ``models`` mesh each block is padded to the
+        shard count on its own (every chip its share of every depth) and
+        the (F, G) matrix equals the unsharded one."""
+        import transmogrifai_tpu.models.trees as T
+        from transmogrifai_tpu.evaluators import \
+            BinaryClassificationEvaluator
+        from transmogrifai_tpu.parallel import make_mesh
+        X, y, masks, Xv, yv, rows = _depth_blocks_table(seed=11, F=3)
+        spec = BinaryClassificationEvaluator().device_metric_spec()
+        # 3 folds x (3, 1, 2) members = 9, 3 and 6 lanes: pads of 3, 1, 2
+        grid = [{"max_depth": dd, "min_instances_per_node": m}
+                for dd, m in ((2, 5), (4, 5), (2, 10), (3, 5), (2, 20),
+                              (4, 20))]
+        est = T.RandomForestClassifier(num_trees=3)
+        mesh = make_mesh({"models": 4})
+        monkeypatch.setattr(T, "_depth_mode", lambda: "blocks")
+        (_, blocks), = list(T._candidate_groups(
+            est, grid, masks, mesh, T._FOREST_TRACED, T._FOREST_STATIC))
+        assert [b.depth for b in blocks] == [2, 3, 4]
+        assert [b.count for b in blocks] == [9, 3, 6]
+        assert [len(b.lanes[0]) for b in blocks] == [12, 4, 8]
+        for b in blocks:
+            assert all(len(a) == len(b.fidx) for a in b.lanes)
+            assert (b.lanes[0][b.count:] == 1.0).all()  # all-ones masks
+            assert (b.fidx[b.count:] == 0).all()
+        assert T.tree_depth_blocks(blocks) == {
+            "blocks": 3, "lane_levels": 3 * (2 * 3 + 3 + 4 * 2)}
+        for ctx in ((Xv, yv, spec), (None, yv, spec, rows)):
+            whole = T._forest_fold_grid(est, X, y, masks, grid, None, True,
+                                        eval_ctx=ctx)
+            sharded = T._forest_fold_grid(est, X, y, masks, grid, mesh,
+                                          True, eval_ctx=ctx)
+            assert np.isfinite(whole).all()
+            np.testing.assert_array_equal(whole, sharded)
+        ms = T._forest_fold_grid(est, X, y, masks, grid, None, True)
+        mk = T._forest_fold_grid(est, X, y, masks, grid, mesh, True)
+        for f in range(3):
+            for gi in range(len(grid)):
+                np.testing.assert_array_equal(ms[f][gi].feats,
+                                              mk[f][gi].feats)
+                np.testing.assert_array_equal(ms[f][gi].leaves,
+                                              mk[f][gi].leaves)
+
+    @pytest.mark.parametrize("K", [2, 7])
+    def test_scanned_votes_are_the_mean_of_the_picked_leaves(self, K):
+        """``_candidate_scores`` adds a classification forest's votes up a
+        tree at a time (a (nv, K) pick a step, not one (T, nv, K) pick):
+        the same bits as the mean over the trees of each row's leaf row,
+        at K = 2 (binary scores) and K = 7 (vote probabilities)."""
+        import jax
+        import jax.numpy as jnp
+        import transmogrifai_tpu.models.trees as T
+        from transmogrifai_tpu.evaluators.device_metrics import (
+            binary_from_votes, vote_probability)
+        rng = np.random.default_rng(K)
+        trees, depth, nv = 11, 4, 97
+        leaves = rng.dirichlet(np.ones(K), size=(trees, 2 ** depth))
+        leaf = rng.integers(0, 2 ** depth, size=(trees, nv))
+        from_votes = binary_from_votes if K == 2 else vote_probability
+        # both under ``jit``, as the kernel runs them: the compiler treats
+        # the division by the tree count alike on both sides
+        got = jax.jit(lambda le, lf: T._candidate_scores(
+            "forest", "binary" if K == 2 else "multiclass", depth, None,
+            None, le, 0.0, None, lf))(jnp.asarray(leaves), jnp.asarray(leaf))
+        want = jax.jit(lambda le, lf: from_votes(jnp.mean(
+            le[jnp.arange(trees)[:, None], lf], axis=0)))(
+            jnp.asarray(leaves), jnp.asarray(leaf))
+        for g, w in zip(got if K == 2 else (got,),
+                        want if K == 2 else (want,)):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("K", [2, 7])
+    def test_fused_forest_metric_is_the_materialized_models(
+            self, monkeypatch, K):
+        """The fused kernel's metric of a lane (votes scanned on the
+        device, the metric mapped over the lanes) is the metric of that
+        lane's materialized model, blocks and all: of its ``raw_arrays``
+        (the device twin of ``predict_arrays``, the mean of the picked
+        leaf rows) under ``jit``, whose probabilities are
+        ``predict_arrays``' to the last place or two (numpy divides by the
+        tree count where the compiler multiplies by its reciprocal, which
+        can part two scores that tie, so the METRIC is compared on the
+        device's side)."""
+        import jax
+        import jax.numpy as jnp
+        import transmogrifai_tpu.models.trees as T
+        from transmogrifai_tpu.evaluators import (
+            BinaryClassificationEvaluator, MultiClassificationEvaluator)
+        from transmogrifai_tpu.evaluators.device_metrics import (
+            binary_from_votes, metric_fn, vote_probability)
+        X, y, masks, Xv, yv, _ = _depth_blocks_table(seed=K, n=240, F=2)
+        if K == 7:
+            y = np.digitize(X[:, 0] + 0.3 * X[:, 1],
+                            [-1.2, -0.7, -0.2, 0.2, 0.7, 1.2]).astype(float)
+            yv = np.stack([y[masks[f] == 0][:yv.shape[1]] for f in (0, 1)])
+        spec = (BinaryClassificationEvaluator() if K == 2
+                else MultiClassificationEvaluator()).device_metric_spec()
+        grid = [{"max_depth": dd} for dd in (2, 4, 3)]
+        est = T.RandomForestClassifier(num_trees=5)
+        monkeypatch.setattr(T, "_depth_mode", lambda: "blocks")
+        mm = T._forest_fold_grid(est, X, y, masks, grid, None, True,
+                                 eval_ctx=(Xv, yv, spec))
+        models = T._forest_fold_grid(est, X, y, masks, grid, None, True)
+        mfn = metric_fn(*spec)
+        from_votes = binary_from_votes if K == 2 else vote_probability
+        for f in range(2):
+            for gi in range(len(grid)):
+                model = models[f][gi]
+                metric, prob = jax.jit(lambda xv, yv: (
+                    mfn(yv, from_votes(model.raw_arrays(xv))),
+                    vote_probability(model.raw_arrays(xv))))(
+                    jnp.asarray(Xv[f]), jnp.asarray(yv[f]))
+                np.testing.assert_allclose(
+                    mm[f, gi], float(metric), rtol=1e-12,
+                    err_msg=f"fold {f} point {grid[gi]}")
+                np.testing.assert_allclose(
+                    model.predict_arrays(Xv[f]).probability,
+                    np.asarray(prob), rtol=0, atol=1e-15)
+        assert len(np.unique(mm)) > 3
+
+    @pytest.mark.parametrize("kind", ["binary", "multiclass", "regression"])
+    def test_lane_metrics_map_is_each_lanes_metric(self, kind):
+        """``_lane_metrics`` runs the metric ONE lane at a time over the
+        lanes of all the blocks (``lax.map``): each entry is the bits of
+        the metric called on that lane alone, and every block gets the
+        vector of its own lanes back."""
+        import jax
+        import jax.numpy as jnp
+        import transmogrifai_tpu.models.trees as T
+        from transmogrifai_tpu.evaluators import (
+            BinaryClassificationEvaluator, MultiClassificationEvaluator,
+            RegressionEvaluator)
+        from transmogrifai_tpu.evaluators.device_metrics import metric_fn
+        rng = np.random.default_rng(3)
+        nv, sizes = 61, (3, 1, 4)
+        ev = {"binary": BinaryClassificationEvaluator,
+              "multiclass": MultiClassificationEvaluator,
+              "regression": RegressionEvaluator}[kind]()
+        mfn = metric_fn(*ev.device_metric_spec())
+        classes = {"binary": 2, "multiclass": 4, "regression": 0}[kind]
+        yv = jnp.asarray(rng.normal(size=(2, nv)) if kind == "regression"
+                         else rng.integers(0, classes, size=(2, nv)
+                                           ).astype(float))
+
+        def scores(lanes):
+            if kind == "regression":
+                return jnp.asarray(rng.normal(size=(lanes, nv)))
+            prob = jnp.asarray(rng.dirichlet(np.ones(classes),
+                                             size=(lanes, nv)))
+            if kind == "multiclass":
+                return prob
+            return prob[:, :, 1], (prob[:, :, 1] > 0.5).astype(float)
+        blocks = tuple((None, jnp.asarray(rng.integers(0, 2, size=k)))
+                       for k in sizes)
+        per_block = tuple(scores(k) for k in sizes)
+        got = jax.jit(lambda yv, fi, sc: T._lane_metrics(
+            mfn, yv, tuple((None, f) for f in fi), sc))(
+            yv, tuple(b[-1] for b in blocks), per_block)
+        assert [g.shape for g in got] == [(k,) for k in sizes]
+        alone = jax.jit(lambda y, sc: mfn(y, sc))
+        for block, sc, g in zip(blocks, per_block, got):
+            for lane, fold in enumerate(np.asarray(block[-1])):
+                own = (tuple(a[lane] for a in sc) if kind == "binary"
+                       else sc[lane])
+                assert float(g[lane]) == float(alone(yv[fold], own))
+
+    @pytest.mark.parametrize("family", ["forest", "gbt"])
+    def test_default_grid_reads_3_blocks_378_lane_levels(
+            self, monkeypatch, family):
+        """The selector's default tree grids (18 points: depth 3 / 6 / 12,
+        six each) under 3 folds: the ``search.fetch`` span of the family's
+        one program carries ``depth_blocks`` = 3 and ``depth_lane_levels``
+        = 18 x (3 + 6 + 12) = 378 (every lane to depth 12: 648)."""
+        import transmogrifai_tpu.models.trees as T
+        from transmogrifai_tpu.evaluators import \
+            BinaryClassificationEvaluator
+        from transmogrifai_tpu.models.registry import default_binary_models
+        from transmogrifai_tpu.observability import trace
+        _, (rf, grid_rf), (gbt, grid_gbt), _ = default_binary_models()
+        X, y, masks, _, yv, rows = _depth_blocks_table(seed=13, n=90, F=3)
+        ctx = (None, yv, BinaryClassificationEvaluator().device_metric_spec(),
+               rows)
+        monkeypatch.setattr(T, "_depth_mode", lambda: "blocks")
+        trace.configure(True)
+        try:
+            if family == "forest":
+                mm = T._forest_fold_grid(rf.with_params(num_trees=2), X, y,
+                                         masks, grid_rf, None, True,
+                                         eval_ctx=ctx)
+            else:
+                mm = T._gbt_fold_grid(gbt.with_params(num_rounds=1), X, y,
+                                      masks, grid_gbt, None, "logistic",
+                                      eval_ctx=ctx)
+            (span,) = [s for s in trace.spans()
+                       if s["name"] == "search.fetch"]
+        finally:
+            trace.configure(False)
+            trace.reset()
+        assert mm.shape == (3, 18) and np.isfinite(mm).all()
+        assert span["attrs"]["depth_blocks"] == 3
+        assert span["attrs"]["depth_lane_levels"] == 378
 
 
 class TestMatmulChunk:

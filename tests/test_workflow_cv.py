@@ -247,14 +247,14 @@ def test_insearch_balancing_flips_winner():
 
 
 def test_r5_tree_flags_compose_end_to_end(rng, monkeypatch):
-    """The r5 tree paths — ``mask`` depth, TX_TREE_EDGES=fold, histogram
+    """The r5 tree paths — depth ``blocks``, TX_TREE_EDGES=fold, histogram
     subtraction (``+sub``) — must compose: one end-to-end search with
     ALL of them on, plus an in-search balancer, still trains, scores and
     reaches sane quality. Combinations are where path interactions
     regress (each path's own parity is covered by its unit tests)."""
     from transmogrifai_tpu.models import GBTClassifier, trees
     from transmogrifai_tpu.selector.splitters import DataBalancer
-    monkeypatch.setattr(trees, "_depth_mode", lambda: "mask")
+    monkeypatch.setattr(trees, "_depth_mode", lambda: "blocks")
     monkeypatch.setenv("TX_TREE_EDGES", "fold")
     monkeypatch.setattr(trees, "_hist_mode", lambda n, tb: "scatter+sub")
     recs = []

@@ -48,7 +48,7 @@ import logging
 import os
 import threading
 from collections import OrderedDict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -404,9 +404,7 @@ def _hist_mode(n: int, total_bins: int) -> str:
     """The one owner of the level histograms' path, worked out from what
     the code can observe (no environment variable, no option):
 
-    - "scatter" on a CPU: fused segment_sum, the tests' reference (r4
-      measured the flagship search ~10% faster under it there even at
-      small n*TB);
+    - "scatter" on a CPU: fused segment_sum, the tests' reference;
     - "matmul" on an accelerator (XLA scatters serialize there): one-hot
       contractions on the MXU over the (n, total_bins) bin indicator built
       once per tree. On a TPU the einsum is a default-precision, i.e.
@@ -423,10 +421,9 @@ def _hist_mode(n: int, total_bins: int) -> str:
     children only (half the slots) and derive each right child as parent
     - left (the parent histogram is the previous level's, and the per-row
     stats are level-invariant within a tree). Mathematically identical;
-    float cancellation can move near-tie splits. One look at the chip
-    (PERF.md section 6, PR 30) read +2.6 % on the one-chip search cell and
-    +0.4 % on the 1M-row fit: making it the accelerator's default is
-    ROADMAP S2 (e), a ``perf_opt`` with a claim.
+    float cancellation can move near-tie splits. Making it the
+    accelerator's default is ROADMAP S2 (e), a ``perf_opt`` with a claim;
+    no driver record holds a measurement of it.
 
     ``n`` is the rows one device holds (a row-sharded fit passes its
     shard's). Decided at trace time; every jitted entry pins the result
@@ -646,21 +643,23 @@ def tree_eval_forms() -> dict:
 
 
 @contextlib.contextmanager
-def _fetch_span():
+def _fetch_span(**group):
     """The ``search.fetch`` span of a fold-grid driver, carrying
     :func:`tree_route_forms`, :func:`tree_sum_forms`,
     :func:`tree_eval_forms` and :func:`tree_compress_levels` as the scalar
     attributes ``route_dense`` / ``route_gather``, ``sums_dense`` /
     ``sums_scatter``, ``eval_in_fit`` / ``eval_traverse`` and
-    ``compress_carried``: read when the span opens (what its profiler
-    annotation keeps) and again when it closes, because a program's first
-    call traces inside the span."""
+    ``compress_carried`` (the process's counts so far: read when the span
+    opens, which its profiler annotation keeps, and again when it closes,
+    because a program's first call traces inside the span), and ``group``,
+    the attributes of the call's own group (``depth_blocks`` /
+    ``depth_lane_levels``, see tree_depth_blocks)."""
     def attrs():
         return {prefix + k: v for prefix, counts in (
             ("route_", tree_route_forms()), ("sums_", tree_sum_forms()),
             ("eval_", tree_eval_forms()),
             ("compress_", tree_compress_levels())) for k, v in counts.items()}
-    with _trace.span("search.fetch", **attrs()) as rec:
+    with _trace.span("search.fetch", **attrs(), **group) as rec:
         yield
         if rec is not None:
             rec["attrs"].update(attrs())
@@ -703,6 +702,310 @@ def _slot_sums(stats: jnp.ndarray, slot: jnp.ndarray,
          for s in range(stats.shape[1])], axis=1)
 
 
+class _TreeState(NamedTuple):
+    """What one lane of a :class:`_TreeGrower` carries from level to level:
+    each row's within-level ``node`` id and ``slot``, the slots' node ids
+    and their ``active`` count (compressed levels, see _carry_slots), the
+    heaps so far (2^level - 1 entries after ``level`` levels), the last
+    level's ``went_right`` and, under histogram subtraction only, the last
+    level's histogram."""
+    node: jnp.ndarray
+    slot: jnp.ndarray
+    node_of_slot: jnp.ndarray
+    active: jnp.ndarray
+    feat_heap: jnp.ndarray
+    thr_heap: jnp.ndarray
+    went_right: jnp.ndarray
+    prev_hist: Optional[jnp.ndarray]
+
+
+class _TreeGrower:
+    """The level-wise growth of trees over ONE packed binned design (see
+    :class:`_PackedDesign`), a range of levels at a time: what
+    :func:`_grow_tree` runs from the root to the leaves for one tree, and
+    what :func:`_grow_blocks` runs a segment at a time for the lanes of a
+    fold-grid program, each over the lanes still growing.
+
+    Built once a tree (outside any ``vmap`` over lanes) it holds what no
+    lane owns: the design, the bin indicator of the ``matmul`` mode, the
+    resolved forms (see _hist_mode, _route_form) and the per-level keys of
+    the per-node feature draw (the chain ``key, sub = split(key)`` a level,
+    to ``max_depth``). ``levels`` and ``leaves`` are functions of one
+    lane's statistics and state and are ``vmap``ped by their caller."""
+
+    def __init__(self, packed: jnp.ndarray, feat_of: jnp.ndarray,
+                 block_start: jnp.ndarray, packed_thr: jnp.ndarray, dtype,
+                 *, max_depth: int,
+                 feat_key: Optional[jnp.ndarray] = None,
+                 max_features: Optional[int] = None,
+                 node_cap: Optional[int] = None,
+                 feat_map: Optional[jnp.ndarray] = None,
+                 hist_mode: Optional[str] = None,
+                 axis_name: Optional[str] = None,
+                 row_total: Optional[int] = None):
+        n, d = packed.shape
+        TB = feat_of.shape[0]
+        self.packed, self.feat_of = packed, feat_of
+        self.block_start, self.packed_thr = block_start, packed_thr
+        self.feat_map, self.axis_name = feat_map, axis_name
+        self.cap = min(row_total if row_total is not None else n,
+                       _DEFAULT_NODE_CAP if node_cap is None else node_cap)
+        self.not_a_split = ~jnp.isfinite(packed_thr)  # last + padded bins
+        # resolved here only when the caller did not pin it; jitted entry
+        # points MUST pin it (static arg) or mode switches won't retrace
+        hist_mode = hist_mode or _hist_mode(n, TB)
+        # "<mode>+sub": a value of the static that only tests pass (the
+        # resolver never returns it): histogram subtraction, see _hist_mode
+        self.hist_mode, _, suffix = hist_mode.partition("+")
+        self.sub_enabled = suffix == "sub"
+        if self.hist_mode == "matmul":
+            with jax.named_scope("tree.indicator"):
+                self.bin_oh = _bin_indicator(packed, TB, dtype, feat_of)
+        else:
+            self.bin_oh = None           # scatter / matmul_chunk modes
+        self.route = _route_form(self.hist_mode, d)
+        _ROUTE_FORMS[self.route] += 1
+        self.max_features = (max_features if max_features is not None
+                             and max_features < d else None)
+        self.subkeys = []
+        if self.max_features is not None:
+            key = feat_key
+            for _ in range(max_depth):
+                key, sub = jax.random.split(key)
+                self.subkeys.append(sub)
+
+    def is_identity(self, level: int, depth: int) -> bool:
+        # identity fast path: while every within-level node id fits the
+        # slot cap AND the next level's budget mask cannot bind
+        # (2^(level+1) <= cap, or this is the tree's last level), slots ARE
+        # node ids and nothing is ranked. Empty nodes produce all-zero
+        # histograms -> -inf gains -> they write the already-initialized
+        # (0, inf) heap entries, so results are bit-identical to the
+        # compressed path. With the default cap (256) this covers every
+        # level of trees up to depth 9; only deeper trees carry compressed
+        # slots from level to level (_carry_slots), and once a level is
+        # compressed every later one is.
+        return 2 ** level <= self.cap and (
+            level + 1 == depth or 2 ** (level + 1) <= self.cap)
+
+    def sums_form(self, depth: int) -> str:
+        # one form of the node sums a tree, by its widest: the leaf sum's
+        # columns, two under each slot of the last level
+        return _sums_form(self.hist_mode,
+                          2 * min(2 ** max(depth - 1, 0), self.cap))
+
+    def level_forms(self, lo: int, hi: int, depth: int) -> tuple:
+        """What of levels [lo, hi) depends on the tree's ``depth``: lanes
+        of different depths whose forms agree trace the same levels (see
+        _grow_blocks). A level: is it an identity level, does the budget
+        mask apply, are the slots carried over at its end."""
+        return (self.sums_form(depth),) + tuple(
+            (self.is_identity(level, depth),
+             level + 1 < depth and not self.is_identity(level, depth),
+             level + 1 < depth and not self.is_identity(level + 1, depth))
+            for level in range(lo, hi))
+
+    def _node_sums(self, sums: str):
+        def node_sums(values, slots, num_slots):
+            if sums == "dense":
+                out = _slot_sums(values, slots, num_slots)
+            else:
+                out = jax.ops.segment_sum(values, slots,
+                                          num_segments=num_slots)
+            return (jax.lax.psum(out, self.axis_name) if self.axis_name
+                    else out)
+        return node_sums
+
+    def levels(self, state: Optional[_TreeState], stats: jnp.ndarray,
+               gain_fn, min_info_gain, lo: int, hi: int,
+               depth: int) -> _TreeState:
+        """Levels [lo, hi) of ONE lane's tree of static ``depth`` from its
+        ``state`` after level ``lo`` (None at the root)."""
+        packed, feat_of = self.packed, self.feat_of
+        n, d = packed.shape
+        TB = feat_of.shape[0]
+        cap, route, hist_mode = self.cap, self.route, self.hist_mode
+        axis_name = self.axis_name
+        node_sums = self._node_sums(self.sums_form(depth))
+        heap_len = 2 ** hi - 1
+        if state is None:
+            node = jnp.zeros((n,), jnp.int32)
+            feat_heap = jnp.zeros((max(heap_len, 1),), jnp.int32)[:heap_len]
+            thr_heap = jnp.full((max(heap_len, 1),), jnp.inf,
+                                stats.dtype)[:heap_len]
+            # a level 0 that is no identity level (cap == 1) has one slot
+            slot, node_of_slot, active = node, jnp.zeros((1,), jnp.int32), 1
+            went_right, prev_hist = node, None
+        else:
+            (node, slot, node_of_slot, active, feat_heap, thr_heap,
+             went_right, prev_hist) = state
+            grown = heap_len - feat_heap.shape[0]
+            feat_heap = jnp.concatenate(
+                [feat_heap, jnp.zeros((grown,), feat_heap.dtype)])
+            thr_heap = jnp.concatenate(
+                [thr_heap, jnp.full((grown,), jnp.inf, thr_heap.dtype)])
+        prev_identity = lo > 0 and self.is_identity(lo - 1, depth)
+        for level in range(lo, hi):
+            identity = self.is_identity(level, depth)
+            C = min(2 ** level, cap)               # static slots this level
+            if identity:
+                slot = node
+                node_of_slot = jnp.arange(C, dtype=jnp.int32)
+            with jax.named_scope("tree.hist"):
+                if (self.sub_enabled and identity and prev_identity
+                        and prev_hist is not None):
+                    # histogram subtraction (the LightGBM trick): rows
+                    # routed left stayed even-numbered (`node = 2*node +
+                    # (1-go_left)`), so build ONLY the left-child
+                    # histograms — half the contraction — indexed by
+                    # parent (slot >> 1); each right child is parent -
+                    # left. Stats are level-invariant within a tree and
+                    # bins never change, so prev_hist[p] IS the parent's
+                    # full histogram. Odd-slot rows park on sentinel slot C
+                    # (== 2*C_half): one_hot zeroes it, scatter drops it.
+                    C_half = C // 2
+                    slot_sub = jnp.where((slot & 1) == 0, slot >> 1, C)
+                    hist_even = _level_histograms(
+                        packed, slot_sub, stats, C_half, TB, self.bin_oh,
+                        mode=hist_mode, axis_name=axis_name,
+                        feat_of=feat_of)
+                    hist = jnp.stack([hist_even, prev_hist - hist_even],
+                                     axis=1).reshape(C, TB, stats.shape[1])
+                else:
+                    hist = _level_histograms(
+                        packed, slot, stats, C, TB, self.bin_oh,
+                        mode=hist_mode, axis_name=axis_name,
+                        feat_of=feat_of)
+            prev_hist, prev_identity = hist, identity
+            with jax.named_scope("tree.split"):
+                cs = jnp.cumsum(hist, axis=1)      # packed-axis running sum
+                # per-feature segmented cumsum: subtract the running sum at
+                # the owning block's start; splitting at bin b sends
+                # bins<=b left
+                base = jnp.where(
+                    (self.block_start > 0)[None, :, None],
+                    cs[:, jnp.maximum(self.block_start - 1, 0), :], 0.0)
+                left = cs - base
+                with jax.named_scope("tree.node_sums"):
+                    if identity:
+                        # unlike compression (which only materializes
+                        # non-empty slots), identity slots include empty
+                        # nodes; their all-zero histograms yield -inf/zero
+                        # gains under every default gain, but a user-set
+                        # gamma<0 with min_child_weight<=0 could make an
+                        # empty node's XGB gain positive — so count rows
+                        # per slot (folded into the total reduction as an
+                        # extra ones column) and mask empty slots out of
+                        # split_ok below
+                        aug = node_sums(
+                            jnp.concatenate(
+                                [stats, jnp.ones((n, 1), stats.dtype)],
+                                axis=1),
+                            slot, C)
+                        total = aug[:, None, :-1]
+                        nonempty = aug[:, -1] > 0
+                    else:
+                        total = node_sums(stats, slot, C)[:, None, :]
+                right = total - left
+                gain = gain_fn(left, right, total)         # (C, TB)
+                gain = jnp.where(self.not_a_split[None, :], -jnp.inf, gain)
+                if self.max_features is not None:
+                    sub = self.subkeys[level]
+                    if identity:
+                        # node_of_slot is arange(C) here — the node-keyed
+                        # gather below would be a no-op
+                        u = jax.random.uniform(sub, (C, d))
+                    elif 2 ** level <= cap:
+                        # node-keyed draw: invariant to slot numbering, so
+                        # the identity and compressed paths pick identical
+                        # per-node feature subsets. A sentinel (empty) slot
+                        # clamps onto the last node's row — safe not
+                        # because that row is unused but because
+                        # sentinel-slot outputs never reach the heap
+                        # (mode="drop") or routing
+                        u = jax.random.uniform(sub, (2 ** level, d))[
+                            jnp.clip(node_of_slot, 0, 2 ** level - 1)]
+                    else:
+                        u = jax.random.uniform(sub, (C, d))
+                    kth = jnp.sort(u, axis=1)[
+                        :, self.max_features - 1:self.max_features]
+                    gain = jnp.where((u <= kth)[:, feat_of], gain, -jnp.inf)
+                best = jnp.argmax(gain, axis=1)        # (C,) packed bin
+                best_gain = jnp.take_along_axis(gain, best[:, None],
+                                                axis=1)[:, 0]
+                split_ok = best_gain >= jnp.maximum(min_info_gain, 1e-12)
+                if identity:
+                    split_ok &= nonempty
+                if level + 1 < depth and not identity:
+                    # budget mask: next level holds at most
+                    # min(2^(level+1), cap) slots; each split adds one net
+                    # node, so only the first (budget - active) slots may
+                    # split. Binds only near capacity (the identity fast
+                    # path above is taken exactly when it cannot bind).
+                    budget = min(2 ** (level + 1), cap)
+                    split_ok &= jnp.arange(C) < (budget - active)
+                bfeat = jnp.where(split_ok, feat_of[best], 0)
+                thr = jnp.where(split_ok, self.packed_thr[best], jnp.inf)
+                heap_pos = jnp.where(node_of_slot == _SLOT_SENTINEL,
+                                     _SLOT_SENTINEL,
+                                     2 ** level - 1 + node_of_slot)
+                # feat_map translates design-local feature ids (e.g. a
+                # per-tree feature pool) back to ORIGINAL column ids for
+                # the heap
+                heap_feat = (bfeat if self.feat_map is None
+                             else jnp.where(split_ok, self.feat_map[bfeat],
+                                            0))
+                feat_heap = feat_heap.at[heap_pos].set(heap_feat,
+                                                       mode="drop")
+                thr_heap = thr_heap.at[heap_pos].set(
+                    thr.astype(thr_heap.dtype), mode="drop")
+            # route rows: packed[i, f*] <= best_packed  <=>  bin <= b; a
+            # denied split routes everything left via the TB sentinel
+            with jax.named_scope("tree.route"):
+                best_r = jnp.where(split_ok, best, TB)
+                if route == "dense":
+                    go_left = _route_left_dense(packed, slot, bfeat, best_r)
+                else:
+                    go_left = (packed[jnp.arange(n), bfeat[slot]]
+                               <= best_r[slot])
+                # within-level index
+                went_right = 1 - go_left.astype(jnp.int32)
+                node = 2 * node + went_right
+            if level + 1 < depth and not self.is_identity(level + 1, depth):
+                with jax.named_scope("tree.compress"):
+                    slot, node_of_slot, active = _carry_slots(
+                        slot, node_of_slot, went_right,
+                        min(2 ** (level + 1), cap), node_sums, route)
+        return _TreeState(node, slot, node_of_slot,
+                          jnp.asarray(active, jnp.int32), feat_heap,
+                          thr_heap, went_right,
+                          prev_hist if self.sub_enabled else None)
+
+    def leaves(self, state: _TreeState, stats: jnp.ndarray,
+               depth: int) -> jnp.ndarray:
+        """(2^depth, S) per-leaf sums of ONE lane's finished tree."""
+        sums = self.sums_form(depth)
+        _SUM_FORMS[sums] += 1
+        node_sums = self._node_sums(sums)
+        with jax.named_scope("tree.node_sums"):
+            if (sums == "scatter" or depth == 0
+                    or self.is_identity(depth - 1, depth)):
+                # the last level's slots were its node ids (or there is
+                # none): a leaf's column is its id
+                return node_sums(stats, state.node, 2 ** depth)
+            # a leaf is (last level's slot, side): summed over 2 * C
+            # columns, not 2^depth segments, then placed by the columns'
+            # leaf ids, which the slots' node ids give (a column no row
+            # reached adds zeros to its leaf, as an empty segment does; an
+            # unused slot's two columns land nowhere)
+            C = min(2 ** (depth - 1), self.cap)
+            by_column = node_sums(stats, 2 * state.slot + state.went_right,
+                                  2 * C)
+            return jnp.zeros((2 ** depth, stats.shape[1]), stats.dtype).at[
+                _child_ids(state.node_of_slot, 2 ** depth)].set(
+                by_column, mode="drop")
+
+
 def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                block_start: jnp.ndarray, packed_thr: jnp.ndarray,
                stats: jnp.ndarray, *, depth: int, gain_fn,
@@ -713,17 +1016,9 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                feat_map: Optional[jnp.ndarray] = None,
                hist_mode: Optional[str] = None,
                axis_name: Optional[str] = None,
-               row_total: Optional[int] = None,
-               depth_limit=None):
+               row_total: Optional[int] = None):
     """Grow one complete tree of static ``depth`` over a packed binned
     design (see :class:`_PackedDesign`).
-
-    ``depth_limit`` (optional TRACED scalar <= depth) truncates growth:
-    levels >= depth_limit are denied splits, so one compiled program at
-    the grid's max depth serves every depth candidate as a vmapped lane
-    (the ``mask`` depth mode — the compile-count reduction; a denied split
-    routes all rows left, so shallower trees are exact, just stored in
-    a deeper heap of +inf thresholds).
 
     gain_fn(left, right, total) -> (..., ) gains with -inf where a split
     is invalid; ``left/right`` are (C, TB, S) and ``total`` (C, 1, S).
@@ -764,195 +1059,80 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     Returns (feat_heap (2^depth - 1,), thr_heap (2^depth - 1,),
     leaf_stats (2^depth, S), final node assignment (n,)).
     """
-    n, d = packed.shape
-    TB = feat_of.shape[0]
-    cap = min(row_total if row_total is not None else n,
-              _DEFAULT_NODE_CAP if node_cap is None else node_cap)
-    node = jnp.zeros((n,), jnp.int32)
-    heap_len = max(2 ** depth - 1, 1)
-    feat_heap = jnp.zeros((heap_len,), jnp.int32)[:2 ** depth - 1]
-    thr_heap = jnp.full((heap_len,), jnp.inf, stats.dtype)[:2 ** depth - 1]
-    not_a_split = ~jnp.isfinite(packed_thr)     # last + padded bins
-    # resolved here only when the caller did not pin it; jitted entry
-    # points MUST pin it (static arg) or mode switches won't retrace
-    hist_mode = hist_mode or _hist_mode(n, TB)
-    # "<mode>+sub": a value of the static that only tests pass (the
-    # resolver never returns it): histogram subtraction, see _hist_mode
-    hist_mode, _, suffix = hist_mode.partition("+")
-    sub_enabled = suffix == "sub"
-    if hist_mode == "matmul":
-        with jax.named_scope("tree.indicator"):
-            bin_oh = _bin_indicator(packed, TB, stats.dtype, feat_of)
-    else:
-        bin_oh = None                # scatter / matmul_chunk modes
-    route = _route_form(hist_mode, d)
-    _ROUTE_FORMS[route] += 1
-    # one form of the node sums a tree, by its widest: the leaf sum's
-    # columns, two under each slot of the last level
-    sums = _sums_form(hist_mode, 2 * min(2 ** max(depth - 1, 0), cap))
-    _SUM_FORMS[sums] += 1
+    grower = _TreeGrower(
+        packed, feat_of, block_start, packed_thr, stats.dtype,
+        max_depth=depth, feat_key=feat_key, max_features=max_features,
+        node_cap=node_cap, feat_map=feat_map, hist_mode=hist_mode,
+        axis_name=axis_name, row_total=row_total)
+    state = grower.levels(None, stats, gain_fn, min_info_gain, 0, depth,
+                          depth)
+    return (state.feat_heap, state.thr_heap,
+            grower.leaves(state, stats, depth), state.node)
 
-    def node_sums(values, slots, num_slots):
-        if sums == "dense":
-            out = _slot_sums(values, slots, num_slots)
-        else:
-            out = jax.ops.segment_sum(values, slots, num_segments=num_slots)
-        return jax.lax.psum(out, axis_name) if axis_name else out
-    key = feat_key
-    prev_hist = None        # previous level's (C_prev, TB, S) histogram
-    prev_identity = False
 
-    def is_identity(level):
-        # identity fast path: while every within-level node id fits the
-        # slot cap AND the next level's budget mask cannot bind
-        # (2^(level+1) <= cap, or this is the last level), slots ARE
-        # node ids and nothing is ranked. Empty nodes produce all-zero
-        # histograms -> -inf gains -> they write the already-initialized
-        # (0, inf) heap entries, so results are bit-identical to the
-        # compressed path. With the default cap (256) this covers every
-        # level of trees up to depth 9; only deeper trees carry compressed
-        # slots from level to level (_carry_slots), and once a level is
-        # compressed every later one is.
-        return 2 ** level <= cap and (
-            level + 1 == depth or 2 ** (level + 1) <= cap)
-    # a level 0 that is no identity level (cap == 1) has one slot
-    slot, node_of_slot, active = node, jnp.zeros((1,), jnp.int32), 1
-    for level in range(depth):
-        identity = is_identity(level)
-        C = min(2 ** level, cap)                   # static slots this level
-        if identity:
-            slot = node
-            node_of_slot = jnp.arange(C, dtype=jnp.int32)
-        with jax.named_scope("tree.hist"):
-            if (sub_enabled and identity and prev_identity
-                    and prev_hist is not None):
-                # histogram subtraction (the LightGBM trick): rows routed
-                # left stayed even-numbered (`node = 2*node + (1-go_left)`),
-                # so build ONLY the left-child histograms — half the
-                # contraction — indexed by parent (slot >> 1); each right
-                # child is parent - left. Stats are level-invariant within
-                # a tree and bins never change, so prev_hist[p] IS the
-                # parent's full histogram. Odd-slot rows park on sentinel
-                # slot C (== 2*C_half): one_hot zeroes it, scatter drops it.
-                C_half = C // 2
-                slot_sub = jnp.where((slot & 1) == 0, slot >> 1, C)
-                hist_even = _level_histograms(
-                    packed, slot_sub, stats, C_half, TB, bin_oh,
-                    mode=hist_mode, axis_name=axis_name, feat_of=feat_of)
-                hist = jnp.stack([hist_even, prev_hist - hist_even],
-                                 axis=1).reshape(C, TB, stats.shape[1])
+def _grow_blocks(grower: _TreeGrower, depths: tuple, lanes: tuple,
+                 stats: jnp.ndarray, lane_args: tuple, gain_of):
+    """One tree a lane of a fold-grid program whose lanes come in DEPTH
+    BLOCKS (see _candidate_groups): ``stats`` (L, n, S) and the per-lane
+    ``lane_args`` ((L,) each) hold block ``b``'s ``lanes[b]`` lanes, which
+    grow to ``depths[b]`` (ascending), one block after another;
+    ``gain_of(*lane_args)`` gives a lane's ``(gain_fn, min_info_gain)``.
+
+    The levels are traced ONCE, not once a block: segment ``[depths[k-1],
+    depths[k])`` runs as one ``vmap`` over the lanes of every block still
+    growing (all 54 lanes of a default grid through levels 0-2, the 36
+    deeper ones through 3-5, the 18 deepest through 6-11), then block
+    ``k`` takes its leaves and leaves. So a lane still grows to its own
+    depth and no deeper, and the program holds the levels of its deepest
+    block only: what it takes to trace, lower, compile and load. Blocks
+    whose levels differ in form over a segment (``level_forms``: a
+    depth-9 lane's last level is an identity level, a depth-12 lane's
+    level 8 is compressed) run that segment apart.
+
+    Returns a tuple a block of ``(feat_heap (lanes, 2^depth - 1), thr_heap,
+    leaf_stats (lanes, 2^depth, S), node (lanes, n))``."""
+    ends = np.cumsum(lanes)
+    block = [slice(int(e - k), int(e)) for e, k in zip(ends, lanes)]
+    states: List[Optional[_TreeState]] = [None] * len(depths)
+    lo = 0
+    for first, hi in enumerate(depths):
+        alike: Dict[tuple, list] = {}
+        for b in range(first, len(depths)):
+            alike.setdefault(grower.level_forms(lo, hi, depths[b]),
+                             []).append(b)
+        for members in alike.values():
+            depth = depths[members[0]]
+
+            def stacked(of_block):
+                parts = [of_block(b) for b in members]
+                return (parts[0] if len(parts) == 1 else
+                        jax.tree_util.tree_map(
+                            lambda *a: jnp.concatenate(a), *parts))
+
+            def levels(state, lane_stats, *args):
+                gain_fn, min_info_gain = gain_of(*args)
+                return grower.levels(state, lane_stats, gain_fn,
+                                     min_info_gain, lo, hi, depth)
+            lane_stats = stacked(lambda b: stats[block[b]])
+            args = stacked(lambda b: tuple(a[block[b]] for a in lane_args))
+            if lo == 0:
+                grown = jax.vmap(functools.partial(levels, None))(
+                    lane_stats, *args)
             else:
-                hist = _level_histograms(packed, slot, stats, C, TB, bin_oh,
-                                         mode=hist_mode, axis_name=axis_name,
-                                         feat_of=feat_of)
-        prev_hist, prev_identity = hist, identity
-        with jax.named_scope("tree.split"):
-            cs = jnp.cumsum(hist, axis=1)          # packed-axis running sum
-            # per-feature segmented cumsum: subtract the running sum at the
-            # owning block's start; splitting at bin b sends bins<=b left
-            base = jnp.where((block_start > 0)[None, :, None],
-                             cs[:, jnp.maximum(block_start - 1, 0), :], 0.0)
-            left = cs - base
-            with jax.named_scope("tree.node_sums"):
-                if identity:
-                    # unlike compression (which only materializes
-                    # non-empty slots), identity slots include empty nodes;
-                    # their all-zero histograms yield -inf/zero gains under
-                    # every default gain, but a user-set gamma<0 with
-                    # min_child_weight<=0 could make an empty node's XGB
-                    # gain positive — so count rows per slot (folded into
-                    # the total reduction as an extra ones column) and mask
-                    # empty slots out of split_ok below
-                    aug = node_sums(
-                        jnp.concatenate(
-                            [stats, jnp.ones((n, 1), stats.dtype)], axis=1),
-                        slot, C)
-                    total = aug[:, None, :-1]
-                    nonempty = aug[:, -1] > 0
-                else:
-                    total = node_sums(stats, slot, C)[:, None, :]
-            right = total - left
-            gain = gain_fn(left, right, total)         # (C, TB)
-            gain = jnp.where(not_a_split[None, :], -jnp.inf, gain)
-            if max_features is not None and max_features < d:
-                key, sub = jax.random.split(key)
-                if identity:
-                    # node_of_slot is arange(C) here — the node-keyed
-                    # gather below would be a no-op
-                    u = jax.random.uniform(sub, (C, d))
-                elif 2 ** level <= cap:
-                    # node-keyed draw: invariant to slot numbering, so the
-                    # identity and compressed paths pick identical per-node
-                    # feature subsets. A sentinel (empty) slot clamps onto
-                    # the last node's row — safe not because that row is
-                    # unused but because sentinel-slot outputs never reach
-                    # the heap (mode="drop") or routing
-                    u = jax.random.uniform(sub, (2 ** level, d))[
-                        jnp.clip(node_of_slot, 0, 2 ** level - 1)]
-                else:
-                    u = jax.random.uniform(sub, (C, d))
-                kth = jnp.sort(u, axis=1)[:, max_features - 1:max_features]
-                gain = jnp.where((u <= kth)[:, feat_of], gain, -jnp.inf)
-            best = jnp.argmax(gain, axis=1)            # (C,) packed bin index
-            best_gain = jnp.take_along_axis(gain, best[:, None], axis=1)[:, 0]
-            split_ok = best_gain >= jnp.maximum(min_info_gain, 1e-12)
-            if depth_limit is not None:
-                split_ok &= level < depth_limit
-            if identity:
-                split_ok &= nonempty
-            if level + 1 < depth and not identity:
-                # budget mask: next level holds at most min(2^(level+1), cap)
-                # slots; each split adds one net node, so only the first
-                # (budget - active) slots may split. Binds only near capacity
-                # (the identity fast path above is taken exactly when it
-                # cannot bind).
-                budget = min(2 ** (level + 1), cap)
-                split_ok &= jnp.arange(C) < (budget - active)
-            bfeat = jnp.where(split_ok, feat_of[best], 0)
-            thr = jnp.where(split_ok, packed_thr[best], jnp.inf)
-            heap_pos = jnp.where(node_of_slot == _SLOT_SENTINEL,
-                                 _SLOT_SENTINEL, 2 ** level - 1 + node_of_slot)
-            # feat_map translates design-local feature ids (e.g. a per-tree
-            # feature pool) back to ORIGINAL column ids for the heap
-            heap_feat = (bfeat if feat_map is None
-                         else jnp.where(split_ok, feat_map[bfeat], 0))
-            feat_heap = feat_heap.at[heap_pos].set(heap_feat, mode="drop")
-            thr_heap = thr_heap.at[heap_pos].set(thr.astype(thr_heap.dtype),
-                                                 mode="drop")
-        # route rows: packed[i, f*] <= best_packed  <=>  bin <= b; a
-        # denied split routes everything left via the TB sentinel
-        with jax.named_scope("tree.route"):
-            best_r = jnp.where(split_ok, best, TB)
-            if route == "dense":
-                go_left = _route_left_dense(packed, slot, bfeat, best_r)
-            else:
-                go_left = (packed[jnp.arange(n), bfeat[slot]]
-                           <= best_r[slot])
-            # within-level index
-            went_right = 1 - go_left.astype(jnp.int32)
-            node = 2 * node + went_right
-        if level + 1 < depth and not is_identity(level + 1):
-            with jax.named_scope("tree.compress"):
-                slot, node_of_slot, active = _carry_slots(
-                    slot, node_of_slot, went_right,
-                    min(2 ** (level + 1), cap), node_sums, route)
-    with jax.named_scope("tree.node_sums"):
-        if sums == "scatter" or depth == 0 or identity:
-            # the last level's slots were its node ids (or there is none):
-            # a leaf's column is its id
-            leaf_stats = node_sums(stats, node, 2 ** depth)
-        else:
-            # a leaf is (last level's slot, side): summed over 2 * C columns,
-            # not 2^depth segments, then placed by the columns' leaf ids,
-            # which the slots' node ids give (a column no row reached adds
-            # zeros to its leaf, as an empty segment does; an unused slot's
-            # two columns land nowhere)
-            by_column = node_sums(stats, 2 * slot + went_right, 2 * C)
-            leaf_stats = jnp.zeros((2 ** depth, stats.shape[1]),
-                                   stats.dtype).at[
-                _child_ids(node_of_slot, 2 ** depth)].set(
-                by_column, mode="drop")
-    return feat_heap, thr_heap, leaf_stats, node
+                grown = jax.vmap(levels)(stacked(lambda b: states[b]),
+                                         lane_stats, *args)
+            at = 0
+            for b in members:
+                states[b] = jax.tree_util.tree_map(
+                    lambda a: a[at:at + lanes[b]], grown)
+                at += lanes[b]
+        lo = hi
+    return tuple(
+        (state.feat_heap, state.thr_heap,
+         jax.vmap(lambda st, ls: grower.leaves(st, ls, depth))(
+             state, stats[block[b]]),
+         state.node)
+        for b, (depth, state) in enumerate(zip(depths, states)))
 
 
 def _traverse(X: jnp.ndarray, feat_heap: jnp.ndarray, thr_heap: jnp.ndarray,
@@ -1188,55 +1368,70 @@ def _tree_block_size(n: int, total_bins: int, depth: int, s_dim: int,
 def _forest_body(packed, feat_of, block_start, packed_thr,
                  binned, col_thr, narrow_idx, wide_idx, y, key, mask,
                  min_instances, min_info_gain, subsample, *, kind: str,
-                 depth: int, num_classes: int, num_trees: int,
+                 depth, num_classes: int, num_trees: int,
                  max_features: Optional[int], pool_cfg: Optional[tuple],
                  impurity: str, bootstrap: bool,
                  hist_mode: Optional[str],
                  axis_name: Optional[str] = None,
                  row_total: Optional[int] = None,
-                 outer_batch: int = 1,
-                 depth_limit=None, val_rows=None):
+                 val_rows=None, lanes: Optional[tuple] = None):
     """Shared forest program: ``mask`` (n,) row weights let one body
     serve the single fit (mask=ones), the fold x grid batched kernel
     (mask = fold membership, traced per-candidate hyperparams), and the
     "models"-axis mesh path — masked rows contribute nothing to
     histograms or leaves, which is exactly fitting on the subset.
     ``axis_name`` row-shards the fit: every cross-row reduction psums
-    over that mesh axis (see _grow_tree) and bootstrap draws slice a
+    over that mesh axis (see _TreeGrower) and bootstrap draws slice a
     global-shaped sample (_row_draw). Independent trees are fit in
-    vmapped blocks (see _tree_block_size); ``outer_batch`` tells the
-    budget how many of these bodies an enclosing vmap runs at once.
+    vmapped blocks (see _tree_block_size).
+
+    One fit (``lanes`` None: ``mask`` (n,), scalar hyperparameters, an int
+    ``depth``), or the lanes of a fold-grid program in depth blocks
+    (``lanes[b]`` lanes that grow to ``depth[b]``, ascending; ``mask``
+    (L, n), the hyperparameters (L,) and ``val_rows`` (L, nv) hold the
+    blocks one after another): every per-lane step then runs under
+    ``vmap`` over all the lanes, a tree's feature pool and bin indicator
+    are made once for all of them, and the trees grow through
+    :func:`_grow_blocks`.
 
     ``val_rows`` ((nv,) int32 positions in the fitted table; the fused
     fit+metric kernel's in-fit form, see _eval_form) adds a fourth
-    output: the leaf ``_grow_tree`` routed each of those rows to in every
+    output: the leaf the grower routed each of those rows to in every
     tree, (T, nv) int32: ``_traverse``'s leaf, without the walk. Without
     it the body returns (feats, thrs, leaves) and traces what it always
-    did."""
+    did. With ``lanes`` it returns a tuple a block of those, lanes
+    first."""
     assert val_rows is None or axis_name is None, \
         "val_rows index the whole table: not under row sharding"
     n, d = packed.shape
     dtype = packed_thr.dtype
+    over = _over_lanes(lanes)
+    depths = (depth,) if lanes is None else depth
     if kind == "cls":
         onehot = jax.nn.one_hot(y.astype(jnp.int32), num_classes,
                                 dtype=dtype)
-        gain_fn = (_gini_gain(min_instances) if impurity == "gini"
-                   else _entropy_gain(min_instances))
+        y_mean = jnp.zeros(() if lanes is None else (sum(lanes),), dtype)
+
+        def gain_of(min_instances, min_info_gain):
+            return (_gini_gain(min_instances) if impurity == "gini"
+                    else _entropy_gain(min_instances)), min_info_gain
     else:
-        gain_fn = _variance_gain(min_instances)
         # a regression lane's statistics carry the label CENTRED on the
         # lane's masked mean, which re-enters at the leaves: the gain does
         # not see the shift (see _variance_gain), and a label of 1998 +- 11
         # would spend its significant bits on the 1998 (_variance_stats)
         def _gsum(v):
             return jax.lax.psum(v, axis_name) if axis_name else v
-        y_mean = _gsum(jnp.sum(mask * y)) / jnp.maximum(
-            _gsum(jnp.sum(mask)), 1.0)
-        y_centred = y - y_mean
+        y_mean = over(lambda mask: _gsum(jnp.sum(mask * y)) / jnp.maximum(
+            _gsum(jnp.sum(mask)), 1.0))(mask)
+
+        def gain_of(min_instances, min_info_gain):
+            return _variance_gain(min_instances), min_info_gain
 
     def one_tree(tkey):
         pkey, wkey, fkey = jax.random.split(tkey, 3)
-        with jax.named_scope("tree.bootstrap"):
+
+        def tree_stats(mask, subsample, y_mean):
             if bootstrap:
                 w = _row_draw(
                     lambda k, m: jax.random.poisson(k, subsample,
@@ -1245,55 +1440,74 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
             else:
                 w = jnp.ones((n,), dtype)
             w = w * mask
-            stats = (onehot * w[:, None] if kind == "cls"
-                     else _variance_stats(w, y_centred))
+            return (onehot * w[:, None] if kind == "cls"
+                    else _variance_stats(w, y - y_mean))
+
+        def tree_leaves(leaf_stats, y_mean):
+            if kind == "cls":
+                lw = jnp.sum(leaf_stats, axis=-1, keepdims=True)
+                return jnp.where(lw > 0,
+                                 leaf_stats / jnp.maximum(lw, 1e-12),
+                                 1.0 / num_classes)
+            return y_mean + (leaf_stats[:, 1] + leaf_stats[:, 2]
+                             ) / jnp.maximum(leaf_stats[:, 0], 1e-12)
+        with jax.named_scope("tree.bootstrap"):
+            stats = over(tree_stats)(mask, subsample, y_mean)
+        design, pool = (packed, feat_of, block_start, packed_thr), None
         if pool_cfg is not None:
             with jax.named_scope("tree.pool"):
-                pool, p_sub, fo_sub, bs_sub, thr_sub = _tree_pool(
+                pool, *design = _tree_pool(
                     pkey, binned, col_thr, narrow_idx, wide_idx, pool_cfg)
-            feat, thr, leaf_stats, node = _grow_tree(
-                p_sub, fo_sub, bs_sub, thr_sub, stats, depth=depth,
-                gain_fn=gain_fn, min_info_gain=min_info_gain,
-                feat_key=fkey, max_features=max_features, feat_map=pool,
-                hist_mode=hist_mode, axis_name=axis_name,
-                row_total=row_total, depth_limit=depth_limit)
-        else:
-            feat, thr, leaf_stats, node = _grow_tree(
-                packed, feat_of, block_start, packed_thr, stats,
-                depth=depth, gain_fn=gain_fn,
-                min_info_gain=min_info_gain, feat_key=fkey,
-                max_features=max_features, hist_mode=hist_mode,
-                axis_name=axis_name, row_total=row_total,
-                depth_limit=depth_limit)
-        if kind == "cls":
-            lw = jnp.sum(leaf_stats, axis=-1, keepdims=True)
-            leaf = jnp.where(lw > 0, leaf_stats / jnp.maximum(lw, 1e-12),
-                             1.0 / num_classes)
-        else:
-            leaf = y_mean + (leaf_stats[:, 1] + leaf_stats[:, 2]
-                             ) / jnp.maximum(leaf_stats[:, 0], 1e-12)
-        if val_rows is None:
-            return feat, thr, leaf
-        return feat, thr, leaf, node[val_rows]
+        grower = _TreeGrower(
+            *design, dtype, max_depth=max(depths), feat_key=fkey,
+            max_features=max_features, feat_map=pool, hist_mode=hist_mode,
+            axis_name=axis_name, row_total=row_total)
+        if lanes is None:
+            state = grower.levels(None, stats, *gain_of(
+                min_instances, min_info_gain), 0, depth, depth)
+            tree = (state.feat_heap, state.thr_heap, tree_leaves(
+                grower.leaves(state, stats, depth), y_mean))
+            return tree if val_rows is None else (
+                *tree, state.node[val_rows])
+        grown = _grow_blocks(grower, depths, lanes, stats,
+                             (min_instances, min_info_gain), gain_of)
+        at, trees = 0, []
+        for k, (feat, thr, leaf_stats, node) in zip(lanes, grown):
+            mine = slice(at, at + k)
+            tree = (feat, thr, jax.vmap(tree_leaves)(leaf_stats,
+                                                     y_mean[mine]))
+            if val_rows is not None:
+                tree += (jax.vmap(lambda nd, rows: nd[rows])(
+                    node, val_rows[mine]),)
+            trees.append(tree)
+            at += k
+        return tuple(trees)
 
     keys = jax.random.split(key, num_trees)
-    # full-design TB is a safe upper bound for the pooled design's
-    tb = _tree_block_size(
+    # full-design TB is a safe upper bound for the pooled design's; the
+    # lanes of a block share the budget, and the blocks one block size
+    tb = min(_tree_block_size(
         row_total if row_total is not None else n,
-        int(feat_of.shape[0]), depth,
+        int(feat_of.shape[0]), block_depth,
         num_classes if kind == "cls" else 3, num_trees,
-        hist_mode or "scatter", pool_cfg is not None, outer_batch)
+        hist_mode or "scatter", pool_cfg is not None, block_lanes)
+        for block_depth, block_lanes in zip(depths, lanes or (1,)))
     if tb >= num_trees:
-        return jax.vmap(one_tree)(keys)
-    if tb == 1:
+        outs = jax.vmap(one_tree)(keys)
+    elif tb == 1:
         _, outs = jax.lax.scan(lambda c, k: (c, one_tree(k)), None, keys)
+    else:
+        pad = (-num_trees) % tb
+        keys_p = jnp.concatenate([keys, keys[:pad]], axis=0)
+        _, outs = jax.lax.scan(
+            lambda c, kb: (c, jax.vmap(one_tree)(kb)), None,
+            keys_p.reshape(-1, tb, *keys.shape[1:]))
+        outs = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[2:])[:num_trees], outs)
+    if lanes is None:
         return outs
-    pad = (-num_trees) % tb
-    keys_p = jnp.concatenate([keys, keys[:pad]], axis=0)
-    _, outs = jax.lax.scan(
-        lambda c, kb: (c, jax.vmap(one_tree)(kb)), None,
-        keys_p.reshape(-1, tb, *keys.shape[1:]))
-    return tuple(a.reshape((-1,) + a.shape[2:])[:num_trees] for a in outs)
+    # a block's trees come out trees first: lanes first
+    return jax.tree_util.tree_map(lambda a: jnp.moveaxis(a, 0, 1), outs)
 
 
 @functools.partial(
@@ -1336,45 +1550,65 @@ def _fit_forest_regressor(packed, feat_of, block_start, packed_thr,
         impurity="", bootstrap=bootstrap, hist_mode=hist_mode)
 
 
+def _over_lanes(lanes: Optional[tuple]):
+    """How a tree family's body runs a per-lane step (see _forest_body,
+    _gbt_body): as it is for one fit (``lanes`` None), under ``jax.vmap``
+    over the leading axis of its arguments for the lanes of a fold-grid
+    program."""
+    return (lambda step: step) if lanes is None else jax.vmap
+
+
 def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
               step_size, reg_lambda, gamma, min_child_weight, subsample,
-              *, depth: int, num_rounds: int, objective: str,
+              *, depth, num_rounds: int, objective: str,
               hist_mode: Optional[str],
               axis_name: Optional[str] = None,
               row_total: Optional[int] = None,
-              depth_limit=None):
+              lanes: Optional[tuple] = None):
     """Shared boosting program with row-mask semantics (see
     _forest_body): masked rows get zero grad/hess weight; the base
     margin is the mask-weighted mean. ``axis_name`` row-shards the fit
     (psum'd histograms/means, global-sliced subsampling).
 
-    Returns (feats, thrs, leaves, base, margins): ``margins`` (n,) is the
-    scan's final carry, the finished margin of EVERY row of the table,
-    masked or not (``_grow_tree`` routes them all, and each round adds
-    its leaf value to all of them): what the in-fit form of the fused
-    fit+metric kernel scores the held-out rows from (see _eval_form).
-    It is the sequential float32 sum over rounds, not ``base +
-    sum(vals)``: equal to a few ulp. A caller that drops it compiles
-    what it compiled without it."""
+    One fit (``lanes`` None: ``mask`` (n,), scalar hyperparameters, an int
+    ``depth``), or the lanes of a fold-grid program in depth blocks
+    (``lanes[b]`` lanes that grow to ``depth[b]``, ascending; ``mask``
+    (L, n) and the hyperparameters (L,) hold the blocks one after
+    another): every per-lane step then runs under ``vmap`` over all the
+    lanes, and the trees grow through :func:`_grow_blocks`.
+
+    Returns (feats, thrs, leaves, base, margins), a tuple a block of them
+    with ``lanes``: ``margins`` (n,) is the scan's final carry, the
+    finished margin of EVERY row of the table, masked or not (the grower
+    routes them all, and each round adds its leaf value to all of them):
+    what the in-fit form of the fused fit+metric kernel scores the
+    held-out rows from (see _eval_form). It is the sequential float32 sum
+    over rounds, not ``base + sum(vals)``: equal to a few ulp. A caller
+    that drops it compiles what it compiled without it."""
     n, d = packed.shape
     dtype = packed_thr.dtype
-    gain_fn = _xgb_gain(reg_lambda, gamma, min_child_weight)
+    over = _over_lanes(lanes)
+    depths = (depth,) if lanes is None else depth
 
     def _gsum(v):
         return jax.lax.psum(v, axis_name) if axis_name else v
 
-    msum = jnp.maximum(_gsum(jnp.sum(mask)), 1.0)
-    mean_y = _gsum(jnp.sum(mask * y)) / msum
-    if objective == "logistic":
-        p0 = jnp.clip(mean_y, 1e-6, 1 - 1e-6)
-        base = jnp.log(p0 / (1 - p0))
-    else:
-        base = mean_y
-    margins0 = jnp.broadcast_to(base.astype(dtype), (n,))
+    def start(mask):
+        msum = jnp.maximum(_gsum(jnp.sum(mask)), 1.0)
+        mean_y = _gsum(jnp.sum(mask * y)) / msum
+        if objective == "logistic":
+            p0 = jnp.clip(mean_y, 1e-6, 1 - 1e-6)
+            base = jnp.log(p0 / (1 - p0))
+        else:
+            base = mean_y
+        return base, jnp.broadcast_to(base.astype(dtype), (n,))
+    base, margins0 = over(start)(mask)
 
-    def one_round(carry, rkey):
-        margins = carry
-        with jax.named_scope("gbt.round"):
+    def gain_of(reg_lambda, gamma, min_child_weight):
+        return _xgb_gain(reg_lambda, gamma, min_child_weight), 0.0
+
+    def one_round(margins, rkey):
+        def round_stats(margins, mask, subsample):
             if objective == "logistic":
                 p = jax.nn.sigmoid(margins)
                 g, h = p - y, jnp.maximum(p * (1 - p), 1e-12)
@@ -1384,22 +1618,51 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
                 lambda k, mm: jax.random.bernoulli(k, subsample,
                                                    (mm,)).astype(dtype),
                 rkey, n, axis_name, row_total) * mask
-            g, h = g * m, h * m
-            feat, thr, leaf_stats, node = _grow_tree(
-                packed, feat_of, block_start, packed_thr,
-                jnp.stack([g, h], axis=1), depth=depth,
-                gain_fn=gain_fn, min_info_gain=0.0, hist_mode=hist_mode,
-                axis_name=axis_name, row_total=row_total,
-                depth_limit=depth_limit)
+            return jnp.stack([g * m, h * m], axis=1)
+
+        def add_tree(margins, leaf_stats, node, step_size, reg_lambda):
             vals = (-step_size * leaf_stats[:, 0]
                     / (leaf_stats[:, 1] + reg_lambda))
             vals = jnp.where(jnp.sum(jnp.abs(leaf_stats), axis=1) > 0,
                              vals, 0.0)
-            margins = margins + vals[node]
-        return margins, (feat, thr, vals)
-    margins, (feats, thrs, leaves) = jax.lax.scan(
+            return margins + vals[node], vals
+        with jax.named_scope("gbt.round"):
+            stats = over(round_stats)(margins, mask, subsample)
+            grower = _TreeGrower(
+                packed, feat_of, block_start, packed_thr, dtype,
+                max_depth=max(depths), hist_mode=hist_mode,
+                axis_name=axis_name, row_total=row_total)
+            if lanes is None:
+                state = grower.levels(
+                    None, stats, *gain_of(reg_lambda, gamma,
+                                          min_child_weight), 0, depth, depth)
+                margins, vals = add_tree(
+                    margins, grower.leaves(state, stats, depth), state.node,
+                    step_size, reg_lambda)
+                return margins, (state.feat_heap, state.thr_heap, vals)
+            grown = _grow_blocks(grower, depths, lanes, stats,
+                                 (reg_lambda, gamma, min_child_weight),
+                                 gain_of)
+            at, new_margins, trees = 0, [], []
+            for k, (feat, thr, leaf_stats, node) in zip(lanes, grown):
+                mine = slice(at, at + k)
+                block_margins, vals = jax.vmap(add_tree)(
+                    margins[mine], leaf_stats, node, step_size[mine],
+                    reg_lambda[mine])
+                new_margins.append(block_margins)
+                trees.append((feat, thr, vals))
+                at += k
+            return jnp.concatenate(new_margins), tuple(trees)
+    margins, trees = jax.lax.scan(
         one_round, margins0, jax.random.split(key, num_rounds))
-    return feats, thrs, leaves, base, margins
+    if lanes is None:
+        return (*trees, base, margins)
+    ends = np.cumsum(lanes)
+    # a block's trees come out of the scan rounds first: lanes first
+    return tuple(
+        tuple(jnp.moveaxis(a, 0, 1) for a in block_trees)
+        + (base[e - k:e], margins[e - k:e])
+        for block_trees, e, k in zip(trees, ends, lanes))
 
 
 @functools.partial(
@@ -1418,12 +1681,12 @@ def _fit_gbt(packed, feat_of, block_start, packed_thr, y, key, *, depth: int,
 
 def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
                       mask, step_size, reg_lambda, gamma,
-                      min_child_weight, subsample, *, depth: int,
+                      min_child_weight, subsample, *, depth,
                       num_rounds: int, num_classes: int,
                       hist_mode: Optional[str],
                       axis_name: Optional[str] = None,
                       row_total: Optional[int] = None,
-                      depth_limit=None):
+                      lanes: Optional[tuple] = None):
     """K-class softmax boosting: each round fits one tree PER CLASS on
     the softmax gradients/hessians (g_k = p_k - 1[y=k],
     h_k = p_k(1-p_k)) — the ``multi:softprob`` objective the reference
@@ -1431,25 +1694,36 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
     itself has no multiclass mode). The K trees of a round see the same
     fixed margins, so they vmap as one batched program (histogram width
     x K, sequential depth unchanged). Base margins are the log class
-    priors. Returns (feats (R,K,H), thrs (R,K,H), leaves (R,K,L),
-    base (K,), margins (n,K)): the last is the scan's final carry, every
-    row's finished margins (see _gbt_body)."""
+    priors. One fit, or with ``lanes`` the lanes of a fold-grid program in
+    depth blocks, as in _gbt_body: the K trees of a lane are then K lanes
+    of :func:`_grow_blocks`. Returns (feats (R,K,H), thrs (R,K,H), leaves
+    (R,K,L), base (K,), margins (n,K)), a tuple a block of them with
+    ``lanes``: the last is the scan's final carry, every row's finished
+    margins (see _gbt_body)."""
     n, d = packed.shape
     dtype = packed_thr.dtype
-    gain_fn = _xgb_gain(reg_lambda, gamma, min_child_weight)
+    K = num_classes
+    over = _over_lanes(lanes)
+    depths = (depth,) if lanes is None else depth
 
     def _gsum(v):
         return jax.lax.psum(v, axis_name) if axis_name else v
 
-    onehot = jax.nn.one_hot(y.astype(jnp.int32), num_classes, dtype=dtype)
-    counts = _gsum(jnp.sum(mask[:, None] * onehot, axis=0))
-    priors = jnp.clip(counts / jnp.maximum(jnp.sum(counts), 1.0),
-                      1e-6, 1.0)
-    base = jnp.log(priors)
-    margins0 = jnp.broadcast_to(base, (n, num_classes)).astype(dtype)
+    onehot = jax.nn.one_hot(y.astype(jnp.int32), K, dtype=dtype)
+
+    def start(mask):
+        counts = _gsum(jnp.sum(mask[:, None] * onehot, axis=0))
+        priors = jnp.clip(counts / jnp.maximum(jnp.sum(counts), 1.0),
+                          1e-6, 1.0)
+        base = jnp.log(priors)
+        return base, jnp.broadcast_to(base, (n, K)).astype(dtype)
+    base, margins0 = over(start)(mask)
+
+    def gain_of(reg_lambda, gamma, min_child_weight):
+        return _xgb_gain(reg_lambda, gamma, min_child_weight), 0.0
 
     def one_round(margins, rkey):
-        with jax.named_scope("gbt.round"):
+        def round_stats(margins, mask, subsample):
             p = jax.nn.softmax(margins, axis=1)
             g = p - onehot                                  # (n, K)
             h = jnp.maximum(p * (1.0 - p), 1e-12)
@@ -1457,28 +1731,61 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
                 lambda k, mm: jax.random.bernoulli(k, subsample,
                                                    (mm,)).astype(dtype),
                 rkey, n, axis_name, row_total) * mask
+            return jnp.stack([g.T * m, h.T * m], axis=2)    # (K, n, 2)
 
-            def per_class(gk, hk):
-                feat, thr, leaf_stats, node = _grow_tree(
-                    packed, feat_of, block_start, packed_thr,
-                    jnp.stack([gk * m, hk * m], axis=1), depth=depth,
-                    gain_fn=gain_fn, min_info_gain=0.0,
-                    hist_mode=hist_mode, axis_name=axis_name,
-                    row_total=row_total, depth_limit=depth_limit)
-                vals = (-step_size * leaf_stats[:, 0]
-                        / (leaf_stats[:, 1] + reg_lambda))
-                vals = jnp.where(
-                    jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
-                return feat, thr, vals, vals[node]
-
-            feats, thrs, vals, delta = jax.vmap(
-                per_class, in_axes=(1, 1))(g, h)            # over classes
-            margins = margins + delta.T
-        return margins, (feats, thrs, vals)
-
-    margins, (feats, thrs, leaves) = jax.lax.scan(
+        def class_tree(leaf_stats, node, step_size, reg_lambda):
+            vals = (-step_size * leaf_stats[:, 0]
+                    / (leaf_stats[:, 1] + reg_lambda))
+            vals = jnp.where(
+                jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
+            return vals, vals[node]
+        with jax.named_scope("gbt.round"):
+            stats = over(round_stats)(margins, mask, subsample)
+            grower = _TreeGrower(
+                packed, feat_of, block_start, packed_thr, dtype,
+                max_depth=max(depths), hist_mode=hist_mode,
+                axis_name=axis_name, row_total=row_total)
+            if lanes is None:
+                def per_class(class_stats):
+                    state = grower.levels(
+                        None, class_stats, *gain_of(
+                            reg_lambda, gamma, min_child_weight), 0, depth,
+                        depth)
+                    return (state.feat_heap, state.thr_heap, *class_tree(
+                        grower.leaves(state, class_stats, depth),
+                        state.node, step_size, reg_lambda))
+                feats, thrs, vals, delta = jax.vmap(per_class)(stats)
+                return margins + delta.T, (feats, thrs, vals)
+            # a lane's K trees are K lanes of the grower, a block's lanes
+            # still one after another
+            grown = _grow_blocks(
+                grower, depths, tuple(k * K for k in lanes),
+                stats.reshape((-1,) + stats.shape[2:]),
+                tuple(jnp.repeat(a, K) for a in (reg_lambda, gamma,
+                                                 min_child_weight)),
+                gain_of)
+            at, new_margins, trees = 0, [], []
+            for k, (feat, thr, leaf_stats, node) in zip(lanes, grown):
+                mine = slice(at, at + k)
+                vals, delta = jax.vmap(class_tree)(
+                    leaf_stats, node, jnp.repeat(step_size[mine], K),
+                    jnp.repeat(reg_lambda[mine], K))
+                new_margins.append(margins[mine] + jnp.swapaxes(
+                    delta.reshape(k, K, n), 1, 2))
+                trees.append(tuple(a.reshape((k, K) + a.shape[1:])
+                                   for a in (feat, thr, vals)))
+                at += k
+            return jnp.concatenate(new_margins), tuple(trees)
+    margins, trees = jax.lax.scan(
         one_round, margins0, jax.random.split(key, num_rounds))
-    return feats, thrs, leaves, base, margins
+    if lanes is None:
+        return (*trees, base, margins)
+    ends = np.cumsum(lanes)
+    # a block's trees come out of the scan rounds first: lanes first
+    return tuple(
+        tuple(jnp.moveaxis(a, 0, 1) for a in block_trees)
+        + (base[e - k:e], margins[e - k:e])
+        for block_trees, e, k in zip(trees, ends, lanes))
 
 
 @functools.partial(
@@ -1516,68 +1823,102 @@ def _predict_leaves(X, feats, thrs, depth: int):
 # fold's train rows (feature-distribution information only — standard
 # for histogram-GBM cross-validation).
 
+def _all_lanes(blocks: tuple):
+    """The per-lane arguments of a fold-grid program's DEPTH BLOCKS (see
+    _candidate_groups) as the tree bodies take them (see _forest_body,
+    _gbt_body ``lanes``): each argument with the blocks' lanes one block
+    after another on its leading axis, and the lane count a block."""
+    return (tuple(jnp.concatenate(arg) for arg in zip(*blocks)),
+            tuple(block[0].shape[0] for block in blocks))
+
+
+def _lane_metrics(mfn, yv, blocks: tuple, scores: tuple):
+    """The validation metric of every lane of a fused fit+metric program,
+    a vector a depth block: ``mfn(yv[fold], scores)`` with ``fold`` the
+    last of a block's per-lane arguments and ``scores[b]`` the validation
+    scores of block ``b``'s lanes. ONE ``lax.map`` over the lanes of all
+    the blocks and not a ``vmap``: the chip's executable of the binary
+    curve metrics (a sort, cumulative sums and a running minimum over the
+    validation rows, each unrolled into some hundred small operations)
+    grows with the lanes it is batched over, 22 MB at 18 lanes x 16,384
+    rows and 44 MB at 54, where one lane at a time is 4 MB (compiles for a
+    v5e, PERF.md section 6, PR 35): what a process pays when it loads the
+    program, against some hundred small operations a lane when it runs
+    (``fg.metric`` of the boosted program on the chip: 0.045 s a train
+    batched, 0.037 s mapped)."""
+    folds = jnp.concatenate([block[-1] for block in blocks])
+    lanes = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *scores)
+    with jax.named_scope("fg.metric"):
+        metrics = jax.lax.map(lambda lane: mfn(yv[lane[0]], lane[1]),
+                              (folds, lanes))
+    ends = np.cumsum([block[-1].shape[0] for block in blocks])
+    return tuple(jnp.split(metrics, ends[:-1]))
+
+
+def _shard_blocks(batched, mesh, blocks: int, traced: int, shared: int,
+                  out_spec):
+    """``batched(blocks, *shared_args)``, a tree family's fold-grid
+    program over its depth blocks (see _candidate_groups), as it is
+    jitted: itself without a mesh; with one, under ``shard_map`` with every
+    leaf of every block sharded over ``models`` (a block's (lanes, n) row
+    masks, then its ``traced`` per-lane vectors; each block's result
+    ``out_spec``) and the ``shared`` arguments replicated: a chip holds
+    its share of EVERY depth, which is why each block is padded to the
+    shard count on its own."""
+    if mesh is None:
+        return batched
+    from jax.sharding import PartitionSpec as P
+    lane_specs = (P("models", None),) + (P("models"),) * traced
+    return shard_map(
+        batched, mesh=mesh,
+        in_specs=((lane_specs,) * blocks,) + (P(),) * shared,
+        out_specs=(out_spec,) * blocks, check_vma=False)
+
+
 @functools.lru_cache(maxsize=32)
 def _forest_fg_kernel(statics: tuple, mesh=None):
-    (kind, depth, num_classes, num_trees, max_features, pool_cfg,
+    (kind, depths, num_classes, num_trees, max_features, pool_cfg,
      impurity, bootstrap, hist_mode) = statics
-
-    def one(ob, mask, mi, mg, sr, dl, packed, feat_of, block_start,
-            packed_thr, binned, col_thr, narrow, wide, y, key):
-        return _forest_body(
-            packed, feat_of, block_start, packed_thr, binned, col_thr,
-            narrow, wide, y, key, mask, mi, mg, sr, kind=kind,
-            depth=depth, num_classes=num_classes, num_trees=num_trees,
-            max_features=max_features, pool_cfg=pool_cfg,
-            impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
-            outer_batch=ob, depth_limit=dl)
+    from jax.sharding import PartitionSpec as P
 
     # named apart from the boosted programs' ``batched``: the function a
     # ``jax.jit`` wraps names the program (``jit_forest_batched``)
-    def forest_batched(masks, mi, mg, sr, dl, *rest):
-        ob = masks.shape[0]     # candidate lanes share the block budget
+    def forest_batched(blocks, packed, feat_of, block_start, packed_thr,
+                       binned, col_thr, narrow, wide, y, key):
+        (mask, mi, mg, sr), lanes = _all_lanes(blocks)
         with jax.named_scope("fg.forest"):
-            return jax.vmap(functools.partial(one, ob),
-                            in_axes=(0, 0, 0, 0, 0) + (None,) * 10
-                            )(masks, mi, mg, sr, dl, *rest)
+            return _forest_body(
+                packed, feat_of, block_start, packed_thr, binned, col_thr,
+                narrow, wide, y, key, mask, mi, mg, sr, kind=kind,
+                depth=depths, num_classes=num_classes,
+                num_trees=num_trees, max_features=max_features,
+                pool_cfg=pool_cfg, impurity=impurity, bootstrap=bootstrap,
+                hist_mode=hist_mode, lanes=lanes)
 
-    if mesh is None:
-        return jax.jit(forest_batched)
-    from jax.sharding import PartitionSpec as P
     leaves_spec = (P("models", None, None, None) if kind == "cls"
                    else P("models", None, None))
-    return jax.jit(shard_map(
-        forest_batched, mesh=mesh,
-        in_specs=(P("models", None), P("models"), P("models"),
-                  P("models"), P("models")) + (P(),) * 10,
-        out_specs=(P("models", None, None), P("models", None, None),
-                   leaves_spec), check_vma=False))
+    return jax.jit(_shard_blocks(
+        forest_batched, mesh, len(depths), 3, 10,
+        (P("models", None, None), P("models", None, None), leaves_spec)))
 
 
 @functools.lru_cache(maxsize=32)
 def _gbt_fg_kernel(statics: tuple, mesh=None):
-    depth, num_rounds, objective, hist_mode = statics
-
-    def one(mask, ss, rl, ga, mcw, sub, dl, packed, feat_of, block_start,
-            packed_thr, y, key):
-        return _gbt_body(packed, feat_of, block_start, packed_thr, y,
-                         key, mask, ss, rl, ga, mcw, sub, depth=depth,
-                         num_rounds=num_rounds, objective=objective,
-                         hist_mode=hist_mode, depth_limit=dl)[:4]
-
-    def batched(masks, ss, rl, ga, mcw, sub, dl, *rest):
-        with jax.named_scope("fg.gbt"):
-            return jax.vmap(one, in_axes=(0,) * 7 + (None,) * 6
-                            )(masks, ss, rl, ga, mcw, sub, dl, *rest)
-
-    if mesh is None:
-        return jax.jit(batched)
+    depths, num_rounds, objective, hist_mode = statics
     from jax.sharding import PartitionSpec as P
-    return jax.jit(shard_map(
-        batched, mesh=mesh,
-        in_specs=(P("models", None),) + (P("models"),) * 6 + (P(),) * 6,
-        out_specs=(P("models", None, None), P("models", None, None),
-                   P("models", None, None), P("models")),
-        check_vma=False))
+
+    def batched(blocks, packed, feat_of, block_start, packed_thr, y, key):
+        (mask, ss, rl, ga, mcw, sub), lanes = _all_lanes(blocks)
+        with jax.named_scope("fg.gbt"):
+            return tuple(fitted[:4] for fitted in _gbt_body(
+                packed, feat_of, block_start, packed_thr, y, key, mask,
+                ss, rl, ga, mcw, sub, depth=depths, num_rounds=num_rounds,
+                objective=objective, hist_mode=hist_mode, lanes=lanes))
+
+    return jax.jit(_shard_blocks(
+        batched, mesh, len(depths), 5, 6,
+        (P("models", None, None), P("models", None, None),
+         P("models", None, None), P("models"))))
 
 
 def _gbt_scores(spec_kind, margin):
@@ -1617,10 +1958,26 @@ def _candidate_scores(kind, spec_kind, depth, feats, thrs, leaves, base,
     if leaf is None:
         leaf = jax.vmap(lambda fh, th: _traverse(Xv, fh, th, depth)
                         )(feats, thrs)
-    vals = leaves[jnp.arange(leaves.shape[0])[:, None], leaf]
-    if kind == "gbt":
-        return _gbt_scores(spec_kind, base + jnp.sum(vals, axis=0))
-    agg = jnp.mean(vals, axis=0)            # (nv, K) votes or (nv,) values
+    if leaves.ndim == 3:
+        # a classification forest's votes, added up a tree at a time (the
+        # sum the mean of the picked (T, nv, K) leaf rows makes, in its
+        # order: tests/test_tree_models.py pins the bits). One pick of all
+        # the rows is laid out by the chip's compiler, for a shallow tree,
+        # with the K classes along a 128-wide tile: 64x the bytes at K = 2,
+        # 7.7 GB for 18 lanes x 50 trees x 16,384 rows (PERF.md section 6,
+        # PR 35)
+        def add_tree(votes, tree):
+            tree_leaves, tree_leaf = tree
+            return votes + tree_leaves[tree_leaf], None
+        votes, _ = jax.lax.scan(
+            add_tree, jnp.zeros((leaf.shape[1], leaves.shape[2]),
+                                leaves.dtype), (leaves, leaf))
+        agg = votes / leaves.shape[0]                   # (nv, K) votes
+    else:
+        vals = leaves[jnp.arange(leaves.shape[0])[:, None], leaf]
+        if kind == "gbt":
+            return _gbt_scores(spec_kind, base + jnp.sum(vals, axis=0))
+        agg = jnp.mean(vals, axis=0)                    # (nv,) values
     if spec_kind == "binary":
         return binary_from_votes(agg)
     if spec_kind == "multiclass":
@@ -1633,51 +1990,46 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
                         in_fit: bool = False):
     """Fit + validation-metric fusion of _forest_fg_kernel: candidates
     never materialize on host — the program returns one metric scalar
-    per candidate (see evaluators/device_metrics.py for why).
+    per candidate, a vector a depth block (see evaluators/device_metrics.py
+    for why).
 
     ``val`` is the stacked validation matrix (F, nv, d) in the "traverse"
     form and, with ``in_fit``, the (F, nv) int32 positions of the
     validation rows in the fitted table instead (see _eval_form): the
     program then holds no ``_traverse`` and never sees ``X_val``."""
-    (kind, depth, num_classes, num_trees, max_features, pool_cfg,
+    (kind, depths, num_classes, num_trees, max_features, pool_cfg,
      impurity, bootstrap, hist_mode) = statics
+    from jax.sharding import PartitionSpec as P
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
-    def one(ob, mask, mi, mg, sr, dl, fi, val, yv, packed, feat_of,
-            block_start, packed_thr, binned, col_thr, narrow, wide, y,
-            key):
-        feats, thrs, leaves, *in_fit_leaf = _forest_body(
-            packed, feat_of, block_start, packed_thr, binned, col_thr,
-            narrow, wide, y, key, mask, mi, mg, sr, kind=kind,
-            depth=depth, num_classes=num_classes, num_trees=num_trees,
-            max_features=max_features, pool_cfg=pool_cfg,
-            impurity=impurity, bootstrap=bootstrap, hist_mode=hist_mode,
-            outer_batch=ob, depth_limit=dl,
-            val_rows=val[fi] if in_fit else None)
-        with jax.named_scope("fg.metric"):
-            scores = _candidate_scores(
-                "forest", spec[0], depth, feats, thrs, leaves, 0.0,
-                None if in_fit else val[fi], *in_fit_leaf)
-            return mfn(yv[fi], scores)
-
-    def forest_batched(masks, mi, mg, sr, dl, fi, val, yv, *rest):
-        ob = masks.shape[0]
+    def forest_batched(blocks, val, yv, packed, feat_of, block_start,
+                       packed_thr, binned, col_thr, narrow, wide, y, key):
         _eval_form(in_fit)
+        (mask, mi, mg, sr, fi), lanes = _all_lanes(blocks)
         with jax.named_scope("fg.forest"):
-            return jax.vmap(functools.partial(one, ob),
-                            in_axes=(0, 0, 0, 0, 0, 0, None, None)
-                            + (None,) * 10
-                            )(masks, mi, mg, sr, dl, fi, val, yv, *rest)
+            fitted = _forest_body(
+                packed, feat_of, block_start, packed_thr, binned, col_thr,
+                narrow, wide, y, key, mask, mi, mg, sr, kind=kind,
+                depth=depths, num_classes=num_classes,
+                num_trees=num_trees, max_features=max_features,
+                pool_cfg=pool_cfg, impurity=impurity, bootstrap=bootstrap,
+                hist_mode=hist_mode, lanes=lanes,
+                val_rows=val[fi] if in_fit else None)
+            with jax.named_scope("fg.metric"):
+                scores = tuple(
+                    jax.vmap(lambda fold, feats, thrs, leaves, *in_fit_leaf:
+                             _candidate_scores(
+                                 "forest", spec[0], depth, feats, thrs,
+                                 leaves, 0.0,
+                                 None if in_fit else val[fold],
+                                 *in_fit_leaf))(block[-1], *trees)
+                    for depth, block, trees in zip(depths, blocks, fitted))
+            return _lane_metrics(mfn, yv, blocks, scores)
 
-    if mesh is None:
-        return jax.jit(forest_batched)
-    from jax.sharding import PartitionSpec as P
-    return jax.jit(shard_map(
-        forest_batched, mesh=mesh,
-        in_specs=(P("models", None), P("models"), P("models"),
-                  P("models"), P("models"), P("models")) + (P(),) * 12,
-        out_specs=P("models"), check_vma=False))
+    return jax.jit(_shard_blocks(
+        forest_batched, mesh, len(depths), 4, 12,
+        P("models")))
 
 
 @functools.lru_cache(maxsize=32)
@@ -1686,39 +2038,36 @@ def _gbt_eval_kernel(statics: tuple, spec: tuple, mesh=None,
     """Fit + validation-metric fusion of _gbt_fg_kernel; ``val`` and
     ``in_fit`` as in _forest_eval_kernel (the in-fit form scores the
     validation rows from the fit's final margins, _gbt_body)."""
-    depth, num_rounds, objective, hist_mode = statics
+    depths, num_rounds, objective, hist_mode = statics
+    from jax.sharding import PartitionSpec as P
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
-    def one(mask, ss, rl, ga, mcw, sub, dl, fi, val, yv, packed, feat_of,
-            block_start, packed_thr, y, key):
-        feats, thrs, leaves, base, margins = _gbt_body(
-            packed, feat_of, block_start, packed_thr, y, key, mask, ss,
-            rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
-            objective=objective, hist_mode=hist_mode, depth_limit=dl)
-        with jax.named_scope("fg.metric"):
-            if in_fit:
-                scores = _gbt_scores(spec[0], margins[val[fi]])
-            else:
-                scores = _candidate_scores("gbt", spec[0], depth, feats,
-                                           thrs, leaves, base, val[fi])
-            return mfn(yv[fi], scores)
-
-    def batched(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv, *rest):
+    def batched(blocks, val, yv, packed, feat_of, block_start, packed_thr,
+                y, key):
         _eval_form(in_fit)
+        (mask, ss, rl, ga, mcw, sub, fi), lanes = _all_lanes(blocks)
         with jax.named_scope("fg.gbt"):
-            return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
-                            + (None,) * 6
-                            )(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv,
-                              *rest)
+            fitted = _gbt_body(
+                packed, feat_of, block_start, packed_thr, y, key, mask,
+                ss, rl, ga, mcw, sub, depth=depths, num_rounds=num_rounds,
+                objective=objective, hist_mode=hist_mode, lanes=lanes)
 
-    if mesh is None:
-        return jax.jit(batched)
-    from jax.sharding import PartitionSpec as P
-    return jax.jit(shard_map(
-        batched, mesh=mesh,
-        in_specs=(P("models", None),) + (P("models"),) * 7 + (P(),) * 8,
-        out_specs=P("models"), check_vma=False))
+            def lane_scores(depth, fold, feats, thrs, leaves, base,
+                            margins):
+                if in_fit:
+                    return _gbt_scores(spec[0], margins[val[fold]])
+                return _candidate_scores("gbt", spec[0], depth, feats,
+                                         thrs, leaves, base, val[fold])
+            with jax.named_scope("fg.metric"):
+                scores = tuple(
+                    jax.vmap(functools.partial(lane_scores, depth))(
+                        block[-1], *trees)
+                    for depth, block, trees in zip(depths, blocks, fitted))
+            return _lane_metrics(mfn, yv, blocks, scores)
+
+    return jax.jit(_shard_blocks(
+        batched, mesh, len(depths), 6, 8, P("models")))
 
 
 @functools.lru_cache(maxsize=32)
@@ -1726,31 +2075,21 @@ def _gbt_softmax_fg_kernel(statics: tuple, mesh=None):
     """Fold×grid kernel for K-class softmax boosting (the multiclass
     XGBoost path, _gbt_softmax_body) — mirrors _gbt_fg_kernel's
     candidate contract."""
-    depth, num_rounds, num_classes, hist_mode = statics
-
-    def one(mask, ss, rl, ga, mcw, sub, dl, packed, feat_of, block_start,
-            packed_thr, y, key):
-        return _gbt_softmax_body(
-            packed, feat_of, block_start, packed_thr, y, key, mask, ss,
-            rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
-            num_classes=num_classes, hist_mode=hist_mode,
-            depth_limit=dl)[:4]
-
-    def batched(masks, ss, rl, ga, mcw, sub, dl, *rest):
-        with jax.named_scope("fg.gbt_softmax"):
-            return jax.vmap(one, in_axes=(0,) * 7 + (None,) * 6
-                            )(masks, ss, rl, ga, mcw, sub, dl, *rest)
-
-    if mesh is None:
-        return jax.jit(batched)
+    depths, num_rounds, num_classes, hist_mode = statics
     from jax.sharding import PartitionSpec as P
-    return jax.jit(shard_map(
-        batched, mesh=mesh,
-        in_specs=(P("models", None),) + (P("models"),) * 6 + (P(),) * 6,
-        out_specs=(P("models", None, None, None),
-                   P("models", None, None, None),
-                   P("models", None, None, None), P("models", None)),
-        check_vma=False))
+
+    def batched(blocks, packed, feat_of, block_start, packed_thr, y, key):
+        (mask, ss, rl, ga, mcw, sub), lanes = _all_lanes(blocks)
+        with jax.named_scope("fg.gbt_softmax"):
+            return tuple(fitted[:4] for fitted in _gbt_softmax_body(
+                packed, feat_of, block_start, packed_thr, y, key, mask,
+                ss, rl, ga, mcw, sub, depth=depths, num_rounds=num_rounds,
+                num_classes=num_classes, hist_mode=hist_mode, lanes=lanes))
+
+    return jax.jit(_shard_blocks(
+        batched, mesh, len(depths), 5, 6,
+        (P("models", None, None, None), P("models", None, None, None),
+         P("models", None, None, None), P("models", None))))
 
 
 def _softmax_margins(feats, thrs, leaves, base, depth: int, Xv):
@@ -1773,39 +2112,38 @@ def _gbt_softmax_eval_kernel(statics: tuple, spec: tuple, mesh=None,
     multiclass metric consumes softmax probabilities, matching the host
     ClassifierModel.raw_to_probability ranking exactly. ``val`` and
     ``in_fit`` as in _forest_eval_kernel."""
-    depth, num_rounds, num_classes, hist_mode = statics
+    depths, num_rounds, num_classes, hist_mode = statics
+    from jax.sharding import PartitionSpec as P
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
 
-    def one(mask, ss, rl, ga, mcw, sub, dl, fi, val, yv, packed, feat_of,
-            block_start, packed_thr, y, key):
-        feats, thrs, leaves, base, margins = _gbt_softmax_body(
-            packed, feat_of, block_start, packed_thr, y, key, mask, ss,
-            rl, ga, mcw, sub, depth=depth, num_rounds=num_rounds,
-            num_classes=num_classes, hist_mode=hist_mode, depth_limit=dl)
-        with jax.named_scope("fg.metric"):
-            if in_fit:
-                margins = margins[val[fi]]
-            else:
-                margins = _softmax_margins(feats, thrs, leaves, base,
-                                           depth, val[fi])
-            return mfn(yv[fi], jax.nn.softmax(margins, axis=1))
-
-    def batched(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv, *rest):
+    def batched(blocks, val, yv, packed, feat_of, block_start, packed_thr,
+                y, key):
         _eval_form(in_fit)
+        (mask, ss, rl, ga, mcw, sub, fi), lanes = _all_lanes(blocks)
         with jax.named_scope("fg.gbt_softmax"):
-            return jax.vmap(one, in_axes=(0,) * 8 + (None, None)
-                            + (None,) * 6
-                            )(masks, ss, rl, ga, mcw, sub, dl, fi, val, yv,
-                              *rest)
+            fitted = _gbt_softmax_body(
+                packed, feat_of, block_start, packed_thr, y, key, mask,
+                ss, rl, ga, mcw, sub, depth=depths, num_rounds=num_rounds,
+                num_classes=num_classes, hist_mode=hist_mode, lanes=lanes)
 
-    if mesh is None:
-        return jax.jit(batched)
-    from jax.sharding import PartitionSpec as P
-    return jax.jit(shard_map(
-        batched, mesh=mesh,
-        in_specs=(P("models", None),) + (P("models"),) * 7 + (P(),) * 8,
-        out_specs=P("models"), check_vma=False))
+            def lane_scores(depth, fold, feats, thrs, leaves, base,
+                            margins):
+                if in_fit:
+                    margins = margins[val[fold]]
+                else:
+                    margins = _softmax_margins(feats, thrs, leaves, base,
+                                               depth, val[fold])
+                return jax.nn.softmax(margins, axis=1)
+            with jax.named_scope("fg.metric"):
+                scores = tuple(
+                    jax.vmap(functools.partial(lane_scores, depth))(
+                        block[-1], *trees)
+                    for depth, block, trees in zip(depths, blocks, fitted))
+            return _lane_metrics(mfn, yv, blocks, scores)
+
+    return jax.jit(_shard_blocks(
+        batched, mesh, len(depths), 6, 8, P("models")))
 
 
 def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
@@ -1832,41 +2170,28 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
     y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
         y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
                                        _GBT_SKEY))
-    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
+    for cand0, blocks in groups:
         with _trace.span("search.design"):
             design, _ = _design_args(X, cand0.max_bins,
                                      edge_rows=edge_rows)
-        statics = (depth_cap, cand0.num_rounds, num_classes_k,
+        statics = (tuple(b.depth for b in blocks), cand0.num_rounds,
+                   num_classes_k,
                    _hist_mode(n, int(design[1].shape[0])))
-        _note_compile("gbt_softmax", statics, masks_p.shape)
-        vecs_j = [jnp.asarray(v) for v in vecs]
+        _note_compile("gbt_softmax", statics,
+                      tuple(b.lanes[0].shape for b in blocks))
+        key = jax.random.PRNGKey(cand0.seed)
         if eval_ctx is not None:
-            fn = _gbt_softmax_eval_kernel(statics, spec, mesh, in_fit)
-            with _fetch_span():
-                mm = to_host(fn(
-                    jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                    val_j, yv_j, *design[:4], y_j,
-                    jax.random.PRNGKey(cand0.seed)))[:count]
-            _scatter_group_metrics(metric_mat, mm, members, F, gk)
+            fetched = _run_blocks(
+                _gbt_softmax_eval_kernel(statics, spec, mesh, in_fit),
+                blocks, True, val_j, yv_j, *design[:4], y_j, key)
+            _scatter_block_metrics(metric_mat, blocks, fetched)
             continue
-        fn = _gbt_softmax_fg_kernel(statics, mesh)
-        with _fetch_span():
-            feats, thrs, leaves, base = fn(
-                jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
-                jax.random.PRNGKey(cand0.seed))
-            feats = to_host(feats)[:count]
-            thrs = to_host(thrs)[:count]
-            leaves = to_host(leaves)[:count]
-            base = to_host(base)[:count]
-        for f in range(F):
-            for j, (gi, cand) in enumerate(members):
-                c = f * gk + j
-                fe, th, le = _trim_tree_arrays(
-                    feats[c], thrs[c], leaves[c], depth_cap,
-                    cand.max_depth, leaf_axis=2)
-                models[f][gi] = GBTMulticlassClassifierModel(
-                    fe, th, le, depth=cand.max_depth, base=base[c],
-                    n_features=d)
+        fetched = _run_blocks(_gbt_softmax_fg_kernel(statics, mesh), blocks,
+                              False, *design[:4], y_j, key)
+        for f, gi, cand, (fe, th, le, base) in _block_lanes(
+                blocks, fetched, F):
+            models[f][gi] = GBTMulticlassClassifierModel(
+                fe, th, le, depth=cand.max_depth, base=base, n_features=d)
     return metric_mat if eval_ctx is not None else models
 
 
@@ -2341,24 +2666,27 @@ def _fold_edges_mode() -> bool:
 
 
 def _depth_mode() -> str:
-    """How the fold×grid search handles the max_depth sweep:
+    """How the fold×grid search lays out a grid's ``max_depth`` sweep (the
+    default tree grids sweep 3 / 6 / 12, a third of the lanes each):
 
-    - "static": one program per distinct depth — lanes do exactly their
-      own work.
-    - "mask": ONE compiled program per tree family at the grid's
-      deepest depth; each candidate's depth is a traced per-lane limit
-      (_grow_tree depth_limit). Cuts tree-family compile count 3x on
-      the default grids (flagship: 6 -> 2 programs) at the price of
-      shallow lanes running the deep lane's masked levels.
+    - "static": one program per distinct depth, the CPU path and the tests'
+      reference.
+    - "blocks": ONE program per tree family and static group, as many to
+      compile, load and dispatch as there are families, with the lanes in
+      static DEPTH BLOCKS inside it (see _candidate_groups,
+      _grow_blocks): a lane's tree is what "static" grows for its depth,
+      so every lane grows to the depth its grid point asks for and no
+      deeper, and a level is traced once, for the lanes of every block
+      that still grows there.
 
-    Chosen by platform: mask on accelerators, static on CPU (same split
-    _hist_mode uses). The choice rests on builder-reported runs that no
-    driver record holds (mask ~2x faster warm on a v5e, where the search
-    is dispatch-bound; ~4x slower on one CPU core, where the masked
-    levels are real work); ROADMAP S3 measures both sides on the chip
-    cells. chip_smoke.py runs the flagship under mask: 2 tree programs
-    instead of 6, same winner and holdout AuPR as static on CPU."""
-    return "static" if jax.default_backend() == "cpu" else "mask"
+    Chosen by platform, the split _hist_mode uses: blocks on an
+    accelerator, static on a CPU. Nothing else selects it, and a grid with
+    one depth is one block under either answer. A level costs in proportion
+    to its slots (2^level, capped at _DEFAULT_NODE_CAP): summed over the
+    levels a depth-12 lane asks for 1,279 slot-levels, a depth-6 lane for
+    63 and a depth-3 lane for 7. The measurements: PERF.md section 6
+    (PR 35)."""
+    return "static" if jax.default_backend() == "cpu" else "blocks"
 
 
 #: (kernel kind, statics, call shape) triples seen — each is one XLA
@@ -2415,60 +2743,69 @@ _GBT_TILED = ("step_size", "reg_lambda", "gamma", "min_child_weight",
 _GBT_SKEY = ("max_depth", "num_rounds", "max_bins", "seed")
 
 
-def _trim_tree_arrays(feats, thrs, leaves, depth_cap: int, depth: int,
-                      leaf_axis: int = 1):
-    """Slice a depth_cap-shaped (heap, leaves) candidate back to its own
-    ``depth`` (``mask`` depth mode materialization): levels >= depth hold
-    only (0, +inf) denied splits, and a truncated node ``l``'s rows all
-    sit in its leftmost descendant leaf ``l << (cap - depth)`` — so the
-    heap prefix plus a strided leaf gather reproduce the static-depth
-    model bit-exactly (up to 512x less host memory for a depth-3 lane
-    in a depth-12 group).
+class _DepthBlock(NamedTuple):
+    """The lanes of a fold-grid group that grow to one ``depth`` (see
+    _candidate_groups): ``members`` the block's (grid index, candidate)
+    pairs; ``lanes`` its per-lane arguments ``(masks, *traced_vecs)``,
+    fold-major over the members and padded to the mesh's shard count;
+    ``fidx`` each lane's fold index (0 on a padding lane), which the fused
+    fit+metric kernels take as one more per-lane argument; ``count`` the
+    lanes before padding."""
+    depth: int
+    members: list
+    lanes: tuple
+    fidx: np.ndarray
+    count: int
 
-    Heaps put H last everywhere ((T, H) forests, (R, K, H) softmax);
-    the LEAF axis varies — (T, L[, K]) forests/GBT vs (R, K, L) softmax
-    — hence ``leaf_axis``."""
-    if depth == depth_cap:
-        return feats, thrs, leaves
-    h = 2 ** depth - 1
-    sl = [slice(None)] * leaves.ndim
-    sl[leaf_axis] = slice(None, None, 2 ** (depth_cap - depth))
-    return feats[..., :h], thrs[..., :h], leaves[tuple(sl)]
+
+def tree_depth_blocks(blocks) -> dict:
+    """The depth blocks of one fold-grid group (see _candidate_groups),
+    ``{"blocks": b, "lane_levels": m}``, beside :func:`tree_route_forms` and
+    its siblings: ``b`` the distinct depths of the group, each a block of
+    lanes in its program; ``m`` the sum over the group's lanes, padding left
+    out, of the depth each grows to (a default grid's 18 points x 3 folds: 3
+    blocks and 18 x (3 + 6 + 12) = 378, where every lane run to depth 12
+    would be 648)."""
+    return {"blocks": len(blocks),
+            "lane_levels": sum(b.count * b.depth for b in blocks)}
 
 
 def _candidate_groups(est, grid, masks, mesh, traced_fields, skey_fields):
     """The shared fold-major candidate-batching contract of the three
     fold×grid drivers (forest / binary-GBT / softmax-GBT): partition
-    grid points into static shape groups, flatten (fold, candidate)
-    lanes fold-major, tile the traced hyperparameter vectors (plus the
-    trailing depth-limit lane for the ``mask`` depth mode), and pad to the
-    mesh shard count.
+    grid points into static shape groups, one program each, and lay a
+    group's lanes out as DEPTH BLOCKS: one block a distinct ``max_depth``
+    of the group, ascending, each block fold-major over its own members
+    (lane ``f * len(members) + j``), with the traced hyperparameter
+    vectors tiled to its lanes, and padded to the mesh shard count on its
+    own, so that every chip holds its share of every depth. Under the
+    "static" depth mode ``max_depth`` is part of the group's key and every
+    group is one block; under "blocks" it is not (see _depth_mode).
 
-    Yields (members, cand0, depth_cap, traced_vecs, masks_p, fidx,
-    count, gk) per group; ``traced_vecs`` follows ``traced_fields``
-    order with the depth-limit vector appended."""
-    mask_depth = _depth_mode() == "mask"
+    Yields (cand0, blocks) per group, ``blocks`` a tuple of
+    :class:`_DepthBlock`, whose depths are the static the kernels take."""
+    static_depth = _depth_mode() == "static"
     F, n = masks.shape
     groups: Dict[tuple, list] = {}
     for gi, p in enumerate(grid):
         cand = est.with_params(**p)
-        key = tuple(None if f == "max_depth" and mask_depth
+        key = tuple(None if f == "max_depth" and not static_depth
                     else getattr(cand, f, "") for f in skey_fields)
         groups.setdefault(key, []).append((gi, cand))
-    for members in groups.values():
-        cand0 = members[0][1]
-        depth_cap = max(c.max_depth for _, c in members)
-        gk = len(members)
-        vecs = [np.tile([float(getattr(c, f)) for _, c in members], F)
-                for f in traced_fields]
-        vecs.append(np.tile([float(c.max_depth) for _, c in members], F))
-        masks_c = np.repeat(masks, gk, axis=0)
-        fidx = np.repeat(np.arange(F, dtype=np.int32), gk)
-        (masks_p, *vecs), count = _pad_candidates(
-            mesh, [masks_c, *vecs], n)
-        fidx = np.concatenate(
-            [fidx, np.zeros(len(masks_p) - count, dtype=np.int32)])
-        yield members, cand0, depth_cap, vecs, masks_p, fidx, count, gk
+    for group in groups.values():
+        blocks = []
+        for depth in sorted({c.max_depth for _, c in group}):
+            members = [m for m in group if m[1].max_depth == depth]
+            gk = len(members)
+            vecs = [np.tile([float(getattr(c, f)) for _, c in members], F)
+                    for f in traced_fields]
+            lanes, count = _pad_candidates(
+                mesh, [np.repeat(masks, gk, axis=0), *vecs], n)
+            fidx = np.zeros(len(lanes[0]), dtype=np.int32)
+            fidx[:count] = np.repeat(np.arange(F, dtype=np.int32), gk)
+            blocks.append(_DepthBlock(depth, members, tuple(lanes), fidx,
+                                      count))
+        yield group[0][1], tuple(blocks)
 
 
 def _eval_ctx_parts(eval_ctx):
@@ -2516,12 +2853,40 @@ def _fold_grid_head(y, eval_ctx, groups):
         return y_j, val_j, yv_j, spec, in_fit, groups
 
 
-def _scatter_group_metrics(metric_mat, mm, members, F: int, gk: int):
-    """Write one group's (padded, fold-major) metric vector back into
-    the (F, G) matrix."""
-    for f in range(F):
-        for j, (gi, _) in enumerate(members):
-            metric_mat[f, gi] = mm[f * gk + j]
+def _run_blocks(fn, blocks, fused: bool, *shared) -> list:
+    """One call of a fold-grid program (see _shard_blocks) under its
+    ``search.fetch`` span, and its result on the host, an entry a depth
+    block (a metric vector, or a tuple of tree arrays), each block's
+    padding lanes cut. A ``fused`` fit+metric kernel takes each lane's
+    fold index after the block's other per-lane arguments."""
+    counts = tree_depth_blocks(blocks)
+    with _fetch_span(depth_blocks=counts["blocks"],
+                     depth_lane_levels=counts["lane_levels"]):
+        out = fn(tuple(tuple(jnp.asarray(a) for a in
+                             b.lanes + ((b.fidx,) if fused else ()))
+                       for b in blocks), *shared)
+        return [jax.tree_util.tree_map(lambda a: to_host(a)[:b.count], res)
+                for b, res in zip(blocks, out)]
+
+
+def _scatter_block_metrics(metric_mat, blocks, fetched) -> None:
+    """Write a group's metric vectors, one a depth block (fold-major over
+    the block's members), back into the (F, G) matrix."""
+    F = metric_mat.shape[0]
+    for b, mm in zip(blocks, fetched):
+        metric_mat[:, [gi for gi, _ in b.members]] = mm.reshape(
+            F, len(b.members))
+
+
+def _block_lanes(blocks, fetched, F: int):
+    """``(f, gi, candidate, the lane's arrays)`` of every fitted lane of
+    a model-materializing fold-grid call: a block's heaps are at its
+    lanes' own depth."""
+    for b, arrays in zip(blocks, fetched):
+        for f in range(F):
+            for j, (gi, cand) in enumerate(b.members):
+                c = f * len(b.members) + j
+                yield f, gi, cand, tuple(a[c] for a in arrays)
 
 
 def _fold_edge_recurse(fold_grid_fn, est, X, y, masks, grid, mesh,
@@ -2584,7 +2949,9 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
     y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
         y, eval_ctx, _candidate_groups(est, grid, masks, mesh,
                                        _FOREST_TRACED, _FOREST_STATIC))
-    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
+    model_cls = (TreeEnsembleClassifierModel if classification
+                 else TreeEnsembleRegressorModel)
+    for cand0, blocks in groups:
         with _trace.span("search.design"):
             design, widths = _design_args(X, cand0.max_bins,
                                           edge_rows=edge_rows)
@@ -2592,40 +2959,26 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
                                    classification) \
             if cand0.bootstrap else None
         (narrow, wide), pool_cfg, mf = _pool_plan(widths, mf)
-        statics = ("cls" if classification else "reg", depth_cap,
+        statics = ("cls" if classification else "reg",
+                   tuple(b.depth for b in blocks),
                    k if classification else 0, cand0.num_trees, mf,
                    pool_cfg, getattr(cand0, "impurity", ""),
                    cand0.bootstrap,
                    _hist_mode(n, int(design[1].shape[0])))
-        _note_compile("forest", statics, masks_p.shape)
-        vecs_j = [jnp.asarray(v) for v in vecs]
+        _note_compile("forest", statics,
+                      tuple(b.lanes[0].shape for b in blocks))
+        key = jax.random.PRNGKey(cand0.seed)
         if eval_ctx is not None:
-            fn = _forest_eval_kernel(statics, spec, mesh, in_fit)
-            with _fetch_span():
-                mm = to_host(fn(
-                    jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                    val_j, yv_j, *design, narrow, wide, y_j,
-                    jax.random.PRNGKey(cand0.seed)))[:count]
-            _scatter_group_metrics(metric_mat, mm, members, F, gk)
+            fetched = _run_blocks(
+                _forest_eval_kernel(statics, spec, mesh, in_fit), blocks,
+                True, val_j, yv_j, *design, narrow, wide, y_j, key)
+            _scatter_block_metrics(metric_mat, blocks, fetched)
             continue
-        fn = _forest_fg_kernel(statics, mesh)
-        with _fetch_span():
-            feats, thrs, leaves = fn(
-                jnp.asarray(masks_p), *vecs_j, *design, narrow, wide,
-                y_j, jax.random.PRNGKey(cand0.seed))
-            feats = to_host(feats)[:count]
-            thrs = to_host(thrs)[:count]
-            leaves = to_host(leaves)[:count]
-        model_cls = (TreeEnsembleClassifierModel if classification
-                     else TreeEnsembleRegressorModel)
-        for f in range(F):
-            for j, (gi, cand) in enumerate(members):
-                c = f * gk + j
-                fe, th, le = _trim_tree_arrays(
-                    feats[c], thrs[c], leaves[c], depth_cap,
-                    cand.max_depth)
-                models[f][gi] = model_cls(
-                    fe, th, le, depth=cand.max_depth, n_features=d)
+        fetched = _run_blocks(_forest_fg_kernel(statics, mesh), blocks,
+                              False, *design, narrow, wide, y_j, key)
+        for f, gi, cand, (fe, th, le) in _block_lanes(blocks, fetched, F):
+            models[f][gi] = model_cls(fe, th, le, depth=cand.max_depth,
+                                      n_features=d)
     return metric_mat if eval_ctx is not None else models
 
 
@@ -2656,41 +3009,28 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
     y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
         y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
                                        _GBT_SKEY))
-    for members, cand0, depth_cap, vecs, masks_p, fidx, count, gk in groups:
+    for cand0, blocks in groups:
         with _trace.span("search.design"):
             design, _ = _design_args(X, cand0.max_bins,
                                      edge_rows=edge_rows)
-        statics = (depth_cap, cand0.num_rounds, objective,
+        statics = (tuple(b.depth for b in blocks), cand0.num_rounds,
+                   objective,
                    _hist_mode(n, int(design[1].shape[0])))
-        _note_compile("gbt", statics, masks_p.shape)
-        vecs_j = [jnp.asarray(v) for v in vecs]
+        _note_compile("gbt", statics,
+                      tuple(b.lanes[0].shape for b in blocks))
+        key = jax.random.PRNGKey(cand0.seed)
         if eval_ctx is not None:
-            fn = _gbt_eval_kernel(statics, spec, mesh, in_fit)
-            with _fetch_span():
-                mm = to_host(fn(
-                    jnp.asarray(masks_p), *vecs_j, jnp.asarray(fidx),
-                    val_j, yv_j, *design[:4], y_j,
-                    jax.random.PRNGKey(cand0.seed)))[:count]
-            _scatter_group_metrics(metric_mat, mm, members, F, gk)
+            fetched = _run_blocks(
+                _gbt_eval_kernel(statics, spec, mesh, in_fit), blocks,
+                True, val_j, yv_j, *design[:4], y_j, key)
+            _scatter_block_metrics(metric_mat, blocks, fetched)
             continue
-        fn = _gbt_fg_kernel(statics, mesh)
-        with _fetch_span():
-            feats, thrs, leaves, base = fn(
-                jnp.asarray(masks_p), *vecs_j, *design[:4], y_j,
-                jax.random.PRNGKey(cand0.seed))
-            feats = to_host(feats)[:count]
-            thrs = to_host(thrs)[:count]
-            leaves = to_host(leaves)[:count]
-            base = to_host(base)[:count]
-        for f in range(F):
-            for j, (gi, cand) in enumerate(members):
-                c = f * gk + j
-                fe, th, le = _trim_tree_arrays(
-                    feats[c], thrs[c], leaves[c], depth_cap,
-                    cand.max_depth)
-                models[f][gi] = model_cls(
-                    fe, th, le, depth=cand.max_depth,
-                    base=float(base[c]), n_features=d)
+        fetched = _run_blocks(_gbt_fg_kernel(statics, mesh), blocks, False,
+                              *design[:4], y_j, key)
+        for f, gi, cand, (fe, th, le, base) in _block_lanes(
+                blocks, fetched, F):
+            models[f][gi] = model_cls(fe, th, le, depth=cand.max_depth,
+                                      base=float(base), n_features=d)
     return metric_mat if eval_ctx is not None else models
 
 
