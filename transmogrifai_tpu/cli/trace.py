@@ -58,8 +58,11 @@ def _self_times(records: List[dict]) -> Dict[int, float]:
 
 def summarize_trace(records: List[dict], top: int = 10) -> dict:
     """The ``tx trace`` summary document: span/trace counts, top span
-    NAMES by total self time, compile share (section-recorded compile
-    seconds vs total root wall), and the request traces present."""
+    NAMES by total self time, compile share (the ``compile.program``
+    spans' trace + lower + backend seconds where the file has such
+    spans, else the sections' recorded compile seconds, vs total root
+    wall), the five costliest programs, and the request traces
+    present."""
     selfs = _self_times(records)
     by_name: Dict[str, dict] = {}
     for r in records:
@@ -71,8 +74,19 @@ def summarize_trace(records: List[dict], top: int = 10) -> dict:
         rec["self_seconds"] += selfs.get(r["sid"], 0.0)
     roots = [r for r in records if r.get("parent") is None]
     root_wall = sum(r.get("dur") or 0.0 for r in roots)
-    compile_s = sum((r.get("attrs") or {}).get("compile_seconds", 0.0)
-                    for r in records)
+    programs = sorted(
+        ({"program": a.get("program", "?"), "thread": a.get("thread", "?"),
+          "cache": a.get("cache", "?"),
+          "seconds": round(sum(a.get(k, 0.0) for k in (
+              "trace_s", "lower_s", "backend_s")), 6)}
+         for a in (r.get("attrs") or {} for r in records
+                   if r.get("name") == "compile.program")),
+        key=lambda row: -row["seconds"])
+    # sections nest (a segment's seconds hold its stages'), the
+    # programs do not: where a file has them they are the compile bill
+    compile_s = sum(row["seconds"] for row in programs) if programs \
+        else sum((r.get("attrs") or {}).get("compile_seconds", 0.0)
+                 for r in records)
     requests = sorted({r["trace"] for r in records
                        if r.get("name") == "serve.request"})
     events = sum(len(r.get("events") or ()) for r in records)
@@ -84,6 +98,7 @@ def summarize_trace(records: List[dict], top: int = 10) -> dict:
         "compile_seconds": round(compile_s, 6),
         "compile_share": round(compile_s / root_wall, 4)
         if root_wall > 0 else 0.0,
+        "costliest_programs": programs[:5],
         "span_events": events,
         "requests": requests[:200],
         "request_count": len(requests),
@@ -149,6 +164,9 @@ def _print_text(summary: dict, request: Optional[dict]) -> None:
     print(f"root wall {summary['root_wall_seconds']:.4f}s, compile "
           f"{summary['compile_seconds']:.4f}s "
           f"({summary['compile_share']:.1%} of root wall)")
+    for row in summary["costliest_programs"]:
+        print(f"  {row['program']:<32} {row['cache']:<9} "
+              f"{row['seconds']:>9.4f}s  on {row['thread']}")
     print("\ntop spans by self time:")
     print(f"  {'name':<32} {'calls':>6} {'self s':>10} {'total s':>10}")
     for row in summary["top_self_time"]:

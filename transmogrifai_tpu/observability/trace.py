@@ -29,8 +29,9 @@ substrate:
   (Perfetto) can place spans on the wall clock without any span paying
   a ``time.time()`` call.
 - **Integration points.** ``utils/compile_time`` sections report into
-  the CURRENT span as child spans carrying their compile/execute split
-  (registered via :func:`configure`); ``runtime/telemetry.event``
+  the CURRENT span as child spans carrying their compile/execute split,
+  and its compile log's records as ``compile.program`` child spans
+  (both registered via :func:`configure`); ``runtime/telemetry.event``
   fault/retry/quarantine events attach to the current span as span
   events. Neither module imports this one at module level in reverse —
   the dependency is one-way (observability imports nothing from the
@@ -93,8 +94,10 @@ _STACK: contextvars.ContextVar = contextvars.ContextVar(
 def configure(enabled: bool, path: Optional[str] = None) -> None:
     """Turn tracing on/off at runtime. ``path`` additionally streams
     every finished span to a JSONL file (header line first). Also
-    (un)registers the compile-time section observer so section
-    wall/compile splits land as child spans of whatever span is open."""
+    (un)registers the compile-time section and compile-log observers so
+    section wall/compile splits, and every program that is traced,
+    lowered and loaded or compiled, land as child spans of whatever
+    span is open."""
     global _ENABLED, _PATH, _FILE, _SPANS
     if _PATH is not None and (not enabled or path != _PATH):
         _drain_pending()            # pending spans land before close
@@ -111,6 +114,7 @@ def configure(enabled: bool, path: Optional[str] = None) -> None:
             _SPANS = deque(_SPANS, maxlen=_buffer_cap())
     from ..utils import compile_time
     compile_time.set_section_observer(_note_section if enabled else None)
+    compile_time.set_program_observer(_note_program if enabled else None)
 
 
 def configure_from_env() -> bool:
@@ -296,6 +300,19 @@ def _note_section(label: str, wall: float, compile_s: float) -> None:
              attrs={"compile_seconds": round(compile_s, 6),
                     "execute_seconds": round(max(wall - compile_s, 0.0),
                                              6)})
+
+
+def _note_program(rec: dict) -> None:
+    """utils/compile_time compile-log observer: a closed record becomes
+    a ``compile.program`` child of the span open on the thread that
+    paid for it (a program's first call traces inside ``search.fetch``),
+    over the record's own ``t0`` .. ``t1`` and carrying its other
+    fields. Outside any span it is dropped, as a section is: the log
+    (``compile_time.compile_log()``) has it anyway."""
+    stack = _STACK.get() if _ENABLED else ()
+    if stack:
+        add_span("compile.program", rec.pop("t0"), rec.pop("t1"),
+                 parent=(stack[-1]["trace"], stack[-1]["sid"]), attrs=rec)
 
 
 #: spans awaiting JSONL serialization — the hot path pays two atomic
