@@ -48,7 +48,7 @@ import logging
 import os
 import threading
 from collections import OrderedDict
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +62,7 @@ from .base import (ClassifierModel, Predictor, RegressionModel,
                    check_fold_classes, num_classes, subset_grid)
 from ..observability import trace as _trace
 from ..parallel.mesh import to_host
+from ..utils.jax_setup import with_frame_room
 
 __all__ = [
     "DecisionTreeClassifier", "DecisionTreeRegressor",
@@ -81,8 +82,9 @@ __all__ = [
 #: fold-grid program's body (``fg.softmax``: the multinomial logistic lanes,
 #: ``fg.bayes``: ``models/bayes.py``; ``fg.glm``: the IRLS lanes of
 #: ``models/glm.py``, with ``glm.gram`` and ``glm.solve`` inside an
-#: iteration), and the linear cores' ``lin.*`` (``models/linear.py``,
-#: ``parallel/cv.py``). The one list of the package:
+#: iteration), the linear cores' ``lin.*`` (``models/linear.py``,
+#: ``parallel/cv.py``), and ``fg.order``, the row gathers that put the table
+#: in every fold's own order (``_by_fold``). The one list of the package:
 #: the benchmark's scope readers take it from this attribute. A scope is a
 #: path component of the ``op_name`` of the ops traced under it and exists
 #: only while JAX traces: it adds no operation and changes no program's name.
@@ -90,7 +92,8 @@ SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
           "tree.split", "tree.route", "tree.bootstrap", "tree.pool",
           "gbt.round", "fg.metric", "fg.gbt", "fg.forest",
           "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve",
-          "fg.softmax", "fg.bayes", "fg.glm", "glm.gram", "glm.solve")
+          "fg.softmax", "fg.bayes", "fg.glm", "glm.gram", "glm.solve",
+          "fg.order")
 
 # ---------------------------------------------------------------------------
 # binning — packed variable-width bins
@@ -461,6 +464,25 @@ def _bin_indicator(packed: jnp.ndarray, total_bins: int, dtype,
             ).astype(dtype)
 
 
+def _fold_indicator(packed: jnp.ndarray, feat_of: jnp.ndarray, dtype,
+                    hist_mode: Optional[str], hist_rows: Optional[int]
+                    ) -> Optional[jnp.ndarray]:
+    """The bin indicator that every tree of one fold's body shares (see
+    _forest_body ``hist_rows``), built ONCE a call of the program and not
+    once a tree or a round: the (hist_rows, TB) indicator of the rows the
+    fold trains on, under the whole-matrix ``matmul`` mode; None elsewhere
+    (the growers then see to their own, as a pooled tree must: its design
+    is its own). XLA does not move the build out of the trees' ``scan``
+    itself, the indicator being some 17 times its inputs' bytes: built
+    once a tree it read 0.90 + 0.36 s of the regression pool's train (my
+    chip run, PR 37, PERF.md section 6)."""
+    if hist_rows is None or (hist_mode or "").partition("+")[0] != "matmul":
+        return None
+    with jax.named_scope("tree.indicator"):
+        return _bin_indicator(packed[:hist_rows], int(feat_of.shape[0]),
+                              dtype, feat_of)
+
+
 def _level_histograms(packed: jnp.ndarray, slot: jnp.ndarray,
                       stats: jnp.ndarray, num_slots: int,
                       total_bins: int,
@@ -642,23 +664,43 @@ def tree_eval_forms() -> dict:
     return dict(_EVAL_FORMS)
 
 
+#: how many traced tree growers contracted each set of rows in their level
+#: histograms (see tree_hist_rows)
+_HIST_ROWS = {"head": 0, "all": 0}
+
+
+def tree_hist_rows() -> dict:
+    """Traced tree growers (:class:`_TreeGrower`) so far in this process by
+    the rows their level histograms contract, ``{"head": k, "all": m}``:
+    "head" where the lane sees the table in its fold's own order and
+    contracts the rows it trains on, the static head of that order (see
+    _fold_order: the in-fit form of a fold-grid program without a mesh,
+    under the ``matmul`` family); "all" everywhere else (a single fit, the
+    traverse form, a search mesh, the ``scatter`` mode), where the held-out
+    rows of a lane are contracted at weight zero."""
+    return dict(_HIST_ROWS)
+
+
 @contextlib.contextmanager
 def _fetch_span(**group):
     """The ``search.fetch`` span of a fold-grid driver, carrying
     :func:`tree_route_forms`, :func:`tree_sum_forms`,
-    :func:`tree_eval_forms` and :func:`tree_compress_levels` as the scalar
-    attributes ``route_dense`` / ``route_gather``, ``sums_dense`` /
-    ``sums_scatter``, ``eval_in_fit`` / ``eval_traverse`` and
-    ``compress_carried`` (the process's counts so far: read when the span
-    opens, which its profiler annotation keeps, and again when it closes,
-    because a program's first call traces inside the span), and ``group``,
-    the attributes of the call's own group (``depth_blocks`` /
-    ``depth_lane_levels``, see tree_depth_blocks)."""
+    :func:`tree_eval_forms`, :func:`tree_compress_levels` and
+    :func:`tree_hist_rows` as the scalar attributes ``route_dense`` /
+    ``route_gather``, ``sums_dense`` / ``sums_scatter``, ``eval_in_fit`` /
+    ``eval_traverse``, ``compress_carried`` and ``hist_head`` / ``hist_all``
+    (the process's counts so far: read when the span opens, which its
+    profiler annotation keeps, and again when it closes, because a
+    program's first call traces inside the span), and ``group``, the
+    attributes of the call's own group (``depth_blocks`` /
+    ``depth_lane_levels``, see tree_depth_blocks; ``hist_row_share``, the
+    rows a lane's histograms contract over the rows it holds)."""
     def attrs():
         return {prefix + k: v for prefix, counts in (
             ("route_", tree_route_forms()), ("sums_", tree_sum_forms()),
             ("eval_", tree_eval_forms()),
-            ("compress_", tree_compress_levels())) for k, v in counts.items()}
+            ("compress_", tree_compress_levels()),
+            ("hist_", tree_hist_rows())) for k, v in counts.items()}
     with _trace.span("search.fetch", **attrs(), **group) as rec:
         yield
         if rec is not None:
@@ -731,7 +773,17 @@ class _TreeGrower:
     resolved forms (see _hist_mode, _route_form) and the per-level keys of
     the per-node feature draw (the chain ``key, sub = split(key)`` a level,
     to ``max_depth``). ``levels`` and ``leaves`` are functions of one
-    lane's statistics and state and are ``vmap``ped by their caller."""
+    lane's statistics and state and are ``vmap``ped by their caller.
+
+    ``hist_rows`` (a static; default: every row) is the count of leading
+    rows that carry weight: the indicator is built over them, the level
+    histograms contract them and the node sums add them up, while every
+    row is routed and counted where rows are counted (an identity level's
+    empty slots, the occupancy of _carry_slots). The lanes of one FOLD of
+    a fold-grid program share such a design, the table in the fold's own
+    order (see _fold_order), and the grower is then built inside the
+    fold's body (see _by_fold). ``bin_oh``: the indicator of those rows where
+    the caller holds it already (see _fold_indicator)."""
 
     def __init__(self, packed: jnp.ndarray, feat_of: jnp.ndarray,
                  block_start: jnp.ndarray, packed_thr: jnp.ndarray, dtype,
@@ -742,9 +794,16 @@ class _TreeGrower:
                  feat_map: Optional[jnp.ndarray] = None,
                  hist_mode: Optional[str] = None,
                  axis_name: Optional[str] = None,
-                 row_total: Optional[int] = None):
+                 row_total: Optional[int] = None,
+                 hist_rows: Optional[int] = None,
+                 bin_oh: Optional[jnp.ndarray] = None):
         n, d = packed.shape
         TB = feat_of.shape[0]
+        # the leading rows that carry weight (see _fold_order): what the
+        # level histograms contract and the node sums add up; every row is
+        # still routed
+        self.hist_rows = n if hist_rows is None else hist_rows
+        _HIST_ROWS["head" if self.hist_rows < n else "all"] += 1
         self.packed, self.feat_of = packed, feat_of
         self.block_start, self.packed_thr = block_start, packed_thr
         self.feat_map, self.axis_name = feat_map, axis_name
@@ -758,11 +817,11 @@ class _TreeGrower:
         # resolver never returns it): histogram subtraction, see _hist_mode
         self.hist_mode, _, suffix = hist_mode.partition("+")
         self.sub_enabled = suffix == "sub"
-        if self.hist_mode == "matmul":
+        if self.hist_mode == "matmul" and bin_oh is None:
             with jax.named_scope("tree.indicator"):
-                self.bin_oh = _bin_indicator(packed, TB, dtype, feat_of)
-        else:
-            self.bin_oh = None           # scatter / matmul_chunk modes
+                bin_oh = _bin_indicator(self.head(packed), TB, dtype,
+                                        feat_of)
+        self.bin_oh = bin_oh             # None: scatter / matmul_chunk modes
         self.route = _route_form(self.hist_mode, d)
         _ROUTE_FORMS[self.route] += 1
         self.max_features = (max_features if max_features is not None
@@ -773,6 +832,12 @@ class _TreeGrower:
             for _ in range(max_depth):
                 key, sub = jax.random.split(key)
                 self.subkeys.append(sub)
+
+    def head(self, rows: jnp.ndarray) -> jnp.ndarray:
+        """The leading ``hist_rows`` of a per-row array (itself where that
+        is every row: no operation is traced)."""
+        return (rows if self.hist_rows == rows.shape[0]
+                else rows[:self.hist_rows])
 
     def is_identity(self, level: int, depth: int) -> bool:
         # identity fast path: while every within-level node id fits the
@@ -866,14 +931,16 @@ class _TreeGrower:
                     C_half = C // 2
                     slot_sub = jnp.where((slot & 1) == 0, slot >> 1, C)
                     hist_even = _level_histograms(
-                        packed, slot_sub, stats, C_half, TB, self.bin_oh,
+                        self.head(packed), self.head(slot_sub),
+                        self.head(stats), C_half, TB, self.bin_oh,
                         mode=hist_mode, axis_name=axis_name,
                         feat_of=feat_of)
                     hist = jnp.stack([hist_even, prev_hist - hist_even],
                                      axis=1).reshape(C, TB, stats.shape[1])
                 else:
                     hist = _level_histograms(
-                        packed, slot, stats, C, TB, self.bin_oh,
+                        self.head(packed), self.head(slot),
+                        self.head(stats), C, TB, self.bin_oh,
                         mode=hist_mode, axis_name=axis_name,
                         feat_of=feat_of)
             prev_hist, prev_identity = hist, identity
@@ -896,16 +963,26 @@ class _TreeGrower:
                         # empty node's XGB gain positive — so count rows
                         # per slot (folded into the total reduction as an
                         # extra ones column) and mask empty slots out of
-                        # split_ok below
-                        aug = node_sums(
-                            jnp.concatenate(
-                                [stats, jnp.ones((n, 1), stats.dtype)],
-                                axis=1),
-                            slot, C)
-                        total = aug[:, None, :-1]
-                        nonempty = aug[:, -1] > 0
+                        # split_ok below. The rows counted are all the
+                        # lane holds: behind ``hist_rows`` they carry no
+                        # statistic and get a pass of their own
+                        if self.hist_rows < n:
+                            total = node_sums(self.head(stats),
+                                              self.head(slot), C)[:, None, :]
+                            nonempty = node_sums(
+                                jnp.ones((n, 1), jnp.int32), slot,
+                                C)[:, 0] > 0
+                        else:
+                            aug = node_sums(
+                                jnp.concatenate(
+                                    [stats, jnp.ones((n, 1), stats.dtype)],
+                                    axis=1),
+                                slot, C)
+                            total = aug[:, None, :-1]
+                            nonempty = aug[:, -1] > 0
                     else:
-                        total = node_sums(stats, slot, C)[:, None, :]
+                        total = node_sums(self.head(stats), self.head(slot),
+                                          C)[:, None, :]
                 right = total - left
                 gain = gain_fn(left, right, total)         # (C, TB)
                 gain = jnp.where(self.not_a_split[None, :], -jnp.inf, gain)
@@ -992,15 +1069,17 @@ class _TreeGrower:
                     or self.is_identity(depth - 1, depth)):
                 # the last level's slots were its node ids (or there is
                 # none): a leaf's column is its id
-                return node_sums(stats, state.node, 2 ** depth)
+                return node_sums(self.head(stats), self.head(state.node),
+                                 2 ** depth)
             # a leaf is (last level's slot, side): summed over 2 * C
             # columns, not 2^depth segments, then placed by the columns'
             # leaf ids, which the slots' node ids give (a column no row
             # reached adds zeros to its leaf, as an empty segment does; an
             # unused slot's two columns land nowhere)
             C = min(2 ** (depth - 1), self.cap)
-            by_column = node_sums(stats, 2 * state.slot + state.went_right,
-                                  2 * C)
+            by_column = node_sums(
+                self.head(stats),
+                self.head(2 * state.slot + state.went_right), 2 * C)
             return jnp.zeros((2 ** depth, stats.shape[1]), stats.dtype).at[
                 _child_ids(state.node_of_slot, 2 ** depth)].set(
                 by_column, mode="drop")
@@ -1016,7 +1095,8 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                feat_map: Optional[jnp.ndarray] = None,
                hist_mode: Optional[str] = None,
                axis_name: Optional[str] = None,
-               row_total: Optional[int] = None):
+               row_total: Optional[int] = None,
+               hist_rows: Optional[int] = None):
     """Grow one complete tree of static ``depth`` over a packed binned
     design (see :class:`_PackedDesign`).
 
@@ -1056,6 +1136,13 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
     ``matmul`` family. They are never read from ``hist``, which holds
     them but is a bf16-pass contraction on the chip.
 
+    With ``hist_rows`` only the leading ``hist_rows`` rows carry
+    statistics: the level histograms contract them and the node sums add
+    them up, and what ``stats`` holds behind them is never read; the rows
+    behind them are routed like every other and occupy their nodes (the
+    order of a fold-grid lane whose held-out rows come last, see
+    _fold_order).
+
     Returns (feat_heap (2^depth - 1,), thr_heap (2^depth - 1,),
     leaf_stats (2^depth, S), final node assignment (n,)).
     """
@@ -1063,7 +1150,7 @@ def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
         packed, feat_of, block_start, packed_thr, stats.dtype,
         max_depth=depth, feat_key=feat_key, max_features=max_features,
         node_cap=node_cap, feat_map=feat_map, hist_mode=hist_mode,
-        axis_name=axis_name, row_total=row_total)
+        axis_name=axis_name, row_total=row_total, hist_rows=hist_rows)
     state = grower.levels(None, stats, gain_fn, min_info_gain, 0, depth,
                           depth)
     return (state.feat_heap, state.thr_heap,
@@ -1374,7 +1461,8 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
                  hist_mode: Optional[str],
                  axis_name: Optional[str] = None,
                  row_total: Optional[int] = None,
-                 val_rows=None, lanes: Optional[tuple] = None):
+                 val_rows=None, lanes: Optional[tuple] = None,
+                 hist_rows: Optional[int] = None):
     """Shared forest program: ``mask`` (n,) row weights let one body
     serve the single fit (mask=ones), the fold x grid batched kernel
     (mask = fold membership, traced per-candidate hyperparams), and the
@@ -1400,9 +1488,21 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
     tree, (T, nv) int32: ``_traverse``'s leaf, without the walk. Without
     it the body returns (feats, thrs, leaves) and traces what it always
     did. With ``lanes`` it returns a tuple a block of those, lanes
-    first."""
+    first.
+
+    ``hist_rows`` (with ``lanes``; instead of ``val_rows``): the body is
+    one FOLD's, mapped over the folds of the program (see _by_fold): the
+    design, ``binned`` and ``y`` are the table in the fold's own order,
+    the rows it trains on first (``hist_rows`` of them, the rows ``mask``
+    may weigh) and its held-out rows last (see _fold_order). The trees
+    contract the head alone (see _TreeGrower) and the fourth output is the
+    tail of every tree's final nodes, a slice. The bootstrap weights are
+    drawn in that order: one stream a tree for every fold, keyed by the
+    row's position in the lane's order."""
     assert val_rows is None or axis_name is None, \
         "val_rows index the whole table: not under row sharding"
+    assert hist_rows is None or (val_rows is None and axis_name is None
+                                 and lanes is not None)
     n, d = packed.shape
     dtype = packed_thr.dtype
     over = _over_lanes(lanes)
@@ -1427,6 +1527,9 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
 
         def gain_of(min_instances, min_info_gain):
             return _variance_gain(min_instances), min_info_gain
+
+    held_oh = None if pool_cfg is not None else _fold_indicator(
+        packed, feat_of, dtype, hist_mode, hist_rows)
 
     def one_tree(tkey):
         pkey, wkey, fkey = jax.random.split(tkey, 3)
@@ -1461,7 +1564,8 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
         grower = _TreeGrower(
             *design, dtype, max_depth=max(depths), feat_key=fkey,
             max_features=max_features, feat_map=pool, hist_mode=hist_mode,
-            axis_name=axis_name, row_total=row_total)
+            axis_name=axis_name, row_total=row_total, hist_rows=hist_rows,
+            bin_oh=held_oh)
         if lanes is None:
             state = grower.levels(None, stats, *gain_of(
                 min_instances, min_info_gain), 0, depth, depth)
@@ -1479,13 +1583,16 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
             if val_rows is not None:
                 tree += (jax.vmap(lambda nd, rows: nd[rows])(
                     node, val_rows[mine]),)
+            elif hist_rows is not None:
+                tree += (node[:, hist_rows:],)
             trees.append(tree)
             at += k
         return tuple(trees)
 
     keys = jax.random.split(key, num_trees)
     # full-design TB is a safe upper bound for the pooled design's; the
-    # lanes of a block share the budget, and the blocks one block size
+    # lanes of a block share the budget (a fold's body holds its own lanes
+    # and indicator, one fold at a time), and the blocks one block size
     tb = min(_tree_block_size(
         row_total if row_total is not None else n,
         int(feat_of.shape[0]), block_depth,
@@ -1564,7 +1671,8 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
               hist_mode: Optional[str],
               axis_name: Optional[str] = None,
               row_total: Optional[int] = None,
-              lanes: Optional[tuple] = None):
+              lanes: Optional[tuple] = None,
+              hist_rows: Optional[int] = None):
     """Shared boosting program with row-mask semantics (see
     _forest_body): masked rows get zero grad/hess weight; the base
     margin is the mask-weighted mean. ``axis_name`` row-shards the fit
@@ -1584,7 +1692,12 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
     what the in-fit form of the fused fit+metric kernel scores the
     held-out rows from (see _eval_form). It is the sequential float32 sum
     over rounds, not ``base + sum(vals)``: equal to a few ulp. A caller
-    that drops it compiles what it compiled without it."""
+    that drops it compiles what it compiled without it.
+
+    ``hist_rows``: one FOLD's body over the table in the fold's own order,
+    as in _forest_body: the rounds' trees contract the leading
+    ``hist_rows`` rows, the held-out rows' margins are the tail of
+    ``margins``, and a round's subsample is drawn in that order."""
     n, d = packed.shape
     dtype = packed_thr.dtype
     over = _over_lanes(lanes)
@@ -1606,6 +1719,7 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
 
     def gain_of(reg_lambda, gamma, min_child_weight):
         return _xgb_gain(reg_lambda, gamma, min_child_weight), 0.0
+    held_oh = _fold_indicator(packed, feat_of, dtype, hist_mode, hist_rows)
 
     def one_round(margins, rkey):
         def round_stats(margins, mask, subsample):
@@ -1631,7 +1745,8 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
             grower = _TreeGrower(
                 packed, feat_of, block_start, packed_thr, dtype,
                 max_depth=max(depths), hist_mode=hist_mode,
-                axis_name=axis_name, row_total=row_total)
+                axis_name=axis_name, row_total=row_total,
+                hist_rows=hist_rows, bin_oh=held_oh)
             if lanes is None:
                 state = grower.levels(
                     None, stats, *gain_of(reg_lambda, gamma,
@@ -1686,7 +1801,8 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
                       hist_mode: Optional[str],
                       axis_name: Optional[str] = None,
                       row_total: Optional[int] = None,
-                      lanes: Optional[tuple] = None):
+                      lanes: Optional[tuple] = None,
+                      hist_rows: Optional[int] = None):
     """K-class softmax boosting: each round fits one tree PER CLASS on
     the softmax gradients/hessians (g_k = p_k - 1[y=k],
     h_k = p_k(1-p_k)) — the ``multi:softprob`` objective the reference
@@ -1699,7 +1815,7 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
     of :func:`_grow_blocks`. Returns (feats (R,K,H), thrs (R,K,H), leaves
     (R,K,L), base (K,), margins (n,K)), a tuple a block of them with
     ``lanes``: the last is the scan's final carry, every row's finished
-    margins (see _gbt_body)."""
+    margins (see _gbt_body). ``hist_rows`` as in _gbt_body."""
     n, d = packed.shape
     dtype = packed_thr.dtype
     K = num_classes
@@ -1721,6 +1837,7 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
 
     def gain_of(reg_lambda, gamma, min_child_weight):
         return _xgb_gain(reg_lambda, gamma, min_child_weight), 0.0
+    held_oh = _fold_indicator(packed, feat_of, dtype, hist_mode, hist_rows)
 
     def one_round(margins, rkey):
         def round_stats(margins, mask, subsample):
@@ -1744,7 +1861,8 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
             grower = _TreeGrower(
                 packed, feat_of, block_start, packed_thr, dtype,
                 max_depth=max(depths), hist_mode=hist_mode,
-                axis_name=axis_name, row_total=row_total)
+                axis_name=axis_name, row_total=row_total,
+                hist_rows=hist_rows, bin_oh=held_oh)
             if lanes is None:
                 def per_class(class_stats):
                     state = grower.levels(
@@ -1830,6 +1948,70 @@ def _all_lanes(blocks: tuple):
     after another on its leading axis, and the lane count a block."""
     return (tuple(jnp.concatenate(arg) for arg in zip(*blocks)),
             tuple(block[0].shape[0] for block in blocks))
+
+
+def _fold_order(masks: np.ndarray, val_rows: np.ndarray
+                ) -> Optional[np.ndarray]:
+    """(F, n) int32 ``order``: the rows of the fitted table in each fold's
+    OWN ORDER, the rows the fold trains on first (ascending) and its
+    held-out rows ``val_rows[f]`` last, as they were given. A function of
+    ``val_rows`` alone, and its shapes are the program's own (``n``,
+    ``nv``, ``F``): k folds, the one split of a train-validation split and
+    a racing rung's subset of folds alike. In that order the rows that can
+    carry weight are the static head ``[0, n - nv)`` and a lane's held-out
+    rows the static tail: the level histograms contract the head and not
+    the whole table (see _TreeGrower ``hist_rows``), and the held-out
+    rows' leaves or margins are a slice, not a per-row gather.
+
+    None where the folds do not allow it: a fold names a row twice, or
+    ``masks`` (F, n) gives weight to a row its fold holds out, whose
+    statistics the head form would drop. Rows of the head may carry weight
+    zero (the rows the validator drops to make the folds equal, a racing
+    rung's thinned rows)."""
+    F, n = masks.shape
+    order = np.empty((F, n), dtype=np.int32)
+    for f, held_rows in enumerate(val_rows):
+        held = np.zeros(n, dtype=bool)
+        held[held_rows] = True
+        if held.sum() != len(held_rows) or masks[f, held].any():
+            return None
+        order[f] = np.concatenate([np.nonzero(~held)[0], held_rows])
+    return order
+
+
+def _by_fold(fit, blocks: tuple, order, tables: tuple):
+    """A fold-grid program's fits a FOLD at a time: ``fit(fold_tables,
+    lane_args, lanes)``, one fold's body (see _forest_body ``hist_rows``),
+    mapped over the folds one after another (``lax.map``). A fold's body
+    sees ``tables`` (per-row arrays of the fitted table) in the fold's own
+    order, ``table[order[f]]``: row gathers once a call of the program, not
+    once a tree or a level; and the fold's lanes: the per-lane arguments of
+    the depth ``blocks`` (each fold-major, lane ``f * gk + j``, see
+    _candidate_groups) as (F, lanes a fold) with the blocks side by side,
+    ``lanes`` counting a block's lanes a fold. The body is traced once, as
+    the lanes-only form is, and a fold's lanes share its design and
+    indicator as the lanes of the whole table share theirs there.
+
+    Why a map and not a ``vmap`` over the folds, which would run the
+    folds' contractions as one batched one: on the chip the mapped form is
+    the faster (my chip runs, PR 37, PERF.md section 6: ``.search`` 24.6
+    against 22.4 models x folds/s, the binary pool 33.0 against 30.8; a
+    third of the temporaries), and a second batching pass over the trees'
+    or rounds' ``scan`` body costs what the first trace of it cost:
+    tracing was 40-50 % slower here and ``setup_s`` went over its bound.
+    Returns what the bodies return, a tuple a block, with the lanes back on
+    one leading axis in the blocks' own order."""
+    F = order.shape[0]
+    lane_args = tuple(
+        jnp.concatenate([a.reshape((F, -1) + a.shape[1:]) for a in arg],
+                        axis=1) for arg in zip(*blocks))
+    lanes = tuple(block[0].shape[0] // F for block in blocks)
+    with jax.named_scope("fg.order"):
+        fold_tables = tuple(table[order] for table in tables)
+    fitted = jax.lax.map(lambda fold: fit(*fold, lanes),
+                         (fold_tables, lane_args))
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), fitted)
 
 
 def _lane_metrics(mfn, yv, blocks: tuple, scores: tuple):
@@ -1987,7 +2169,7 @@ def _candidate_scores(kind, spec_kind, depth, feats, thrs, leaves, base,
 
 @functools.lru_cache(maxsize=32)
 def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
-                        in_fit: bool = False):
+                        in_fit: bool = False, head: bool = False):
     """Fit + validation-metric fusion of _forest_fg_kernel: candidates
     never materialize on host — the program returns one metric scalar
     per candidate, a vector a depth block (see evaluators/device_metrics.py
@@ -1996,26 +2178,46 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
     ``val`` is the stacked validation matrix (F, nv, d) in the "traverse"
     form and, with ``in_fit``, the (F, nv) int32 positions of the
     validation rows in the fitted table instead (see _eval_form): the
-    program then holds no ``_traverse`` and never sees ``X_val``."""
+    program then holds no ``_traverse`` and never sees ``X_val``. With
+    ``head`` (an in-fit form; see _fold_grid_head) ``val`` is the (F, n)
+    ``order`` of _fold_order, whose tail those positions are, and ``mask``
+    lies in that order: every fold's lanes fit on the table in the fold's
+    own order (see _by_fold) and the leaves of the held-out rows are the
+    tail of every tree's nodes."""
     (kind, depths, num_classes, num_trees, max_features, pool_cfg,
      impurity, bootstrap, hist_mode) = statics
     from jax.sharding import PartitionSpec as P
     from ..evaluators.device_metrics import metric_fn
     mfn = metric_fn(*spec)
+    pooled = pool_cfg is not None
 
     def forest_batched(blocks, val, yv, packed, feat_of, block_start,
                        packed_thr, binned, col_thr, narrow, wide, y, key):
         _eval_form(in_fit)
-        (mask, mi, mg, sr, fi), lanes = _all_lanes(blocks)
+        body = functools.partial(
+            _forest_body, kind=kind, depth=depths, num_classes=num_classes,
+            num_trees=num_trees, max_features=max_features,
+            pool_cfg=pool_cfg, impurity=impurity, bootstrap=bootstrap,
+            hist_mode=hist_mode)
+
+        def fold_fit(tables, lane_args, lanes):
+            # a pooled tree reads ``binned`` alone, any other ``packed``
+            rows, fold_y = tables
+            return body(
+                packed if pooled else rows, feat_of, block_start,
+                packed_thr, rows if pooled else binned, col_thr, narrow,
+                wide, fold_y, key, *lane_args[:4], lanes=lanes,
+                hist_rows=val.shape[1] - yv.shape[1])
         with jax.named_scope("fg.forest"):
-            fitted = _forest_body(
-                packed, feat_of, block_start, packed_thr, binned, col_thr,
-                narrow, wide, y, key, mask, mi, mg, sr, kind=kind,
-                depth=depths, num_classes=num_classes,
-                num_trees=num_trees, max_features=max_features,
-                pool_cfg=pool_cfg, impurity=impurity, bootstrap=bootstrap,
-                hist_mode=hist_mode, lanes=lanes,
-                val_rows=val[fi] if in_fit else None)
+            if head:
+                fitted = _by_fold(fold_fit, blocks, val,
+                                  (binned if pooled else packed, y))
+            else:
+                (mask, mi, mg, sr, fi), lanes = _all_lanes(blocks)
+                fitted = body(
+                    packed, feat_of, block_start, packed_thr, binned,
+                    col_thr, narrow, wide, y, key, mask, mi, mg, sr,
+                    lanes=lanes, val_rows=val[fi] if in_fit else None)
             with jax.named_scope("fg.metric"):
                 scores = tuple(
                     jax.vmap(lambda fold, feats, thrs, leaves, *in_fit_leaf:
@@ -2032,12 +2234,33 @@ def _forest_eval_kernel(statics: tuple, spec: tuple, mesh=None,
         P("models")))
 
 
+def _boosted_fits(body, head: bool, blocks: tuple, val, yv, packed,
+                  feat_of, block_start, packed_thr, y, key):
+    """The fits of a boosted fold-grid program's lanes, a tuple a depth
+    block (``body``: _gbt_body or _gbt_softmax_body with its statics
+    bound): the lanes one after another over the shared table, or with
+    ``head`` every fold's lanes over the table in the fold's own order
+    (see _by_fold; ``val`` is then the (F, n) ``order``)."""
+    if not head:
+        lane_args, lanes = _all_lanes(blocks)
+        return body(packed, feat_of, block_start, packed_thr, y, key,
+                    *lane_args[:6], lanes=lanes)
+    return _by_fold(
+        lambda tables, lane_args, lanes: body(
+            tables[0], feat_of, block_start, packed_thr, tables[1], key,
+            *lane_args[:6], lanes=lanes,
+            hist_rows=val.shape[1] - yv.shape[1]),
+        blocks, val, (packed, y))
+
+
 @functools.lru_cache(maxsize=32)
 def _gbt_eval_kernel(statics: tuple, spec: tuple, mesh=None,
-                     in_fit: bool = False):
-    """Fit + validation-metric fusion of _gbt_fg_kernel; ``val`` and
-    ``in_fit`` as in _forest_eval_kernel (the in-fit form scores the
-    validation rows from the fit's final margins, _gbt_body)."""
+                     in_fit: bool = False, head: bool = False):
+    """Fit + validation-metric fusion of _gbt_fg_kernel; ``val``,
+    ``in_fit`` and ``head`` as in _forest_eval_kernel (the in-fit form
+    scores the validation rows from the fit's final margins, _gbt_body:
+    picked at their positions, or with ``head`` the tail of the lane's
+    rows)."""
     depths, num_rounds, objective, hist_mode = statics
     from jax.sharding import PartitionSpec as P
     from ..evaluators.device_metrics import metric_fn
@@ -2046,15 +2269,18 @@ def _gbt_eval_kernel(statics: tuple, spec: tuple, mesh=None,
     def batched(blocks, val, yv, packed, feat_of, block_start, packed_thr,
                 y, key):
         _eval_form(in_fit)
-        (mask, ss, rl, ga, mcw, sub, fi), lanes = _all_lanes(blocks)
         with jax.named_scope("fg.gbt"):
-            fitted = _gbt_body(
-                packed, feat_of, block_start, packed_thr, y, key, mask,
-                ss, rl, ga, mcw, sub, depth=depths, num_rounds=num_rounds,
-                objective=objective, hist_mode=hist_mode, lanes=lanes)
+            fitted = _boosted_fits(
+                functools.partial(
+                    _gbt_body, depth=depths, num_rounds=num_rounds,
+                    objective=objective, hist_mode=hist_mode),
+                head, blocks, val, yv, packed, feat_of, block_start,
+                packed_thr, y, key)
 
             def lane_scores(depth, fold, feats, thrs, leaves, base,
                             margins):
+                if head:
+                    return _gbt_scores(spec[0], margins[-yv.shape[1]:])
                 if in_fit:
                     return _gbt_scores(spec[0], margins[val[fold]])
                 return _candidate_scores("gbt", spec[0], depth, feats,
@@ -2107,11 +2333,11 @@ def _softmax_margins(feats, thrs, leaves, base, depth: int, Xv):
 
 @functools.lru_cache(maxsize=32)
 def _gbt_softmax_eval_kernel(statics: tuple, spec: tuple, mesh=None,
-                             in_fit: bool = False):
+                             in_fit: bool = False, head: bool = False):
     """Fit + validation-metric fusion of _gbt_softmax_fg_kernel: the
     multiclass metric consumes softmax probabilities, matching the host
-    ClassifierModel.raw_to_probability ranking exactly. ``val`` and
-    ``in_fit`` as in _forest_eval_kernel."""
+    ClassifierModel.raw_to_probability ranking exactly. ``val``,
+    ``in_fit`` and ``head`` as in _gbt_eval_kernel."""
     depths, num_rounds, num_classes, hist_mode = statics
     from jax.sharding import PartitionSpec as P
     from ..evaluators.device_metrics import metric_fn
@@ -2120,16 +2346,19 @@ def _gbt_softmax_eval_kernel(statics: tuple, spec: tuple, mesh=None,
     def batched(blocks, val, yv, packed, feat_of, block_start, packed_thr,
                 y, key):
         _eval_form(in_fit)
-        (mask, ss, rl, ga, mcw, sub, fi), lanes = _all_lanes(blocks)
         with jax.named_scope("fg.gbt_softmax"):
-            fitted = _gbt_softmax_body(
-                packed, feat_of, block_start, packed_thr, y, key, mask,
-                ss, rl, ga, mcw, sub, depth=depths, num_rounds=num_rounds,
-                num_classes=num_classes, hist_mode=hist_mode, lanes=lanes)
+            fitted = _boosted_fits(
+                functools.partial(
+                    _gbt_softmax_body, depth=depths, num_rounds=num_rounds,
+                    num_classes=num_classes, hist_mode=hist_mode),
+                head, blocks, val, yv, packed, feat_of, block_start,
+                packed_thr, y, key)
 
             def lane_scores(depth, fold, feats, thrs, leaves, base,
                             margins):
-                if in_fit:
+                if head:
+                    margins = margins[-yv.shape[1]:]
+                elif in_fit:
                     margins = margins[val[fold]]
                 else:
                     margins = _softmax_margins(feats, thrs, leaves, base,
@@ -2167,27 +2396,29 @@ def _gbt_softmax_fold_grid(est, X, y, masks, grid, mesh, num_classes_k,
     d = X.shape[1]
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
-        y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
-                                       _GBT_SKEY))
-    for cand0, blocks in groups:
+    head = _fold_grid_head(
+        y, eval_ctx, masks, mesh, lambda m: _candidate_groups(
+            est, grid, m, mesh, _GBT_TILED, _GBT_SKEY))
+    for cand0, blocks in head.groups:
         with _trace.span("search.design"):
             design, _ = _design_args(X, cand0.max_bins,
                                      edge_rows=edge_rows)
         statics = (tuple(b.depth for b in blocks), cand0.num_rounds,
                    num_classes_k,
-                   _hist_mode(n, int(design[1].shape[0])))
+                   _hist_mode(head.hist_rows or n, int(design[1].shape[0])))
         _note_compile("gbt_softmax", statics,
                       tuple(b.lanes[0].shape for b in blocks))
         key = jax.random.PRNGKey(cand0.seed)
         if eval_ctx is not None:
             fetched = _run_blocks(
-                _gbt_softmax_eval_kernel(statics, spec, mesh, in_fit),
-                blocks, True, val_j, yv_j, *design[:4], y_j, key)
+                _gbt_softmax_eval_kernel(statics, head.spec, mesh,
+                                         *head.form),
+                blocks, True, head.val, head.yv, *design[:4], head.y, key,
+                hist_row_share=head.hist_row_share)
             _scatter_block_metrics(metric_mat, blocks, fetched)
             continue
         fetched = _run_blocks(_gbt_softmax_fg_kernel(statics, mesh), blocks,
-                              False, *design[:4], y_j, key)
+                              False, *design[:4], head.y, key)
         for f, gi, cand, (fe, th, le, base) in _block_lanes(
                 blocks, fetched, F):
             models[f][gi] = GBTMulticlassClassifierModel(
@@ -2826,45 +3057,98 @@ def _eval_ctx_parts(eval_ctx):
     return X_val, y_val, spec, val_rows
 
 
-def _fold_grid_head(y, eval_ctx, groups):
+class _FoldGridHead(NamedTuple):
+    """What :func:`_fold_grid_head` hands a fold-grid driver: the labels
+    ``y`` on the device; with an ``eval_ctx`` the fused kernels' ``val``
+    (the validation matrix, the validation rows' positions, or every
+    fold's row order: see ``form``), the validation labels ``yv`` and the
+    metric ``spec``, else None; ``in_fit`` (see _eval_form);
+    ``hist_rows``, the leading rows of a lane that its level histograms
+    contract where the lanes see the table in their fold's own order (see
+    _fold_order), else None; ``rows``, the table's; and the candidate
+    ``groups``."""
+    y: jnp.ndarray
+    val: Optional[jnp.ndarray]
+    yv: Optional[jnp.ndarray]
+    spec: Optional[tuple]
+    in_fit: bool
+    hist_rows: Optional[int]
+    rows: int
+    groups: Iterable
+
+    @property
+    def form(self) -> tuple:
+        """The fused kernels' ``(in_fit, head)``."""
+        return self.in_fit, self.hist_rows is not None
+
+    @property
+    def hist_row_share(self) -> float:
+        """The rows a lane's histograms contract over the rows it holds."""
+        return (self.hist_rows or self.rows) / self.rows
+
+
+def _fold_grid_head(y, eval_ctx, masks, mesh, groups_of) -> _FoldGridHead:
     """What a fold-grid driver does on the host before its first design, under
     the span ``search.head``: the labels and (with ``eval_ctx``) the stacked
     validation folds go to the device, and the first candidate group is laid
-    out (the later ones stay lazy: a group's masks are lanes x rows).
+    out (``groups_of(masks)``: _candidate_groups over the driver's fields;
+    the later groups stay lazy: a group's masks are lanes x rows).
     Of the validation folds the labels always go; the matrix ``X_val`` only
     in the "traverse" form (see _eval_ctx_parts): with ``val_rows`` the
     (F, nv) int32 row indices go in its place and the matrix is not read.
-    Returns (y, X_val or val_rows, y_val on the device, metric spec, whether
-    the form is in-fit, the groups); the validation entries are None
-    without ``eval_ctx``."""
+
+    With ``val_rows``, no mesh (under one a chip's lanes mix folds, see
+    _shard_blocks) and a histogram mode of the ``matmul`` family (a question
+    of the backend alone, see _hist_mode) the form is ``head``: every lane
+    sees the table in its fold's own order where the folds allow it (see
+    _fold_order). The (F, n) order goes to the device instead of the (F, nv)
+    positions, which are its tail, and the groups' masks are laid out in it,
+    here on the host."""
     with _trace.span("search.head"):
         y_j = jnp.asarray(y)
-        val_j = yv_j = spec = None
+        val_j = yv_j = spec = hist_rows = None
         in_fit = False
+        n = masks.shape[1]
         if eval_ctx is not None:
             X_val, y_val, spec, val_rows = _eval_ctx_parts(eval_ctx)
             in_fit = val_rows is not None
-            val_j = (jnp.asarray(val_rows) if in_fit else
-                     jnp.asarray(np.asarray(X_val, dtype=np.float64)))
+            order = None
+            if in_fit and mesh is None and _hist_mode(n, 1) != "scatter":
+                order = _fold_order(masks, val_rows)
+            if order is not None:
+                hist_rows = n - val_rows.shape[1]
+                masks = np.take_along_axis(masks, order, axis=1)
+                val_j = jnp.asarray(order)
+            else:
+                val_j = (jnp.asarray(val_rows) if in_fit else
+                         jnp.asarray(np.asarray(X_val, dtype=np.float64)))
             yv_j = jnp.asarray(np.asarray(y_val, dtype=np.float64))
+        groups = groups_of(masks)
         first = next(groups, None)
         if first is not None:
             groups = itertools.chain([first], groups)
-        return y_j, val_j, yv_j, spec, in_fit, groups
+        return _FoldGridHead(y_j, val_j, yv_j, spec, in_fit, hist_rows, n,
+                             groups)
 
 
-def _run_blocks(fn, blocks, fused: bool, *shared) -> list:
+def _run_blocks(fn, blocks, fused: bool, *shared,
+                hist_row_share: float = 1.0) -> list:
     """One call of a fold-grid program (see _shard_blocks) under its
     ``search.fetch`` span, and its result on the host, an entry a depth
     block (a metric vector, or a tuple of tree arrays), each block's
     padding lanes cut. A ``fused`` fit+metric kernel takes each lane's
-    fold index after the block's other per-lane arguments."""
+    fold index after the block's other per-lane arguments.
+    ``hist_row_share``: the span's attribute (see _FoldGridHead)."""
     counts = tree_depth_blocks(blocks)
     with _fetch_span(depth_blocks=counts["blocks"],
-                     depth_lane_levels=counts["lane_levels"]):
-        out = fn(tuple(tuple(jnp.asarray(a) for a in
-                             b.lanes + ((b.fidx,) if fused else ()))
-                       for b in blocks), *shared)
+                     depth_lane_levels=counts["lane_levels"],
+                     hist_row_share=hist_row_share):
+        lanes = tuple(tuple(jnp.asarray(a) for a in
+                            b.lanes + ((b.fidx,) if fused else ()))
+                      for b in blocks)
+        # the first call traces and lowers: a quarter of a million small
+        # Python calls, which must not sit at the end of a frame-stack chunk
+        out = with_frame_room(lambda: fn(lanes, *shared))
         return [jax.tree_util.tree_map(lambda a: to_host(a)[:b.count], res)
                 for b, res in zip(blocks, out)]
 
@@ -2946,12 +3230,12 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
     k = num_classes(y)
     models = [[None] * G for _ in range(F)]
     metric_mat = np.full((F, G), np.nan)
-    y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
-        y, eval_ctx, _candidate_groups(est, grid, masks, mesh,
-                                       _FOREST_TRACED, _FOREST_STATIC))
+    head = _fold_grid_head(
+        y, eval_ctx, masks, mesh, lambda m: _candidate_groups(
+            est, grid, m, mesh, _FOREST_TRACED, _FOREST_STATIC))
     model_cls = (TreeEnsembleClassifierModel if classification
                  else TreeEnsembleRegressorModel)
-    for cand0, blocks in groups:
+    for cand0, blocks in head.groups:
         with _trace.span("search.design"):
             design, widths = _design_args(X, cand0.max_bins,
                                           edge_rows=edge_rows)
@@ -2964,18 +3248,19 @@ def _forest_fold_grid(est, X, y, masks, grid, mesh, classification: bool,
                    k if classification else 0, cand0.num_trees, mf,
                    pool_cfg, getattr(cand0, "impurity", ""),
                    cand0.bootstrap,
-                   _hist_mode(n, int(design[1].shape[0])))
+                   _hist_mode(head.hist_rows or n, int(design[1].shape[0])))
         _note_compile("forest", statics,
                       tuple(b.lanes[0].shape for b in blocks))
         key = jax.random.PRNGKey(cand0.seed)
         if eval_ctx is not None:
             fetched = _run_blocks(
-                _forest_eval_kernel(statics, spec, mesh, in_fit), blocks,
-                True, val_j, yv_j, *design, narrow, wide, y_j, key)
+                _forest_eval_kernel(statics, head.spec, mesh, *head.form),
+                blocks, True, head.val, head.yv, *design, narrow, wide,
+                head.y, key, hist_row_share=head.hist_row_share)
             _scatter_block_metrics(metric_mat, blocks, fetched)
             continue
         fetched = _run_blocks(_forest_fg_kernel(statics, mesh), blocks,
-                              False, *design, narrow, wide, y_j, key)
+                              False, *design, narrow, wide, head.y, key)
         for f, gi, cand, (fe, th, le) in _block_lanes(blocks, fetched, F):
             models[f][gi] = model_cls(fe, th, le, depth=cand.max_depth,
                                       n_features=d)
@@ -3006,27 +3291,28 @@ def _gbt_fold_grid(est, X, y, masks, grid, mesh, objective: str,
     metric_mat = np.full((F, G), np.nan)
     model_cls = (GBTClassifierModel if objective == "logistic"
                  else GBTRegressorModel)
-    y_j, val_j, yv_j, spec, in_fit, groups = _fold_grid_head(
-        y, eval_ctx, _candidate_groups(est, grid, masks, mesh, _GBT_TILED,
-                                       _GBT_SKEY))
-    for cand0, blocks in groups:
+    head = _fold_grid_head(
+        y, eval_ctx, masks, mesh, lambda m: _candidate_groups(
+            est, grid, m, mesh, _GBT_TILED, _GBT_SKEY))
+    for cand0, blocks in head.groups:
         with _trace.span("search.design"):
             design, _ = _design_args(X, cand0.max_bins,
                                      edge_rows=edge_rows)
         statics = (tuple(b.depth for b in blocks), cand0.num_rounds,
                    objective,
-                   _hist_mode(n, int(design[1].shape[0])))
+                   _hist_mode(head.hist_rows or n, int(design[1].shape[0])))
         _note_compile("gbt", statics,
                       tuple(b.lanes[0].shape for b in blocks))
         key = jax.random.PRNGKey(cand0.seed)
         if eval_ctx is not None:
             fetched = _run_blocks(
-                _gbt_eval_kernel(statics, spec, mesh, in_fit), blocks,
-                True, val_j, yv_j, *design[:4], y_j, key)
+                _gbt_eval_kernel(statics, head.spec, mesh, *head.form),
+                blocks, True, head.val, head.yv, *design[:4], head.y, key,
+                hist_row_share=head.hist_row_share)
             _scatter_block_metrics(metric_mat, blocks, fetched)
             continue
         fetched = _run_blocks(_gbt_fg_kernel(statics, mesh), blocks, False,
-                              *design[:4], y_j, key)
+                              *design[:4], head.y, key)
         for f, gi, cand, (fe, th, le, base) in _block_lanes(
                 blocks, fetched, F):
             models[f][gi] = model_cls(fe, th, le, depth=cand.max_depth,

@@ -12,7 +12,8 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["enable_compilation_cache", "backend_block", "device_trace"]
+__all__ = ["enable_compilation_cache", "backend_block", "device_trace",
+           "with_frame_room"]
 
 
 def device_trace(log_dir: str):
@@ -101,3 +102,39 @@ def backend_block() -> dict:
         "compile_cache": {"dir": _cache_dir(),
                           **compile_time.cache_counts()},
     }
+
+
+#: slots of the frame that :func:`with_frame_room` pushes: just over half a
+#: MiB, so that CPython rounds its chunk up to one MiB and leaves the callees
+#: the other half
+_ROOMY_SLOTS = 66_000
+_ROOMY: dict = {}
+
+
+def with_frame_room(fn):
+    """``fn()``, called from a frame so large that the interpreter gives it a
+    chunk of the frame stack of its own, with half a MiB of room below it.
+
+    CPython 3.11 and 3.12 keep a thread's frames in chunks of 16 KiB, take a
+    new chunk where a call does not fit the current one and give it back
+    when that call returns. A loop of small calls that sits right at a
+    chunk's end maps and unmaps memory on every call: 180 times slower here
+    (a function of one return, called 100,000 times at every depth: once in
+    270 depths), and dearer still under a sandboxed kernel, where those are
+    two system calls. Tracing a fold-grid program is a quarter of a million
+    such calls at depths of 150 to 300 frames, so whether a program's trace
+    takes 3 s or 14 s on the chip machine is decided by how deep the stack
+    happens to be where it starts: what made the same boosted program trace
+    in 10 s on the main thread and in 3 s on a family thread (PERF.md
+    section 6, PR 36), and the other way round one ``lax.map`` deeper
+    (PR 37). Below this frame no call meets a chunk's end for some 1,500
+    frames. The frame costs its 66,000 empty slots, 0.3 ms a call, and
+    0.2 s once a process to make."""
+    roomy = _ROOMY.get("fn")
+    if roomy is None:
+        names = ", ".join(f"v{i}" for i in range(_ROOMY_SLOTS))
+        scope: dict = {}
+        exec(compile(f"def roomy(fn):\n    if fn is None:\n        {names} = fn"
+                     "\n    return fn()\n", "<with_frame_room>", "exec"), scope)
+        roomy = _ROOMY["fn"] = scope["roomy"]
+    return roomy(fn)
