@@ -449,6 +449,11 @@ class TestTrainSpans:
             # the CPU's scatter mode keeps segment_sum)
             assert s["attrs"]["sums_scatter"] >= 1
             assert s["attrs"]["sums_dense"] >= 0
+        # the winner's scoring says which form its walk took
+        # (trees.tree_traverse_forms; a CPU gathers), its own included
+        (train_eval,) = named("search.train_eval")
+        assert train_eval["attrs"]["traverse_gather"] >= 1
+        assert train_eval["attrs"]["traverse_dense"] >= 0
         assert all(s["dur"] is not None for s in spans)
 
     def test_scoring_spans_nest_under_guarded(self, trained):

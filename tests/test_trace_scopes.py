@@ -22,7 +22,7 @@ import jax.numpy as jnp                               # noqa: E402
 from benchmark.layer_metrics import (                 # noqa: E402
     fit_node_sums_s, fit_route_s, search_compress_s,
     search_design_s_per_train, search_node_sums_s, search_route_s,
-    winner_tail_s_per_train)
+    tail_traverse_s, winner_tail_s_per_train)
 from benchmark.trace import scopes                    # noqa: E402
 from transmogrifai_tpu.models import GBTClassifier, trees   # noqa: E402
 
@@ -479,11 +479,40 @@ def test_scope_reader(reader, program, scope, table_of, capsys):
     assert "no package scope in this trace" in capsys.readouterr().out
 
 
+@pytest.mark.parametrize("trains", [1, 2])
+def test_tail_traverse_reader_divides_by_traced_trains(trains, table_of):
+    """ISSUE 40: ``tree.traverse`` in ``jit__predict_leaves``, per TRACED
+    TRAIN and not per run over devices: the program runs twice a train, on
+    one chip of four under a mesh."""
+    assert "tree.traverse" in trees.SCOPES
+    plain = _hand_made()
+    for device in plain["devices"]:
+        device["modules"] = [[m[0].replace("jit_batched", "jit__predict_leaves"),
+                              *m[1:]] for m in device["modules"]]
+        device["ops"][2][3] = device["ops"][2][3].replace(
+            "tree.route/gather", "tree.traverse/gather")
+    table_of(plain)
+    reps = [{"ok": True, "traced": True}] * trains + [
+        {"ok": True, "traced": False}, {"ok": False, "traced": True}]
+    obs = {"trace": {"devices": [{}, {}, {}, {}]}, "reps": reps}
+    assert tail_traverse_s.read(obs) == pytest.approx(6000e-9 / trains)
+    assert tail_traverse_s.read(dict(obs, reps=[])) is None
+    assert tail_traverse_s.read(dict(obs, trace=None)) is None
+    # the parent commit: the program walks without the scope
+    for device in plain["devices"]:
+        device["ops"][2][3] = device["ops"][2][3].replace(
+            "tree.traverse/", "")
+    table_of(plain)
+    assert tail_traverse_s.read(obs) is None
+
+
 def test_scope_readers_without_a_trace(table_of, monkeypatch):
     monkeypatch.setattr(scopes, "newest_trace", lambda: None)
     scopes.table.cache_clear()
     assert all(reader.read({"trace": {"devices": [{}]}}) is None
                for reader, _, _ in SCOPE_READERS)
+    assert tail_traverse_s.read({"trace": {"devices": [{}]}, "reps": [
+        {"ok": True, "traced": True}]}) is None
 
 
 def _span(sid, name, parent, dur):

@@ -88,12 +88,14 @@ __all__ = [
 #: the benchmark's scope readers take it from this attribute. A scope is a
 #: path component of the ``op_name`` of the ops traced under it and exists
 #: only while JAX traces: it adds no operation and changes no program's name.
+#: ``tree.traverse`` is the walk of finished heaps (``_traverse``, either
+#: form): the scoring programs' (``jit__predict_leaves``), not a fit's.
 SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
           "tree.split", "tree.route", "tree.bootstrap", "tree.pool",
           "gbt.round", "fg.metric", "fg.gbt", "fg.forest",
           "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve",
           "fg.softmax", "fg.bayes", "fg.glm", "glm.gram", "glm.solve",
-          "fg.order")
+          "fg.order", "tree.traverse")
 
 # ---------------------------------------------------------------------------
 # binning — packed variable-width bins
@@ -682,29 +684,44 @@ def tree_hist_rows() -> dict:
 
 
 @contextlib.contextmanager
+def _counted_span(name: str, counters, **group):
+    """The span ``name`` carrying the process's counts so far of each
+    ``(prefix, counter)`` as the scalar attributes ``prefix + key``: read
+    when the span opens, which its profiler annotation keeps, and again
+    when it closes, because a program's first call traces inside the
+    span; and ``group``, attributes of the call's own."""
+    def attrs():
+        return {prefix + k: v for prefix, counter in counters
+                for k, v in counter().items()}
+    with _trace.span(name, **attrs(), **group) as rec:
+        yield
+        if rec is not None:
+            rec["attrs"].update(attrs())
+
+
 def _fetch_span(**group):
     """The ``search.fetch`` span of a fold-grid driver, carrying
     :func:`tree_route_forms`, :func:`tree_sum_forms`,
     :func:`tree_eval_forms`, :func:`tree_compress_levels` and
-    :func:`tree_hist_rows` as the scalar attributes ``route_dense`` /
+    :func:`tree_hist_rows` as the attributes ``route_dense`` /
     ``route_gather``, ``sums_dense`` / ``sums_scatter``, ``eval_in_fit`` /
     ``eval_traverse``, ``compress_carried`` and ``hist_head`` / ``hist_all``
-    (the process's counts so far: read when the span opens, which its
-    profiler annotation keeps, and again when it closes, because a
-    program's first call traces inside the span), and ``group``, the
-    attributes of the call's own group (``depth_blocks`` /
-    ``depth_lane_levels``, see tree_depth_blocks; ``hist_row_share``, the
-    rows a lane's histograms contract over the rows it holds)."""
-    def attrs():
-        return {prefix + k: v for prefix, counts in (
-            ("route_", tree_route_forms()), ("sums_", tree_sum_forms()),
-            ("eval_", tree_eval_forms()),
-            ("compress_", tree_compress_levels()),
-            ("hist_", tree_hist_rows())) for k, v in counts.items()}
-    with _trace.span("search.fetch", **attrs(), **group) as rec:
-        yield
-        if rec is not None:
-            rec["attrs"].update(attrs())
+    (see _counted_span), and ``group``, the attributes of the call's own
+    group (``depth_blocks`` / ``depth_lane_levels``, see
+    tree_depth_blocks; ``hist_row_share``, the rows a lane's histograms
+    contract over the rows it holds)."""
+    return _counted_span("search.fetch", (
+        ("route_", tree_route_forms), ("sums_", tree_sum_forms),
+        ("eval_", tree_eval_forms), ("compress_", tree_compress_levels),
+        ("hist_", tree_hist_rows)), **group)
+
+
+def train_eval_span():
+    """The selector's ``search.train_eval`` span (the refitted winner's
+    scores on its training rows), carrying :func:`tree_traverse_forms` as
+    ``traverse_dense`` / ``traverse_gather`` (see _counted_span)."""
+    return _counted_span("search.train_eval",
+                         (("traverse_", tree_traverse_forms),))
 
 
 def _route_left_dense(packed: jnp.ndarray, slot: jnp.ndarray,
@@ -1222,19 +1239,88 @@ def _grow_blocks(grower: _TreeGrower, depths: tuple, lanes: tuple,
         for b, (depth, state) in enumerate(zip(depths, states)))
 
 
+#: widest design (columns) and deepest tree the dense traversal still
+#: takes (see _traverse_form). One walk on a v5e, 20 trees x 49,152 rows,
+#: median of five (builder's chip runs, PR 40, PERF.md section 6), gather
+#: -> dense: at 200 columns 60.8 -> 1.26 ms at depth 3, 151 -> 1.93 ms at
+#: depth 6, 335 -> 8.2 ms at depth 12, 652 -> 24.7 ms at 14, 699 -> 89.8 ms
+#: at 16 (the gather adds a level's cost, the dense form doubles a level's
+#: nodes: they would meet near depth 18); at depth 6, 208 -> 6.2 / 47.0 ms
+#: at 1,000 / 4,000 columns and 175 -> 146 ms at 16,000; at depth 3, 74.5
+#: -> 82.9 ms at 16,000, where the gather wins; at depth 12, 376 -> 144 ms
+#: at 8,192. At 196,608 rows and depth 6, 949 -> 4.95 ms
+_TRAVERSE_DENSE_MAX_D = 8192
+_TRAVERSE_DENSE_MAX_DEPTH = 16
+
+#: how many traced ``_traverse`` walks took each form
+_TRAVERSE_FORMS = {"dense": 0, "gather": 0}
+
+
+def _traverse_form(d: int, depth: int) -> str:
+    """How ``_traverse`` reads each row's split column and threshold:
+    "gather" (``feat_heap[heap]``, ``thr_heap[heap]``, ``X[rows, f]``:
+    three per-row gathers a level, O(n); cheap on a CPU, the tests'
+    reference; 27-31 ns a row on the chip) or "dense" (selects over a
+    level's nodes and over the columns, O(n * (2^level + d)) elementwise,
+    no gather). Chosen at trace time from the backend and the walk's
+    shape: dense on an accelerator up to ``_TRAVERSE_DENSE_MAX_D``
+    columns and ``_TRAVERSE_DENSE_MAX_DEPTH`` levels. Both forms give the
+    same integers."""
+    if (jax.default_backend() == "cpu" or d > _TRAVERSE_DENSE_MAX_D
+            or depth > _TRAVERSE_DENSE_MAX_DEPTH):
+        return "gather"
+    return "dense"
+
+
+def tree_traverse_forms() -> dict:
+    """Traced ``_traverse`` walks so far in this process by form,
+    ``{"dense": k, "gather": m}`` (see _traverse_form): the record of which
+    path the compiled scoring programs hold."""
+    return dict(_TRAVERSE_FORMS)
+
+
 def _traverse(X: jnp.ndarray, feat_heap: jnp.ndarray, thr_heap: jnp.ndarray,
               depth: int) -> jnp.ndarray:
-    """Leaf index in [0, 2^depth) for every row; static-depth descent."""
-    n = X.shape[0]
+    """Leaf index in [0, 2^depth) for every row; static-depth descent in
+    the form of _traverse_form. A row goes left where ``x <= t`` in the
+    dtype both promote to (a NaN walks right). The dense form moves ``x``
+    and ``t`` as integer bits, so the compare sees the gather's operands:
+    level ``l``'s nodes are the static heap slice ``[2^l - 1, 2^(l+1) -
+    1)``; a row's feature id and threshold are selected over that slice
+    (nodes on the major axis, rows along the lanes), its value over the
+    columns of ``X.T``."""
+    n, d = X.shape
+    form = _traverse_form(d, depth)
+    _TRAVERSE_FORMS[form] += 1
     node = jnp.zeros((n,), jnp.int32)
-    rows = jnp.arange(n)
-    for level in range(depth):
-        heap = 2 ** level - 1 + node     # levels concatenate into the heap
-        f = feat_heap[heap]
-        t = thr_heap[heap]
-        go_left = X[rows, f] <= t
-        node = 2 * node + (1 - go_left.astype(jnp.int32))
-    return node
+    with jax.named_scope("tree.traverse"):
+        if form == "gather":
+            rows = jnp.arange(n)
+            for level in range(depth):
+                heap = 2 ** level - 1 + node  # levels concatenate
+                f = feat_heap[heap]
+                t = thr_heap[heap]
+                go_left = X[rows, f] <= t
+                node = 2 * node + (1 - go_left.astype(jnp.int32))
+            return node
+        dtype = jnp.result_type(X.dtype, thr_heap.dtype)      # a float
+        bits = jnp.int64 if jnp.dtype(dtype).itemsize == 8 else jnp.int32
+        x_bits = jax.lax.bitcast_convert_type(X.astype(dtype), bits).T
+        t_bits = jax.lax.bitcast_convert_type(thr_heap.astype(dtype), bits)
+        column = jnp.arange(d, dtype=jnp.int32)[:, None]
+        for level in range(depth):
+            first, m = 2 ** level - 1, 2 ** level
+            mine = node[None, :] == jnp.arange(m, dtype=jnp.int32)[:, None]
+            f = jnp.sum(jnp.where(mine, feat_heap[first:first + m, None], 0),
+                        axis=0, dtype=feat_heap.dtype)
+            t = jnp.sum(jnp.where(mine, t_bits[first:first + m, None], 0),
+                        axis=0, dtype=bits)
+            x = jnp.sum(jnp.where(f[None, :] == column, x_bits, 0), axis=0,
+                        dtype=bits)
+            go_left = (jax.lax.bitcast_convert_type(x, dtype)
+                       <= jax.lax.bitcast_convert_type(t, dtype))
+            node = 2 * node + (1 - go_left.astype(jnp.int32))
+        return node
 
 
 # ---------------------------------------------------------------------------
@@ -1923,7 +2009,11 @@ def _fit_gbt_softmax(packed, feat_of, block_start, packed_thr, y, key, *,
 
 @functools.partial(jax.jit, static_argnames=("depth",))
 def _predict_leaves(X, feats, thrs, depth: int):
-    """(T, n) leaf index per tree via vmapped static-depth traversal."""
+    """(T, n) leaf index per tree via vmapped static-depth traversal (see
+    _traverse_form). The dense form's selects fuse into their reductions
+    under the ``vmap``: compiled for a v5e at 20 trees of depth 12 and 200
+    columns, 0 MB of temporaries at 49,152 rows and 157 MB, the (T, n)
+    carries, at 196,608 (PR 40)."""
     return jax.vmap(lambda f, t: _traverse(X, f, t, depth))(feats, thrs)
 
 
@@ -2123,7 +2213,7 @@ def _candidate_scores(kind, spec_kind, depth, feats, thrs, leaves, base,
 
     The leaf has two sources (see _eval_form). "traverse", ``leaf`` None:
     the raw validation matrix ``Xv`` (nv, d) is READ here and walked down
-    every finished heap (``_traverse``: three per-row gathers a level);
+    every finished heap (``_traverse``, in its _traverse_form);
     the form for validation rows that are not rows of the fitted table
     (``validate_prepared``, direct callers). "in_fit", ``leaf`` (T, nv)
     given (_forest_body ``val_rows``): ``Xv`` is not read; the rows were
