@@ -225,6 +225,29 @@ def test_every_scope_is_used_in_trees():
         == set(trees.SCOPES)
 
 
+@pytest.fixture(scope="module")
+def sanity_hlo():
+    """The sanity checker's two statistics programs, compiled at a tiny
+    size, their HLO texts joined."""
+    from transmogrifai_tpu.checkers import sanity_checker
+    X = jnp.asarray(np.eye(3)[np.arange(12) % 3])
+    y = jnp.asarray(np.arange(12) % 2, X.dtype)
+    onehot = jnp.stack([y, 1.0 - y], axis=1)
+    return (sanity_checker._column_statistics.lower(X, y).compile().as_text()
+            + sanity_checker._indicator_tables.lower(
+                X, onehot, jnp.ones(3, bool)).compile().as_text())
+
+
+@pytest.mark.parametrize("scope", ("sanity.stats", "sanity.contingency"))
+def test_sanity_programs_carry_scope(sanity_hlo, scope):
+    """``checkers.sanity_checker.SCOPES``, the list ``sanity_stats_roofline``
+    reads, as ``trees.SCOPES`` is the tree readers'."""
+    from transmogrifai_tpu.checkers import sanity_checker
+    assert scope in sanity_checker.SCOPES
+    assert scope in _components(sanity_hlo)
+    assert scope not in trees.SCOPES
+
+
 def test_scopes_move_no_number():
     """The arrays of a tiny boosted fit, as the parent commit (1ce6bab,
     before any scope) computed them under the suite's float64."""

@@ -9,7 +9,10 @@ correlations and categorical association stats, prunes problematic
 columns, and emits the full summary. The heavy math runs as XLA kernels
 (utils/stats.py): one fused pass for moments + label correlation, and
 per-group contingency tables for Cramér's V / chi² / mutual info /
-association-rule confidence.
+association-rule confidence. A device matrix (the compiled prepare plan's)
+stays on the device: its tables are one contraction of the indicator
+columns with the one-hot label there, and only the (columns x labels)
+counts come back (docs/prepare.md).
 
 Pruning rules (same thresholds as the reference defaults):
 - variance < ``min_variance``                       -> drop column
@@ -28,21 +31,56 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..features.columns import FeatureColumn
+from ..runtime.telemetry import host_pull
 from ..stages.base import AllowLabelAsInput, BinaryEstimator, BinaryModel
 from ..types import OPVector, RealNN
-from ..utils.stats import col_stats, contingency_stats, correlation_with_label
+from ..utils.stats import column_moments, contingency_stats, label_correlation
 from ..utils.vector_meta import VectorMetadata
 
 __all__ = ["SanityChecker", "SanityCheckerModel", "SanityCheckerSummary",
-           "ColumnStatistics"]
+           "ColumnStatistics", "SCOPES"]
 
 #: labels with more distinct values than this are treated as continuous and
 #: categorical association stats are skipped (reference categoricalLabel
 #: heuristic in SanityChecker.fitFn)
 MAX_LABEL_CARDINALITY = 100
+
+#: ``jax.named_scope`` names of the statistics programs, as
+#: ``models/trees.SCOPES`` names the tree kernels' (docs/observability.md):
+#: the moments and label correlation of every column, and the contraction
+#: that counts the indicator columns' contingency tables on the device
+SCOPES = ("sanity.stats", "sanity.contingency")
+
+#: a float32 count is exact up to 2**24: past it the tables are counted on
+#: the host
+_EXACT_ROWS = 2 ** 24
+
+
+@jax.jit
+def _column_statistics(X, y):
+    """(mean, variance, min, max, label correlation) of every column, one
+    program for either placement of ``X``."""
+    with jax.named_scope(SCOPES[0]):
+        w = jnp.ones((X.shape[0],), X.dtype)
+        moments = column_moments(X, w)
+        return moments + (label_correlation(X, y.astype(X.dtype), w),)
+
+
+@jax.jit
+def _indicator_tables(X, onehot_label, indicator):
+    """Every column's (label) counts, ``X.T @ onehot_label``, and whether
+    each ``indicator`` column holds only 0 and 1: for those columns the
+    counts are integers, exact in float32 in any summation order."""
+    with jax.named_scope(SCOPES[1]):
+        tables = jnp.matmul(X.T, onehot_label.astype(X.dtype),
+                            precision=jax.lax.Precision.HIGHEST)
+        binary = jnp.all((X == 0) | (X == 1) | ~indicator[None, :])
+        return tables, binary
 
 
 @dataclass
@@ -135,20 +173,21 @@ class SanityChecker(AllowLabelAsInput, BinaryEstimator):
 
     # -- fitting -----------------------------------------------------------
     def fit_columns(self, cols: List[FeatureColumn]) -> "SanityCheckerModel":
-        y = np.asarray(cols[0].data, dtype=np.float64)
-        X = np.asarray(cols[1].data, dtype=np.float64)
+        y = host_pull(cols[0].data, np.float64)
+        X = host_pull(cols[1].data, np.float64)
         meta = cols[1].metadata or VectorMetadata(name="features")
         return self._fit_stats(y, X, meta)
 
     def fit_device(self, arrays, protos) -> "SanityCheckerModel":
         """Compiled-prepare fit (plans/prepare.py): the feature matrix
         arrives as the device array the fused vectorize→combine program
-        produced and feeds the stats kernels (utils/stats.py — already
-        XLA) WITHOUT the host materialization ``fit_columns`` pays.
-        Identical fitted state: the moment/correlation kernels are the
-        same jnp programs either way, and the contingency tables are
-        integer counts (one-hot indicator sums) — exact in any order."""
-        y = np.asarray(arrays[0], dtype=np.float64)  # labels are tiny;
+        produced and stays there: the moments and label correlations are
+        the programs ``fit_columns`` runs, and the contingency tables one
+        contraction on the device, of which only the (columns x labels)
+        counts come back — WITHOUT the host materialization
+        ``fit_columns`` pays. Identical fitted state: the tables are
+        integer counts (one-hot indicator sums), exact in any order."""
+        y = host_pull(arrays[0], np.float64)  # labels are tiny;
         X = arrays[1]                # the group logic walks them host-side
         meta = (protos[1].metadata if protos and protos[1] is not None
                 else None) or VectorMetadata(name="features")
@@ -157,7 +196,7 @@ class SanityChecker(AllowLabelAsInput, BinaryEstimator):
     def _fit_stats(self, y: np.ndarray, X, meta: VectorMetadata
                    ) -> "SanityCheckerModel":
         """Shared fit body; ``X`` may be host numpy OR a device (jax)
-        array — the statistics run through the same XLA kernels and
+        array — the statistics run through the same XLA programs and
         produce the same model either way."""
         n, d = X.shape
 
@@ -172,8 +211,8 @@ class SanityChecker(AllowLabelAsInput, BinaryEstimator):
             Xs, ys = X, y
             sample_size = int(n)
 
-        stats = col_stats(Xs)
-        corr = correlation_with_label(Xs, ys)
+        mean, variance, mins, maxs, corr = (
+            host_pull(v) for v in _column_statistics(jnp.asarray(Xs), ys))
 
         names = meta.column_names() if meta.size == d else \
             [f"f{i}" for i in range(d)]
@@ -181,8 +220,8 @@ class SanityChecker(AllowLabelAsInput, BinaryEstimator):
         for j in range(d):
             rec = ColumnStatistics(
                 name=names[j], column_index=j,
-                variance=float(stats.variance[j]), mean=float(stats.mean[j]),
-                min=float(stats.min[j]), max=float(stats.max[j]),
+                variance=float(variance[j]), mean=float(mean[j]),
+                min=float(mins[j]), max=float(maxs[j]),
                 corr_label=float(corr[j]))
             if meta.size == d:
                 mc = meta.columns[j]
@@ -215,22 +254,12 @@ class SanityChecker(AllowLabelAsInput, BinaryEstimator):
         if meta.size == d and 2 <= len(labels) <= MAX_LABEL_CARDINALITY:
             onehot_label = ys[:, None] == labels[None, :]
             groups = meta.indicator_groups()
-            # gather every indicator column ONCE (a device X pays one
-            # small transfer of the 0/1 indicator block instead of one
-            # per column; the sums below are integer counts, so the
-            # result is bit-identical to the per-column walk)
+            # every group's table from ONE contraction of the indicator
+            # columns with the one-hot label, counted where X lives
             all_idx = sorted({j for idxs in groups.values()
                               for j in idxs})
             local = {j: k for k, j in enumerate(all_idx)}
-            Xind = (np.asarray(Xs[:, np.asarray(all_idx)],
-                               dtype=np.float64)
-                    if all_idx else np.zeros((sample_size, 0)))
-            # ALL groups' tables in one matmul: indicator columns are
-            # exactly 0/1, so every entry is an integer count — exact
-            # in any summation order (bitwise equal to the former
-            # per-level broadcast-sum, at a fraction of the cost: this
-            # loop was the dominant fit cost on wide categorical data)
-            tables_all = Xind.T @ onehot_label.astype(np.float64)
+            tables_all = self._contingency_counts(Xs, onehot_label, all_idx)
             for group_key, indices in groups.items():
                 # contingency: level rows x label cols
                 table = tables_all[[local[j] for j in indices], :]
@@ -275,6 +304,29 @@ class SanityChecker(AllowLabelAsInput, BinaryEstimator):
             output_metadata=(meta.select(kept) if meta.size == d else None))
         model.summary = summary
         return model
+
+    @staticmethod
+    def _contingency_counts(Xs, onehot_label: np.ndarray,
+                          all_idx: List[int]) -> np.ndarray:
+        """(indicator columns x labels) counts, float64. A device ``Xs``
+        is counted where it lives and only the counts come back; a host
+        one (or a device one with more rows than float32 counts exactly,
+        or an indicator column that is not 0/1) as float64 on the host:
+        the same integers either way."""
+        onehot = onehot_label.astype(np.float64)
+        if not all_idx:
+            return np.zeros((0, onehot.shape[1]))
+        if not isinstance(Xs, np.ndarray) and len(Xs) < _EXACT_ROWS:
+            indicator = np.zeros(Xs.shape[1], bool)
+            indicator[all_idx] = True
+            tables, binary = _indicator_tables(Xs, onehot, indicator)
+            if bool(host_pull(binary)):
+                return host_pull(tables, np.float64)[all_idx]
+        # ALL groups' tables in one matmul: indicator columns are
+        # exactly 0/1, so every entry is an integer count — exact in any
+        # summation order
+        Xind = host_pull(Xs[:, np.asarray(all_idx)], np.float64)
+        return Xind.T @ onehot
 
 
 class SanityCheckerModel(AllowLabelAsInput, BinaryModel):

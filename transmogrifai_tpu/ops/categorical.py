@@ -12,9 +12,15 @@ subclasses). Semantics preserved:
 - vector metadata records each category as an ``indicator_value`` grouped
   by the parent feature, which is what SanityChecker's Cramér's V and
   group-aware pruning key off.
+
+A PickList column's strings are walked in C, not in a Python loop a row:
+the fit counts them in one pass (``collections.Counter``), the encoder maps
+them in one pass (``map`` of the index's ``get`` into ``np.fromiter``).
 """
 from __future__ import annotations
 
+import itertools
+from collections import Counter
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -106,13 +112,10 @@ class OneHotVectorizerModel(SequenceModel):
         cats = self.categories[i]
         index = {c: j for j, c in enumerate(cats)}
         other = len(cats)
-        null = other + 1 if self.track_nulls else -1
-        get = index.get
-        # one C-allocated pass (np.fromiter) — this encoder is the
-        # train-prepare hot loop for wide categorical data
-        return np.fromiter(
-            (null if v is None else get(v, other) for v in col.data),
-            dtype=np.int32, count=col.n_rows)
+        index[None] = other + 1 if self.track_nulls else -1
+        # one C-level pass (``map`` into np.fromiter): the serving encoder
+        return np.fromiter(map(index.get, col.data, itertools.repeat(other)),
+                           dtype=np.int32, count=col.n_rows)
 
     def transform_arrays(self, arrays):
         import jax
@@ -141,10 +144,8 @@ class OneHotVectorizer(SequenceEstimator):
     def fit_columns(self, cols: List[FeatureColumn]) -> OneHotVectorizerModel:
         categories = []
         for col in cols:
-            counts: dict = {}
-            for v in col.data:
-                if v is not None:
-                    counts[v] = counts.get(v, 0) + 1
+            counts = Counter(col.data)      # one C-level pass
+            counts.pop(None, None)
             categories.append(
                 _top_categories(counts, self.top_k, self.min_support))
         return OneHotVectorizerModel(categories=categories,

@@ -163,6 +163,17 @@ def mesh_model_shards(mesh: Optional[Mesh]) -> int:
     return int(mesh.shape.get("models", 1))
 
 
+def _device_float(a):
+    """``a`` as the device array the kernels take, in the canonical float
+    dtype (float32 unless x64 is on): a device array is converted where it
+    lives, never round-tripped through the host; a host one goes through
+    float64 first, as it always has. The same values, dtype and shape
+    either way, so the same compiled program."""
+    if isinstance(a, jax.Array):
+        return a.astype(jax.dtypes.canonicalize_dtype(np.float64))
+    return jnp.asarray(np.asarray(a, dtype=np.float64))
+
+
 def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
                          masks: np.ndarray, grid: np.ndarray, *,
                          mesh: Optional[Mesh] = None,
@@ -184,7 +195,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
     intercept, in the ORIGINAL feature space; (F, G, k, d+1) for "softmax".
     """
     with _trace.span("search.head"):
-        X = np.asarray(X, dtype=np.float64)
+        X = _device_float(X)
         y = np.asarray(y, dtype=np.float64)
         masks = np.asarray(masks, dtype=np.float64)
         grid = np.asarray(grid, dtype=np.float64).reshape(-1, 2)
@@ -217,7 +228,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         alphas = np.concatenate([alphas, np.zeros(pad_c)])
     pad_r = (-n) % d_shards                        # pad row axis
     if pad_r:
-        X = np.concatenate([X, np.zeros((pad_r, d))], axis=0)
+        X = jnp.concatenate([X, jnp.zeros((pad_r, d), X.dtype)], axis=0)
         y = np.concatenate([y, np.zeros(pad_r)])
         wmat = np.concatenate(
             [wmat, np.zeros((wmat.shape[0], pad_r))], axis=1)
@@ -255,7 +266,7 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
             "multiclass" the softmax of the lane's logits.
     """
     with _trace.span("search.head"):
-        X = np.asarray(X, dtype=np.float64)
+        X = _device_float(X)
         y = np.asarray(y, dtype=np.float64)
         masks = np.asarray(masks, dtype=np.float64)
         grid = np.asarray(grid, dtype=np.float64).reshape(-1, 2)
@@ -269,7 +280,7 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         alphas = np.tile(grid[:, 1], F)
         wmat = np.repeat(masks, G, axis=0)            # (F*G, n)
         fidx = np.repeat(np.arange(F, dtype=np.int32), G)
-        Xv = jnp.asarray(np.asarray(X_val, dtype=np.float64))
+        Xv = _device_float(X_val)
         yv = jnp.asarray(np.asarray(y_val, dtype=np.float64))
 
     if mesh is None:
@@ -291,7 +302,7 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         fidx = np.concatenate([fidx, np.zeros(pad_c, dtype=np.int32)])
     pad_r = (-n) % d_shards
     if pad_r:
-        X = np.concatenate([X, np.zeros((pad_r, d))], axis=0)
+        X = jnp.concatenate([X, jnp.zeros((pad_r, d), X.dtype)], axis=0)
         y = np.concatenate([y, np.zeros(pad_r)])
         wmat = np.concatenate(
             [wmat, np.zeros((wmat.shape[0], pad_r))], axis=1)

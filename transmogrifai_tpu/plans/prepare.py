@@ -59,6 +59,7 @@ import numpy as np
 from ..features.columns import Dataset, FeatureColumn
 from ..features.feature import Feature, topo_layers
 from ..features.generator import FeatureGeneratorStage
+from ..observability import trace as _trace
 from ..runtime import telemetry as _telemetry
 from ..runtime.faults import maybe_inject
 from ..runtime.retry import RetryPolicy
@@ -256,6 +257,7 @@ class PreparePlan:
         global _LAST_PLAN
         _LAST_PLAN = self
         compile_time.install()
+        _telemetry.count(_telemetry.PREPARE_PULL_BYTES, 0)
         import jax  # noqa: F401  (device path; deferred like the plans)
         stages = [s for layer in topo_layers(list(self.result_features))
                   for s in layer
@@ -413,8 +415,10 @@ class PreparePlan:
         if all(s == "host" for s in srcs):
             # vocab builders fit on raw/host-materialized columns — the
             # data is host-resident either way, nothing to place
-            return self._host_fit(stage, ds, n_rows,
-                                  reason="inputs host-resident")
+            with _trace.span("prepare.encode", phase="fit",
+                             columns=len(in_names), rows=n_rows):
+                return self._host_fit(stage, ds, n_rows,
+                                      reason="inputs host-resident")
         ds = self._flush(ds)    # fit needs VALUES of device outputs
         where, why = self.placement.decide_fit(stage, n_rows)
         if where == "device":
@@ -487,7 +491,7 @@ class PreparePlan:
         # device inputs: device-env arrays pass through by name; host
         # columns encode once per distinct (encoder, column) key
         in_keys: List[str] = []
-        sources: List[Tuple[str, Any]] = []   # (key, array)
+        inputs_of: List[Tuple[PlanStep, int, str]] = []
         seen = set()
         produced = {s.out_name for s in steps}
         for step in steps:
@@ -496,12 +500,17 @@ class PreparePlan:
                 if key in seen or key in produced:
                     continue
                 seen.add(key)
-                if self._producer.get(name) == "device":
-                    arr = self._device_env[name]
-                else:
-                    arr = stage_encode(step.stage, i, ds[name])
                 in_keys.append(key)
-                sources.append((key, arr))
+                inputs_of.append((step, i, name))
+        host = sum(self._producer.get(name) != "device"
+                   for _, _, name in inputs_of)
+        with _trace.span("prepare.encode", phase="encode", columns=host,
+                         rows=n):
+            sources: List[Tuple[str, Any]] = [   # (key, array)
+                (key, self._device_env[name]
+                 if self._producer.get(name) == "device"
+                 else stage_encode(step.stage, i, ds[name]))
+                for key, (step, i, name) in zip(in_keys, inputs_of)]
 
         # canonical POSITIONAL form: inputs 0..K-1 in discovery order,
         # then one slot per step output. Stage uids / feature names

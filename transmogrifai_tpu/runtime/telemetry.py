@@ -23,7 +23,7 @@ _log = logging.getLogger(__name__)
 
 __all__ = ["count", "counters", "reset", "note_dispatch", "dispatch_log",
            "event", "events_mark", "events_since", "events_dropped",
-           "OVERFLOW_EVENT"]
+           "host_pull", "OVERFLOW_EVENT", "PREPARE_PULL_BYTES"]
 
 _LOCK = threading.Lock()
 _COUNTERS: Dict[str, int] = {}
@@ -61,6 +61,21 @@ def _events_cap() -> int:
 def count(name: str, n: int = 1) -> None:
     with _LOCK:
         _COUNTERS[name] = _COUNTERS.get(name, 0) + n
+
+
+#: bytes the prepare plan and the checkers read back from the device in a
+#: train (:func:`host_pull`); ``PreparePlan.execute`` makes it present at 0
+PREPARE_PULL_BYTES = "prepare_host_pull_bytes"
+
+
+def host_pull(x, dtype=None):
+    """``np.asarray(x, dtype)``, counting the bytes of ``x`` under
+    :data:`PREPARE_PULL_BYTES` when it is a device array (a host array
+    moves nothing and is not counted)."""
+    import numpy as np
+    if not isinstance(x, np.ndarray) and hasattr(x, "addressable_shards"):
+        count(PREPARE_PULL_BYTES, int(x.nbytes))
+    return np.asarray(x, dtype=dtype)
 
 
 def counters() -> Dict[str, int]:

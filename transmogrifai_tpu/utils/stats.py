@@ -15,7 +15,8 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["ColStats", "col_stats", "correlation_with_label",
+__all__ = ["ColStats", "col_stats", "column_moments",
+           "correlation_with_label", "label_correlation",
            "correlation_matrix", "ContingencyStats", "contingency_stats",
            "chi_square", "cramers_v"]
 
@@ -35,23 +36,27 @@ class ColStats:
 def col_stats(X, w: Optional[np.ndarray] = None) -> ColStats:
     """Weighted column statistics in one device pass."""
     X = jnp.asarray(X)
-    n = X.shape[0]
-    if w is None:
-        w = jnp.ones((n,), X.dtype)
-    else:
-        w = jnp.asarray(w, X.dtype)
+    w = jnp.ones((X.shape[0],), X.dtype) if w is None \
+        else jnp.asarray(w, X.dtype)
+    mean, var, mn, mx = column_moments(X, w)
+    live = w > 0
+    nnz = jnp.sum((X != 0) & live[:, None], axis=0)
+    return ColStats(count=int(jnp.sum(live)), mean=np.asarray(mean),
+                    variance=np.asarray(var), min=np.asarray(mn),
+                    max=np.asarray(mx), num_nonzeros=np.asarray(nnz))
+
+
+def column_moments(X, w):
+    """(mean, sample variance, min, max) of every column under row weights
+    ``w``, as traceable jnp arrays: the body of :func:`col_stats`, for
+    callers that fuse it into a program of their own."""
     wsum = jnp.sum(w)
     mean = (w @ X) / wsum
     var = (w @ (X - mean) ** 2) / jnp.maximum(wsum - 1.0, 1.0)
     live = w > 0
     big = jnp.where(live[:, None], X, jnp.inf)
     small = jnp.where(live[:, None], X, -jnp.inf)
-    mn = jnp.min(big, axis=0)
-    mx = jnp.max(small, axis=0)
-    nnz = jnp.sum((X != 0) & live[:, None], axis=0)
-    return ColStats(count=int(jnp.sum(live)), mean=np.asarray(mean),
-                    variance=np.asarray(var), min=np.asarray(mn),
-                    max=np.asarray(mx), num_nonzeros=np.asarray(nnz))
+    return mean, var, jnp.min(big, axis=0), jnp.max(small, axis=0)
 
 
 def correlation_matrix(X, w: Optional[np.ndarray] = None) -> np.ndarray:
@@ -88,8 +93,16 @@ def correlation_with_label(X, y, w: Optional[np.ndarray] = None
     # this lands on f32 without requesting — and warning about — f64
     X = jnp.asarray(X)
     y = jnp.asarray(y, X.dtype).reshape(-1)
-    n = X.shape[0]
-    w = jnp.ones((n,), X.dtype) if w is None else jnp.asarray(w, X.dtype)
+    w = jnp.ones((X.shape[0],), X.dtype) if w is None \
+        else jnp.asarray(w, X.dtype)
+    return np.asarray(label_correlation(X, y, w))
+
+
+def label_correlation(X, y, w):
+    """Pearson correlation of every column of ``X`` with ``y`` under row
+    weights ``w``, as a traceable jnp array: the body of
+    :func:`correlation_with_label`, for callers that fuse it into a
+    program of their own."""
     wsum = jnp.sum(w)
     sw = jnp.sqrt(w)
     Xc = (X - (w @ X) / wsum) * sw[:, None]
@@ -97,8 +110,7 @@ def correlation_with_label(X, y, w: Optional[np.ndarray] = None
     cov = (yc @ Xc) / wsum
     sd = jnp.sqrt((jnp.sum(Xc * Xc, axis=0) / wsum)
                   * (jnp.sum(yc * yc) / wsum))
-    corr = jnp.where(sd > 0, cov / jnp.where(sd > 0, sd, 1.0), jnp.nan)
-    return np.asarray(corr)
+    return jnp.where(sd > 0, cov / jnp.where(sd > 0, sd, 1.0), jnp.nan)
 
 
 @dataclass
