@@ -19,7 +19,7 @@ so a hyperparameter grid can ``vmap`` over (reg_param, elastic_net).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,7 @@ from .solvers import design_lipschitz, fista_minimize, lbfgs_minimize
 
 __all__ = ["LogisticRegression", "LogisticRegressionModel",
            "LinearRegression", "LinearRegressionModel",
-           "LinearSVC", "LinearSVCModel"]
+           "LinearSVC", "LinearSVCModel", "lane_designs"]
 
 # ---------------------------------------------------------------------------
 # shared weighted-fit cores
@@ -48,6 +48,23 @@ __all__ = ["LogisticRegression", "LogisticRegressionModel",
 
 def _psum(x, axis_name: Optional[str]):
     return jax.lax.psum(x, axis_name) if axis_name else x
+
+
+#: precision of every product of a linear lane with its design: the margins
+#: or the multinomial's (n, d) x (d, k) logits (the gradient's product
+#: inherits it), the power iteration's, the fold statistics, the lanes'
+#: validation logits and a K-class model's scores. Float32 in full on the
+#: chip too, whose default is one bf16 pass, coarser than a float16 table:
+#: with it the multinomial refit read 2e-3 to 5e-3 from the same steps in
+#: float64 where this reads 2e-5 to 1e-4 (PERF.md). Under vmap a
+#: step's products are (n, d) x (d, L) on the multiplier (see _LaneDesign),
+#: where the default would round every step's coefficients and residuals to
+#: bf16 as well
+_LANE_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b):
+    return jnp.matmul(a, b, precision=_LANE_PRECISION)
 
 
 def _weighted_standardize(X, w, axis_name=None):
@@ -69,22 +86,118 @@ def _weighted_standardize(X, w, axis_name=None):
     return (X - mu) / safe, mu, safe, wsum
 
 
-def _unstandardize_coefs(w: jnp.ndarray, b: jnp.ndarray, mu: jnp.ndarray,
-                         sigma: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Map coefficients fitted on standardized X back to the original
-    feature space: w/sigma, b - (w/sigma).mu  (works for (d,) and (k,d))."""
-    w_orig = w / sigma
-    b_orig = b - w_orig @ mu if w.ndim == 1 else b - w_orig @ mu
-    return w_orig, b_orig
+#: how many traced linear cores built their lane's design in each form (see
+#: _lane_design_form and lane_designs)
+_LANE_DESIGNS = {"shared": 0, "per_lane": 0}
 
 
-def _prep(X, w, standardize: bool, axis_name):
+def _lane_design_form() -> str:
+    """How a linear core builds its lane's design: "shared" (one
+    standardized matrix for every lane of a program, the lane's fold
+    statistics folded into its coefficients; a step's products under vmap
+    are (n, d) x (d, L)) or "per_lane" (the lane's own weighted
+    standardization of the design, one (n, d) copy a lane; batched
+    products). Chosen at trace time from the backend: "per_lane" on a CPU,
+    whose small products take a different emitter by width, so a shared
+    product's lane would not keep its bits across the lane counts of a
+    search mesh's shards (``resolve_search_mesh``); "shared" on an
+    accelerator. The two forms agree to rounding."""
+    return "per_lane" if jax.default_backend() == "cpu" else "shared"
+
+
+def lane_designs() -> dict:
+    """Traced linear cores so far in this process by the form of their
+    lane's design, ``{"shared": k, "per_lane": m}`` (see
+    _lane_design_form): the record of which path the compiled fold-grid
+    programs hold."""
+    return dict(_LANE_DESIGNS)
+
+
+class _LaneDesign(NamedTuple):
+    """A lane's standardized design ``Xs = (Z - c) * scale``, kept as its
+    factors. In the "shared" form ``Z = (X - m) / s`` depends on ``X``
+    alone, so the lanes that ``vmap`` batches share ONE (n, d) array;
+    ``c`` and ``scale`` are the lane's (d,) fold statistics in Z's units,
+    and a product with ``Xs`` is one with ``Z`` and a (d,) correction:
+    under vmap, (n, d) x (d, L). In the "per_lane" form ``Z`` is the lane's
+    own ``Xs``, ``c`` 0 and ``scale`` 1."""
+    Z: jnp.ndarray       # (n, d)
+    c: jnp.ndarray       # (d,) the fold's weighted mean of Z
+    scale: jnp.ndarray   # (d,) 1 / the fold's deviation; 0 = constant
+    m: jnp.ndarray       # (d,) Z's origin, raw units
+    s: jnp.ndarray       # (d,) Z's unit, raw units
+    wsum: jnp.ndarray    # the lane's total row weight
+
+    @property
+    def shape(self):
+        return self.Z.shape
+
+    @property
+    def dtype(self):
+        return self.Z.dtype
+
+    def matvec(self, v):
+        """``Xs @ v``: (n,) for ``v`` (d,), (n, k) for ``v`` (d, k)."""
+        u = (v.T * self.scale).T
+        return _dot(self.Z, u) - _dot(self.c, u)
+
+    def rmatvec(self, r):
+        """``Xs.T @ r`` for ``r`` (n,), shard-local under a mesh."""
+        return self.scale * (_dot(r, self.Z) - self.c * jnp.sum(r))
+
+    def dense(self):
+        """``Xs`` itself, one (n, d) array a lane: for the normal
+        equations only."""
+        return (self.Z - self.c) * self.scale
+
+    def to_original(self, v, b):
+        """Coefficients ``v`` ((d,) or (k, d)) and intercept ``b`` fitted on
+        ``Xs``, mapped to the raw columns: ``v / sigma`` and ``b - (v /
+        sigma) . mu``, where the fold's ``mu = m + c s`` and ``sigma = s /
+        scale``; a constant column's coefficient is 0."""
+        w_orig = v * self.scale / self.s
+        return w_orig, b - w_orig @ (self.m + self.c * self.s)
+
+
+def _lane_design(X, w, standardize: bool, axis_name) -> _LaneDesign:
+    """The lane of weights ``w``'s design over ``X`` (see _LaneDesign), in
+    the form of _lane_design_form.
+
+    "shared": ``m`` and ``s`` are every row's mean and deviation,
+    unweighted (psum'd over a mesh data axis, so every shard holds the same
+    origin; ``s = 1`` where it is 0). The lane's statistics come in one
+    pass in Z's units: ``c = w @ Z / wsum``, ``var = w @ (Z * Z) / wsum - c
+    * c``. Because Z's origin is the all-rows mean, ``c`` is small and the
+    subtraction keeps the digits raw columns far from 0 would lose. A
+    column constant on the lane's training rows (a one-hot category missing
+    from the fold's rows) keeps a ``var`` of rounding noise, a share of
+    order ``eps`` of its second moment ``w @ (Z * Z) / wsum``; every share
+    under ``sqrt(eps)`` counts as constant, and its ``scale`` 0 holds its
+    coefficient at 0. "per_lane": ``_weighted_standardize``. Without
+    ``standardize``, ``Z`` is ``X`` and the statistics 0 and 1."""
     n, d = X.shape
+    form = _lane_design_form()
+    _LANE_DESIGNS[form] += 1
     with jax.named_scope("lin.standardize"):
-        if standardize:
-            return _weighted_standardize(X, w, axis_name)
         wsum = jnp.maximum(_psum(jnp.sum(w), axis_name), 1e-12)
-        return X, jnp.zeros(d, X.dtype), jnp.ones(d, X.dtype), wsum
+        zeros, ones = jnp.zeros(d, X.dtype), jnp.ones(d, X.dtype)
+        if not standardize:
+            return _LaneDesign(X, zeros, ones, zeros, ones, wsum)
+        if form == "per_lane":
+            Xs, mu, sigma, _ = _weighted_standardize(X, w, axis_name)
+            return _LaneDesign(Xs, zeros, ones, mu, sigma, wsum)
+        rows = _psum(jnp.asarray(n, X.dtype), axis_name)
+        m = _psum(jnp.sum(X, axis=0), axis_name) / rows
+        s = jnp.sqrt(_psum(jnp.sum((X - m) ** 2, axis=0), axis_name) / rows)
+        s = jnp.where(s > 0, s, 1.0)
+        Z = (X - m) / s
+        c = _psum(_dot(w, Z), axis_name) / wsum
+        second = _psum(_dot(w, Z * Z), axis_name) / wsum
+        var = second - c * c
+        varies = var > float(np.finfo(X.dtype).eps) ** 0.5 * second
+        scale = jnp.where(varies,
+                          jax.lax.rsqrt(jnp.where(varies, var, 1.0)), 0.0)
+        return _LaneDesign(Z, c, scale, m, s, wsum)
 
 
 def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
@@ -98,8 +211,8 @@ def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
     runs FISTA with a STATIC trip count — optax L-BFGS's data-dependent
     linesearch loops de-sync collective rendezvous across shards.
     """
-    d = X.shape[1]
-    Xs, mu, sigma, wsum = _prep(X, w, standardize, axis_name)
+    D = _lane_design(X, w, standardize, axis_name)
+    d = D.shape[1]
     s = 2.0 * y - 1.0  # {0,1} -> {-1,+1}
     l2 = reg * (1.0 - alpha)
     l1 = reg * alpha
@@ -107,21 +220,21 @@ def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
     # wsum), the reg term is divided across shards — so an explicit psum
     # of the gradient reconstructs the exact global gradient. Autodiff
     # therefore never transposes a collective (see fista_minimize).
-    nshards = _psum(jnp.asarray(1.0, Xs.dtype), axis_name)
+    nshards = _psum(jnp.asarray(1.0, D.dtype), axis_name)
 
     def smooth(params):
         wv, b = params[:d], params[d]
-        m = Xs @ wv + (b if fit_intercept else 0.0)
-        return (jnp.sum(w * jnp.logaddexp(0.0, -s * m)) / wsum
+        m = D.matvec(wv) + (b if fit_intercept else 0.0)
+        return (jnp.sum(w * jnp.logaddexp(0.0, -s * m)) / D.wsum
                 + 0.5 * l2 * jnp.sum(wv * wv) / nshards)
 
-    w0 = jnp.zeros(d + 1, Xs.dtype)
+    w0 = jnp.zeros(d + 1, D.dtype)
     force_fista = solver == "fista" or axis_name is not None
     with jax.named_scope("lin.solve"):
         if use_l1 or force_fista:
-            mask = jnp.concatenate([jnp.ones(d, Xs.dtype),
-                                    jnp.zeros(1, Xs.dtype)])
-            lip = design_lipschitz(Xs, l2, curvature_bound=0.25, w=w,
+            mask = jnp.concatenate([jnp.ones(d, D.dtype),
+                                    jnp.zeros(1, D.dtype)])
+            lip = design_lipschitz(D, l2, curvature_bound=0.25, w=w,
                                    axis_name=axis_name) + 0.25
             params = fista_minimize(smooth, l1, w0, lip,
                                     max_iter=max_iter * 5,
@@ -130,17 +243,7 @@ def binary_logistic_core(X, y, w, reg, alpha, *, fit_intercept: bool,
         else:
             params = lbfgs_minimize(smooth, w0, max_iter=max_iter)
     wv, b = params[:d], jnp.where(fit_intercept, params[d], 0.0)
-    return _unstandardize_coefs(wv, b, mu, sigma)
-
-
-#: precision of the multinomial (n, d) x (d, k) products: the core's logits
-#: (its gradient's product inherits it), the lanes' validation logits and a
-#: K-class model's scores. Float32 in full on the chip too, whose default is
-#: one bf16 pass, coarser than a float16 table: with it the refit read 2e-3 to
-#: 5e-3 from the same steps in float64 where this reads 2e-5 to 1e-4
-#: (PERF.md, PR 32). The binary cores' products are matrix-vector and never
-#: reach the multiplier
-_SOFTMAX_PRECISION = jax.lax.Precision.HIGHEST
+    return D.to_original(wv, b)
 
 
 def multinomial_logistic_core(X, y, w, reg, alpha, *, k: int,
@@ -155,29 +258,28 @@ def multinomial_logistic_core(X, y, w, reg, alpha, *, k: int,
     ``l2 > 0`` its minimiser is unique, so no centring of the rows of ``W``
     is needed. Solver choice, the shard-local objective and the static trip
     count under a mesh or solver="fista" are ``binary_logistic_core``'s."""
-    d = X.shape[1]
-    Xs, mu, sigma, wsum = _prep(X, w, standardize, axis_name)
-    onehot = jax.nn.one_hot(y.astype(jnp.int32), k, dtype=Xs.dtype)
+    D = _lane_design(X, w, standardize, axis_name)
+    d = D.shape[1]
+    onehot = jax.nn.one_hot(y.astype(jnp.int32), k, dtype=D.dtype)
     l2 = reg * (1.0 - alpha)
     l1 = reg * alpha
-    nshards = _psum(jnp.asarray(1.0, Xs.dtype), axis_name)
+    nshards = _psum(jnp.asarray(1.0, D.dtype), axis_name)
 
     def smooth(params):     # shard-local; the solver psums the gradient
         W = params[:, :d]
-        logits = jnp.matmul(Xs, W.T, precision=_SOFTMAX_PRECISION) \
-            + (params[:, d] if fit_intercept else 0.0)
+        logits = D.matvec(W.T) + (params[:, d] if fit_intercept else 0.0)
         ll = jnp.sum(w * jnp.sum(onehot * jax.nn.log_softmax(logits),
-                                 axis=1)) / wsum
+                                 axis=1)) / D.wsum
         return -ll + 0.5 * l2 * jnp.sum(W * W) / nshards
 
-    W0 = jnp.zeros((k, d + 1), Xs.dtype)
+    W0 = jnp.zeros((k, d + 1), D.dtype)
     force_fista = solver == "fista" or axis_name is not None
     with jax.named_scope("lin.solve"):
         if use_l1 or force_fista:
-            mask = jnp.concatenate([jnp.ones((k, d), Xs.dtype),
-                                    jnp.zeros((k, 1), Xs.dtype)], axis=1)
+            mask = jnp.concatenate([jnp.ones((k, d), D.dtype),
+                                    jnp.zeros((k, 1), D.dtype)], axis=1)
             # the softmax Hessian in the logits, diag(p) - p p^T, is <= 1/2
-            lip = design_lipschitz(Xs, l2, curvature_bound=0.5, w=w,
+            lip = design_lipschitz(D, l2, curvature_bound=0.5, w=w,
                                    axis_name=axis_name) + 0.5
             params = fista_minimize(smooth, l1, W0, lip,
                                     max_iter=max_iter * 5,
@@ -186,8 +288,8 @@ def multinomial_logistic_core(X, y, w, reg, alpha, *, k: int,
         else:
             params = lbfgs_minimize(smooth, W0, max_iter=max_iter)
     W = params[:, :d]
-    b = params[:, d] if fit_intercept else jnp.zeros(k, Xs.dtype)
-    return _unstandardize_coefs(W, b, mu, sigma)
+    b = params[:, d] if fit_intercept else jnp.zeros(k, D.dtype)
+    return D.to_original(W, b)
 
 
 def linear_regression_core(X, y, w, reg, alpha, *, fit_intercept: bool,
@@ -195,12 +297,13 @@ def linear_regression_core(X, y, w, reg, alpha, *, fit_intercept: bool,
                            axis_name: Optional[str] = None,
                            solver: str = "auto"):
     """Weighted OLS/ridge/elastic-net fit -> (coefficients, intercept).
-    Non-L1 solves closed-form normal equations (loop-free, mesh-safe);
-    L1 runs FISTA with a static trip count under a mesh."""
-    d = X.shape[1]
-    Xs, mu, sigma, wsum = _prep(X, w, standardize, axis_name)
-    ybar = (_psum(jnp.sum(w * y), axis_name) / wsum if fit_intercept
-            else jnp.asarray(0.0, Xs.dtype))
+    Non-L1 solves closed-form normal equations (loop-free, mesh-safe), on
+    a lane's own copy of its design; L1 runs FISTA over the shared one,
+    with a static trip count under a mesh."""
+    D = _lane_design(X, w, standardize, axis_name)
+    d = D.shape[1]
+    ybar = (_psum(jnp.sum(w * y), axis_name) / D.wsum if fit_intercept
+            else jnp.asarray(0.0, D.dtype))
     yc = y - ybar
     l2 = reg * (1.0 - alpha)
     l1 = reg * alpha
@@ -209,29 +312,29 @@ def linear_regression_core(X, y, w, reg, alpha, *, fit_intercept: bool,
         # ridge normal equations on the MXU (reference: MLlib "normal"
         # solver / breeze L-BFGS; one (d,d) psum-reduced solve here)
         with jax.named_scope("lin.solve"):
-            A = (_psum(Xs.T @ (w[:, None] * Xs), axis_name) / wsum
-                 + l2 * jnp.eye(d, dtype=Xs.dtype))
+            Xs = D.dense()
+            A = (_psum(Xs.T @ (w[:, None] * Xs), axis_name) / D.wsum
+                 + l2 * jnp.eye(d, dtype=D.dtype))
             wv = jnp.linalg.solve(
-                A, _psum(Xs.T @ (w * yc), axis_name) / wsum)
+                A, _psum(Xs.T @ (w * yc), axis_name) / D.wsum)
     else:
-        nshards = _psum(jnp.asarray(1.0, Xs.dtype), axis_name)
+        nshards = _psum(jnp.asarray(1.0, D.dtype), axis_name)
 
         def smooth(wv):     # shard-local; solver psums the gradient
-            r = Xs @ wv - yc
-            return (jnp.sum(w * r * r) / (2.0 * wsum)
+            r = D.matvec(wv) - yc
+            return (jnp.sum(w * r * r) / (2.0 * D.wsum)
                     + 0.5 * l2 * jnp.sum(wv * wv) / nshards)
         with jax.named_scope("lin.solve"):
-            lip = design_lipschitz(Xs, l2, curvature_bound=1.0, w=w,
+            lip = design_lipschitz(D, l2, curvature_bound=1.0, w=w,
                                    axis_name=axis_name) + 1e-3
-            wv = fista_minimize(smooth, l1, jnp.zeros(d, Xs.dtype), lip,
+            wv = fista_minimize(smooth, l1, jnp.zeros(d, D.dtype), lip,
                                 max_iter=max_iter * 5,
                                 tol=0.0 if (solver == "fista"
                                             or axis_name is not None)
                                 else 1e-7,
                                 grad_psum_axis=axis_name)
-    w_orig = wv / sigma
-    b = ybar - w_orig @ mu if fit_intercept else jnp.asarray(0.0, Xs.dtype)
-    return w_orig, b
+    w_orig, b = D.to_original(wv, ybar)
+    return w_orig, b if fit_intercept else jnp.asarray(0.0, D.dtype)
 
 
 def linear_svc_core(X, y, w, reg, alpha, *, fit_intercept: bool,
@@ -242,23 +345,23 @@ def linear_svc_core(X, y, w, reg, alpha, *, fit_intercept: bool,
     smooth TPU-friendly variant with near-identical decision boundaries
     (documented deviation). ``alpha``/``use_l1`` accepted for kernel-
     signature uniformity; L1 is not part of MLlib LinearSVC."""
-    d = X.shape[1]
-    Xs, mu, sigma, wsum = _prep(X, w, standardize, axis_name)
+    D = _lane_design(X, w, standardize, axis_name)
+    d = D.shape[1]
     s = 2.0 * y - 1.0
-    nshards = _psum(jnp.asarray(1.0, Xs.dtype), axis_name)
+    nshards = _psum(jnp.asarray(1.0, D.dtype), axis_name)
 
     def loss(params):       # shard-local; solver psums the gradient
         wv, b = params[:d], params[d]
-        m = Xs @ wv + (b if fit_intercept else 0.0)
+        m = D.matvec(wv) + (b if fit_intercept else 0.0)
         viol = jnp.maximum(0.0, 1.0 - s * m)
-        return (jnp.sum(w * viol * viol) / wsum
+        return (jnp.sum(w * viol * viol) / D.wsum
                 + 0.5 * reg * jnp.sum(wv * wv) / nshards)
 
-    w0 = jnp.zeros(d + 1, Xs.dtype)
+    w0 = jnp.zeros(d + 1, D.dtype)
     with jax.named_scope("lin.solve"):
         if solver == "fista" or axis_name is not None:
             # squared hinge has phi'' <= 2
-            lip = design_lipschitz(Xs, reg, curvature_bound=2.0, w=w,
+            lip = design_lipschitz(D, reg, curvature_bound=2.0, w=w,
                                    axis_name=axis_name) + 2.0
             params = fista_minimize(loss, 0.0, w0, lip,
                                     max_iter=max_iter * 5, tol=0.0,
@@ -266,7 +369,7 @@ def linear_svc_core(X, y, w, reg, alpha, *, fit_intercept: bool,
         else:
             params = lbfgs_minimize(loss, w0, max_iter=max_iter)
     wv, b = params[:d], jnp.where(fit_intercept, params[d], 0.0)
-    return _unstandardize_coefs(wv, b, mu, sigma)
+    return D.to_original(wv, b)
 
 
 # ---------------------------------------------------------------------------
@@ -417,8 +520,7 @@ class LogisticRegressionModel(ClassifierModel):
         if self.coefficients.ndim == 1:
             m = X @ c + float(self.intercept)
             return jnp.stack([-m, m], axis=1)
-        return (jnp.matmul(X, c.T, precision=_SOFTMAX_PRECISION)
-                + jnp.asarray(self.intercept, X.dtype))
+        return _dot(X, c.T) + jnp.asarray(self.intercept, X.dtype)
 
 
 # ---------------------------------------------------------------------------

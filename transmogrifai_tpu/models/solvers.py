@@ -61,29 +61,25 @@ def lbfgs_minimize(loss_fn: Callable, w0, max_iter: int = 100,
     return final_params
 
 
-def _power_iteration_sq_norm(X: jnp.ndarray, iters: int = 16,
-                             w: jnp.ndarray | None = None,
+def _power_iteration_sq_norm(design, w: jnp.ndarray, iters: int = 16,
                              axis_name: str | None = None) -> jnp.ndarray:
-    """Largest eigenvalue of X^T diag(w) X / sum(w) (Lipschitz constant
-    scale) via power iteration — static iteration count for XLA. With
-    ``axis_name`` set, X/w are row shards of a mesh data axis and the
-    matvec reductions cross it via psum."""
-    n, d = X.shape
-    v0 = jnp.ones((d,), X.dtype) / jnp.sqrt(d)
+    """Largest eigenvalue of A^T diag(w) A / sum(w) (Lipschitz constant
+    scale) via power iteration — static iteration count for XLA. ``design``
+    is the operator A: ``design.matvec(v)`` is A v, ``design.rmatvec(r)``
+    A^T r, ``design.shape`` (n, d) (a lane's design, ``linear._LaneDesign``,
+    never materialises A). With ``axis_name`` set, A's rows and ``w`` are
+    row shards of a mesh data axis and the matvec reductions cross it via
+    psum."""
+    d = design.shape[1]
+    v0 = jnp.ones((d,), design.dtype) / jnp.sqrt(d)
 
     def psum(x):
         return jax.lax.psum(x, axis_name) if axis_name else x
 
-    if w is None:
-        wsum = psum(jnp.asarray(float(n), X.dtype))
+    wsum = jnp.maximum(psum(jnp.sum(w)), 1e-12)
 
-        def matvec(v):
-            return psum(X.T @ (X @ v)) / wsum
-    else:
-        wsum = jnp.maximum(psum(jnp.sum(w)), 1e-12)
-
-        def matvec(v):
-            return psum(X.T @ (w * (X @ v))) / wsum
+    def matvec(v):
+        return psum(design.rmatvec(w * design.matvec(v))) / wsum
 
     def body(_, v):
         u = matvec(v)       # u is replicated across the data axis
@@ -148,13 +144,13 @@ def fista_minimize(smooth_loss: Callable, l1: float, w0: jnp.ndarray,
     return w
 
 
-def design_lipschitz(X: jnp.ndarray, l2: float,
-                     curvature_bound: float = 0.25,
-                     w: jnp.ndarray | None = None,
-                     axis_name: str | None = None) -> jnp.ndarray:
+def design_lipschitz(design, l2: float, curvature_bound: float = 0.25, *,
+                     w: jnp.ndarray, axis_name: str | None = None
+                     ) -> jnp.ndarray:
     """Lipschitz bound for losses of the form
     sum(w*phi(x.b))/sum(w) + l2/2 ||b||^2 where phi'' <= curvature_bound
-    (0.25 for logistic, 1.0 for squared). ``w`` are optional row weights
+    (0.25 for logistic, 1.0 for squared), over the rows of the operator
+    ``design`` (see _power_iteration_sq_norm). ``w`` are the row weights
     (fold masks); ``axis_name`` enables mesh data-axis psum."""
     return (curvature_bound
-            * _power_iteration_sq_norm(X, w=w, axis_name=axis_name) + l2)
+            * _power_iteration_sq_norm(design, w, axis_name=axis_name) + l2)

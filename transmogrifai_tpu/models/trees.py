@@ -41,7 +41,6 @@ device arrays and are safe to call inside ``shard_map``.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import itertools
 import logging
@@ -683,22 +682,6 @@ def tree_hist_rows() -> dict:
     return dict(_HIST_ROWS)
 
 
-@contextlib.contextmanager
-def _counted_span(name: str, counters, **group):
-    """The span ``name`` carrying the process's counts so far of each
-    ``(prefix, counter)`` as the scalar attributes ``prefix + key``: read
-    when the span opens, which its profiler annotation keeps, and again
-    when it closes, because a program's first call traces inside the
-    span; and ``group``, attributes of the call's own."""
-    def attrs():
-        return {prefix + k: v for prefix, counter in counters
-                for k, v in counter().items()}
-    with _trace.span(name, **attrs(), **group) as rec:
-        yield
-        if rec is not None:
-            rec["attrs"].update(attrs())
-
-
 def _fetch_span(**group):
     """The ``search.fetch`` span of a fold-grid driver, carrying
     :func:`tree_route_forms`, :func:`tree_sum_forms`,
@@ -706,11 +689,11 @@ def _fetch_span(**group):
     :func:`tree_hist_rows` as the attributes ``route_dense`` /
     ``route_gather``, ``sums_dense`` / ``sums_scatter``, ``eval_in_fit`` /
     ``eval_traverse``, ``compress_carried`` and ``hist_head`` / ``hist_all``
-    (see _counted_span), and ``group``, the attributes of the call's own
-    group (``depth_blocks`` / ``depth_lane_levels``, see
+    (see trace.counted_span), and ``group``, the attributes of the call's
+    own group (``depth_blocks`` / ``depth_lane_levels``, see
     tree_depth_blocks; ``hist_row_share``, the rows a lane's histograms
     contract over the rows it holds)."""
-    return _counted_span("search.fetch", (
+    return _trace.counted_span("search.fetch", (
         ("route_", tree_route_forms), ("sums_", tree_sum_forms),
         ("eval_", tree_eval_forms), ("compress_", tree_compress_levels),
         ("hist_", tree_hist_rows)), **group)
@@ -719,8 +702,8 @@ def _fetch_span(**group):
 def train_eval_span():
     """The selector's ``search.train_eval`` span (the refitted winner's
     scores on its training rows), carrying :func:`tree_traverse_forms` as
-    ``traverse_dense`` / ``traverse_gather`` (see _counted_span)."""
-    return _counted_span("search.train_eval",
+    ``traverse_dense`` / ``traverse_gather`` (see trace.counted_span)."""
+    return _trace.counted_span("search.train_eval",
                          (("traverse_", tree_traverse_forms),))
 
 

@@ -38,9 +38,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..observability import trace as _trace
 
-from ..models.linear import (_SOFTMAX_PRECISION, binary_logistic_core,
-                             linear_regression_core, linear_svc_core,
-                             multinomial_logistic_core)
+from ..models.linear import (_LANE_PRECISION, binary_logistic_core,
+                             lane_designs, linear_regression_core,
+                             linear_svc_core, multinomial_logistic_core)
 
 __all__ = ["fold_masks", "fit_linear_fold_grid", "eval_linear_fold_grid",
            "models_mesh", "resolve_search_mesh", "mesh_model_shards",
@@ -174,6 +174,15 @@ def _device_float(a):
     return jnp.asarray(np.asarray(a, dtype=np.float64))
 
 
+def _fetch_span(lanes: int):
+    """The ``search.fetch`` span of the linear fold-grid functions,
+    carrying :func:`linear.lane_designs` as ``design_shared`` /
+    ``design_per_lane`` (see trace.counted_span) and ``lanes``, the call's
+    (fold, grid point) lanes."""
+    return _trace.counted_span("search.fetch", (("design_", lane_designs),),
+                               lanes=lanes)
+
+
 def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
                          masks: np.ndarray, grid: np.ndarray, *,
                          mesh: Optional[Mesh] = None,
@@ -213,7 +222,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
 
     if mesh is None:
         fn = _local_kernel(cfg)
-        with _trace.span("search.fetch"):
+        with _fetch_span(F * G):
             params = fn(jnp.asarray(wmat), jnp.asarray(regs),
                         jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
             return np.asarray(params).reshape(F, G, *lane)
@@ -234,7 +243,7 @@ def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
             [wmat, np.zeros((wmat.shape[0], pad_r))], axis=1)
 
     fn = _mesh_kernel(cfg, mesh)
-    with _trace.span("search.fetch"):
+    with _fetch_span(F * G):
         params = fn(jnp.asarray(wmat), jnp.asarray(regs),
                     jnp.asarray(alphas), jnp.asarray(X), jnp.asarray(y))
         return to_host(params)[:FG].reshape(F, G, *lane)
@@ -285,7 +294,7 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
 
     if mesh is None:
         fn = _local_eval_kernel(cfg, spec)
-        with _trace.span("search.fetch"):
+        with _fetch_span(F * G):
             mm = fn(jnp.asarray(wmat), jnp.asarray(regs),
                     jnp.asarray(alphas), jnp.asarray(fidx), jnp.asarray(X),
                     jnp.asarray(y), Xv, yv)
@@ -307,7 +316,7 @@ def eval_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,
         wmat = np.concatenate(
             [wmat, np.zeros((wmat.shape[0], pad_r))], axis=1)
     fn = _mesh_eval_kernel(cfg, spec, mesh)
-    with _trace.span("search.fetch"):
+    with _fetch_span(F * G):
         mm = fn(jnp.asarray(wmat), jnp.asarray(regs), jnp.asarray(alphas),
                 jnp.asarray(fidx), jnp.asarray(X), jnp.asarray(y), Xv, yv)
         return to_host(mm)[:FG].reshape(F, G)
@@ -339,7 +348,7 @@ def _candidate_eval(cfg, spec, params, fi, Xv, yv):
         if cfg[0] == "softmax":
             W = params.reshape(cfg[5], d + 1)
             raw = jnp.matmul(Xv[fi], W[:, :d].T,
-                             precision=_SOFTMAX_PRECISION) + W[:, d]
+                             precision=_LANE_PRECISION) + W[:, d]
         else:
             m = Xv[fi] @ params[:d] + params[d]
         if spec[0] == "multiclass":
