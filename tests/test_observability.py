@@ -449,6 +449,10 @@ class TestTrainSpans:
             # the CPU's scatter mode keeps segment_sum)
             assert s["attrs"]["sums_scatter"] >= 1
             assert s["attrs"]["sums_dense"] >= 0
+            # and how their boosting rounds read the rows' leaf values
+            # (trees.tree_pick_forms; the CPU's scatter mode gathers)
+            assert s["attrs"]["pick_gather"] >= 1
+            assert s["attrs"]["pick_dense"] >= 0
         # the winner's scoring says which form its walk took
         # (trees.tree_traverse_forms; a CPU gathers), its own included
         (train_eval,) = named("search.train_eval")

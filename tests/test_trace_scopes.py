@@ -20,7 +20,7 @@ import jax                                            # noqa: E402
 import jax.numpy as jnp                               # noqa: E402
 
 from benchmark.layer_metrics import (                 # noqa: E402
-    fit_node_sums_s, fit_route_s, search_compress_s,
+    fit_node_sums_s, fit_route_s, gbt_pick_s, search_compress_s,
     search_design_s_per_train, search_node_sums_s, search_route_s,
     tail_traverse_s, winner_tail_s_per_train)
 from benchmark.trace import scopes                    # noqa: E402
@@ -96,7 +96,8 @@ def fold_grid_hlo():
 
 
 FIT_SCOPES = ("tree.indicator", "tree.compress", "tree.hist",
-              "tree.node_sums", "tree.split", "tree.route", "gbt.round")
+              "tree.node_sums", "tree.split", "tree.route", "gbt.round",
+              "gbt.pick")
 
 
 @pytest.mark.parametrize("scope", FIT_SCOPES)
@@ -113,7 +114,7 @@ def test_fit_gbt_keeps_its_program_name(fit_gbt_hlo):
 
 @pytest.mark.parametrize("scope", ("fg.gbt", "fg.metric", "gbt.round",
                                    "tree.hist", "tree.node_sums",
-                                   "tree.split", "tree.route"))
+                                   "tree.split", "tree.route", "gbt.pick"))
 def test_fold_grid_program_carries_scope(fold_grid_hlo, scope):
     assert scope in trees.SCOPES
     assert scope in _components(fold_grid_hlo)
@@ -184,6 +185,52 @@ def test_no_row_scatter_under_tree_node_sums(program, rows, placements,
         (shape,) = re.findall(
             re.escape(updates) + r" = \w+\[([\d,]*)\]", hlo)
         assert int(shape.split(",")[0]) == 2 * 24 != rows
+
+
+@pytest.mark.parametrize("program, table_reads", [
+    ("fit_gbt_hlo", 1), ("fold_grid_hlo", 0)])
+def test_no_row_gather_under_gbt_pick(program, table_reads, request):
+    """Under the ``matmul`` family a round reads its rows' leaf
+    values by a select over the last level's slots. The one gather allowed
+    is the fit fixture's read of its (slot, side) table, 2 * 24 leaf values
+    of a compressed last level (24 rows at depth 6), never one a row; the
+    fold-grid fixture's depth-2 trees end on an identity level and read
+    none."""
+    hlo = request.getfixturevalue(program)
+    picked = [line for line in hlo.splitlines() if "gbt.pick" in line]
+    assert any(re.search(r"gbt\.pick\)?/reduce_sum", line)
+               for line in picked)
+    gathers = [line for line in picked
+               if re.search(r"= \S+ gather\(", line)]
+    assert len(gathers) == table_reads
+    for line in gathers:
+        assert re.search(r"= \w+\[(\d+)", line).group(1) == str(2 * 24)
+
+
+def test_pick_form_follows_hist_mode_and_retraces(monkeypatch):
+    """``tree_pick_forms`` counts one traced pick a round body by form: the
+    gather under a CPU's default ``scatter`` mode, the dense read under the
+    ``matmul`` family."""
+    rng = np.random.default_rng(44)
+    X = rng.normal(size=(80, 4))
+    y = (X[:, 2] > 0).astype(np.float64)
+    seen = []
+    for mode in (None, "matmul", None, "matmul_chunk"):
+        if mode is not None:
+            monkeypatch.setattr(trees, "_hist_mode", lambda n, tb, m=mode: m)
+        else:
+            monkeypatch.undo()
+        before = trees.tree_pick_forms()
+        GBTClassifier(num_rounds=3, max_depth=3, max_bins=8).fit_arrays(X, y)
+        after = trees.tree_pick_forms()
+        seen.append({k: after[k] - before[k] for k in after})
+    # one fit traces one round body, so one pick; the third fit finds the
+    # first one's program and traces nothing
+    assert seen[0] == {"dense": 0, "gather": 1}
+    assert seen[1] == {"dense": 1, "gather": 0}
+    assert seen[2] == {"dense": 0, "gather": 0}
+    assert seen[3] == {"dense": 1, "gather": 0}
+    assert set(trees.tree_pick_forms()) == {"dense", "gather"}
 
 
 def test_sum_form_follows_hist_mode_and_retraces(monkeypatch):
@@ -460,7 +507,8 @@ SCOPE_READERS = [(fit_route_s, "jit__fit_gbt", "tree.route"),
                  (fit_node_sums_s, "jit__fit_gbt", "tree.node_sums"),
                  (search_route_s, "jit_batched", "tree.route"),
                  (search_node_sums_s, "jit_batched", "tree.node_sums"),
-                 (search_compress_s, "jit_batched", "tree.compress")]
+                 (search_compress_s, "jit_batched", "tree.compress"),
+                 (gbt_pick_s, "jit_batched", "gbt.pick")]
 
 
 @pytest.fixture

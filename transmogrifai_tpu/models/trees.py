@@ -89,12 +89,14 @@ __all__ = [
 #: only while JAX traces: it adds no operation and changes no program's name.
 #: ``tree.traverse`` is the walk of finished heaps (``_traverse``, either
 #: form): the scoring programs' (``jit__predict_leaves``), not a fit's.
+#: ``gbt.pick``, inside ``gbt.round``, adds each row's leaf value of a
+#: round's finished tree to its margin (``_TreeGrower.add_leaf_values``).
 SCOPES = ("tree.indicator", "tree.compress", "tree.hist", "tree.node_sums",
           "tree.split", "tree.route", "tree.bootstrap", "tree.pool",
           "gbt.round", "fg.metric", "fg.gbt", "fg.forest",
           "fg.gbt_softmax", "fg.linear", "lin.standardize", "lin.solve",
           "fg.softmax", "fg.bayes", "fg.glm", "glm.gram", "glm.solve",
-          "fg.order", "tree.traverse")
+          "fg.order", "tree.traverse", "gbt.pick")
 
 # ---------------------------------------------------------------------------
 # binning — packed variable-width bins
@@ -682,21 +684,34 @@ def tree_hist_rows() -> dict:
     return dict(_HIST_ROWS)
 
 
+#: how many traced boosting rounds read their rows' leaf values in each
+#: form (see _TreeGrower.add_leaf_values)
+_PICK_FORMS = {"dense": 0, "gather": 0}
+
+
+def tree_pick_forms() -> dict:
+    """Traced leaf-value picks of boosting rounds so far in this process by
+    form, ``{"dense": k, "gather": m}`` (see _TreeGrower.add_leaf_values):
+    the record of which path the compiled boosted programs hold."""
+    return dict(_PICK_FORMS)
+
+
 def _fetch_span(**group):
     """The ``search.fetch`` span of a fold-grid driver, carrying
     :func:`tree_route_forms`, :func:`tree_sum_forms`,
-    :func:`tree_eval_forms`, :func:`tree_compress_levels` and
-    :func:`tree_hist_rows` as the attributes ``route_dense`` /
-    ``route_gather``, ``sums_dense`` / ``sums_scatter``, ``eval_in_fit`` /
-    ``eval_traverse``, ``compress_carried`` and ``hist_head`` / ``hist_all``
-    (see trace.counted_span), and ``group``, the attributes of the call's
-    own group (``depth_blocks`` / ``depth_lane_levels``, see
-    tree_depth_blocks; ``hist_row_share``, the rows a lane's histograms
-    contract over the rows it holds)."""
+    :func:`tree_eval_forms`, :func:`tree_compress_levels`,
+    :func:`tree_hist_rows` and :func:`tree_pick_forms` as the attributes
+    ``route_dense`` / ``route_gather``, ``sums_dense`` / ``sums_scatter``,
+    ``eval_in_fit`` / ``eval_traverse``, ``compress_carried``,
+    ``hist_head`` / ``hist_all`` and ``pick_dense`` / ``pick_gather`` (see
+    trace.counted_span), and ``group``, the attributes of the call's own
+    group (``depth_blocks`` / ``depth_lane_levels``, see tree_depth_blocks;
+    ``hist_row_share``, the rows a lane's histograms contract over the rows
+    it holds)."""
     return _trace.counted_span("search.fetch", (
         ("route_", tree_route_forms), ("sums_", tree_sum_forms),
         ("eval_", tree_eval_forms), ("compress_", tree_compress_levels),
-        ("hist_", tree_hist_rows)), **group)
+        ("hist_", tree_hist_rows), ("pick_", tree_pick_forms)), **group)
 
 
 def train_eval_span():
@@ -759,6 +774,34 @@ class _TreeState(NamedTuple):
     thr_heap: jnp.ndarray
     went_right: jnp.ndarray
     prev_hist: Optional[jnp.ndarray]
+
+
+def _leaf_values_dense(vals: jnp.ndarray, state: _TreeState,
+                       by_id: bool) -> jnp.ndarray:
+    """``vals[state.node]`` for every row of a finished tree without a
+    per-row gather. A row ends in the last level's ``slot`` on the side
+    ``went_right``, and its leaf is that column's child (see _child_ids):
+    the (C, 2) table of leaf values by slot and side is ``vals`` itself
+    where the last level's slots are its node ids (``by_id``, an identity
+    level) and is read from it where they carry them (a compressed level:
+    2C entries, a sentinel slot's reading nothing); then every row selects
+    its slot's entry over the slot axis, C compares a row. The selects run
+    on the values' bits and sum integers, one non-zero term a row, so the
+    result is the gather's to the bit, signed zeros included; rows along
+    the lanes, the slots reduced on the major axis, as _carry_slots reads
+    its rank table."""
+    bits = jax.lax.bitcast_convert_type(
+        vals, jnp.dtype(f"int{8 * vals.dtype.itemsize}"))
+    if not by_id:
+        bits = bits.at[_child_ids(state.node_of_slot, vals.shape[0])].get(
+            mode="fill", fill_value=0)
+    table = bits.reshape(-1, 2)
+    mine = jnp.where(state.went_right[None, :] > 0, table[:, 1:],
+                     table[:, :1])
+    of_slot = state.slot[None, :] == jnp.arange(
+        table.shape[0], dtype=state.slot.dtype)[:, None]
+    picked = jnp.sum(jnp.where(of_slot, mine, 0), axis=0, dtype=bits.dtype)
+    return jax.lax.bitcast_convert_type(picked, vals.dtype)
 
 
 class _TreeGrower:
@@ -1084,6 +1127,24 @@ class _TreeGrower:
                 _child_ids(state.node_of_slot, 2 ** depth)].set(
                 by_column, mode="drop")
 
+    def add_leaf_values(self, margins: jnp.ndarray, state: _TreeState,
+                        vals: jnp.ndarray, depth: int) -> jnp.ndarray:
+        """``margins + vals[state.node]`` of ONE lane's finished tree
+        (``vals`` (2^depth,), one value a leaf): a boosting round's step,
+        scope ``gbt.pick``. The read is the transpose of the leaf sums
+        (``leaves``), so it takes their form (see _sums_form): the gather
+        under ``scatter``, the CPU path and the tests' reference;
+        :func:`_leaf_values_dense` under the ``matmul`` family, where a
+        batched per-row gather is slow. Both give the same bits."""
+        form = "dense" if self.sums_form(depth) == "dense" else "gather"
+        _PICK_FORMS[form] += 1
+        with jax.named_scope("gbt.pick"):
+            if form == "gather":
+                return margins + vals[state.node]
+            return margins + _leaf_values_dense(
+                vals, state, depth > 0 and self.is_identity(depth - 1,
+                                                            depth))
+
 
 def _grow_tree(packed: jnp.ndarray, feat_of: jnp.ndarray,
                block_start: jnp.ndarray, packed_thr: jnp.ndarray,
@@ -1177,7 +1238,9 @@ def _grow_blocks(grower: _TreeGrower, depths: tuple, lanes: tuple,
     level 8 is compressed) run that segment apart.
 
     Returns a tuple a block of ``(feat_heap (lanes, 2^depth - 1), thr_heap,
-    leaf_stats (lanes, 2^depth, S), node (lanes, n))``."""
+    leaf_stats (lanes, 2^depth, S), state)``: the lanes' final
+    :class:`_TreeState`, whose ``node`` (lanes, n) is every row's leaf and
+    whose last level's slots are what ``add_leaf_values`` reads."""
     ends = np.cumsum(lanes)
     block = [slice(int(e - k), int(e)) for e, k in zip(ends, lanes)]
     states: List[Optional[_TreeState]] = [None] * len(depths)
@@ -1218,7 +1281,7 @@ def _grow_blocks(grower: _TreeGrower, depths: tuple, lanes: tuple,
         (state.feat_heap, state.thr_heap,
          jax.vmap(lambda st, ls: grower.leaves(st, ls, depth))(
              state, stats[block[b]]),
-         state.node)
+         state)
         for b, (depth, state) in enumerate(zip(depths, states)))
 
 
@@ -1645,15 +1708,15 @@ def _forest_body(packed, feat_of, block_start, packed_thr,
         grown = _grow_blocks(grower, depths, lanes, stats,
                              (min_instances, min_info_gain), gain_of)
         at, trees = 0, []
-        for k, (feat, thr, leaf_stats, node) in zip(lanes, grown):
+        for k, (feat, thr, leaf_stats, state) in zip(lanes, grown):
             mine = slice(at, at + k)
             tree = (feat, thr, jax.vmap(tree_leaves)(leaf_stats,
                                                      y_mean[mine]))
             if val_rows is not None:
                 tree += (jax.vmap(lambda nd, rows: nd[rows])(
-                    node, val_rows[mine]),)
+                    state.node, val_rows[mine]),)
             elif hist_rows is not None:
-                tree += (node[:, hist_rows:],)
+                tree += (state.node[:, hist_rows:],)
             trees.append(tree)
             at += k
         return tuple(trees)
@@ -1734,6 +1797,13 @@ def _over_lanes(lanes: Optional[tuple]):
     return (lambda step: step) if lanes is None else jax.vmap
 
 
+def _leaf_values(leaf_stats, step_size, reg_lambda):
+    """A boosted tree's (2^depth,) leaf values from its (2^depth, 2) leaf
+    sums of gradients and hessians; 0 where a leaf holds no row."""
+    vals = -step_size * leaf_stats[:, 0] / (leaf_stats[:, 1] + reg_lambda)
+    return jnp.where(jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
+
+
 def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
               step_size, reg_lambda, gamma, min_child_weight, subsample,
               *, depth, num_rounds: int, objective: str,
@@ -1803,12 +1873,6 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
                 rkey, n, axis_name, row_total) * mask
             return jnp.stack([g * m, h * m], axis=1)
 
-        def add_tree(margins, leaf_stats, node, step_size, reg_lambda):
-            vals = (-step_size * leaf_stats[:, 0]
-                    / (leaf_stats[:, 1] + reg_lambda))
-            vals = jnp.where(jnp.sum(jnp.abs(leaf_stats), axis=1) > 0,
-                             vals, 0.0)
-            return margins + vals[node], vals
         with jax.named_scope("gbt.round"):
             stats = over(round_stats)(margins, mask, subsample)
             grower = _TreeGrower(
@@ -1820,20 +1884,22 @@ def _gbt_body(packed, feat_of, block_start, packed_thr, y, key, mask,
                 state = grower.levels(
                     None, stats, *gain_of(reg_lambda, gamma,
                                           min_child_weight), 0, depth, depth)
-                margins, vals = add_tree(
-                    margins, grower.leaves(state, stats, depth), state.node,
-                    step_size, reg_lambda)
-                return margins, (state.feat_heap, state.thr_heap, vals)
+                vals = _leaf_values(grower.leaves(state, stats, depth),
+                                    step_size, reg_lambda)
+                return (grower.add_leaf_values(margins, state, vals, depth),
+                        (state.feat_heap, state.thr_heap, vals))
             grown = _grow_blocks(grower, depths, lanes, stats,
                                  (reg_lambda, gamma, min_child_weight),
                                  gain_of)
             at, new_margins, trees = 0, [], []
-            for k, (feat, thr, leaf_stats, node) in zip(lanes, grown):
+            for k, block_depth, (feat, thr, leaf_stats, state) in zip(
+                    lanes, depths, grown):
                 mine = slice(at, at + k)
-                block_margins, vals = jax.vmap(add_tree)(
-                    margins[mine], leaf_stats, node, step_size[mine],
-                    reg_lambda[mine])
-                new_margins.append(block_margins)
+                vals = jax.vmap(_leaf_values)(leaf_stats, step_size[mine],
+                                              reg_lambda[mine])
+                new_margins.append(jax.vmap(functools.partial(
+                    grower.add_leaf_values, depth=block_depth))(
+                    margins[mine], state, vals))
                 trees.append((feat, thr, vals))
                 at += k
             return jnp.concatenate(new_margins), tuple(trees)
@@ -1919,12 +1985,6 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
                 rkey, n, axis_name, row_total) * mask
             return jnp.stack([g.T * m, h.T * m], axis=2)    # (K, n, 2)
 
-        def class_tree(leaf_stats, node, step_size, reg_lambda):
-            vals = (-step_size * leaf_stats[:, 0]
-                    / (leaf_stats[:, 1] + reg_lambda))
-            vals = jnp.where(
-                jnp.sum(jnp.abs(leaf_stats), axis=1) > 0, vals, 0.0)
-            return vals, vals[node]
         with jax.named_scope("gbt.round"):
             stats = over(round_stats)(margins, mask, subsample)
             grower = _TreeGrower(
@@ -1933,16 +1993,20 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
                 axis_name=axis_name, row_total=row_total,
                 hist_rows=hist_rows, bin_oh=held_oh)
             if lanes is None:
-                def per_class(class_stats):
+                def per_class(class_stats, class_margins):
                     state = grower.levels(
                         None, class_stats, *gain_of(
                             reg_lambda, gamma, min_child_weight), 0, depth,
                         depth)
-                    return (state.feat_heap, state.thr_heap, *class_tree(
-                        grower.leaves(state, class_stats, depth),
-                        state.node, step_size, reg_lambda))
-                feats, thrs, vals, delta = jax.vmap(per_class)(stats)
-                return margins + delta.T, (feats, thrs, vals)
+                    vals = _leaf_values(
+                        grower.leaves(state, class_stats, depth), step_size,
+                        reg_lambda)
+                    return (grower.add_leaf_values(class_margins, state,
+                                                   vals, depth),
+                            state.feat_heap, state.thr_heap, vals)
+                class_margins, feats, thrs, vals = jax.vmap(per_class)(
+                    stats, margins.T)
+                return class_margins.T, (feats, thrs, vals)
             # a lane's K trees are K lanes of the grower, a block's lanes
             # still one after another
             grown = _grow_blocks(
@@ -1952,13 +2016,19 @@ def _gbt_softmax_body(packed, feat_of, block_start, packed_thr, y, key,
                                                  min_child_weight)),
                 gain_of)
             at, new_margins, trees = 0, [], []
-            for k, (feat, thr, leaf_stats, node) in zip(lanes, grown):
+            for k, block_depth, (feat, thr, leaf_stats, state) in zip(
+                    lanes, depths, grown):
                 mine = slice(at, at + k)
-                vals, delta = jax.vmap(class_tree)(
-                    leaf_stats, node, jnp.repeat(step_size[mine], K),
+                vals = jax.vmap(_leaf_values)(
+                    leaf_stats, jnp.repeat(step_size[mine], K),
                     jnp.repeat(reg_lambda[mine], K))
-                new_margins.append(margins[mine] + jnp.swapaxes(
-                    delta.reshape(k, K, n), 1, 2))
+                # a lane's K class margins as K lanes of the grower
+                class_margins = jax.vmap(functools.partial(
+                    grower.add_leaf_values, depth=block_depth))(
+                    jnp.swapaxes(margins[mine], 1, 2).reshape(k * K, n),
+                    state, vals)
+                new_margins.append(jnp.swapaxes(
+                    class_margins.reshape(k, K, n), 1, 2))
                 trees.append(tuple(a.reshape((k, K) + a.shape[1:])
                                    for a in (feat, thr, vals)))
                 at += k
