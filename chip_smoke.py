@@ -172,8 +172,8 @@ def phase_train(mode: str) -> int:
                                   synthetic_titanic)
     from transmogrifai_tpu.artifacts.store import read_manifest
     from transmogrifai_tpu.evaluators import BinaryClassificationEvaluator
-    from transmogrifai_tpu.models.trees import (_depth_mode, _hist_mode,
-                                                tree_kernel_compiles)
+    from transmogrifai_tpu.models.trees import tree_kernel_compiles
+    from transmogrifai_tpu.models.trees.forms import _depth_mode, _hist_mode
     from transmogrifai_tpu.parallel.cv import resolve_search_mesh
     from transmogrifai_tpu.runtime import telemetry
     from transmogrifai_tpu.selector import (BinaryClassificationModelSelector,

@@ -35,9 +35,9 @@ def main() -> None:
 
     from transmogrifai_tpu.evaluators import BinaryClassificationEvaluator
     from transmogrifai_tpu.models.trees import (GBTClassifier,
-                                                RandomForestClassifier,
-                                                _forest_fold_grid,
-                                                _gbt_fold_grid)
+                                                RandomForestClassifier)
+    from transmogrifai_tpu.models.trees.fold_grid import (_forest_fold_grid,
+                                                          _gbt_fold_grid)
 
     rng = np.random.default_rng(0)
     n, d, F = args.rows, args.cols, args.folds

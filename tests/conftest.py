@@ -93,13 +93,68 @@ def rng():
 def retired_tree_switches(monkeypatch):
     """Every environment variable that used to select a tree-kernel path
     (ISSUE 30), set to a value that would flip the choice if anything
-    still read it. The resolvers in ``models/trees.py`` work their answer
-    out from the platform and the sizes alone."""
+    still read it. The resolvers in ``models/trees/forms.py`` work their
+    answer out from the platform and the sizes alone."""
     for name, value in (("TREE_HIST", "matmul_bf16"), ("TREE_SUB", "1"),
                         ("TREE_DEPTH", "bogus"), ("TREE_BINNING", "bogus"),
                         ("TREE_BLOCK_MB", "1"),
                         ("DEVICE_BIN_MIN_ELEMS", "1")):
         monkeypatch.setenv("TX_" + name, value)
+
+
+# ---------------------------------------------------------------------------
+# the tree tests' tables and environments (tests/tree_cases.py holds their
+# cases and spies)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def binary_data():
+    rng = np.random.default_rng(0)
+    n = 400
+    X = rng.normal(size=(n, 6))
+    # axis-aligned interaction a tree can represent exactly
+    y = ((X[:, 0] > 0.3) & (X[:, 2] < 0.5)).astype(np.float64)
+    return X, y
+
+
+@pytest.fixture(scope="module")
+def regression_data():
+    rng = np.random.default_rng(1)
+    n = 400
+    X = rng.normal(size=(n, 5))
+    y = np.where(X[:, 0] > 0, 3.0, -1.0) + np.where(X[:, 1] > 1, 2.0, 0.0)
+    y = y + 0.01 * rng.normal(size=n)
+    return X, y
+
+
+@pytest.fixture
+def infit_env(monkeypatch):
+    """``configure(binning)``: host or device binning, depth blocks in one
+    program, and an empty design memo on either side of the test."""
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.models.trees import forms
+
+    def configure(binning):
+        monkeypatch.setattr(forms, "_bin_on_device",
+                            lambda elems: binning == "device")
+        monkeypatch.setattr(forms, "_depth_mode", lambda: "blocks")
+        trees.clear_design_cache()
+    yield configure
+    trees.clear_design_cache()
+
+
+@pytest.fixture
+def head_env(monkeypatch):
+    """The chip's choices on the CPU: the ``matmul`` histogram family (so
+    the head form applies), depth blocks in one program, host binning."""
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.models.trees import forms
+    monkeypatch.setattr(forms, "_hist_mode", lambda n, tb: "matmul")
+    monkeypatch.setattr(forms, "_depth_mode", lambda: "blocks")
+    monkeypatch.setattr(forms, "_bin_on_device", lambda elems: False)
+    trees.clear_design_cache()
+    yield monkeypatch
+    trees.clear_design_cache()
 
 
 def pytest_configure(config):

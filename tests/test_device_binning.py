@@ -1,4 +1,5 @@
-"""Device-side quantile binning (models/trees._PackedDesign._bin_device).
+"""Device-side quantile binning (models/trees/design.py
+``_PackedDesign._bin_device``).
 
 The device path (f32 sorts + quantile gathers + compare-sum digitize)
 must reproduce the host f64 loop exactly on data where f32 is exact:
@@ -9,8 +10,8 @@ sample points (frac = 0) and every comparison is representable.
 import numpy as np
 import pytest
 
-from transmogrifai_tpu.models import trees
-from transmogrifai_tpu.models.trees import _PackedDesign
+from transmogrifai_tpu.models.trees import design, forms
+from transmogrifai_tpu.models.trees.design import _PackedDesign
 
 
 def _data(n=3201, seed=0):
@@ -37,7 +38,7 @@ def _assert_designs_equal(a: _PackedDesign, b: _PackedDesign):
 def test_device_matches_host(monkeypatch):
     X = _data()
     host = _PackedDesign(X, 32)
-    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
+    monkeypatch.setattr(forms, "_bin_on_device", lambda elems: True)
     dev = _PackedDesign(X, 32)
     _assert_designs_equal(host, dev)
 
@@ -47,7 +48,7 @@ def test_device_matches_host_edge_rows(monkeypatch):
     X = _data()
     edge_rows = np.arange(0, X.shape[0], 2)[:1601]  # m-1 = 1600
     host = _PackedDesign(X, 32, edge_rows=edge_rows)
-    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
+    monkeypatch.setattr(forms, "_bin_on_device", lambda elems: True)
     dev = _PackedDesign(X, 32, edge_rows=edge_rows)
     _assert_designs_equal(host, dev)
 
@@ -56,8 +57,8 @@ def test_device_digitize_chunked(monkeypatch):
     """Row-chunk padding path: force tiny chunks and a ragged tail."""
     X = _data(n=777)
     host = _PackedDesign(X, 32)
-    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
-    monkeypatch.setattr(trees, "_HIST_CHUNK_ELEMS", 10_000)
+    monkeypatch.setattr(forms, "_bin_on_device", lambda elems: True)
+    monkeypatch.setattr(forms, "_HIST_CHUNK_ELEMS", 10_000)
     dev = _PackedDesign(X, 32)
     np.testing.assert_array_equal(np.asarray(host.binned),
                                   np.asarray(dev.binned))
@@ -72,8 +73,8 @@ def test_cpu_fits_stay_on_the_host():
 
 @pytest.mark.parametrize("backend, elems, on_device", [
     ("cpu", 100_000_000, False),
-    ("tpu", trees._DEVICE_BIN_MIN_ELEMS - 1, False),
-    ("tpu", trees._DEVICE_BIN_MIN_ELEMS, True),
+    ("tpu", forms._DEVICE_BIN_MIN_ELEMS - 1, False),
+    ("tpu", forms._DEVICE_BIN_MIN_ELEMS, True),
 ])
 def test_binning_place_follows_backend_and_size(
         monkeypatch, retired_tree_switches, backend, elems, on_device):
@@ -82,8 +83,8 @@ def test_binning_place_follows_backend_and_size(
     (``retired_tree_switches``) are not read."""
     import jax
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert trees._DEVICE_BIN_MIN_ELEMS == 4_000_000
-    assert trees._bin_on_device(elems) is on_device
+    assert forms._DEVICE_BIN_MIN_ELEMS == 4_000_000
+    assert forms._bin_on_device(elems) is on_device
 
 
 def test_device_fit_quality(monkeypatch):
@@ -98,9 +99,9 @@ def test_device_fit_quality(monkeypatch):
     est = GBTClassifier(num_rounds=5, max_depth=3)
     acc_host = float(np.mean(
         est.fit_arrays(X, y).predict_arrays(X).data == y))
-    monkeypatch.setattr(trees, "_bin_on_device", lambda elems: True)
-    trees._DESIGN_CACHE.clear()
+    monkeypatch.setattr(forms, "_bin_on_device", lambda elems: True)
+    design._DESIGN_CACHE.clear()
     acc_dev = float(np.mean(
         est.fit_arrays(X, y).predict_arrays(X).data == y))
-    trees._DESIGN_CACHE.clear()
+    design._DESIGN_CACHE.clear()
     assert acc_host > 0.9 and abs(acc_host - acc_dev) < 0.02

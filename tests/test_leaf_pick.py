@@ -1,16 +1,18 @@
 """A boosting round adds each row's leaf value to its margin
-(models/trees.py ``_TreeGrower.add_leaf_values``) in the form of the tree's
-node sums: a per-row gather under the ``scatter`` mode, the CPU default, and
-a select over the last level's slots (``_leaf_values_dense``) under the
-``matmul`` family, the accelerator default. The two must give the same bits:
-margins, and through them every later round's trees.
+(models/trees/grower.py ``_TreeGrower.add_leaf_values``) in the form of the
+tree's node sums: a per-row gather under the ``scatter`` mode, the CPU
+default, and a select over the last level's slots (``_leaf_values_dense``)
+under the ``matmul`` family, the accelerator default. The two must give the
+same bits: margins, and through them every later round's trees.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from transmogrifai_tpu.models import trees
+from transmogrifai_tpu.models.trees import bodies, grower
+from transmogrifai_tpu.models.trees.design import _design_args
+from tree_cases import spy_on
 
 
 def _gathered(vals, state, by_id):
@@ -27,7 +29,7 @@ def _table(n=200, d=6, seed=44, classes=2, dtype=np.float64):
     else:
         y = np.digitize(score, np.quantile(score, [1 / 3, 2 / 3]))
         y = y.astype(np.float64)
-    design, _ = trees._design_args(X.astype(dtype), 16)
+    design, _ = _design_args(X.astype(dtype), 16)
     return design[:4], jnp.asarray(y, design[3].dtype), rng
 
 
@@ -44,7 +46,7 @@ def _boosted_lanes(objective, depths, lanes, hist_rows=None, dtype=np.float64):
 
     def hp(v):
         return jnp.asarray(np.linspace(v, 1.5 * v, L), y.dtype)
-    return jax.jit(lambda: trees._gbt_body(
+    return jax.jit(lambda: bodies._gbt_body(
         *design, y, jax.random.PRNGKey(3), jnp.asarray(mask), hp(0.3),
         hp(1.0), jnp.zeros(L, y.dtype), hp(0.5), hp(0.8), depth=depths,
         num_rounds=3, objective=objective, hist_mode="matmul",
@@ -53,32 +55,32 @@ def _boosted_lanes(objective, depths, lanes, hist_rows=None, dtype=np.float64):
 
 def _fit(depth, gamma=0.0, dtype=np.float64):
     design, y, _ = _table(dtype=dtype)
-    trees._fit_gbt.clear_cache()
+    bodies._fit_gbt.clear_cache()
     try:
-        return trees._fit_gbt(
+        return bodies._fit_gbt(
             *design, y, jax.random.PRNGKey(5), depth=depth, num_rounds=3,
             step_size=0.3, reg_lambda=1.0, gamma=gamma,
             min_child_weight=0.5, subsample=0.9, objective="logistic",
             hist_mode="matmul")
     finally:
-        trees._fit_gbt.clear_cache()
+        bodies._fit_gbt.clear_cache()
 
 
 def _softmax(lanes):
     design, y, rng = _table(classes=3)
     if lanes is None:
-        trees._fit_gbt_softmax.clear_cache()
+        bodies._fit_gbt_softmax.clear_cache()
         try:
-            return trees._fit_gbt_softmax(
+            return bodies._fit_gbt_softmax(
                 *design, y, jax.random.PRNGKey(7), depth=4, num_rounds=2,
                 num_classes=3, step_size=0.3, reg_lambda=1.0, gamma=0.0,
                 min_child_weight=0.5, subsample=1.0, hist_mode="matmul")
         finally:
-            trees._fit_gbt_softmax.clear_cache()
+            bodies._fit_gbt_softmax.clear_cache()
     L, n = sum(lanes), y.shape[0]
     mask = (rng.random((L, n)) > 0.2).astype(y.dtype)
     ones = jnp.ones(L, y.dtype)
-    return jax.jit(lambda: trees._gbt_softmax_body(
+    return jax.jit(lambda: bodies._gbt_softmax_body(
         *design, y, jax.random.PRNGKey(7), jnp.asarray(mask), 0.3 * ones,
         ones, 0.0 * ones, 0.5 * ones, ones, depth=(2, 5), num_rounds=2,
         num_classes=3, hist_mode="matmul", lanes=lanes))()
@@ -108,13 +110,11 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_dense_pick_equals_gather(case, monkeypatch):
     if case == "lanes_compressed":
-        monkeypatch.setattr(trees, "_DEFAULT_NODE_CAP", 8)
-    before = trees.tree_pick_forms()
+        monkeypatch.setattr(grower, "_DEFAULT_NODE_CAP", 8)
+    picks = spy_on(monkeypatch, grower, "_leaf_values_dense")
     dense = jax.tree_util.tree_leaves(CASES[case]())
-    after = trees.tree_pick_forms()
-    assert after["dense"] > before["dense"]
-    assert after["gather"] == before["gather"]
-    monkeypatch.setattr(trees, "_leaf_values_dense", _gathered)
+    assert picks                        # the case traced the dense read
+    monkeypatch.setattr(grower, "_leaf_values_dense", _gathered)
     gathered = jax.tree_util.tree_leaves(CASES[case]())
     assert len(dense) == len(gathered)
     for a, b in zip(dense, gathered):
@@ -142,7 +142,7 @@ def test_dense_read_is_the_gather_bit_for_bit(level, dtype):
         node_of_slot = np.arange(C, dtype=np.int32)
     elif level == "compressed":
         C = 12
-        node_of_slot = np.full(C, trees._SLOT_SENTINEL, np.int32)
+        node_of_slot = np.full(C, grower._SLOT_SENTINEL, np.int32)
         node_of_slot[:9] = np.sort(rng.choice(2 ** (depth - 1), 9,
                                               replace=False))
     else:
@@ -157,11 +157,11 @@ def test_dense_read_is_the_gather_bit_for_bit(level, dtype):
     vals[1::5] = -0.0
     if level == "root":
         vals[:] = -0.0
-    state = trees._TreeState(
+    state = grower._TreeState(
         jnp.asarray(node), jnp.asarray(slot), jnp.asarray(node_of_slot),
         jnp.asarray(occupied), jnp.zeros(0, jnp.int32), jnp.zeros(0, dtype),
         jnp.asarray(went_right), None)
-    picked = np.asarray(trees._leaf_values_dense(
+    picked = np.asarray(grower._leaf_values_dense(
         jnp.asarray(vals), state, level == "identity"))
     assert picked.dtype == dtype
     np.testing.assert_array_equal(picked.view(np.uint8),

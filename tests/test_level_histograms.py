@@ -1,6 +1,6 @@
-"""The level histograms (models/trees._level_histograms) under every path
-the resolver (``_hist_mode``) can choose: ``scatter`` (fused segment sums),
-``matmul`` (one contraction over the whole bin indicator) and
+"""The level histograms (models/trees/grower.py ``_level_histograms``) under
+every path the resolver (``_hist_mode``) can choose: ``scatter`` (fused
+segment sums), ``matmul`` (one contraction over the whole bin indicator) and
 ``matmul_chunk`` (the same contraction, the indicator rebuilt per bin
 block), each against the contraction written out in NumPy.
 ``_HIST_CHUNK_ELEMS`` is patched small enough that ``scatter`` runs several
@@ -10,7 +10,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from transmogrifai_tpu.models import trees
+from transmogrifai_tpu.models.trees import forms, grower
 
 MODES = ("scatter", "matmul", "matmul_chunk")
 
@@ -46,9 +46,9 @@ def _reference(packed, slot, stats, num_slots, total_bins):
 def _histograms(mode, packed, feat_of, slot, stats, num_slots, total_bins):
     packed, feat_of, stats = (jnp.asarray(packed), jnp.asarray(feat_of),
                               jnp.asarray(stats))
-    bin_oh = (trees._bin_indicator(packed, total_bins, stats.dtype, feat_of)
+    bin_oh = (grower._bin_indicator(packed, total_bins, stats.dtype, feat_of)
               if mode == "matmul" else None)
-    return np.asarray(trees._level_histograms(
+    return np.asarray(grower._level_histograms(
         packed, jnp.asarray(slot), stats, num_slots, total_bins, bin_oh,
         mode=mode, feat_of=feat_of))
 
@@ -58,7 +58,7 @@ def _histograms(mode, packed, feat_of, slot, stats, num_slots, total_bins):
 def test_matches_numpy_contraction(monkeypatch, mode, n, total_bins, d,
                                    num_slots, s_dim):
     rng = np.random.default_rng(n + total_bins)
-    monkeypatch.setattr(trees, "_HIST_CHUNK_ELEMS", 8 * n)
+    monkeypatch.setattr(forms, "_HIST_CHUNK_ELEMS", 8 * n)
     packed, feat_of = _packed_design(rng, n, total_bins, d)
     slot = rng.integers(0, num_slots, size=n).astype(np.int32)
     stats = rng.normal(size=(n, s_dim))
@@ -76,7 +76,7 @@ def test_zero_stats_rows_are_inert(monkeypatch, mode):
     nothing, whatever slot and bins the row holds."""
     rng = np.random.default_rng(0)
     n, total_bins, d, num_slots, s_dim = 100, 20, 4, 4, 2
-    monkeypatch.setattr(trees, "_HIST_CHUNK_ELEMS", 8 * n)
+    monkeypatch.setattr(forms, "_HIST_CHUNK_ELEMS", 8 * n)
     packed, feat_of = _packed_design(rng, n, total_bins, d)
     slot = rng.integers(0, num_slots, size=n).astype(np.int32)
     stats = rng.normal(size=(n, s_dim))

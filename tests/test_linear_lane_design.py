@@ -208,10 +208,10 @@ def test_a_lane_in_any_company(kind, form, few, fresh_kernels, monkeypatch):
 
 
 def test_fetch_span_carries_the_lane_design():
-    """The linear fold-grid functions' ``search.fetch`` span names the
-    forms of the lanes' designs (``design_shared`` / ``design_per_lane``,
-    traced cores so far, read at open and close; a CPU's per-lane) and the
-    call's ``lanes``."""
+    """The linear fold-grid functions' ``search.fetch`` span names the form
+    of the lanes' design its program holds (``design``, from the pure
+    ``_lane_design_form``: a CPU's ``per_lane``) and the call's
+    ``lanes``."""
     X, labels, masks, _ = table()
     package_trace.configure(True)
     package_trace.reset()
@@ -226,5 +226,5 @@ def test_fetch_span_carries_the_lane_design():
     assert len(spans) == 1
     attrs = spans[0]["attrs"]
     assert attrs["lanes"] == FOLDS * 2
-    assert attrs["design_per_lane"] >= 1 and "design_shared" in attrs
-    assert set(linear.lane_designs()) == {"shared", "per_lane"}
+    assert attrs["design"] == "per_lane"
+    assert not [k for k in attrs if k.startswith("design_")]

@@ -1,4 +1,4 @@
-"""K-class softmax boosting (models/trees._gbt_softmax_body).
+"""K-class softmax boosting (models/trees/bodies.py ``_gbt_softmax_body``).
 
 The reference reaches multiclass boosting through xgboost4j's
 multi:softprob (OpXGBoostClassifier.scala:47); MLlib GBT itself is
@@ -161,14 +161,14 @@ class TestSoftmaxFoldGrid:
         """Softmax lanes under the ``blocks`` depth mode come back at their
         own depth, bit-equal to the per-depth ``static`` programs' (the
         (R, K, H) heaps and (R, K, L) leaves of each block)."""
-        from transmogrifai_tpu.models import trees
+        from transmogrifai_tpu.models.trees import forms
         from transmogrifai_tpu.models.trees import XGBoostClassifier
         X, y, masks, _, _ = self._data()
         est = XGBoostClassifier(num_round=3)
         grid = [{"max_depth": 2}, {"max_depth": 4}]
-        monkeypatch.setattr(trees, "_depth_mode", lambda: "static")
+        monkeypatch.setattr(forms, "_depth_mode", lambda: "static")
         ms = est.fit_fold_grid_arrays(X, y, masks[:1], grid)
-        monkeypatch.setattr(trees, "_depth_mode", lambda: "blocks")
+        monkeypatch.setattr(forms, "_depth_mode", lambda: "blocks")
         mk = est.fit_fold_grid_arrays(X, y, masks[:1], grid)
         for gi in range(2):
             np.testing.assert_array_equal(ms[0][gi].feats, mk[0][gi].feats)

@@ -439,25 +439,17 @@ class TestTrainSpans:
             assert "search.family" not in ancestors(s)
         (refit,) = named("search.refit")
         assert refit["attrs"]["family"] == "GBTClassifier"
-        # a fetch says which routing form the tree programs traced so far
-        # hold (trees.tree_route_forms; the CPU's scatter mode gathers),
-        # its own program's included: that one traced inside the span
+        # a fetch says which histogram mode its own program was compiled
+        # with (the CPU's ``scatter``: its levels gather to route, keep
+        # segment_sum for their node sums and gather their rows' leaf
+        # values), and carries no count of the process's traces
         for s in named("search.fetch"):
-            assert s["attrs"]["route_gather"] >= 1
-            assert s["attrs"]["route_dense"] >= 0
-            # and which form their node sums took (trees.tree_sum_forms;
-            # the CPU's scatter mode keeps segment_sum)
-            assert s["attrs"]["sums_scatter"] >= 1
-            assert s["attrs"]["sums_dense"] >= 0
-            # and how their boosting rounds read the rows' leaf values
-            # (trees.tree_pick_forms; the CPU's scatter mode gathers)
-            assert s["attrs"]["pick_gather"] >= 1
-            assert s["attrs"]["pick_dense"] >= 0
-        # the winner's scoring says which form its walk took
-        # (trees.tree_traverse_forms; a CPU gathers), its own included
+            assert s["attrs"]["hist_mode"] == "scatter"
+            assert not [k for k in s["attrs"] if k.startswith(
+                ("route_", "sums_", "pick_"))]
+        # the winner's scoring is a plain span (a CPU walks by gathers)
         (train_eval,) = named("search.train_eval")
-        assert train_eval["attrs"]["traverse_gather"] >= 1
-        assert train_eval["attrs"]["traverse_dense"] >= 0
+        assert train_eval["attrs"] == {}
         assert all(s["dur"] is not None for s in spans)
 
     def test_scoring_spans_nest_under_guarded(self, trained):

@@ -36,6 +36,8 @@ from transmogrifai_tpu.models.glm import (                        # noqa: E402
 from transmogrifai_tpu.models.linear import LinearRegression      # noqa: E402
 from transmogrifai_tpu.models.trees import (                      # noqa: E402
     DecisionTreeRegressor, GBTRegressor, RandomForestRegressor)
+from transmogrifai_tpu.models.trees import (                      # noqa: E402
+    fold_grid, forms, grower)
 from transmogrifai_tpu.parallel import cv                         # noqa: E402
 from transmogrifai_tpu.runtime import telemetry                   # noqa: E402
 
@@ -115,7 +117,7 @@ def test_variance_statistics_are_two_exact_pieces():
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.poisson(1.0, 500).astype(np.float32))
     yc = jnp.asarray((11.0 * rng.normal(size=500)).astype(np.float32))
-    stats = np.asarray(trees._variance_stats(w, yc))
+    stats = np.asarray(grower._variance_stats(w, yc))
     v = np.asarray(w * yc)
     np.testing.assert_array_equal(stats[:, 1] + stats[:, 2], v)
     np.testing.assert_array_equal(stats[:, 1], to_bfloat16(stats[:, 1]))
@@ -131,7 +133,7 @@ def test_variance_statistics_are_two_exact_pieces():
             ) / s[:, 0].sum()
     sums = [jnp.asarray(stats[m].sum(0, dtype=np.float64))
             for m in (left, ~left, np.ones(500, bool))]
-    got = trees._variance_gain(1.0)(*sums)
+    got = grower._variance_gain(1.0)(*sums)
     assert float(got) == pytest.approx(want, rel=1e-6)
 
 
@@ -160,7 +162,7 @@ def test_regression_tree_under_the_accelerators_histogram(monkeypatch):
     """The ``matmul`` histogram mode (the chip's) grows the plain tree too:
     on a CPU its contraction adds in float64, so what is checked is the
     layout of the statistics, not the bf16 pass."""
-    monkeypatch.setattr(trees, "_hist_mode", lambda n, tb: "matmul")
+    monkeypatch.setattr(forms, "_hist_mode", lambda n, tb: "matmul")
     X, y = table(1500, seed=6)
     kwargs = dict(max_depth=6, min_instances_per_node=10, min_info_gain=0.001)
     model = DecisionTreeRegressor(**kwargs).fit_arrays(X, y)
@@ -382,9 +384,9 @@ def test_only_the_boosted_program_is_named_jit_batched():
     """Of the pool's four families' fold-grid kernels, the boosted one alone
     bears the name every ``search_*_s`` / ``pool_gbt_s`` reader sums."""
     names = {
-        "gbt": trees._gbt_eval_kernel(((3,), 2, "squared", "scatter"), SPEC,
-                                      None, True).__name__,
-        "forest": trees._forest_eval_kernel(
+        "gbt": fold_grid._gbt_eval_kernel(
+            ((3,), 2, "squared", "scatter"), SPEC, None, True).__name__,
+        "forest": fold_grid._forest_eval_kernel(
             ("reg", (3,), 0, 2, None, None, "", True, "scatter"), SPEC, None,
             True).__name__,
         "linear": cv._local_eval_kernel(cv._kernel_cfg(

@@ -1,5 +1,5 @@
 """Device time by named scope (ISSUE 26): the ``jax.named_scope`` names of
-``models/trees.py`` reach the compiled HLO under unchanged program names and
+``models/trees/`` reach the compiled HLO under unchanged program names and
 move no number; ``benchmark/trace/scopes.py`` turns ops with scope paths into
 seconds per scope; the seven new readers read that, or the package's spans,
 and return None where there is nothing to read.
@@ -25,6 +25,10 @@ from benchmark.layer_metrics import (                 # noqa: E402
     tail_traverse_s, winner_tail_s_per_train)
 from benchmark.trace import scopes                    # noqa: E402
 from transmogrifai_tpu.models import GBTClassifier, trees   # noqa: E402
+from transmogrifai_tpu.models.trees import (          # noqa: E402
+    bodies, fold_grid, forms, grower)
+from transmogrifai_tpu.models.trees.design import _design_args  # noqa: E402
+from tree_cases import spy_on                         # noqa: E402
 
 
 def _components(hlo_text):
@@ -52,8 +56,8 @@ def fit_gbt_hlo():
     rng = np.random.default_rng(0)
     X = rng.normal(size=(24, 5))
     y = (X[:, 0] > 0).astype(np.float64)
-    design, _ = trees._design_args(X, 8)
-    return trees._fit_gbt.lower(
+    design, _ = _design_args(X, 8)
+    return bodies._fit_gbt.lower(
         *design[:4], jnp.asarray(y), jax.random.PRNGKey(0), depth=6,
         num_rounds=2, step_size=0.1, reg_lambda=1.0, gamma=0.0,
         min_child_weight=1.0, subsample=1.0, objective="logistic",
@@ -74,7 +78,7 @@ def fold_grid_hlo():
     X_val = np.stack([X[:32], X[32:]])
     y_val = np.stack([y[:32], y[32:]])
     captured = {}
-    real = trees._gbt_eval_kernel
+    real = fold_grid._gbt_eval_kernel
 
     def spy(*key):
         fn = real(*key)
@@ -85,8 +89,8 @@ def fold_grid_hlo():
         return call
 
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(trees, "_gbt_eval_kernel", spy)
-        patch.setattr(trees, "_hist_mode", lambda n, tb: "matmul")
+        patch.setattr(fold_grid, "_gbt_eval_kernel", spy)
+        patch.setattr(forms, "_hist_mode", lambda n, tb: "matmul")
         metrics = GBTClassifier(num_rounds=2, max_depth=2, max_bins=8
                                 ).eval_fold_grid_arrays(
             X, y, masks, [{"gamma": 0.0}, {"gamma": 0.1}], X_val, y_val,
@@ -136,7 +140,7 @@ def test_no_gather_under_tree_route(program, request):
     hlo = request.getfixturevalue(program)
     routed = [line for line in hlo.splitlines() if "tree.route" in line]
     # ``vmap(tree.route)``: the scope is the first opened under the ``vmap``
-    # over the lanes still growing (trees._grow_blocks)
+    # over the lanes still growing (grower._grow_blocks)
     assert any(re.search(r"tree\.route\)?/reduce_or", line)
                for line in routed)
     assert not [line for line in routed
@@ -144,23 +148,22 @@ def test_no_gather_under_tree_route(program, request):
 
 
 def test_route_form_follows_hist_mode_and_retraces(monkeypatch):
+    """The routing form of every tree grower a fit traces (a spy on the
+    resolver ``forms._route_form`` records its answers)."""
     rng = np.random.default_rng(27)
     X = rng.normal(size=(80, 4))
     y = (X[:, 0] > 0).astype(np.float64)
     seen = []
     for mode in ("scatter", "matmul", "scatter", "matmul_chunk"):
-        monkeypatch.setattr(trees, "_hist_mode", lambda n, tb, m=mode: m)
-        before = trees.tree_route_forms()
-        GBTClassifier(num_rounds=2, max_depth=2, max_bins=8).fit_arrays(X, y)
-        after = trees.tree_route_forms()
-        seen.append({k: after[k] - before[k] for k in after})
+        monkeypatch.setattr(forms, "_hist_mode", lambda n, tb, m=mode: m)
+        with pytest.MonkeyPatch.context() as patch:
+            routes = spy_on(patch, forms, "_route_form")
+            GBTClassifier(num_rounds=2, max_depth=2, max_bins=8
+                          ).fit_arrays(X, y)
+        seen.append(set(routes))
     # a fit traces its tree grower under its own mode's form and no other;
     # the third fit finds the first one's program and traces nothing
-    assert seen[0]["gather"] >= 1 and seen[0]["dense"] == 0
-    assert seen[1]["dense"] >= 1 and seen[1]["gather"] == 0
-    assert seen[2] == {"dense": 0, "gather": 0}
-    assert seen[3]["dense"] >= 1 and seen[3]["gather"] == 0
-    assert set(trees.tree_route_forms()) == {"dense", "gather"}
+    assert seen == [{"gather"}, {"dense"}, set(), {"dense"}]
 
 
 @pytest.mark.parametrize("program, rows, placements", [
@@ -208,63 +211,67 @@ def test_no_row_gather_under_gbt_pick(program, table_reads, request):
 
 
 def test_pick_form_follows_hist_mode_and_retraces(monkeypatch):
-    """``tree_pick_forms`` counts one traced pick a round body by form: the
-    gather under a CPU's default ``scatter`` mode, the dense read under the
-    ``matmul`` family."""
+    """One traced pick a round body (a spy on
+    ``_TreeGrower.add_leaf_values``), read densely (a spy on
+    ``_leaf_values_dense``) under the ``matmul`` family and by the gather
+    under a CPU's default ``scatter`` mode."""
     rng = np.random.default_rng(44)
     X = rng.normal(size=(80, 4))
     y = (X[:, 2] > 0).astype(np.float64)
     seen = []
     for mode in (None, "matmul", None, "matmul_chunk"):
         if mode is not None:
-            monkeypatch.setattr(trees, "_hist_mode", lambda n, tb, m=mode: m)
+            monkeypatch.setattr(forms, "_hist_mode", lambda n, tb, m=mode: m)
         else:
             monkeypatch.undo()
-        before = trees.tree_pick_forms()
-        GBTClassifier(num_rounds=3, max_depth=3, max_bins=8).fit_arrays(X, y)
-        after = trees.tree_pick_forms()
-        seen.append({k: after[k] - before[k] for k in after})
+        with pytest.MonkeyPatch.context() as patch:
+            picks = spy_on(patch, grower._TreeGrower, "add_leaf_values")
+            dense = spy_on(patch, grower, "_leaf_values_dense")
+            GBTClassifier(num_rounds=3, max_depth=3, max_bins=8
+                          ).fit_arrays(X, y)
+        seen.append({"dense": len(dense), "gather": len(picks) - len(dense)})
     # one fit traces one round body, so one pick; the third fit finds the
     # first one's program and traces nothing
     assert seen[0] == {"dense": 0, "gather": 1}
     assert seen[1] == {"dense": 1, "gather": 0}
     assert seen[2] == {"dense": 0, "gather": 0}
     assert seen[3] == {"dense": 1, "gather": 0}
-    assert set(trees.tree_pick_forms()) == {"dense", "gather"}
 
 
 def test_sum_form_follows_hist_mode_and_retraces(monkeypatch):
+    """The node-sum form of every tree grower a fit traces (a spy on the
+    resolver ``forms._sums_form`` records its answers)."""
     rng = np.random.default_rng(31)
     X = rng.normal(size=(80, 4))
     y = (X[:, 1] > 0).astype(np.float64)
     seen = []
     for mode in ("scatter", "matmul", "scatter", "matmul_chunk"):
-        monkeypatch.setattr(trees, "_hist_mode", lambda n, tb, m=mode: m)
-        before = trees.tree_sum_forms()
-        GBTClassifier(num_rounds=3, max_depth=2, max_bins=8).fit_arrays(X, y)
-        after = trees.tree_sum_forms()
-        seen.append({k: after[k] - before[k] for k in after})
+        monkeypatch.setattr(forms, "_hist_mode", lambda n, tb, m=mode: m)
+        with pytest.MonkeyPatch.context() as patch:
+            sums = spy_on(patch, forms, "_sums_form")
+            GBTClassifier(num_rounds=3, max_depth=2, max_bins=8
+                          ).fit_arrays(X, y)
+        seen.append(set(sums))
     # a fit traces its tree grower under its own mode's form and no other;
     # the third fit finds the first one's program and traces nothing
-    assert seen[0]["scatter"] >= 1 and seen[0]["dense"] == 0
-    assert seen[1]["dense"] >= 1 and seen[1]["scatter"] == 0
-    assert seen[2] == {"dense": 0, "scatter": 0}
-    assert seen[3]["dense"] >= 1 and seen[3]["scatter"] == 0
-    assert set(trees.tree_sum_forms()) == {"dense", "scatter"}
+    assert seen == [{"scatter"}, {"dense"}, set(), {"dense"}]
 
 
 def test_every_scope_is_used_in_trees():
     """``trees.SCOPES`` is the one list the scope readers take: every name
-    on it is opened somewhere in the four modules that build the fit and
-    fold-grid programs, and they open no other (ISSUE 28 added the linear
+    on it is opened somewhere in the modules that build the fit and
+    fold-grid programs (the tree package's and four others), and they open
+    no other (ISSUE 28 added the linear
     family's in ``models/linear.py`` and ``parallel/cv.py``, ISSUE 32 the
     naive Bayes program's in ``models/bayes.py``, ISSUE 34 the IRLS
     program's ``fg.glm``, ``glm.gram`` and ``glm.solve`` in
     ``models/glm.py``)."""
+    import glob
     from transmogrifai_tpu.models import bayes, glm, linear
     from transmogrifai_tpu.parallel import cv
-    source = "".join(open(module.__file__).read()
-                     for module in (trees, linear, cv, bayes, glm))
+    package = glob.glob(os.path.join(os.path.dirname(trees.__file__), "*.py"))
+    source = "".join(open(path).read() for path in sorted(package) + [
+        module.__file__ for module in (linear, cv, bayes, glm)])
     for scope in trees.SCOPES:
         assert f'jax.named_scope("{scope}")' in source
     assert len(set(trees.SCOPES)) == len(trees.SCOPES)

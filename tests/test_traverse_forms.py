@@ -1,4 +1,4 @@
-"""The walk of finished heaps has two forms (ISSUE 40, models/trees.py
+"""The walk of finished heaps has two forms (ISSUE 40, models/trees/forms.py
 ``_traverse_form``): per-row gathers on a CPU, selects over a level's nodes
 and over the columns on an accelerator. They must give every row the same
 leaf: the same integers, not close ones.
@@ -10,15 +10,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from transmogrifai_tpu.models import (GBTClassifier, RandomForestClassifier,
-                                      trees)
+from transmogrifai_tpu.models import GBTClassifier, RandomForestClassifier
+from transmogrifai_tpu.models import trees
+from transmogrifai_tpu.models.trees import forms, walk
+from tree_cases import spy_on
 
 
 def _walk(form, X, feats, thrs, depth, monkeypatch):
     """(T, n) leaves of ``_traverse`` in ``form``, traced anew."""
-    monkeypatch.setattr(trees, "_traverse_form", lambda d, depth: form)
+    monkeypatch.setattr(forms, "_traverse_form", lambda d, depth: form)
     return np.asarray(jax.jit(jax.vmap(
-        lambda f, t: trees._traverse(X, f, t, depth)))(feats, thrs))
+        lambda f, t: walk._traverse(X, f, t, depth)))(feats, thrs))
 
 
 def _heaps(rng, T, depth, d, unsplit=0.2):
@@ -95,10 +97,10 @@ def test_dense_walk_edge_cases(case, monkeypatch):
 def test_resolver_by_width_and_both_forms_equal_there(side, monkeypatch):
     """``d`` on both sides of the width crossover: the resolver answers by
     it on an accelerator, and either form walks the same rows alike."""
-    monkeypatch.setattr(trees, "_TRAVERSE_DENSE_MAX_D", 8)
+    monkeypatch.setattr(forms, "_TRAVERSE_DENSE_MAX_D", 8)
     d = 8 if side == "at_the_cap" else 9
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert trees._traverse_form(d, 6) == (
+    assert forms._traverse_form(d, 6) == (
         "dense" if side == "at_the_cap" else "gather")
     monkeypatch.undo()
     rng = np.random.default_rng(d)
@@ -114,36 +116,41 @@ def test_resolver_by_width_and_both_forms_equal_there(side, monkeypatch):
     ("tpu", 200, 6, "dense"),
     ("tpu", 200, 12, "dense"),
     ("gpu", 200, 6, "dense"),
-    ("tpu", trees._TRAVERSE_DENSE_MAX_D, trees._TRAVERSE_DENSE_MAX_DEPTH,
+    ("tpu", forms._TRAVERSE_DENSE_MAX_D, forms._TRAVERSE_DENSE_MAX_DEPTH,
      "dense"),
-    ("tpu", trees._TRAVERSE_DENSE_MAX_D + 1, 6, "gather"),
-    ("tpu", 200, trees._TRAVERSE_DENSE_MAX_DEPTH + 1, "gather"),
+    ("tpu", forms._TRAVERSE_DENSE_MAX_D + 1, 6, "gather"),
+    ("tpu", 200, forms._TRAVERSE_DENSE_MAX_DEPTH + 1, "gather"),
 ])
 def test_resolver_by_backend_and_size(backend, d, depth, form, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert trees._traverse_form(d, depth) == form
+    assert forms._traverse_form(d, depth) == form
 
 
 def test_the_suite_walks_by_gather():
     # this suite runs on a CPU: every caller of _traverse gathers here
-    assert trees._traverse_form(200, 6) == "gather"
+    assert forms._traverse_form(200, 6) == "gather"
 
 
 def test_counter_counts_one_traced_walk(monkeypatch):
+    """One program traces its walk once, in the form the resolver gives:
+    a spy on ``_traverse`` counts the traces of two calls, and the gather
+    form holds more gathers than the dense one."""
     rng = np.random.default_rng(5)
     X = jnp.asarray(_table(rng, 50, 4))
     feats, thrs = _heaps(rng, 3, 3, 4)
+    gathers = {}
     for form in ("dense", "gather"):
-        monkeypatch.setattr(trees, "_traverse_form", lambda d, dp, f=form: f)
-        walk = jax.jit(jax.vmap(lambda f, t: trees._traverse(X, f, t, 3)))
-        before = trees.tree_traverse_forms()
-        walk(feats, thrs)
-        walk(feats, thrs)                  # the same program: no trace
-        after = trees.tree_traverse_forms()
-        other = "gather" if form == "dense" else "dense"
-        assert after[form] == before[form] + 1
-        assert after[other] == before[other]
-    assert set(trees.tree_traverse_forms()) == {"dense", "gather"}
+        monkeypatch.setattr(forms, "_traverse_form", lambda d, dp, f=form: f)
+        traced = spy_on(monkeypatch, walk, "_traverse")
+        program = jax.jit(jax.vmap(lambda f, t: walk._traverse(X, f, t, 3)))
+        program(feats, thrs)
+        program(feats, thrs)               # the same program: no trace
+        assert len(traced) == 1
+        gathers[form] = str(jax.make_jaxpr(
+            lambda f, t: walk._traverse(X, f, t, 3))(
+                feats[0], thrs[0])).count("gather[")
+    # three a level: the heaps' feature and threshold, the row's value
+    assert gathers["gather"] == 3 * 3 > gathers["dense"]
 
 
 def _xy(n=240, d=6, seed=11):
@@ -155,12 +162,12 @@ def _xy(n=240, d=6, seed=11):
 
 
 def _in_form(form, monkeypatch, score):
-    monkeypatch.setattr(trees, "_traverse_form", lambda d, depth: form)
-    trees._predict_leaves.clear_cache()
+    monkeypatch.setattr(forms, "_traverse_form", lambda d, depth: form)
+    walk._predict_leaves.clear_cache()
     try:
         return np.asarray(score())
     finally:
-        trees._predict_leaves.clear_cache()
+        walk._predict_leaves.clear_cache()
 
 
 @pytest.mark.parametrize("scorer", ["gbt_predict_raw", "forest_raw_arrays"])
@@ -203,10 +210,10 @@ def test_predict_program_keeps_its_name_and_carries_the_scope(
     rng = np.random.default_rng(3)
     X = jnp.asarray(_table(rng, 40, 5))
     feats, thrs = _heaps(rng, 2, 3, 5)
-    monkeypatch.setattr(trees, "_traverse_form", lambda d, depth: form)
-    trees._predict_leaves.clear_cache()
-    hlo = trees._predict_leaves.lower(X, feats, thrs, 3).compile().as_text()
-    trees._predict_leaves.clear_cache()
+    monkeypatch.setattr(forms, "_traverse_form", lambda d, depth: form)
+    walk._predict_leaves.clear_cache()
+    hlo = walk._predict_leaves.lower(X, feats, thrs, 3).compile().as_text()
+    walk._predict_leaves.clear_cache()
     assert re.search(r"HloModule ([\w.\-]+)", hlo).group(1) \
         == "jit__predict_leaves"
     assert "tree.traverse" in _components(hlo)

@@ -1,11 +1,11 @@
 """Row-sharded (data-parallel) tree training parity.
 
-The promised psum-of-histograms path (models/trees.py module docstring;
+The promised psum-of-histograms path (models/trees package docstring;
 SURVEY §2.9 Rabit-allreduce mapping): a fit whose rows are sharded over
 the virtual 8-device mesh must reproduce the single-device fit exactly
 — same splits, same thresholds, same leaves — because every cross-row
 reduction is a psum of the same partial sums and the bootstrap draws
-are shard-position-stable (models/trees._row_draw).
+are shard-position-stable (models/trees/bodies.py ``_row_draw``).
 """
 import numpy as np
 import pytest
@@ -55,7 +55,7 @@ class TestShardedForestParity:
         # depth > 9 carries compressed slots from level to level (the
         # identity fast path stops covering every level past the slot
         # cap): the columns' occupancy is psum-ed over the row shards
-        # (trees._carry_slots), so every shard gives a node the same slot
+        # (grower._carry_slots), so every shard gives a node the same slot
         X, yc, _ = _data(n=960)
         est = RandomForestClassifier(num_trees=3, max_depth=max_depth,
                                      seed=2, min_instances_per_node=1)
@@ -115,17 +115,17 @@ class TestVmappedTreeBlocks:
         be equal (same per-tree keys, independent lanes). The block size
         is read while tracing, so the jitted fit's cache is dropped on
         either side of the switch."""
-        from transmogrifai_tpu.models import trees
+        from transmogrifai_tpu.models.trees import bodies
         X, yc, _ = _data(n=320)
         est = RandomForestClassifier(num_trees=12, max_depth=4, seed=9)
         scan_model = est.fit_arrays(X, yc)
-        monkeypatch.setattr(trees, "_tree_block_size",
+        monkeypatch.setattr(bodies, "_tree_block_size",
                             lambda *args: 5)        # 12 trees: a ragged tail
-        trees._fit_forest_classifier.clear_cache()
+        bodies._fit_forest_classifier.clear_cache()
         try:
             block_model = est.fit_arrays(X, yc)
         finally:
-            trees._fit_forest_classifier.clear_cache()
+            bodies._fit_forest_classifier.clear_cache()
         np.testing.assert_array_equal(block_model.feats,
                                       scan_model.feats)
         np.testing.assert_allclose(block_model.leaves,
@@ -139,7 +139,7 @@ class TestVmappedTreeBlocks:
         as the fixed budget holds. No variable overrides either
         (``retired_tree_switches``)."""
         import jax
-        from transmogrifai_tpu.models.trees import _tree_block_size
+        from transmogrifai_tpu.models.trees.bodies import _tree_block_size
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
         size = _tree_block_size(1_000, 100, 4, 2, 50, "matmul", False)
         assert (size > 1) is blocks

@@ -252,11 +252,12 @@ def test_r5_tree_flags_compose_end_to_end(rng, monkeypatch):
     ALL of them on, plus an in-search balancer, still trains, scores and
     reaches sane quality. Combinations are where path interactions
     regress (each path's own parity is covered by its unit tests)."""
-    from transmogrifai_tpu.models import GBTClassifier, trees
+    from transmogrifai_tpu.models import GBTClassifier
+    from transmogrifai_tpu.models.trees import forms
     from transmogrifai_tpu.selector.splitters import DataBalancer
-    monkeypatch.setattr(trees, "_depth_mode", lambda: "blocks")
+    monkeypatch.setattr(forms, "_depth_mode", lambda: "blocks")
     monkeypatch.setenv("TX_TREE_EDGES", "fold")
-    monkeypatch.setattr(trees, "_hist_mode", lambda n, tb: "scatter+sub")
+    monkeypatch.setattr(forms, "_hist_mode", lambda n, tb: "scatter+sub")
     recs = []
     for i in range(400):
         y = float(rng.random() < 0.25)

@@ -51,7 +51,7 @@ __all__ = ["SanityChecker", "SanityCheckerModel", "SanityCheckerSummary",
 MAX_LABEL_CARDINALITY = 100
 
 #: ``jax.named_scope`` names of the statistics programs, as
-#: ``models/trees.SCOPES`` names the tree kernels' (docs/observability.md):
+#: ``models/trees`` ``SCOPES`` names the tree kernels' (docs/observability.md):
 #: the moments and label correlation of every column, and the contraction
 #: that counts the indicator columns' contingency tables on the device
 SCOPES = ("sanity.stats", "sanity.contingency")

@@ -696,7 +696,7 @@ class _Visitor(ast.NodeVisitor):
         registered knob parameter with a hardcoded numeric default
         bypasses the TuningPolicy resolution path. Scope discipline:
         the spelling only means the knob in its consumer layer
-        (``eta`` in models/trees.py is a GBT learning rate, legal)."""
+        (``eta`` in models/trees/ is a GBT learning rate, legal)."""
         if self.tuning_path:
             return
         import re

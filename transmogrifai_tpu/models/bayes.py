@@ -193,13 +193,12 @@ class NaiveBayes(Predictor):
             cand = self.with_params(**p)
             groups.setdefault(cand.model_type, []).append((gi, cand))
         X_j, y_j = jnp.asarray(X), jnp.asarray(y)
-        from ..parallel.mesh import to_host
-        from .trees import _pad_candidates
+        from ..parallel.mesh import pad_candidates, to_host
         for model_type, members in groups.items():
             gk = len(members)
             sm = np.tile([float(c.smoothing) for _, c in members], F)
             masks_c = np.repeat(masks, gk, axis=0)   # fold-major
-            (masks_c, sm), _ = _pad_candidates(
+            (masks_c, sm), _ = pad_candidates(
                 mesh, [masks_c, sm], masks_c.shape[1])
             pi, theta = _nb_fit_kernel(k, model_type, mesh)(
                 jnp.asarray(masks_c), jnp.asarray(sm), X_j, y_j)
@@ -243,14 +242,13 @@ class NaiveBayes(Predictor):
         X_j, y_j = jnp.asarray(X), jnp.asarray(y)
         Xv_j = jnp.asarray(np.asarray(X_val, dtype=np.float64))
         yv_j = jnp.asarray(np.asarray(y_val, dtype=np.float64))
-        from ..parallel.mesh import to_host
-        from .trees import _pad_candidates
+        from ..parallel.mesh import pad_candidates, to_host
         for model_type, members in groups.items():
             gk = len(members)
             sm = np.tile([float(c.smoothing) for _, c in members], F)
             masks_c = np.repeat(masks, gk, axis=0)   # fold-major
             fidx = np.repeat(np.arange(F, dtype=np.int32), gk)
-            (masks_c, sm), count = _pad_candidates(
+            (masks_c, sm), count = pad_candidates(
                 mesh, [masks_c, sm], masks_c.shape[1])
             fidx = np.concatenate(
                 [fidx, np.zeros(len(sm) - count, dtype=np.int32)])

@@ -331,7 +331,7 @@ class GeneralizedLinearRegression(Predictor):
         the fit and eval paths (change together): yields per static
         group (key, members, masks_c, regs, vps, fidx, count) with the
         candidate axis padded to the mesh shard count when sharding."""
-        from .trees import _pad_candidates
+        from ..parallel.mesh import pad_candidates
         grid, groups = self._grid_groups(grid)
         masks = np.asarray(masks, dtype=np.float64)
         F = masks.shape[0]
@@ -343,7 +343,7 @@ class GeneralizedLinearRegression(Predictor):
                           F)
             masks_c = np.repeat(masks, gk, axis=0)   # fold-major
             fidx = np.repeat(np.arange(F, dtype=np.int32), gk)
-            (masks_c, regs, vps), count = _pad_candidates(
+            (masks_c, regs, vps), count = pad_candidates(
                 mesh, [masks_c, regs, vps], masks_c.shape[1])
             fidx = np.concatenate(
                 [fidx, np.zeros(len(regs) - count, dtype=np.int32)])

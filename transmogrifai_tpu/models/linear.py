@@ -31,7 +31,7 @@ from .solvers import design_lipschitz, fista_minimize, lbfgs_minimize
 
 __all__ = ["LogisticRegression", "LogisticRegressionModel",
            "LinearRegression", "LinearRegressionModel",
-           "LinearSVC", "LinearSVCModel", "lane_designs"]
+           "LinearSVC", "LinearSVCModel"]
 
 # ---------------------------------------------------------------------------
 # shared weighted-fit cores
@@ -86,11 +86,6 @@ def _weighted_standardize(X, w, axis_name=None):
     return (X - mu) / safe, mu, safe, wsum
 
 
-#: how many traced linear cores built their lane's design in each form (see
-#: _lane_design_form and lane_designs)
-_LANE_DESIGNS = {"shared": 0, "per_lane": 0}
-
-
 def _lane_design_form() -> str:
     """How a linear core builds its lane's design: "shared" (one
     standardized matrix for every lane of a program, the lane's fold
@@ -103,14 +98,6 @@ def _lane_design_form() -> str:
     search mesh's shards (``resolve_search_mesh``); "shared" on an
     accelerator. The two forms agree to rounding."""
     return "per_lane" if jax.default_backend() == "cpu" else "shared"
-
-
-def lane_designs() -> dict:
-    """Traced linear cores so far in this process by the form of their
-    lane's design, ``{"shared": k, "per_lane": m}`` (see
-    _lane_design_form): the record of which path the compiled fold-grid
-    programs hold."""
-    return dict(_LANE_DESIGNS)
 
 
 class _LaneDesign(NamedTuple):
@@ -177,7 +164,6 @@ def _lane_design(X, w, standardize: bool, axis_name) -> _LaneDesign:
     ``standardize``, ``Z`` is ``X`` and the statistics 0 and 1."""
     n, d = X.shape
     form = _lane_design_form()
-    _LANE_DESIGNS[form] += 1
     with jax.named_scope("lin.standardize"):
         wsum = jnp.maximum(_psum(jnp.sum(w), axis_name), 1e-12)
         zeros, ones = jnp.zeros(d, X.dtype), jnp.ones(d, X.dtype)

@@ -258,9 +258,8 @@ class MultilayerPerceptronClassifier(Predictor):
         groups = _group_mlp_grid(grid, self.with_params)
         X_j = jnp.asarray(X)
         y_j = jnp.asarray(y)
-        from ..parallel.mesh import to_host
-        from .trees import _pad_candidates
-        (masks_p,), _ = _pad_candidates(mesh, [masks], masks.shape[1])
+        from ..parallel.mesh import pad_candidates, to_host
+        (masks_p,), _ = pad_candidates(mesh, [masks], masks.shape[1])
         m_j = jnp.asarray(masks_p).astype(X_j.dtype)
         for (hidden, mi, seed), gis in groups.items():
             sizes = (X.shape[1],) + tuple(hidden) + (k,)
@@ -307,10 +306,9 @@ class MultilayerPerceptronClassifier(Predictor):
         X_j, y_j = jnp.asarray(X), jnp.asarray(y)
         Xv_j = jnp.asarray(np.asarray(X_val, dtype=np.float64))
         yv_j = jnp.asarray(np.asarray(y_val, dtype=np.float64))
-        from ..parallel.mesh import to_host
-        from .trees import _pad_candidates
+        from ..parallel.mesh import pad_candidates, to_host
         fidx0 = np.arange(F, dtype=np.int32)
-        (masks_p,), count = _pad_candidates(mesh, [masks], masks.shape[1])
+        (masks_p,), count = pad_candidates(mesh, [masks], masks.shape[1])
         fidx = np.concatenate(
             [fidx0, np.zeros(len(masks_p) - count, dtype=np.int32)])
         m_j = jnp.asarray(masks_p).astype(X_j.dtype)

@@ -11,8 +11,9 @@ DefaultSelectorParams.scala:36-59).
 
 minInfoGain is swept over (0.001, 0.01, 0.1) exactly as
 DefaultSelectorParams.MinInfoGain prescribes: it is a *traced* scalar
-in the batched fold x grid kernels (`trees._FOREST_TRACED`), so the
-sweep adds vmapped candidate lanes, not compiles. For the XGB-style
+in the batched fold x grid kernels (`_FOREST_TRACED` of
+`trees/fold_grid.py`), so the sweep adds vmapped candidate lanes, not
+compiles. For the XGB-style
 GBT booster the analog is ``gamma`` (min split-loss reduction), swept
 over the same values; ``min_child_weight`` (1, 10) plays the
 minInstancesPerNode (10, 100) role on the hessian scale.

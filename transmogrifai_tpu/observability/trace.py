@@ -44,7 +44,6 @@ trace`` summarizes and converts a trace file (cli/trace.py).
 """
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import itertools
 import json
@@ -55,8 +54,7 @@ from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["SCHEMA_VERSION", "configure", "configure_from_env",
-           "enabled", "trace_path", "span", "counted_span", "add_span",
-           "add_event",
+           "enabled", "trace_path", "span", "add_span", "add_event",
            "current_ref", "new_request_id", "spans", "reset", "flush",
            "read_trace", "to_perfetto", "span_tree", "coverage"]
 
@@ -230,23 +228,6 @@ def span(name: str, parent: Optional[Tuple[str, int]] = None,
         trace_id = trace_id or parent[0]
         pid = parent[1]
     return _Span(_new_rec(name, pid, trace_id, attrs))
-
-
-@contextlib.contextmanager
-def counted_span(name: str, counters, **group):
-    """The span ``name`` carrying the process's counts so far of each
-    ``(prefix, counter)`` (``counter()`` a dict of counts) as the scalar
-    attributes ``prefix + key``: read when the span opens, which its
-    profiler annotation keeps, and again when it closes, because a
-    program's first call traces inside the span; and ``group``, attributes
-    of the call's own."""
-    def attrs():
-        return {prefix + k: v for prefix, counter in counters
-                for k, v in counter().items()}
-    with span(name, **attrs(), **group) as rec:
-        yield
-        if rec is not None:
-            rec["attrs"].update(attrs())
 
 
 def add_span(name: str, start: float, end: float,

@@ -9,8 +9,8 @@ PercentileCalibrator.scala, ScalerTransformer.scala}):
   membership (+ optional null/invalid tracking).
 - :class:`DecisionTreeNumericBucketizer` — label-aware buckets from the
   split thresholds of a single-feature decision tree (the reference
-  fits a Spark DecisionTree; here it's the histogram tree builder from
-  models/trees.py, so the whole fit is one XLA program).
+  fits a Spark DecisionTree; here it's the histogram tree builder of
+  models/trees/grower.py, so the whole fit is one XLA program).
 - :class:`PercentileCalibrator` — maps values onto [0, buckets-1] by
   training-set quantiles (reference PercentileCalibrator with
   ``expectedDistribution`` uniform).

@@ -38,9 +38,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..observability import trace as _trace
 
+from ..models import linear as _linear
 from ..models.linear import (_LANE_PRECISION, binary_logistic_core,
-                             lane_designs, linear_regression_core,
-                             linear_svc_core, multinomial_logistic_core)
+                             linear_regression_core, linear_svc_core,
+                             multinomial_logistic_core)
 
 __all__ = ["fold_masks", "fit_linear_fold_grid", "eval_linear_fold_grid",
            "models_mesh", "resolve_search_mesh", "mesh_model_shards",
@@ -175,12 +176,13 @@ def _device_float(a):
 
 
 def _fetch_span(lanes: int):
-    """The ``search.fetch`` span of the linear fold-grid functions,
-    carrying :func:`linear.lane_designs` as ``design_shared`` /
-    ``design_per_lane`` (see trace.counted_span) and ``lanes``, the call's
-    (fold, grid point) lanes."""
-    return _trace.counted_span("search.fetch", (("design_", lane_designs),),
-                               lanes=lanes)
+    """The ``search.fetch`` span of the linear fold-grid functions, with
+    the call's own ``lanes``, its (fold, grid point) lanes, and ``design``,
+    the form of the lanes' design its program holds (see
+    linear._lane_design_form: "shared" on an accelerator, "per_lane" on a
+    CPU)."""
+    return _trace.span("search.fetch", lanes=lanes,
+                       design=_linear._lane_design_form())
 
 
 def fit_linear_fold_grid(kind: str, X: np.ndarray, y: np.ndarray,

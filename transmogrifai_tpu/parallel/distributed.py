@@ -22,7 +22,7 @@ Linear-model matvecs against a feature-sharded matrix contract the
 sharded axis — XLA inserts the psum automatically under jit. Histogram
 trees shard cleanly too: each chip histograms its own feature block and
 split-gain argmaxes reduce with one small psum (the packed-bin layout
-in models/trees.py keeps blocks contiguous).
+in models/trees/design.py keeps blocks contiguous).
 """
 from __future__ import annotations
 

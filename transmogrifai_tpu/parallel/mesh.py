@@ -26,7 +26,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["make_mesh", "cv_mesh", "n_devices", "replicate", "shard_rows",
-           "PartitionSpec", "Mesh", "NamedSharding"]
+           "to_host", "pad_candidates", "PartitionSpec", "Mesh",
+           "NamedSharding"]
 
 
 def n_devices() -> int:
@@ -92,3 +93,23 @@ def to_host(array) -> "np.ndarray":
     from jax.experimental import multihost_utils
     return np.asarray(multihost_utils.process_allgather(
         array, tiled=True))
+
+
+def pad_candidates(mesh, arrays, n_rows):
+    """Pad the flattened candidate axis of a fold-grid program's per-lane
+    ``arrays`` to a multiple of the mesh's ``models`` shard count (padded
+    lanes fit on all-ones masks and are discarded; a 2-D array is a lane's
+    (n_rows,) mask, a 1-D one a lane's scalar). Returns (padded arrays,
+    original count)."""
+    count = arrays[0].shape[0]
+    if mesh is None:
+        return arrays, count
+    shards = mesh.shape["models"]
+    pad = (-count) % shards
+    if not pad:
+        return arrays, count
+    out = []
+    for a in arrays:
+        fill = np.ones((pad, n_rows)) if a.ndim == 2 else np.ones(pad)
+        out.append(np.concatenate([a, fill.astype(a.dtype)], axis=0))
+    return out, count

@@ -56,7 +56,7 @@ _log = logging.getLogger(__name__)
 #: (family, folds, rows, candidates, spec) signatures dispatched — each
 #: is at most a few XLA programs; repeated same-shape searches add no
 #: new keys (the compile-count diagnostic, mirroring
-#: models/trees.tree_kernel_compiles and serving.plan_compiles)
+#: models/trees/fold_grid.tree_kernel_compiles and serving.plan_compiles)
 _RUNG_KEYS: set = set()
 
 

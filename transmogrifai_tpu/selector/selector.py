@@ -18,7 +18,6 @@ import numpy as np
 from ..evaluators.base import EvaluationMetrics, Evaluator
 from ..features.columns import PredictionColumn
 from ..models.base import PredictionModel, Predictor
-from ..models.trees import train_eval_span
 from ..observability import trace as _trace
 from .splitters import Splitter, SplitterSummary
 from .validator import BestEstimator, CrossValidation, ValidationResult, \
@@ -364,7 +363,7 @@ class ModelSelector(Predictor):
 
         # 4. training-set evaluation (reference :172)
         evaluator = self.validator.evaluator
-        with train_eval_span():
+        with _trace.span("search.train_eval"):
             train_eval = evaluator.evaluate_arrays(
                 yp, inner.predict_arrays(Xp))
             holdout_eval = None

@@ -148,7 +148,7 @@ class BestEstimator:
 
 def _batched_fold_raw(fitted_fold_models, X_val):
     """Raw predictions for every tree-family candidate of one fold in
-    one device program (models/trees.batch_predict_raw); {} on a
+    one device program (models/trees/fitted.batch_predict_raw); {} on a
     backend-shaped failure so the per-candidate path takes over. A
     genuine kernel bug PROPAGATES (r4 narrowed the former blanket
     ``except Exception`` to the runtime's transient/family classifier
@@ -333,8 +333,8 @@ class _ValidatorBase:
         X[val_rows[f]]``, from _build_fold_arrays; None where the
         validation rows are not rows of ``X``) goes to the families
         whose kernel takes it (the tree families score the held-out
-        rows where their fit already put them, models/trees._eval_form);
-        the others are called as ever."""
+        rows where their fit already put them, models/trees/fold_grid.py
+        ``_candidate_scores``); the others are called as ever."""
         if (X_val_st is None or spec is None
                 or not hasattr(estimator, "eval_fold_grid_arrays")
                 or not self._use_batched_kernel(estimator)):
@@ -424,7 +424,7 @@ class _ValidatorBase:
         transfer. Threads overlap host orchestration + transfers with
         on-chip compute (the chip still serializes the programs); JAX
         tracing/dispatch is thread-safe and the shared binning memo in
-        models/trees serializes under its own lock.
+        models/trees/design.py serializes under its own lock.
         size guard: concurrent dispatch keeps EVERY family's input
         buffers + intermediates resident at once — at search sizes
         that's noise, but a huge matrix could push peak HBM past the

@@ -30,8 +30,8 @@ loop (local/scoring.py) — a plan:
 
 ``plan_compiles()`` counts distinct (plan, bucket) programs — the
 compile diagnostic (same idiom as
-models/trees.tree_kernel_compiles): a repeated same-bucket batch adds
-zero.
+models/trees/fold_grid.tree_kernel_compiles): a repeated same-bucket batch
+adds zero.
 """
 from __future__ import annotations
 

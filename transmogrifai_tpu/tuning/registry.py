@@ -167,7 +167,7 @@ TUNABLE_PARAM_NAMES = frozenset(
 
 #: param spelling -> the consumer packages where TX-T01 polices it.
 #: Scope discipline: ``eta`` is ALSO a gradient-boosting learning rate
-#: (models/trees.py) — only in the knob's own consumer layer does the
+#: (models/trees/estimators.py) — only in the knob's own consumer layer does the
 #: spelling mean the registered knob.
 TUNABLE_PARAM_SCOPES: Dict[str, frozenset] = {}
 for _k in KNOBS:
